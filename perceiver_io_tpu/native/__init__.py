@@ -6,8 +6,11 @@ here. Build once with::
 
     python -m perceiver_io_tpu.native.build
 
-If the shared library is absent, callers transparently fall back to the Python
-implementations — no build step is required to use the framework.
+If the shared library is absent, callers fall back to the Python
+implementations and a warning says so — no build step is required to use the
+framework. The library is a build product (``*.so`` is git-ignored and kept
+out of the chip tool's copy by ``.chiprunignore``): a machine that wants the
+C path builds it there from ``wordmask.c``.
 
 Reproducibility note: the C path uses its own (deterministic, seed-driven)
 xorshift RNG stream, so seeded runs produce the same masking DISTRIBUTION but
@@ -44,7 +47,10 @@ def load_library() -> Optional[ctypes.CDLL]:
     _load_attempted = True
     path = _lib_path()
     if not os.path.exists(path):
-        logger.info("perceiver_io_tpu native library not built; using Python fallbacks")
+        logger.warning(
+            "perceiver_io_tpu native library not built (python -m perceiver_io_tpu.native.build); "
+            "using the Python fallbacks, whose seeded masking draws differ from the C path's"
+        )
         return None
     logger.info("perceiver_io_tpu native library loaded from %s", path)
     lib = ctypes.CDLL(path)

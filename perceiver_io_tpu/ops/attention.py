@@ -398,7 +398,8 @@ class MultiHeadAttention(nn.Module):
             from perceiver_io_tpu.ops.decode_kernel import decode_kernel_supported, fused_decode_attention_auto
 
             if kv_cache.k.shape[0] == b and decode_kernel_supported(
-                n_q, n_k, num_qk, num_v, self.num_heads, batch_size=b
+                n_q, n_k, num_qk, num_v, self.num_heads, batch_size=b,
+                itemsize=kv_cache.k.dtype.itemsize,
             ):
                 ang = rope_k if rope_k is not None else jnp.zeros((b, n_k, 2), jnp.float32)
                 if ang.shape[0] != b:

@@ -26,7 +26,31 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-_BLOCK = 512
+# KV block candidates, largest first, and the VMEM the kernel may plan for.
+# The kernel's scoped VMEM at block ``blk`` and packed width ``hd = h*d`` was
+# measured by compiling for a v5e without one (binary search on
+# ``vmem_limit_bytes``; PERF.md): the (hd, hd) f32 rotate-half constant, held
+# once, plus per block element the double-buffered K and V blocks and two f32
+# temporaries — 17.0 MiB at (512, 1280) with a bf16 cache, over the 16 MiB
+# scoped default; 11.8 MiB at (256, 1280). The budget leaves the rest of the
+# 16 MiB to what XLA itself parks in VMEM around the call (it moved the
+# measured need by up to 3 MiB between batch and capacity variants).
+_BLOCKS = (512, 256, 128)
+_VMEM_BUDGET = 12 * 2**20
+
+
+def _vmem_estimate(blk: int, hd: int, itemsize: int) -> int:
+    return 4 * hd * hd + blk * hd * (4 * itemsize + 8) + 2**19
+
+
+def _kv_block(capacity: int, hd: int, itemsize: int) -> Optional[int]:
+    """Largest KV block that tiles ``capacity`` and fits the VMEM budget;
+    None when even the smallest does not (the XLA formulation serves)."""
+    for blk in _BLOCKS:
+        blk = min(blk, capacity)
+        if capacity % blk == 0 and _vmem_estimate(blk, hd, itemsize) <= _VMEM_BUDGET:
+            return blk
+    return None
 
 
 def ragged_decode_enabled() -> bool:
@@ -39,39 +63,39 @@ def ragged_decode_enabled() -> bool:
 
 def decode_kernel_supported(
     n_q: int, capacity: int, num_qk: int, num_v: int, num_heads: int = 1,
-    batch_size: Optional[int] = None,
+    batch_size: Optional[int] = None, itemsize: int = 2,
 ) -> bool:
     """Short-query cached decode on TPU with symmetric qk/v widths and a
-    block-tileable cache. ``n_q > 1`` covers multi-query decode (speculative /
-    chunked verification); each query keeps its flash stats in its own scratch
-    row, so n_q is bounded by the 8-sublane scratch tile. Multi-chip: supported
-    when the ambient mesh shards only batch axes and the batch divides evenly
-    (the kernel then runs per-device inside shard_map — no collectives).
-    Kill-switch: PERCEIVER_IO_TPU_DISABLE_DECODE_KERNEL."""
+    block-tileable cache whose KV block fits the VMEM budget (``_kv_block``;
+    ``itemsize`` is the cache dtype's). ``n_q > 1`` covers multi-query decode
+    (speculative / chunked verification); each query keeps its flash stats in
+    its own scratch row, so n_q is bounded by the 8-sublane scratch tile.
+    Sharded traces: supported when the ambient mesh shards only batch axes and
+    the batch divides evenly (the kernel then runs per-device inside shard_map
+    — no collectives). Kill-switch: PERCEIVER_IO_TPU_DISABLE_DECODE_KERNEL."""
     if os.environ.get("PERCEIVER_IO_TPU_DISABLE_DECODE_KERNEL", "0").lower() not in ("0", "false", ""):
         return False
     if jax.default_backend() != "tpu":
         return False
-    if jax.device_count() > 1:
-        from perceiver_io_tpu.ops.flash import _mesh_plan
+    from perceiver_io_tpu.ops.flash import _mesh_plan
 
-        plan = _mesh_plan()
-        if plan is None:
-            return False
-        _, head_axis, b_shards, _ = plan
-        if head_axis is not None:
-            # heads live packed inside the (cap, h*d) cache layout; a sharded
-            # head axis cannot be mapped onto this kernel
-            return False
-        if batch_size is None or (b_shards > 1 and batch_size % b_shards != 0):
-            return False
+    plan = _mesh_plan()
+    if plan is None:
+        return False
+    _, head_axis, b_shards, _ = plan
+    if head_axis is not None:
+        # heads live packed inside the (cap, h*d) cache layout; a sharded
+        # head axis cannot be mapped onto this kernel
+        return False
+    if b_shards > 1 and (batch_size is None or batch_size % b_shards != 0):
+        return False
     return (
         1 <= n_q <= 8  # one (8, 128) scratch sublane of running stats per query
         and num_qk == num_v
         and num_heads <= 128  # per-head stats live in one (8, 128) scratch row
-        and capacity % min(_BLOCK, capacity) == 0
         and capacity >= 128
         and capacity % 8 == 0  # sublane-aligned KV blocks
+        and _kv_block(capacity, num_qk, itemsize) is not None
     )
 
 
@@ -213,7 +237,7 @@ def fused_decode_attention_auto(
     batch divisibility, no sharded head axis — is decode_kernel_supported's job."""
     from perceiver_io_tpu.ops.flash import _mesh_plan
 
-    plan = _mesh_plan() if jax.device_count() > 1 else None
+    plan = _mesh_plan()
     if plan is None or not plan[0]:
         return fused_decode_attention(q, k_cache, v_cache, rope_k, q_pos, pad_slots, live=live, interpret=interpret)
 
@@ -271,7 +295,13 @@ def fused_decode_attention(
 
     b, h, n_q, d = q.shape
     cap = k_cache.shape[1]
-    blk = min(_BLOCK, cap)
+    blk = _kv_block(cap, h * d, k_cache.dtype.itemsize)
+    if blk is None:
+        raise ValueError(
+            f"no KV block of {_BLOCKS} tiles capacity {cap} within the "
+            f"{_VMEM_BUDGET >> 20} MiB VMEM budget at packed width {h * d}; "
+            "gate calls with decode_kernel_supported()"
+        )
     nblocks = cap // blk
     r = rope_k.shape[-1]
 

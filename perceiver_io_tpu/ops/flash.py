@@ -44,20 +44,25 @@ _BATCH_AXES = ("data", "fsdp")
 _HEAD_AXIS = "tensor"
 
 
+def single_device_trace() -> bool:
+    """Is the program being traced a one-device program? With no ambient mesh
+    (or one whose axes all have size 1) a jitted function runs on the single
+    device its arrays live on, however many devices the process can see — a
+    one-chip engine or train step on a four-chip host included. The repo's
+    sharded paths all trace under ``jax.sharding.set_mesh`` (parallel/api.py)."""
+    return all(size == 1 for size in jax.sharding.get_abstract_mesh().shape.values())
+
+
 def _mesh_plan():
-    """(batch_axes, head_axis_or_None, b_shards, h_shards) when the ambient
-    mesh's sharded axes are all batch/head-mappable; None otherwise (no mesh,
-    or axes like 'seq' that this wrapper cannot map)."""
+    """(batch_axes, head_axis_or_None, b_shards, h_shards): the one-device
+    plan ``((), None, 1, 1)`` for a one-device trace, the mapping when the
+    ambient mesh's sharded axes are all batch/head-mappable, None otherwise
+    (axes like 'seq' that this wrapper cannot map)."""
     import numpy as np
 
-    if jax.device_count() == 1:
+    if single_device_trace():
         return ((), None, 1, 1)
-    import perceiver_io_tpu.parallel.mesh  # noqa: F401  (installs jax<0.5 get_abstract_mesh alias)
-
-    mesh = jax.sharding.get_abstract_mesh()
-    if mesh is None or not mesh.axis_names:
-        return None
-    sizes = dict(mesh.shape)
+    sizes = dict(jax.sharding.get_abstract_mesh().shape)
     for name, size in sizes.items():
         if size > 1 and name not in (*_BATCH_AXES, _HEAD_AXIS):
             return None
@@ -85,16 +90,15 @@ def flash_supported(
         return False
     if jax.default_backend() != "tpu":
         return False
-    if jax.device_count() > 1:
-        plan = _mesh_plan()
-        if plan is None:
-            # multi-chip needs the shard_map wrapper, which needs an ambient
-            # mesh whose axes we know how to map (batch/head); else fall back
-            return False
-        _, _, b_shards, h_shards = plan
+    plan = _mesh_plan()
+    if plan is None:
+        # a sharded trace needs the shard_map wrapper, which needs an ambient
+        # mesh whose axes we know how to map (batch/head); else fall back
+        return False
+    _, _, b_shards, h_shards = plan
+    if b_shards > 1 or h_shards > 1:
         if batch_size is None or num_heads is None:
-            # without shapes we cannot certify divisibility on a mesh
-            return b_shards == 1 and h_shards == 1
+            return False  # without shapes we cannot certify divisibility on a mesh
         if batch_size % b_shards != 0 or num_heads % h_shards != 0:
             return False
     if num_qk_channels_per_head != num_v_channels_per_head:
@@ -195,11 +199,8 @@ def _splash_mha_sharded(q, k, v, pad_mask, causal, interpret, plan):
     """Run splash per-device inside shard_map: batch sharded over data/fsdp,
     heads over tensor — embarrassingly parallel, no collectives."""
     import jax.experimental.pallas.ops.tpu.splash_attention as sa
-    from jax.sharding import PartitionSpec as P
-
-    # the new-style jax.shard_map is required here (check_vma semantics); the
-    # legacy experimental API is not signature-compatible with these calls
     from jax import shard_map
+    from jax.sharding import PartitionSpec as P
 
     baxes, head_axis, b_shards, h_shards = plan
     b, h, n_q, _ = q.shape

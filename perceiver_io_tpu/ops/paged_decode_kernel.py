@@ -50,10 +50,11 @@ gather the decode attention itself performs. A freshly allocated page's
 scale is reset to 0 (``reset_page_scales`` / the install's full-row scale
 stamp), which makes the first ratcheted write ZERO any stale bytes a
 previous tenant left — pool history can never leak into a new session's
-bytes. The fused kernel gains a dequant-fused variant (scales ride the
-scalar-prefetch path next to the page table; dead-page skip and ring-offset
-semantics unchanged), pinned BITWISE in interpret mode against feeding the
-XLA-dequantized f32 pool through the same kernel; ``gather_dense``/
+bytes. The fused kernel gains a dequant-fused variant (each row's scales,
+gathered through its page table, arrive as one small VMEM block; dead-page
+skip and ring-offset semantics unchanged), pinned BITWISE in interpret mode
+against feeding the XLA-dequantized f32 pool through the same kernel;
+``gather_dense``/
 ``gather_slot`` dequantize for the XLA fallback and the prefill-finish so
 CPU and sharded pools serve the same layout.
 
@@ -81,6 +82,7 @@ import jax
 import jax.numpy as jnp
 
 from perceiver_io_tpu.ops.decode_kernel import _head_expander, _rotate_half_blockdiag
+from perceiver_io_tpu.ops.flash import single_device_trace
 
 # supported quantized-page modes (serving/engine.py `kv_quant` knob)
 KV_QUANT_MODES = ("int8", "int4")
@@ -442,10 +444,11 @@ def paged_decode_supported(
     quantized: bool = False, qbits: int = 8,
 ) -> bool:
     """Single-query paged decode on TPU: symmetric qk/v widths, sublane-aligned
-    pages. Multi-chip pools are not yet mapped onto this kernel (the paged
-    pool is a single shared buffer; shard_map dispatch is future work) — the
-    XLA fallback serves those. Quantized (int8) pools additionally need
-    32-row pages (the int8 VMEM tile is (32, 128)); the XLA fallback serves
+    pages. Pools sharded over a mesh are not yet mapped onto this kernel (the
+    paged pool is a single shared buffer; shard_map dispatch is future work)
+    — the XLA fallback serves those; a one-chip pool on a many-chip host is a
+    one-device trace and takes the kernel. Quantized (int8) pools additionally
+    need 32-row pages (the int8 VMEM tile is (32, 128)); the XLA fallback serves
     smaller quantized pages with the identical dequant + masking contract.
     Kill-switch: PERCEIVER_IO_TPU_DISABLE_DECODE_KERNEL (shared with the
     dense kernel)."""
@@ -453,7 +456,7 @@ def paged_decode_supported(
 
     if os.environ.get("PERCEIVER_IO_TPU_DISABLE_DECODE_KERNEL", "0").lower() not in ("0", "false", ""):
         return False
-    if jax.default_backend() != "tpu" or jax.device_count() > 1:
+    if jax.default_backend() != "tpu" or not single_device_trace():
         return False
     return (
         n_q == 1  # the engine's decode mode; chunked verification stays dense
@@ -505,20 +508,23 @@ def _paged_kernel(*refs, window, skip_dead_pages, quantized):
     skip-off bitwise). The per-position visibility mask applies the SAME
     bound, so mid-page live boundaries are exact too.
 
-    QUANTIZED pools add two scalar-prefetch sidecars right after the page
-    table — kscale_ref / vscale_ref (N, h) f32, per-page-per-head scales —
-    and k_ref/v_ref blocks arrive int8. The dequant is FUSED: the fetched
-    block's scale row is read from SMEM (h static scalar loads at the page
-    id the index map fetched — un-aliased whenever compute runs), expanded
-    to channels through the same head expander the stats use, and multiplied
-    into the f32 upcast before rotation — bit-identical to feeding the
-    XLA-dequantized f32 pool through this same kernel (tests pin it).
+    QUANTIZED pools add two VMEM operands after the expander — kscale_ref /
+    vscale_ref (1, P, h) f32, row bi's per-page-per-head scales gathered
+    through its page-table row OUTSIDE the kernel (``k_scale[page_table]``)
+    — and k_ref/v_ref blocks arrive int8. The whole (N, h) sidecars cannot
+    ride the scalar-prefetch path: SMEM is 1 MB on a v5e and a real pool's
+    sidecars exceed it from about 1k pages. The dequant is FUSED: step i
+    reads scale row i (the page the index map fetched — un-aliased whenever
+    compute runs), expands it to channels through the same head expander
+    the stats use, and multiplies it into the f32 upcast before rotation —
+    bit-identical to feeding the XLA-dequantized f32 pool through this same
+    kernel (tests pin it).
     """
     import jax.experimental.pallas as pl
 
     if quantized:
-        (start_ref, live_ref, table_ref, kscale_ref, vscale_ref, qbd_ref,
-         k_ref, v_ref, ang_ref, rot_ref, exp_ref,
+        (start_ref, live_ref, table_ref, qbd_ref, k_ref, v_ref, ang_ref,
+         rot_ref, exp_ref, kscale_ref, vscale_ref,
          o_ref, m_ref, l_ref, acc_ref) = refs
     else:
         (start_ref, live_ref, table_ref, qbd_ref, k_ref, v_ref, ang_ref,
@@ -556,16 +562,11 @@ def _paged_kernel(*refs, window, skip_dead_pages, quantized):
         if quantized:
             # whenever compute runs, the page is live and the index map did
             # not alias, so the fetched block IS page table_ref[bi, i] —
-            # read its per-head scale row from SMEM (h static scalar loads)
-            # and expand head -> channels through the same 0/1 expander
-            # (exact selection: one nonzero term per channel)
-            page_id = table_ref[bi, i]
-            kscale = jnp.stack(
-                [kscale_ref[page_id, hh] for hh in range(h)]
-            ).reshape(1, h)
-            vscale = jnp.stack(
-                [vscale_ref[page_id, hh] for hh in range(h)]
-            ).reshape(1, h)
+            # whose scales are row i of this batch row's gathered sidecar.
+            # Expand head -> channels through the same 0/1 expander (exact
+            # selection: one nonzero term per channel)
+            kscale = kscale_ref[0, pl.ds(i, 1), :]  # (1, h)
+            vscale = vscale_ref[0, pl.ds(i, 1), :]
             kexp = jax.lax.dot_general(kscale, exp_ref[:], contract,
                                        preferred_element_type=jnp.float32)
             vexp = jax.lax.dot_general(vscale, exp_ref[:], contract,
@@ -629,8 +630,9 @@ def fused_paged_decode_attention(
     kill-switch behavior (ragged_decode_enabled, ops/decode_kernel.py).
 
     ``k_scale``/``v_scale`` (N, H) switch on the FUSED-DEQUANT variant for
-    int8 pools (module docstring): the scales ride the scalar-prefetch path
-    next to the page table, dead-page skip and ring semantics unchanged."""
+    int8 pools (module docstring): each row's scales are gathered through its
+    page-table row here and reach the kernel as a (1, P, H) VMEM block,
+    dead-page skip and ring semantics unchanged."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -667,24 +669,27 @@ def fused_paged_decode_attention(
     def _ang_map(bi, i, start_ref, live_ref, table_ref, *_):
         return (bi, _alias(i, start_ref, live_ref, bi), 0)
 
-    # quantized pools prefetch the scale sidecars right after the page table
-    # (SMEM, like start/live/table — the kernel reads the fetched page's
-    # scale row with static per-head scalar loads)
-    prefetch = [start, live, jnp.asarray(page_table, jnp.int32)]
+    page_table = jnp.asarray(page_table, jnp.int32)
+    prefetch = [start, live, page_table]
+    in_specs = [
+        pl.BlockSpec((None, h * d, h), lambda bi, i, *_: (bi, 0, 0)),
+        pl.BlockSpec((1, ps, hd), _kv_map),
+        pl.BlockSpec((1, ps, hd), _kv_map),
+        pl.BlockSpec((1, ps, r), _ang_map),
+        pl.BlockSpec((h * d, h * d), lambda bi, i, *_: (0, 0)),
+        pl.BlockSpec((h, h * d), lambda bi, i, *_: (0, 0)),
+    ]
+    row_scales = []
     if quantized:
-        prefetch += [jnp.asarray(k_scale, jnp.float32),
-                     jnp.asarray(v_scale, jnp.float32)]
+        # per-row (P, H) scale tables, one VMEM block per batch row (the
+        # block index is constant over i, so it is fetched once per row)
+        row_scales = [jnp.asarray(k_scale, jnp.float32)[page_table],
+                      jnp.asarray(v_scale, jnp.float32)[page_table]]
+        in_specs += [pl.BlockSpec((1, p, h), lambda bi, i, *_: (bi, 0, 0))] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(b, p),
-        in_specs=[
-            pl.BlockSpec((None, h * d, h), lambda bi, i, *_: (bi, 0, 0)),
-            pl.BlockSpec((1, ps, hd), _kv_map),
-            pl.BlockSpec((1, ps, hd), _kv_map),
-            pl.BlockSpec((1, ps, r), _ang_map),
-            pl.BlockSpec((h * d, h * d), lambda bi, i, *_: (0, 0)),
-            pl.BlockSpec((h, h * d), lambda bi, i, *_: (0, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, hd), lambda bi, i, *_: (bi, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((8, 128), jnp.float32),
@@ -706,5 +711,6 @@ def fused_paged_decode_attention(
         rope_k,
         jnp.asarray(_rotate_half_blockdiag(h, d, r)),
         jnp.asarray(_head_expander(h, d)),
+        *row_scales,
     )
     return out.reshape(b, 1, h, d).transpose(0, 2, 1, 3)

@@ -37,12 +37,17 @@ legacy program (tests/test_ragged_kernel.py pins it in interpret mode), and
 dead-page skip / DMA aliasing reuse ``_page_has_live`` on the transformed
 offsets unchanged.
 
-Quantized pages ride the same scalar-prefetch path as the legacy kernel
-(per-page-per-head f32 scale sidecars, fused dequant before rotation). int4
-pools (ops/paged_decode_kernel.py module docstring) arrive nibble-packed —
-blocks are (ps, C // 2) uint8 — and the kernel unpacks in-stream: low nibble
-minus 8 is the even logical channel's code, high nibble the odd, interleaved
-back to (ps, C) before the scale multiply. A zero byte unpacks to code -8,
+Quantized pages reach the kernel as in the legacy one (per-page-per-head
+f32 scales gathered per item into a small VMEM block, fused dequant before
+rotation). int4 pools (ops/paged_decode_kernel.py module docstring) arrive
+nibble-packed — blocks are (ps, C // 2) uint8 — and the kernel unpacks
+in-stream: low nibble minus 8 is the even logical channel's code, high
+nibble the odd. Mosaic
+lowers neither shifts on i8 vectors nor a lane interleave, so the bytes are
+widened to int32 first and the two nibble planes, concatenated along lanes,
+are put back in channel order by ONE matmul with a constant 0/1 permutation
+matrix (exact: codes and 0/1 are bf16-representable, the MXU accumulates in
+f32) before the scale multiply. A zero byte unpacks to code -8,
 which a fresh page's zero scale dequantizes to 0 — the fresh-page-zeroing
 and quarantine contracts carry through the kernel untouched.
 
@@ -66,11 +71,8 @@ import jax
 import jax.numpy as jnp
 
 from perceiver_io_tpu.ops.decode_kernel import _head_expander, _rotate_half_blockdiag
-from perceiver_io_tpu.ops.paged_decode_kernel import (
-    _expand_scale,
-    _page_has_live,
-    _unpack_codes,
-)
+from perceiver_io_tpu.ops.flash import single_device_trace
+from perceiver_io_tpu.ops.paged_decode_kernel import _page_has_live
 
 
 def ragged_paged_supported(
@@ -79,12 +81,13 @@ def ragged_paged_supported(
 ) -> bool:
     """Ragged paged attention on TPU: the legacy kernel's constraints, plus
     int4 pools (which the legacy single-query kernel gates out — the nibble
-    unpack only exists here). Multi-chip pools still take the XLA fallback."""
+    unpack only exists here). Pools sharded over a mesh still take the XLA
+    fallback."""
     import os
 
     if os.environ.get("PERCEIVER_IO_TPU_DISABLE_DECODE_KERNEL", "0").lower() not in ("0", "false", ""):
         return False
-    if jax.default_backend() != "tpu" or jax.device_count() > 1:
+    if jax.default_backend() != "tpu" or not single_device_trace():
         return False
     return (
         num_qk == num_v
@@ -92,7 +95,33 @@ def ragged_paged_supported(
         and page_size % 8 == 0  # sublane-aligned page blocks
         and page_size >= 8
         and (not quantized or page_size % 32 == 0)  # int8/uint8 tile alignment
-        and (qbits == 8 or num_qk % 2 == 0)  # int4 packs channel pairs
+        # int4: the unpack concatenates the two nibble planes along lanes,
+        # so each (C // 2 wide) plane must be whole 128-lane tiles
+        and (qbits == 8 or num_qk % 256 == 0)
+    )
+
+
+def _nibble_interleave(hd: int):
+    """Constant (hd, hd) 0/1 matrix P with ([lo | hi] @ P)[:, 2j] = lo[:, j]
+    and [:, 2j+1] = hi[:, j]: restores logical channel order from the two
+    lane-concatenated nibble planes of an int4 block."""
+    import numpy as np
+
+    perm = np.zeros((hd, hd), np.float32)
+    j = np.arange(hd // 2)
+    perm[j, 2 * j] = 1.0
+    perm[hd // 2 + j, 2 * j + 1] = 1.0
+    return perm
+
+
+def _unpack_nibbles(block: jax.Array, perm: jax.Array) -> jax.Array:
+    """(ps, hd // 2) uint8 -> (ps, hd) f32 integer codes in logical channel
+    order; value-identical to ``_unpack_codes(block, 4)`` (module docstring)."""
+    b = block.astype(jnp.int32)
+    planes = jnp.concatenate([(b & 0xF) - 8, (b >> 4) - 8], axis=-1)
+    return jax.lax.dot_general(
+        planes.astype(jnp.float32).astype(jnp.bfloat16), perm,
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
     )
 
 
@@ -109,6 +138,8 @@ def _ragged_kernel(*refs, window, skip_dead_pages, quantized, qbits):
     ang_ref   (1, ps, r)  rotary angles per PHYSICAL position of item wi
     rot_ref   (h*d, h*d)  block-diag rotate-half matrix
     exp_ref   (h, h*d)    head->channel expander
+    kscale_ref, vscale_ref (1, P, h)  quantized pools: the item's page scales
+    perm_ref  (h*d, h*d)  int4 pools: nibble-plane interleave (bf16 0/1)
     o_ref     (1, 1, h*d) output row
     scratch: m, l (8, 128) VMEM (per-head stats in row 0), acc (8, h*d)
 
@@ -117,14 +148,10 @@ def _ragged_kernel(*refs, window, skip_dead_pages, quantized, qbits):
     before the fused dequant. Dead pages alias + skip exactly as there."""
     import jax.experimental.pallas as pl
 
-    if quantized:
-        (start_ref, live_ref, table_ref, kscale_ref, vscale_ref, qbd_ref,
-         k_ref, v_ref, ang_ref, rot_ref, exp_ref,
-         o_ref, m_ref, l_ref, acc_ref) = refs
-    else:
-        (start_ref, live_ref, table_ref, qbd_ref, k_ref, v_ref, ang_ref,
-         rot_ref, exp_ref, o_ref, m_ref, l_ref, acc_ref) = refs
-        kscale_ref = vscale_ref = None
+    (start_ref, live_ref, table_ref, qbd_ref, k_ref, v_ref, ang_ref,
+     rot_ref, exp_ref, *quant_refs, o_ref, m_ref, l_ref, acc_ref) = refs
+    kscale_ref, vscale_ref = quant_refs[:2] if quantized else (None, None)
+    perm_ref = quant_refs[2] if quantized and qbits == 4 else None
 
     wi = pl.program_id(0)
     i = pl.program_id(1)
@@ -156,20 +183,16 @@ def _ragged_kernel(*refs, window, skip_dead_pages, quantized, qbits):
         if quantized and qbits == 4:
             # in-stream nibble unpack: (ps, h*d // 2) uint8 -> (ps, h*d) f32
             # integer codes (low nibble = even logical channel, high = odd)
-            k = _unpack_codes(k_ref[0], 4)
+            k = _unpack_nibbles(k_ref[0], perm_ref[:])
         else:
             k = k_ref[0].astype(jnp.float32)  # (ps, h*d)
         if quantized:
             # the fetched block IS page table_ref[wi, i] whenever compute
-            # runs (live page -> no alias): read its per-head scale row from
-            # SMEM and expand head -> channels through the 0/1 expander
-            page_id = table_ref[wi, i]
-            kscale = jnp.stack(
-                [kscale_ref[page_id, hh] for hh in range(h)]
-            ).reshape(1, h)
-            vscale = jnp.stack(
-                [vscale_ref[page_id, hh] for hh in range(h)]
-            ).reshape(1, h)
+            # runs (live page -> no alias), whose scales are row i of this
+            # item's gathered sidecar; expand head -> channels through the
+            # 0/1 expander
+            kscale = kscale_ref[0, pl.ds(i, 1), :]  # (1, h)
+            vscale = vscale_ref[0, pl.ds(i, 1), :]
             kexp = jax.lax.dot_general(kscale, exp_ref[:], contract,
                                        preferred_element_type=jnp.float32)
             vexp = jax.lax.dot_general(vscale, exp_ref[:], contract,
@@ -192,7 +215,7 @@ def _ragged_kernel(*refs, window, skip_dead_pages, quantized, qbits):
 
         prob_x = jax.lax.dot_general(prob, exp_ref[:], contract, preferred_element_type=jnp.float32)
         if quantized and qbits == 4:
-            v = _unpack_codes(v_ref[0], 4)
+            v = _unpack_nibbles(v_ref[0], perm_ref[:])
         else:
             v = v_ref[0].astype(jnp.float32)
         if quantized:
@@ -296,21 +319,30 @@ def fused_ragged_paged_attention(
     def _ang_map(wi, i, start_ref, live_ref, table_ref, *_):
         return (wi, _alias(i, start_ref, live_ref, wi), 0)
 
-    prefetch = [start, live, jnp.asarray(page_table, jnp.int32)]
+    page_table = jnp.asarray(page_table, jnp.int32)
+    prefetch = [start, live, page_table]
+    in_specs = [
+        pl.BlockSpec((None, hd, h), lambda wi, i, *_: (wi, 0, 0)),
+        pl.BlockSpec((1, ps, c_phys), _kv_map),
+        pl.BlockSpec((1, ps, c_phys), _kv_map),
+        pl.BlockSpec((1, ps, r), _ang_map),
+        pl.BlockSpec((hd, hd), lambda wi, i, *_: (0, 0)),
+        pl.BlockSpec((h, hd), lambda wi, i, *_: (0, 0)),
+    ]
+    quant_operands = []
     if quantized:
-        prefetch += [jnp.asarray(k_scale, jnp.float32),
-                     jnp.asarray(v_scale, jnp.float32)]
+        # per-item (P, H) scale tables gathered through the item's table row
+        # (ops/paged_decode_kernel.py: the (N, H) sidecars outgrow SMEM)
+        quant_operands = [jnp.asarray(k_scale, jnp.float32)[page_table],
+                          jnp.asarray(v_scale, jnp.float32)[page_table]]
+        in_specs += [pl.BlockSpec((1, p, h), lambda wi, i, *_: (wi, 0, 0))] * 2
+        if qbits == 4:
+            quant_operands.append(jnp.asarray(_nibble_interleave(hd), jnp.bfloat16))
+            in_specs.append(pl.BlockSpec((hd, hd), lambda wi, i, *_: (0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(w, p),
-        in_specs=[
-            pl.BlockSpec((None, hd, h), lambda wi, i, *_: (wi, 0, 0)),
-            pl.BlockSpec((1, ps, c_phys), _kv_map),
-            pl.BlockSpec((1, ps, c_phys), _kv_map),
-            pl.BlockSpec((1, ps, r), _ang_map),
-            pl.BlockSpec((hd, hd), lambda wi, i, *_: (0, 0)),
-            pl.BlockSpec((h, hd), lambda wi, i, *_: (0, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, hd), lambda wi, i, *_: (wi, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((8, 128), jnp.float32),
@@ -333,6 +365,7 @@ def fused_ragged_paged_attention(
         rope_k,
         jnp.asarray(_rotate_half_blockdiag(h, d, r)),
         jnp.asarray(_head_expander(h, d)),
+        *quant_operands,
     )
     return out.reshape(w, 1, h, d).transpose(0, 2, 1, 3)
 

@@ -93,13 +93,20 @@ def make_batch_put(mesh: Optional[Mesh]) -> Callable:
 
 
 def _with_mesh_context(fn: Callable, mesh: Mesh) -> Callable:
-    """Run (and trace) ``fn`` under the ambient mesh so mesh-aware fast paths
-    (e.g. the shard_map splash-attention wrapper) can see the axes."""
+    """Run (and trace) the jitted ``fn`` under the ambient mesh so mesh-aware
+    fast paths (e.g. the shard_map splash-attention wrapper) can see the axes.
+    ``wrapped.lower`` lowers under the same mesh (``.compile().as_text()``
+    then shows the collectives and kernels of the program that runs)."""
 
     def wrapped(*args, **kwargs):
         with jax.sharding.set_mesh(mesh):
             return fn(*args, **kwargs)
 
+    def lower(*args, **kwargs):
+        with jax.sharding.set_mesh(mesh):
+            return fn.lower(*args, **kwargs)
+
+    wrapped.lower = lower
     return wrapped
 
 

@@ -29,38 +29,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 DATA_AXES = ("data", "fsdp")  # axes the batch dimension is sharded over
 
 
-def _install_mesh_compat():
-    """jax < 0.5 compatibility. The repo's sharded paths, scripts, and tests
-    use ``jax.sharding.set_mesh`` / ``get_abstract_mesh``; on 0.4.x runtimes
-    the same ambient-mesh semantics exist as the ``with mesh:`` resource env
-    (``thread_resources``). Install ADDITIVE aliases so one codebase runs on
-    both — existing attributes are never overridden. Without this, jaxlib
-    0.4.37 raises AttributeError on every mesh-context code path."""
-    if not hasattr(jax.sharding, "get_abstract_mesh"):
-        from jax._src.mesh import thread_resources
-
-        def get_abstract_mesh():
-            mesh = thread_resources.env.physical_mesh
-            return mesh if mesh.axis_names else None
-
-        jax.sharding.get_abstract_mesh = get_abstract_mesh
-    if not hasattr(jax.sharding, "set_mesh"):
-
-        def set_mesh(mesh):
-            """0.4.x alias — `with` form ONLY. Mesh is itself a context
-            manager entering the same resource env the real set_mesh would;
-            a bare imperative ``set_mesh(mesh)`` statement (the modern
-            global-setter usage) cannot be expressed on 0.4.x and would
-            silently install nothing, so every repo call site uses
-            ``with jax.sharding.set_mesh(mesh): ...``."""
-            return mesh
-
-        jax.sharding.set_mesh = set_mesh
-
-
-_install_mesh_compat()
-
-
 def initialize_distributed(coordinator_address: Optional[str] = None, num_processes: Optional[int] = None, process_id: Optional[int] = None):
     """Multi-host bring-up (one JAX process per host). No-op when single-process.
     Replaces torch.distributed/NCCL process-group init, which Lightning performed
@@ -121,10 +89,7 @@ def constrain_batch_sharded(x: jax.Array) -> jax.Array:
     Perceiver AR cross-attention q_norm/concat under data x fsdp meshes. No-op
     without an ambient mesh or without data axes (single device, pure
     tensor/seq meshes), so module code can call it unconditionally."""
-    mesh = jax.sharding.get_abstract_mesh()  # compat-shimmed on jax 0.4.x
-    if mesh is None or not mesh.axis_names:
-        return x
-    sizes = dict(mesh.shape)
+    sizes = dict(jax.sharding.get_abstract_mesh().shape)
     axes = tuple(a for a in DATA_AXES if sizes.get(a, 1) > 1)
     if not axes:
         return x

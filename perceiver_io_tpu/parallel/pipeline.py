@@ -58,7 +58,7 @@ def pipeline_mesh_plan(pipe_axis: str = "pipe"):
     Mirrors ``ring_attention``'s ambient-mesh discovery: modules call this at
     trace time under ``jax.sharding.set_mesh`` / jit-with-mesh context."""
     mesh = jax.sharding.get_abstract_mesh()
-    if mesh is None or not mesh.axis_names or pipe_axis not in mesh.axis_names:
+    if pipe_axis not in mesh.axis_names:
         return None
     size = mesh.shape[pipe_axis]
     if size <= 1:
@@ -109,7 +109,7 @@ def pipeline_layer_stack(
     mesh = jax.sharding.get_abstract_mesh()
     n_data_shards = 1
     for a in batch_axes:
-        if mesh is not None and a in mesh.axis_names:
+        if a in mesh.axis_names:
             n_data_shards *= mesh.shape[a]
     local_batch, rem = divmod(x.shape[0], n_data_shards)
     if rem:

@@ -69,25 +69,7 @@ class _RingCfg(NamedTuple):
 
 def _shard_map(fn, in_specs, out_specs, mesh):
     kwargs = {} if mesh is None else {"mesh": mesh}
-    try:
-        from jax import shard_map  # JAX >= 0.8
-
-        kwargs["check_vma"] = False
-    except ImportError:  # pragma: no cover - older JAX (pre-check_vma kwarg)
-        from jax.experimental.shard_map import shard_map
-
-        kwargs["check_rep"] = False
-        if "mesh" not in kwargs:
-            # the legacy API cannot infer the ambient mesh from context the
-            # way jax.shard_map does; resolve it here (compat-shimmed on
-            # 0.4.x to the `with mesh:` resource env — parallel/mesh.py,
-            # whose import installs the alias)
-            import perceiver_io_tpu.parallel.mesh  # noqa: F401
-
-            ambient = jax.sharding.get_abstract_mesh()
-            if ambient is not None:
-                kwargs["mesh"] = ambient
-    return shard_map(fn, in_specs=in_specs, out_specs=out_specs, **kwargs)
+    return jax.shard_map(fn, in_specs=in_specs, out_specs=out_specs, check_vma=False, **kwargs)
 
 
 def _splash_block_ok(cfg: _RingCfg, nq: int, nkl: int, d: int) -> bool:
@@ -401,11 +383,7 @@ def ring_attention(
         False = einsum blocks, True = force splash (with ``interpret`` for CPU
         testing).
     """
-    if mesh is not None:
-        axis_names = mesh.axis_names
-    else:
-        abstract_mesh = jax.sharding.get_abstract_mesh()
-        axis_names = (abstract_mesh.axis_names or ()) if abstract_mesh is not None else ()
+    axis_names = (mesh if mesh is not None else jax.sharding.get_abstract_mesh()).axis_names
     if seq_axis not in axis_names:
         raise ValueError(
             f"ring attention requires an active mesh with a '{seq_axis}' axis "

@@ -16,6 +16,7 @@ from typing import Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from perceiver_io_tpu.compile_cache import enable_compile_cache
 from perceiver_io_tpu.training.checkpoint import load_pytree
 from perceiver_io_tpu.training.fit import Trainer, TrainerConfig
 from perceiver_io_tpu.training.lrs import constant_with_warmup, cosine_with_warmup
@@ -92,6 +93,7 @@ def run_fit(
     reference's Lightning restart, which replays the epoch)."""
     import json
 
+    enable_compile_cache()
     trainer = Trainer(trainer_cfg)
     train_loader_fn = data_module.train_dataloader
     initial_best = None

@@ -88,7 +88,9 @@ def main(argv=None):
         trainer_cfg,
         tokens_per_batch=flops.tokens_per_step(data.batch_size),
         flops_per_step=flops.train_flops_per_step(data.batch_size),
-        peak_flops=detect_peak_flops(),
+        # MFU is a device metric: reported on an accelerator the peak table
+        # knows (an unknown TPU kind is an error), never on the CPU
+        peak_flops=detect_peak_flops() if jax.default_backend() == "tpu" else None,
     )
 
     def on_eval(state, metrics):

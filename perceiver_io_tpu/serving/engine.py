@@ -1196,6 +1196,17 @@ class ServingEngine:
             jits.append(self._jit_ragged_tick)
         return sum(f._cache_size() for f in jits)
 
+    def lower_tick(self):
+        """The steady-state tick program — the fused ragged tick, or the
+        decode step on composed and dense engines — lowered at this engine's
+        shapes (a ``jax.stages.Lowered``). ``.compile().as_text()`` shows
+        which attention path the program holds: a Pallas kernel is a
+        ``tpu_custom_call``. Lowers an idle descriptor; dispatches nothing."""
+        idle = (self._forced_none, self._use_forced_none)
+        if self.ragged:
+            return self._jit_ragged_tick.lower(*self._ragged_args(True, *idle))
+        return self._jit_decode.lower(self.params, self._cache, self._state, *idle)
+
     # ----------------------------------------------------------------- params
     def set_params(self, params) -> None:
         """Swap the served parameters IN PLACE — the live model-version
@@ -2393,17 +2404,14 @@ class ServingEngine:
             next_logits=self._state.next_logits.at[slot].set(jnp.nan)
         )
 
-    def _dispatch_ragged(self, any_decode: bool, forced, use_forced):
-        """Pack the tick's buffered work — scale resets, prefill chunks,
-        latent finishes, fault poison, the decode flag — into the FIXED-SHAPE
-        ragged descriptor and dispatch the one fused program. Lane packing is
-        pure host-side numpy (the descriptor build time the v11 metrics
-        report); idle lanes carry trash tables / zero counts and are either
-        value-inert (chunk lanes write only the trash page) or skipped
-        outright (finish lanes gate on ``fin_active``). Returns the decode
-        outputs; when ``any_decode`` is False they are the no-decode
-        sentinels and the caller discards them."""
-        t0 = time.perf_counter()
+    def _ragged_args(self, any_decode: bool, forced, use_forced) -> tuple:
+        """The fused tick program's arguments: the tick's buffered work —
+        scale resets, prefill chunks, latent finishes, fault poison, the
+        decode flag — packed into the FIXED-SHAPE ragged descriptor. Lane
+        packing is pure host-side numpy; idle lanes carry trash tables / zero
+        counts and are either value-inert (chunk lanes write only the trash
+        page) or skipped outright (finish lanes gate on ``fin_active``).
+        Reads the buffers, changes nothing."""
         lanes, cap = self._ragged_lanes, self._ragged_chunk_cap
         P = self._pages_per_slot
         n_ch, n_fin = len(self._tick_chunks), len(self._tick_finishes)
@@ -2451,9 +2459,7 @@ class ServingEngine:
             fin_rng[i] = rng
             fin_temp[i], fin_tk[i], fin_tp[i], fin_ds[i], fin_pad[i] = sampling
         poison = -1 if self._tick_poison is None else int(self._tick_poison)
-        self._tick_build_s = time.perf_counter() - t0
-        self._tick_programs += 1
-        tok, finite, self._cache, self._state = self._jit_ragged_tick(
+        return (
             self.params, self._cache, self._state,
             jnp.asarray(reset_ids), bool(self._tick_resets),
             jnp.asarray(ch_ids), jnp.asarray(ch_offset), jnp.asarray(ch_count),
@@ -2464,6 +2470,17 @@ class ServingEngine:
             jnp.asarray(fin_tp), jnp.asarray(fin_ds), jnp.asarray(fin_pad),
             bool(n_fin), poison, bool(any_decode), forced, use_forced,
         )
+
+    def _dispatch_ragged(self, any_decode: bool, forced, use_forced):
+        """Dispatch the tick's ONE fused program over the descriptor
+        ``_ragged_args`` packs (its build time is what the v11 metrics
+        report). Returns the decode outputs; when ``any_decode`` is False
+        they are the no-decode sentinels and the caller discards them."""
+        t0 = time.perf_counter()
+        args = self._ragged_args(any_decode, forced, use_forced)
+        self._tick_build_s = time.perf_counter() - t0
+        self._tick_programs += 1
+        tok, finite, self._cache, self._state = self._jit_ragged_tick(*args)
         self._tick_chunks.clear()
         self._tick_finishes.clear()
         self._tick_resets.clear()

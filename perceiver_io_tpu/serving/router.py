@@ -448,6 +448,19 @@ class ServingRouter:
             )
         self._replica_mode = ("process" if replica_mode == "process"
                               and proc_replicas_enabled() else "inproc")
+        if self._replica_mode == "process" and jax.default_backend() == "tpu":
+            # an accelerator belongs to ONE process: this one initialised the
+            # TPU backend (it holds the params as device arrays), so a worker
+            # that builds its engine on the same chips dies at backend
+            # start-up (libtpu's lockfile; PERF.md, PR 21). Refuse here,
+            # before any worker is spawned.
+            raise RuntimeError(
+                "replica_mode='process' cannot be served from a process whose "
+                "JAX backend is the TPU: this process holds the chip(s), and a "
+                "worker process that needs one fails at backend start-up. Use "
+                "replica_mode='inproc' here; process replicas need a parent "
+                "that never initialises the TPU backend."
+            )
         self._transport_cfg = dict(transport or {})
         # router-level accept journal (the closed fleet durability boundary):
         # fresh submits that park because NO replica can accept are journaled

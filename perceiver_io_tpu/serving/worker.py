@@ -83,8 +83,10 @@ class _Worker:
         import jax
 
         jax.config.update("jax_enable_x64", bool(p["x64"]))
+        from perceiver_io_tpu.compile_cache import enable_compile_cache
         from perceiver_io_tpu.serving.engine import ServingEngine
 
+        enable_compile_cache()  # a restarted replica finds its programs again
         self.engine = ServingEngine(
             p["model"], p["params"],
             metrics_jsonl=p["metrics_jsonl"],

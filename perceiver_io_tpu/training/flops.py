@@ -12,10 +12,14 @@ MFU telemetry (the BASELINE.json north-star metric the reference never measured)
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import Optional
 
 from perceiver_io_tpu.models.core.config import CausalSequenceModelConfig
 
-# bf16 peak TFLOP/s per chip for common TPU generations
+# bf16 peak FLOP/s of one chip, keyed by a lower-case substring of the
+# ``device_kind`` JAX reports ("TPU v5 lite" is a v5e). Source: Google Cloud
+# TPU documentation, the system-architecture page of each generation
+# ("TPU v4": 275, "TPU v5e": 197, "TPU v5p": 459, "TPU v6e": 918 TFLOP/s).
 TPU_PEAK_FLOPS = {
     "v4": 275e12,
     "v5e": 197e12,
@@ -25,17 +29,22 @@ TPU_PEAK_FLOPS = {
 }
 
 
-def detect_peak_flops(default: float = 197e12) -> float:
-    try:
+def detect_peak_flops(device_kind: Optional[str] = None) -> float:
+    """Peak bf16 FLOP/s of ``device_kind`` (default: the kind of the device
+    this process computes on). A kind the table does not hold is an error,
+    never a default: a utilisation against the wrong peak is a wrong number."""
+    if device_kind is None:
         import jax
 
-        kind = jax.devices()[0].device_kind.lower()
-        for name, peak in TPU_PEAK_FLOPS.items():
-            if name in kind:
-                return peak
-    except Exception:
-        pass
-    return default
+        device_kind = jax.devices()[0].device_kind
+    kind = device_kind.lower()
+    for name, peak in TPU_PEAK_FLOPS.items():
+        if name in kind:
+            return peak
+    raise ValueError(
+        f"no peak FLOP/s on record for device_kind {device_kind!r} "
+        f"(known: {sorted(TPU_PEAK_FLOPS)}); add it to TPU_PEAK_FLOPS with its source"
+    )
 
 
 @dataclass

@@ -28,7 +28,10 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
+from perceiver_io_tpu.compile_cache import enable_compile_cache  # noqa: E402
 from perceiver_io_tpu.parallel.mesh import initialize_distributed  # noqa: E402
+
+enable_compile_cache()
 
 initialize_distributed(f"localhost:{port}", num_processes=nprocs, process_id=proc_id)
 assert jax.process_count() == nprocs, jax.process_count()

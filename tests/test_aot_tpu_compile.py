@@ -1,0 +1,126 @@
+"""The main path's Pallas kernels compile for a TPU v5e at real widths.
+
+No chip is attached here: the installed TPU compiler compiles for a DESCRIBED
+``v5e:2x2`` topology (shapes only, nothing runs). That refuses what interpret
+mode cannot see — more SMEM or VMEM than a kernel may use, an op Mosaic does
+not lower, a slice off the tiling — which is how the int8 scale sidecars
+(SMEM), the int4 nibble unpack (``arith.shrui`` on i8 vectors) and the dense
+decode kernel at the flagship's width (16 MiB scoped VMEM) were found. The
+kernel functions are compiled directly: their ``*_supported`` gates ask
+``jax.default_backend()``, which is the CPU here. A compile that passes is
+not a chip run; ``chip_smoke.py`` is.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from perceiver_io_tpu.ops import decode_kernel as dk
+from perceiver_io_tpu.ops import paged_decode_kernel as pdk
+from perceiver_io_tpu.ops import ragged_paged_kernel as rpk
+from perceiver_io_tpu.ops.flash import splash_mha
+
+FLAGSHIP = (10, 128)  # heads x head width: 455M C4 (and 134M GiantMIDI) Perceiver AR
+WIKITEXT = (8, 64)  # 30.7M WikiText Perceiver AR, window 4096
+POOL_PAGES = 2048  # the (N, H) scale sidecars outgrew SMEM from about 1k pages
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One device of a described v5e:2x2, with the persistent compilation cache
+    off: such a compile is written to the cache but cannot be read back without
+    a chip, and every later run would warn about the unreadable entry."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _pool_args(sds, rows, heads, width, window, page, pool):
+    hd = heads * width
+    pages_per_slot = -(-window // page)
+    kp = {
+        "fp": sds((POOL_PAGES, page, hd), jnp.bfloat16),
+        "int8": sds((POOL_PAGES, page, hd), jnp.int8),
+        "int4": sds((POOL_PAGES, page, hd // 2), jnp.uint8),
+    }[pool]
+    scale = None if pool == "fp" else sds((POOL_PAGES, heads), jnp.float32)
+    return dict(
+        q=sds((rows, heads, 1, width), jnp.bfloat16), kp=kp, vp=kp,
+        page_table=sds((rows, pages_per_slot), jnp.int32),
+        start=sds((rows,), jnp.int32), live=sds((rows,), jnp.int32),
+        rope_k=sds((rows, pages_per_slot * page, width // 2), jnp.float32),
+        k_scale=scale, v_scale=scale,
+    )
+
+
+def _paged(sds, heads_width, window, page, pool):
+    args = _pool_args(sds, 16, *heads_width, window, page, pool)
+    return jax.jit(lambda a: pdk.fused_paged_decode_attention(**a, window=window)).lower(args)
+
+
+def _ragged(sds, heads_width, window, page, pool):
+    args = _pool_args(sds, 16, *heads_width, window, page, pool)
+    args["causal_bound"] = sds((16,), jnp.int32)
+    qbits = 4 if pool == "int4" else 8
+    return jax.jit(
+        lambda a: rpk.fused_ragged_paged_attention(**a, window=window, qbits=qbits)
+    ).lower(args)
+
+
+def _dense(sds, heads_width, batch, capacity, n_q):
+    heads, width = heads_width
+    kv = sds((batch, capacity, heads * width), jnp.bfloat16)
+    return jax.jit(
+        lambda q, k, v, ang, pos, pad, live: dk.fused_decode_attention(q, k, v, ang, pos, pad, live=live)
+    ).lower(
+        sds((batch, heads, n_q, width), jnp.bfloat16), kv, kv,
+        sds((batch, capacity, width // 2), jnp.float32), sds((batch,), jnp.int32),
+        sds((batch, capacity), jnp.bool_), sds((batch,), jnp.int32),
+    )
+
+
+def _splash_fwd_bwd(sds, heads_width, n_q, n_k):
+    heads, width = heads_width
+    q = sds((2, heads, n_q, width), jnp.bfloat16)
+    kv = sds((2, heads, n_k, width), jnp.bfloat16)
+    loss = lambda q, k, v: splash_mha(q, k, v, causal=True).astype(jnp.float32).sum()
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv)
+
+
+CASES = {
+    "paged-fp-10x128-w1024": (_paged, FLAGSHIP, 1024, 64, "fp"),
+    "paged-fp-10x128-w512": (_paged, FLAGSHIP, 512, 64, "fp"),
+    "paged-int8-10x128-w1024": (_paged, FLAGSHIP, 1024, 64, "int8"),
+    "paged-int8-10x128-w512": (_paged, FLAGSHIP, 512, 32, "int8"),
+    "paged-fp-8x64-w4096": (_paged, WIKITEXT, 4096, 128, "fp"),
+    "paged-int8-8x64-w4096": (_paged, WIKITEXT, 4096, 128, "int8"),
+    "ragged-fp-10x128-w1024": (_ragged, FLAGSHIP, 1024, 64, "fp"),
+    "ragged-int8-10x128-w1024": (_ragged, FLAGSHIP, 1024, 64, "int8"),
+    "ragged-int4-10x128-w1024": (_ragged, FLAGSHIP, 1024, 128, "int4"),
+    "ragged-int4-8x64-w4096": (_ragged, WIKITEXT, 4096, 64, "int4"),
+    # the flagship's self-attention cache under generate(): batch 8, capacity
+    # 512 — refused at the old fixed 512-row block (16.71M of 16M scoped VMEM)
+    "dense-10x128-b8-cap512": (_dense, FLAGSHIP, 8, 512, 1),
+    "dense-10x128-b8-cap1024-q8": (_dense, FLAGSHIP, 8, 1024, 8),
+    "splash-fwd-bwd-512x1024x128": (_splash_fwd_bwd, FLAGSHIP, 512, 1024),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(v5e, case):
+    build, *shape = CASES[case]
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+    compiled = build(sds, *shape).compile()  # raises what the chip's compiler would raise
+    assert "tpu_custom_call" in compiled.as_text()
