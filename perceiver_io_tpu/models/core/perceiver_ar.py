@@ -835,9 +835,12 @@ class CausalSequenceModel(nn.Module):
         return self.config.max_seq_len - self.config.max_latents
 
     def _head(self, hidden: jax.Array) -> jax.Array:
-        if self.config.output_norm:
-            hidden = self.out_norm(hidden)
-        return self.output_adapter(self.ar.attend(hidden))
+        # "head": a stable scope name profiler-trace tools read device time by
+        # (serving/engine.py TICK_SCOPES); metadata only
+        with jax.named_scope("head"):
+            if self.config.output_norm:
+                hidden = self.out_norm(hidden)
+            return self.output_adapter(self.ar.attend(hidden))
 
     def __call__(self, x: jax.Array, prefix_len: int, pad_mask: Optional[jax.Array] = None) -> jax.Array:
         """Logits (B, N - prefix_len, vocab) over the latent positions."""

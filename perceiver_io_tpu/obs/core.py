@@ -20,11 +20,25 @@ training suites run THROUGH the instrumented paths with the recorder both off
 and on (tests/test_obs.py): spans only ever *time* existing host-side calls,
 they never touch device values.
 
+Spans NEST: every span records the span that was open on its thread when it
+began (``parent`` in its trace event — a per-thread stack shared by ``span()``
+and ``span_begin``/``span_end``), and ``summary()`` reports each phase's SELF
+time (``self_total_s``: its duration minus what its direct children cover), so
+a phase table sums to wall time instead of counting nested work twice.
+
+Profiler bridge: an enabled recorder also enters a
+``jax.profiler.TraceAnnotation`` of the same name for every span, so in any
+``jax.profiler`` trace the program's spans sit in the ``/host:CPU`` plane on
+the clock the device's ``XLA Ops`` are on (docs/observability.md "Reading the
+spans in a profiler trace"). jax is never imported from here: the bridge arms
+itself only once jax is already loaded in the process, and with no profiler
+session a ``TraceMe`` is a flag test.
+
 Clocks are injectable (``clock=`` takes any () -> float seconds callable) so
 span math is exactly reproducible under a fake clock in tests. The recorder
 never calls jax: it can be imported, exercised, and unit-tested without a
 backend, and recording from worker threads (prefetcher, checkpoint writer) is
-safe by construction (one lock, no reentrancy).
+safe by construction (one lock, no reentrancy; span stacks are per thread).
 
 Enablement:
   * explicit: ``ServingEngine(telemetry=...)`` / ``TrainerConfig.telemetry`` —
@@ -39,12 +53,20 @@ Enablement:
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 from collections import deque
 from typing import Callable, Dict, List, Optional
 
 TELEMETRY_ENV = "PERCEIVER_IO_TPU_TELEMETRY"
+
+# ``summary()``'s shape. v2: phases carry ``self_total_s`` and instrumented
+# surfaces declare their phases up front (a declared phase that never ran
+# reads count 0; a name that is absent is not one the program emits). v1, the
+# unversioned summary before it, had neither — a reader finding no ``schema``
+# key is looking at a program older than the tick's tiling phases.
+SUMMARY_SCHEMA = "telemetry-summary/v2"
 
 # Bounded event history: a long-lived engine records several events per
 # generated token forever; an unbounded list is a slow host-memory leak and an
@@ -88,10 +110,16 @@ class NullRecorder:
     def span(self, name: str, **args) -> _NullSpan:
         return _NULL_SPAN
 
-    def span_begin(self, name: str, **args) -> None:
+    def span_begin(self, name: str, at: Optional[float] = None, **args) -> None:
         return None
 
-    def span_end(self, name: str, **args) -> None:
+    def span_end(self, name: str, at: Optional[float] = None, **args) -> None:
+        return None
+
+    def now(self) -> float:
+        return 0.0
+
+    def declare_phases(self, names) -> None:
         return None
 
     def async_begin(self, name: str, span_id, **args) -> None:
@@ -131,24 +159,43 @@ class NullRecorder:
 NULL_RECORDER = NullRecorder()
 
 
+def _new_hist() -> list:
+    """A phase's books: [count, total, max, recent window, self total]."""
+    return [0, 0.0, 0.0, deque(maxlen=HISTOGRAM_WINDOW), 0.0]
+
+
+class _Frame:
+    """One open span on its thread's stack."""
+
+    __slots__ = ("name", "t0", "args", "parent", "children_s", "annotation", "open")
+
+    def __init__(self, name: str, t0: float, args: Dict, parent: Optional["_Frame"]):
+        self.name = name
+        self.t0 = t0
+        self.args = args
+        self.parent = parent
+        self.children_s = 0.0  # what direct children covered (self time = dur - this)
+        self.annotation = None  # the jax.profiler.TraceAnnotation twin, if armed
+        self.open = True
+
+
 class _Span:
     """Context manager recording one complete ("X") span on exit."""
 
-    __slots__ = ("_rec", "_name", "_args", "_t0")
+    __slots__ = ("_rec", "_name", "_args", "_frame")
 
     def __init__(self, rec: "TelemetryRecorder", name: str, args: Dict):
         self._rec = rec
         self._name = name
         self._args = args
-        self._t0 = rec._clock()
+        self._frame = None
 
     def __enter__(self):
+        self._frame = self._rec._push(self._name, self._args, None)
         return self
 
     def __exit__(self, *exc):
-        rec = self._rec
-        t1 = rec._clock()
-        rec._record_complete(self._name, self._t0, t1 - self._t0, self._args)
+        self._rec._pop(self._frame)
         return False
 
 
@@ -187,10 +234,13 @@ class TelemetryRecorder:
         self._max_events = max_events
         self.counters: Dict[str, float] = {}
         self.gauges: Dict[str, float] = {}
-        # name -> [count, total, max, recent-window list]
-        self._hist: Dict[str, list] = {}
-        # (thread ident, name) -> start offset, for span_begin/span_end pairs
-        self._open_spans: Dict[tuple, List[float]] = {}
+        self._hist: Dict[str, list] = {}  # name -> _new_hist()
+        # per-thread stack of open spans (span() and span_begin/span_end
+        # alike): the top at a span's begin is its parent
+        self._local = threading.local()
+        # the jax.profiler.TraceAnnotation class once jax is loaded in this
+        # process (None = not resolved yet, False = unavailable)
+        self._annotation_cls = None
         self.trace_path = trace_path
         self._closed = False
         self._flush_stop = threading.Event()
@@ -220,42 +270,96 @@ class TelemetryRecorder:
             self._dropped += 1
         self._events.append(event)
 
-    def _record_complete(self, name: str, t0: float, dur: float, args: Dict) -> None:
-        start = t0 - self._origin
+    def now(self) -> float:
+        """The recorder's clock: for intervals an instrumented surface
+        measures itself (``observe``) on the clock its spans are on."""
+        return self._clock()
+
+    def _trace_annotation(self):
+        """``jax.profiler.TraceAnnotation`` if jax is loaded in this process,
+        else None. Never imports jax itself (the core stays jax-free)."""
+        cls = self._annotation_cls
+        if cls is None:
+            if "jax" not in sys.modules:
+                return None  # a later span asks again
+            try:
+                from jax.profiler import TraceAnnotation as cls
+            except ImportError:  # a jax without the profiler: no bridge
+                cls = False
+            self._annotation_cls = cls
+        return cls or None
+
+    def _push(self, name: str, args: Dict, at: Optional[float]) -> _Frame:
+        try:
+            stack = self._local.stack
+        except AttributeError:
+            stack = self._local.stack = []
+        frame = _Frame(name, self._clock() if at is None else at, args,
+                       stack[-1] if stack else None)
+        annotation = self._trace_annotation()
+        if annotation is not None:
+            # the same span on the profiler's clock (/host:CPU plane); with
+            # no profiler session this is a flag test
+            frame.annotation = annotation(name, **args)
+            frame.annotation.__enter__()
+        stack.append(frame)
+        return frame
+
+    def _pop(self, frame: _Frame, at: Optional[float] = None) -> float:
+        t1 = self._clock() if at is None else at
+        if frame.annotation is not None:
+            frame.annotation.__exit__(None, None, None)
+        stack = self._local.stack
+        if stack and stack[-1] is frame:
+            stack.pop()
+        else:  # begin/end pairs that interleave (router replicas on one thread)
+            stack.remove(frame)
+        frame.open = False
+        dur = t1 - frame.t0
+        parent = frame.parent
+        if parent is not None and parent.open:
+            parent.children_s += dur
+        event = {"ph": "X", "name": frame.name, "ts": frame.t0 - self._origin,
+                 "dur": dur, "tid": threading.get_ident()}
+        if parent is not None:
+            event["parent"] = parent.name
+        if frame.args:
+            event["args"] = frame.args
         with self._lock:
-            self._observe_locked(name, dur)
-            self._append_event({
-                "ph": "X", "name": name, "ts": start, "dur": dur,
-                "tid": threading.get_ident(), **({"args": args} if args else {}),
-            })
+            self._observe_locked(frame.name, dur, max(dur - frame.children_s, 0.0))
+            self._append_event(event)
+        return t1
 
     def span(self, name: str, **args) -> _Span:
         """Time a with-block as one complete span (also feeds the histogram)."""
         return _Span(self, name, args)
 
-    def span_begin(self, name: str, **args) -> None:
+    def span_begin(self, name: str, at: Optional[float] = None, **args) -> None:
         """Open a span closed later by ``span_end`` on the SAME thread (for
-        phases that do not nest as a with-block, e.g. fetch-wait measured
-        across loop iterations). Begin/end pairs nest per (thread, name)."""
-        t0 = self._clock()
-        with self._lock:
-            self._open_spans.setdefault((threading.get_ident(), name), []).append(t0)
+        phases that do not nest as a with-block, e.g. a tick bracketing two
+        calls). ``at`` (here and on ``span_end``) places the edge at a clock
+        reading the caller already holds — ``span_end`` returns its reading —
+        so consecutive phases tile with no seam between them."""
+        self._push(name, args, at)
 
-    def span_end(self, name: str, **args) -> None:
-        t1 = self._clock()
-        key = (threading.get_ident(), name)
+    def span_end(self, name: str, at: Optional[float] = None, **args) -> Optional[float]:
+        """Close the innermost open span of this name on this thread; returns
+        the clock reading it ended at (None for an unmatched end, which is
+        ignored rather than corrupting the trace)."""
+        for frame in reversed(getattr(self._local, "stack", ())):
+            if frame.name == name:
+                if args:
+                    frame.args = {**frame.args, **args}
+                return self._pop(frame, at)
+        return None
+
+    def declare_phases(self, names) -> None:
+        """Create the (empty) histograms of phases an instrumented surface
+        will emit, so ``summary()`` tells "never happened" (count 0) from "no
+        such phase" (a renamed span)."""
         with self._lock:
-            stack = self._open_spans.get(key)
-            if not stack:
-                return  # unmatched end: ignore rather than corrupt the trace
-            t0 = stack.pop()
-            if not stack:
-                del self._open_spans[key]
-            self._observe_locked(name, t1 - t0)
-            self._append_event({
-                "ph": "X", "name": name, "ts": t0 - self._origin, "dur": t1 - t0,
-                "tid": key[0], **({"args": args} if args else {}),
-            })
+            for name in names:
+                self._hist.setdefault(name, _new_hist())
 
     def async_begin(self, name: str, span_id, **args) -> None:
         """Open an async span (Chrome "b"): a lifecycle that crosses ticks and
@@ -301,14 +405,15 @@ class TelemetryRecorder:
         with self._lock:
             self.gauges[name] = value
 
-    def _observe_locked(self, name: str, seconds: float) -> None:
+    def _observe_locked(self, name: str, seconds: float, self_seconds: Optional[float] = None) -> None:
         h = self._hist.get(name)
         if h is None:
-            h = self._hist[name] = [0, 0.0, 0.0, deque(maxlen=HISTOGRAM_WINDOW)]
+            h = self._hist[name] = _new_hist()
         h[0] += 1
         h[1] += seconds
         h[2] = max(h[2], seconds)
         h[3].append(seconds)
+        h[4] += seconds if self_seconds is None else self_seconds
 
     def observe(self, name: str, seconds: float) -> None:
         """Feed a duration into a phase histogram without a trace event (for
@@ -319,21 +424,24 @@ class TelemetryRecorder:
     # ----------------------------------------------------------------- reading
     def summary(self) -> Dict:
         """Aggregate view: per-phase duration stats + counters + gauges.
-        Percentiles cover the recent ``HISTOGRAM_WINDOW``; count/total are
-        lifetime. This is what the bench ``--profile`` artifacts embed."""
+        Percentiles cover the recent ``HISTOGRAM_WINDOW``; count/total/self
+        total (duration minus what direct child spans covered) are lifetime.
+        This is what the bench ``--profile`` artifacts embed."""
         with self._lock:
             phases = {}
-            for name, (count, total, mx, window) in sorted(self._hist.items()):
+            for name, (count, total, mx, window, self_total) in sorted(self._hist.items()):
                 w = sorted(window)
                 phases[name] = {
                     "count": count,
                     "total_s": round(total, 6),
+                    "self_total_s": round(self_total, 6),
                     "mean_s": round(total / count, 6) if count else 0.0,
                     "p50_s": round(_quantile(w, 0.50), 6),
                     "p95_s": round(_quantile(w, 0.95), 6),
                     "max_s": round(mx, 6),
                 }
             out = {
+                "schema": SUMMARY_SCHEMA,
                 "phases": phases,
                 "counters": dict(sorted(self.counters.items())),
                 "gauges": dict(sorted(self.gauges.items())),

@@ -25,9 +25,10 @@ MANIFEST_SCHEMA = "run-manifest/v1"
 
 # every artifact schema the repo currently writes, in one place
 ARTIFACT_SCHEMAS = {
-    "serving_metrics": "serving-metrics/v12",
+    "serving_metrics": "serving-metrics/v13",
     "train_metrics": "train-metrics/v1",
     "chrome_trace": "chrome-trace/v1",
+    "telemetry_summary": "telemetry-summary/v2",
     "request_journal": "request-journal/v1",
     "run_manifest": MANIFEST_SCHEMA,
 }

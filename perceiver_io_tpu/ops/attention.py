@@ -221,7 +221,8 @@ class MultiHeadAttention(nn.Module):
         if self.dropout > 0.0 and not self.deterministic:
             raise ValueError("paged decode is inference-only (no attention dropout)")
         num_qk, num_v, _ = self._dims()
-        kv_cache = kv_cache.append_token(k, v)
+        with jax.named_scope("cache_append"):
+            kv_cache = kv_cache.append_token(k, v)
         live = jnp.broadcast_to(jnp.asarray(kv_live, jnp.int32).reshape(-1), (b,))
 
         split = lambda t: t.reshape(t.shape[0], t.shape[1], self.num_heads, -1).transpose(0, 2, 1, 3)
@@ -230,52 +231,53 @@ class MultiHeadAttention(nn.Module):
             q = apply_rope(q, rope_q)
 
         n_phys = kv_cache.pages_per_slot * kv_cache.page_size
-        if self.use_flash is not False and pdk.paged_decode_supported(
-            kv_cache.page_size, num_qk, num_v, self.num_heads,
-            quantized=kv_cache.quantized, qbits=kv_cache.qbits,
-        ):
-            ang = rope_k if rope_k is not None else jnp.zeros((b, n_phys, 2), jnp.float32)
-            if ang.shape[0] != b:
-                ang = jnp.broadcast_to(ang, (b, *ang.shape[1:]))
-            o = pdk.fused_paged_decode_attention(
-                q, kv_cache.kp, kv_cache.vp, kv_cache.page_table, kv_cache.start,
-                live, ang, kv_cache.window,
-                # the ragged kill-switch disables the dead-page skip (every
-                # page fetched + masked) but never the visibility bound
-                skip_dead_pages=ragged_decode_enabled(),
-                # int8 pools: scales ride the scalar-prefetch path and the
-                # dequant fuses into the page stream (None on fp pools)
-                k_scale=kv_cache.k_scale, v_scale=kv_cache.v_scale,
-            )
-        elif self.use_flash is not False and rpk.ragged_paged_supported(
-            kv_cache.page_size, num_qk, num_v, self.num_heads,
-            quantized=kv_cache.quantized, qbits=kv_cache.qbits,
-        ):
-            # int4 pools (and anything else the legacy single-query kernel
-            # gates out but the ragged program serves): dispatch the decode
-            # batch as a ragged descriptor of full-bound items — the nibble
-            # unpack fuses into the page stream (ops/ragged_paged_kernel.py)
-            ang = rope_k if rope_k is not None else jnp.zeros((b, n_phys, 2), jnp.float32)
-            if ang.shape[0] != b:
-                ang = jnp.broadcast_to(ang, (b, *ang.shape[1:]))
-            o = rpk.fused_ragged_paged_attention(
-                q, kv_cache.kp, kv_cache.vp, kv_cache.page_table, kv_cache.start,
-                live, jnp.full((b,), kv_cache.window - 1, jnp.int32), ang,
-                kv_cache.window, skip_dead_pages=ragged_decode_enabled(),
-                k_scale=kv_cache.k_scale, v_scale=kv_cache.v_scale,
-                qbits=kv_cache.qbits,
-            )
-        else:
-            k_full, v_full = kv_cache.gather_dense()
-            kf, vf = split(k_full), split(v_full)
-            if rope_k is not None:
-                kf = apply_rope(kf, rope_k)
-            attn = jnp.einsum("bhic,bhjc->bhij", q, kf, preferred_element_type=jnp.float32)
-            neg = jnp.finfo(attn.dtype).min
-            visible = pdk.paged_visibility(kv_cache.start, live, kv_cache.window, n_phys)
-            attn = jnp.where(visible[:, None, None, :], attn, neg)
-            attn = jax.nn.softmax(attn, axis=-1).astype(vf.dtype)
-            o = jnp.einsum("bhij,bhjc->bhic", attn, vf)
+        with jax.named_scope("decode_attention"):
+            if self.use_flash is not False and pdk.paged_decode_supported(
+                kv_cache.page_size, num_qk, num_v, self.num_heads,
+                quantized=kv_cache.quantized, qbits=kv_cache.qbits,
+            ):
+                ang = rope_k if rope_k is not None else jnp.zeros((b, n_phys, 2), jnp.float32)
+                if ang.shape[0] != b:
+                    ang = jnp.broadcast_to(ang, (b, *ang.shape[1:]))
+                o = pdk.fused_paged_decode_attention(
+                    q, kv_cache.kp, kv_cache.vp, kv_cache.page_table, kv_cache.start,
+                    live, ang, kv_cache.window,
+                    # the ragged kill-switch disables the dead-page skip (every
+                    # page fetched + masked) but never the visibility bound
+                    skip_dead_pages=ragged_decode_enabled(),
+                    # int8 pools: scales ride the scalar-prefetch path and the
+                    # dequant fuses into the page stream (None on fp pools)
+                    k_scale=kv_cache.k_scale, v_scale=kv_cache.v_scale,
+                )
+            elif self.use_flash is not False and rpk.ragged_paged_supported(
+                kv_cache.page_size, num_qk, num_v, self.num_heads,
+                quantized=kv_cache.quantized, qbits=kv_cache.qbits,
+            ):
+                # int4 pools (and anything else the legacy single-query kernel
+                # gates out but the ragged program serves): dispatch the decode
+                # batch as a ragged descriptor of full-bound items — the nibble
+                # unpack fuses into the page stream (ops/ragged_paged_kernel.py)
+                ang = rope_k if rope_k is not None else jnp.zeros((b, n_phys, 2), jnp.float32)
+                if ang.shape[0] != b:
+                    ang = jnp.broadcast_to(ang, (b, *ang.shape[1:]))
+                o = rpk.fused_ragged_paged_attention(
+                    q, kv_cache.kp, kv_cache.vp, kv_cache.page_table, kv_cache.start,
+                    live, jnp.full((b,), kv_cache.window - 1, jnp.int32), ang,
+                    kv_cache.window, skip_dead_pages=ragged_decode_enabled(),
+                    k_scale=kv_cache.k_scale, v_scale=kv_cache.v_scale,
+                    qbits=kv_cache.qbits,
+                )
+            else:
+                k_full, v_full = kv_cache.gather_dense()
+                kf, vf = split(k_full), split(v_full)
+                if rope_k is not None:
+                    kf = apply_rope(kf, rope_k)
+                attn = jnp.einsum("bhic,bhjc->bhij", q, kf, preferred_element_type=jnp.float32)
+                neg = jnp.finfo(attn.dtype).min
+                visible = pdk.paged_visibility(kv_cache.start, live, kv_cache.window, n_phys)
+                attn = jnp.where(visible[:, None, None, :], attn, neg)
+                attn = jax.nn.softmax(attn, axis=-1).astype(vf.dtype)
+                o = jnp.einsum("bhij,bhjc->bhic", attn, vf)
         o = o.transpose(0, 2, 1, 3).reshape(o.shape[0], n_q, -1)
         return self.o_proj(o), kv_cache
 
@@ -377,7 +379,11 @@ class MultiHeadAttention(nn.Module):
             return self._paged_cached_attention(q, k, v, kv_cache, rope_q, rope_k, kv_live, scale)
 
         if kv_cache is not None:
-            kv_cache = kv_cache.append(k, v)
+            # "cache_append" / "decode_attention": stable scope names a
+            # profiler trace's device time is read by (serving/engine.py
+            # TICK_SCOPES); metadata only
+            with jax.named_scope("cache_append"):
+                kv_cache = kv_cache.append(k, v)
             k, v = kv_cache.k, kv_cache.v  # full capacity buffers
 
         b, n_q = q.shape[0], q.shape[1]
@@ -407,9 +413,10 @@ class MultiHeadAttention(nn.Module):
                 pad = pad_mask if pad_mask is not None else jnp.zeros((b, n_k), bool)
                 if pad.shape[0] != b:
                     pad = jnp.broadcast_to(pad, (b, n_k))
-                o = fused_decode_attention_auto(
-                    q, kv_cache.k, kv_cache.v, ang, kv_cache.length - 1, pad, live=kv_live
-                )
+                with jax.named_scope("decode_attention"):
+                    o = fused_decode_attention_auto(
+                        q, kv_cache.k, kv_cache.v, ang, kv_cache.length - 1, pad, live=kv_live
+                    )
                 o = o.transpose(0, 2, 1, 3).reshape(o.shape[0], n_q, -1)
                 return self.o_proj(o), kv_cache
 

@@ -54,8 +54,9 @@ set, no bound configured, and no fault armed, all of this is bit-inert —
 compile counts and greedy parity are unchanged (pinned).
 
 Telemetry (docs/observability.md): ``ServingEngine(telemetry=...)`` (or the
-``PERCEIVER_IO_TPU_TELEMETRY`` env) turns on phase spans per tick (admit /
-prefill dispatch / install / decode dispatch / sample-sync / evict),
+``PERCEIVER_IO_TPU_TELEMETRY`` env) turns on phase spans that TILE the tick
+(schedule > admit > prefill dispatch / install / chunk / finish; decode
+dispatch; sample-sync; harvest > evict) plus the sync-to-dispatch host gap,
 per-request lifecycle spans keyed by request id (joinable against the
 serving-metrics/v7 JSONL events), and a compile watchdog that flags any
 program count growing past the churn-never-recompiles budgets at runtime.
@@ -272,7 +273,19 @@ class ServedRequest:
     # preemption): the per-class queue-wait stats measure the current wait,
     # not a sum over preemption cycles
     enqueued_at: float = 0.0
+    # the instant a slot (and, paged, the whole page reservation) was claimed
+    # for this request — the end of its queue wait on BOTH admission paths
+    # (a preempted continuation: its latest claim)
+    slot_claimed_at: Optional[float] = None
+    # DECODE-READY: the one-shot prefill + install are dispatched, or the
+    # split admission's finish lane is buffered — after every chunk tick. Not
+    # the end of the queue wait (that is ``slot_claimed_at``)
     admitted_at: Optional[float] = None
+    # host time right after the sync of the tick that emitted this request's
+    # first FREE-RUNNING token (replayed tokens were delivered before), and of
+    # the tick that emitted its latest one (the inter-token gap's left edge)
+    first_token_at: Optional[float] = None
+    last_token_at: Optional[float] = None
     finished_at: Optional[float] = None
     deadline_s: Optional[float] = None  # TTL from submit; enforced at ticks
     # paged engines: the request's page reservation, computed ONCE at submit
@@ -394,6 +407,17 @@ class _PrefillTask:
 # distinguishes concurrent engines' lifecycle spans in a shared recorder
 _ENGINE_IDS = itertools.count()
 
+# Stable ``jax.named_scope`` names of the phases INSIDE the tick program (the
+# fused ``ragged_tick`` and its composed twins): they ride every operation's
+# ``op_name`` into a profiler trace, where device time is summed per phase
+# (docs/observability.md "Named scopes"). Inside ``tick.decode`` the model's
+# own scopes split further: ``cache_append`` and ``decode_attention``
+# (ops/attention.py), the flax ``mlp`` modules, and ``head``
+# (models/core/perceiver_ar.py). Renaming one is a change to what the trace
+# tools read.
+TICK_SCOPES = {phase: f"tick.{phase}" for phase in (
+    "resets", "chunk_lanes", "finish_lanes", "poison", "sample", "decode")}
+
 
 def default_prefill_buckets(window: int, max_latents: int) -> tuple:
     """Geometric (halving) ladder of prefill bucket lengths, from the full
@@ -471,6 +495,24 @@ class ServingEngine:
         self._span_decode_dispatch = f"{obs_ns}.decode_dispatch"
         self._span_sample_sync = f"{obs_ns}.sample_sync"
         self._span_evict = f"{obs_ns}.evict"
+        self._span_chunk = f"{obs_ns}.prefill_chunk"
+        self._span_finish = f"{obs_ns}.prefill_finish"
+        # the phases that tile the stretch in which the device waits for the
+        # host, from one tick's sync to the next tick's dispatch
+        # (docs/observability.md "The tick, tiled"): harvest | the caller's
+        # time between steps | schedule | decode_dispatch = host_gap. The
+        # spans are recorded every tick; the gap and its four parts are
+        # BOOKED (observed intervals) only for steady-state gaps, so that the
+        # parts' means add up to the gap's over the same ticks.
+        self._span_schedule = f"{obs_ns}.schedule"
+        self._span_harvest = f"{obs_ns}.harvest"
+        self._phase_host_gap = f"{obs_ns}.host_gap"
+        self._phase_gap_harvest = f"{obs_ns}.host_gap.harvest"
+        self._phase_between = f"{obs_ns}.between_steps"
+        self._phase_gap_schedule = f"{obs_ns}.host_gap.schedule"
+        self._phase_gap_dispatch = f"{obs_ns}.host_gap.dispatch"
+        self._phase_wall_decode = f"{obs_ns}.tick_wall.decode_only"
+        self._phase_wall_prefill = f"{obs_ns}.tick_wall.with_prefill"
         self.cache_dtype = cache_dtype if cache_dtype is not None else _cache_dtype(model)
         # Priority classes + engine-local preemption (docs/serving.md): the
         # kill-switch disables the WHOLE feature — queue order reverts to
@@ -495,6 +537,27 @@ class ServingEngine:
         # parity pins run THROUGH them, recorder on and off).
         self._obs, self._owns_telemetry = resolve_recorder(telemetry)
         self._obs_on = self._obs.enabled
+        # every phase this engine can emit exists (empty) from here on: a
+        # reader tells "no chunk lane all run" (count 0) from a renamed span
+        self._obs.declare_phases((
+            self._span_tick, self._span_schedule, self._span_admit,
+            self._span_prefill, self._span_install, self._span_chunk,
+            self._span_finish, self._span_decode_dispatch,
+            self._span_sample_sync, self._span_harvest, self._span_evict,
+            self._phase_host_gap, self._phase_gap_harvest, self._phase_between,
+            self._phase_gap_schedule, self._phase_gap_dispatch,
+            self._phase_wall_decode, self._phase_wall_prefill,
+        ))
+        self._tick_no = 0  # the ``tick=`` argument of the per-tick spans
+        # recorder-clock readings the host gap is measured from: the last
+        # sync's return and the last harvest's end — None across a stretch in
+        # which the engine held no request (an idle gap is not the loop's
+        # cost). A gap in which a program compiled is not booked either
+        # (total_compilations moved since the sync: the router's compile-tick
+        # rule) — the watchdog's ``jax.compile.backend`` phase has that time.
+        self._gap_from: Optional[float] = None
+        self._harvest_end: Optional[float] = None
+        self._gap_compilations = 0
         # per-engine async-span category: request ids restart at 0 per engine,
         # so two engines sharing one caller-owned recorder would otherwise
         # collide on (cat, id) and corrupt the trace's lifetime joins
@@ -734,8 +797,6 @@ class ServingEngine:
         # slot -> in-flight split-prefill task (chunk phase; empty on the
         # classic one-shot path, where admission completes inside _admit)
         self._prefilling: Dict[int, _PrefillTask] = {}
-        self._span_chunk = f"{obs_ns}.prefill_chunk"
-        self._span_finish = f"{obs_ns}.prefill_finish"
         if self.chunked:
             self.metrics.set_chunked_prefill(self.prefill_chunk_tokens)
         if self.paged:
@@ -910,13 +971,14 @@ class ServingEngine:
             # Computed in the same program, harvested with the same device
             # sync as the tokens — detection costs no extra host round-trip,
             # and the token math is untouched (parity pins unaffected).
-            finite = jnp.all(jnp.isfinite(state.next_logits), axis=-1) | ~state.active
-            processed = process_logits_batched(
-                state.next_logits, state.temperature, state.top_k, state.top_p
-            )
-            keys = jax.vmap(jax.random.split)(state.rng)  # (B, 2, 2)
-            tok = sample_token_batched(keys[:, 1], processed, state.do_sample)
-            tok = jnp.where(state.active, tok, state.pad_id).astype(jnp.int32)
+            with jax.named_scope(TICK_SCOPES["sample"]):
+                finite = jnp.all(jnp.isfinite(state.next_logits), axis=-1) | ~state.active
+                processed = process_logits_batched(
+                    state.next_logits, state.temperature, state.top_k, state.top_p
+                )
+                keys = jax.vmap(jax.random.split)(state.rng)  # (B, 2, 2)
+                tok = sample_token_batched(keys[:, 1], processed, state.do_sample)
+                tok = jnp.where(state.active, tok, state.pad_id).astype(jnp.int32)
             # deterministic replay mux (router failover): a replaying slot's
             # token is FORCED to the known stream while the rng chain, cache
             # appends, and logits advance exactly as in the original run —
@@ -924,9 +986,10 @@ class ServingEngine:
             # all-False (every ordinary tick) this is a no-op select and the
             # f64 parity pins run through it.
             tok = jnp.where(use_forced, forced, tok).astype(jnp.int32)
-            logits_t, cache = model.apply(
-                dq(params), tok[:, None], cache, method=decode_method
-            )
+            with jax.named_scope(TICK_SCOPES["decode"]):
+                logits_t, cache = model.apply(
+                    dq(params), tok[:, None], cache, method=decode_method
+                )
             # inactive rows keep their (zeroed-at-release) rng/logits frozen:
             # freed-slot state stays canonical across steps, so pool dumps are
             # reproducible regardless of how long slots idle between requests
@@ -987,7 +1050,8 @@ class ServingEngine:
             # append starts from scale 0 and zeroes stale tenant bytes
             # (ops/paged_decode_kernel.reset_page_scales). Shared prefix
             # pages are never in ``ids`` — their scales belong to the cache.
-            return cache.replace(ca=cache.ca.reset_page_scales(ids))
+            with jax.named_scope(TICK_SCOPES["resets"]):
+                return cache.replace(ca=cache.ca.reset_page_scales(ids))
 
         @partial(jax.jit, donate_argnums=(1,))
         def chunk_kv(params_, cache, ids, offset, count, latent_start, table_row):
@@ -1001,14 +1065,15 @@ class ServingEngine:
             # one per rung); padded rows write zero payloads to the trash
             # page (PagedKVCache.write_rows).
             cb = ids.shape[1]
-            j = jnp.arange(cb)
-            pos = jnp.clip(offset + j, 0, model.max_seq_len - 1)[None, :]
-            latent_mask = ((offset + j) >= latent_start)[None, :]
-            k, v = model.apply(params, ids, pos, latent_mask,
-                               method=type(model).prefill_chunk_kv)
-            return cache.replace(
-                ca=cache.ca.write_rows(table_row, offset, count, k[0], v[0])
-            )
+            with jax.named_scope(TICK_SCOPES["chunk_lanes"]):
+                j = jnp.arange(cb)
+                pos = jnp.clip(offset + j, 0, model.max_seq_len - 1)[None, :]
+                latent_mask = ((offset + j) >= latent_start)[None, :]
+                k, v = model.apply(params, ids, pos, latent_mask,
+                                   method=type(model).prefill_chunk_kv)
+                return cache.replace(
+                    ca=cache.ca.write_rows(table_row, offset, count, k[0], v[0])
+                )
 
         @partial(jax.jit, donate_argnums=(1, 2))
         def prefill_finish(params_, cache, state, slot, table_row, ids, n, rng,
@@ -1018,13 +1083,14 @@ class ServingEngine:
             # prompt tokens against the slot's already-written pages, then
             # the install bookkeeping (table, ring offset, SA cache, slot
             # state activation). Fixed shapes throughout — ONE program ever.
-            req_logits, sa_src = model.apply(
-                params, ids, n, cache.ca, table_row,
-                method=type(model).prefill_finish_paged,
-            )
-            cache = cache.install_finish(slot, table_row, sa_src, n)
-            state = _install_state(state, slot, req_logits, rng,
-                                   temperature, top_k, top_p, do_sample, pad_id)
+            with jax.named_scope(TICK_SCOPES["finish_lanes"]):
+                req_logits, sa_src = model.apply(
+                    params, ids, n, cache.ca, table_row,
+                    method=type(model).prefill_finish_paged,
+                )
+                cache = cache.install_finish(slot, table_row, sa_src, n)
+                state = _install_state(state, slot, req_logits, rng,
+                                       temperature, top_k, top_p, do_sample, pad_id)
             return cache, state
 
         self._jit_ragged_tick = None
@@ -1050,14 +1116,19 @@ class ServingEngine:
                 # across phases' lanes, so batching lanes that the composed
                 # path dispatched serially is f64-identical (the parity
                 # tests pin it).
+                # The phases carry STABLE jax.named_scope names (TICK_SCOPES;
+                # metadata only — the program's instructions are unchanged):
+                # a profiler trace's device time is read per phase from the
+                # operations' op_name (benchmark/trace/gaps.py).
                 params = dq(params_)
 
                 if quantized:
-                    cache = jax.lax.cond(
-                        any_reset,
-                        lambda c: c.replace(ca=c.ca.reset_page_scales(reset_ids)),
-                        lambda c: c, cache,
-                    )
+                    with jax.named_scope(TICK_SCOPES["resets"]):
+                        cache = jax.lax.cond(
+                            any_reset,
+                            lambda c: c.replace(ca=c.ca.reset_page_scales(reset_ids)),
+                            lambda c: c, cache,
+                        )
 
                 def chunk_phase(cache):
                     def body(cache, lane):
@@ -1081,7 +1152,8 @@ class ServingEngine:
                     )
                     return cache
 
-                cache = jax.lax.cond(any_chunk, chunk_phase, lambda c: c, cache)
+                with jax.named_scope(TICK_SCOPES["chunk_lanes"]):
+                    cache = jax.lax.cond(any_chunk, chunk_phase, lambda c: c, cache)
 
                 def finish_phase(carry):
                     def body(carry, lane):
@@ -1108,32 +1180,36 @@ class ServingEngine:
                     )
                     return carry
 
-                cache, state = jax.lax.cond(
-                    any_finish, finish_phase, lambda a: a, (cache, state)
-                )
+                with jax.named_scope(TICK_SCOPES["finish_lanes"]):
+                    cache, state = jax.lax.cond(
+                        any_finish, finish_phase, lambda a: a, (cache, state)
+                    )
                 # serving.nan fault point, fused in the composed position
                 # (after finishes activate their logits, before decode reads)
-                state = jax.lax.cond(
-                    poison_slot >= 0,
-                    lambda s: s.replace(next_logits=s.next_logits.at[
-                        jnp.maximum(poison_slot, 0)].set(jnp.nan)),
-                    lambda s: s, state,
-                )
+                with jax.named_scope(TICK_SCOPES["poison"]):
+                    state = jax.lax.cond(
+                        poison_slot >= 0,
+                        lambda s: s.replace(next_logits=s.next_logits.at[
+                            jnp.maximum(poison_slot, 0)].set(jnp.nan)),
+                        lambda s: s, state,
+                    )
 
                 def decode_phase(args):
                     cache, state = args
                     # verbatim decode_step body (the composed oracle)
-                    finite = jnp.all(jnp.isfinite(state.next_logits), axis=-1) | ~state.active
-                    processed = process_logits_batched(
-                        state.next_logits, state.temperature, state.top_k, state.top_p
-                    )
-                    keys = jax.vmap(jax.random.split)(state.rng)
-                    tok = sample_token_batched(keys[:, 1], processed, state.do_sample)
-                    tok = jnp.where(state.active, tok, state.pad_id).astype(jnp.int32)
-                    tok = jnp.where(use_forced, forced, tok).astype(jnp.int32)
-                    logits_t, cache = model.apply(
-                        params, tok[:, None], cache, method=decode_method
-                    )
+                    with jax.named_scope(TICK_SCOPES["sample"]):
+                        finite = jnp.all(jnp.isfinite(state.next_logits), axis=-1) | ~state.active
+                        processed = process_logits_batched(
+                            state.next_logits, state.temperature, state.top_k, state.top_p
+                        )
+                        keys = jax.vmap(jax.random.split)(state.rng)
+                        tok = sample_token_batched(keys[:, 1], processed, state.do_sample)
+                        tok = jnp.where(state.active, tok, state.pad_id).astype(jnp.int32)
+                        tok = jnp.where(use_forced, forced, tok).astype(jnp.int32)
+                    with jax.named_scope(TICK_SCOPES["decode"]):
+                        logits_t, cache = model.apply(
+                            params, tok[:, None], cache, method=decode_method
+                        )
                     state = state.replace(
                         next_logits=jnp.where(state.active[:, None], logits_t[:, -1],
                                               state.next_logits),
@@ -1344,8 +1420,6 @@ class ServingEngine:
         if self._alloc_blocked_id != request.request_id:
             self._alloc_blocked_id = request.request_id
             self.metrics.record_alloc_failure(request.request_id, need, self._pool.free_pages)
-            if self._obs_on:
-                self._obs.counter_inc(f"{self._obs_ns}.alloc_failures")
         return False
 
     def _shared_pages_in_use(self) -> int:
@@ -1533,7 +1607,6 @@ class ServingEngine:
         self._journal_note_terminal(request, RequestStatus.REJECTED, reason)
         self.metrics.record_reject(request.request_id, reason)
         if self._obs_on:
-            self._obs.counter_inc(f"{self._obs_ns}.rejected")
             self._obs.async_end(self._span_cat, request.request_id,
                                 status="rejected", reason=reason)
         return request
@@ -1560,6 +1633,12 @@ class ServingEngine:
     def _admit(self, slot: int, request: ServedRequest) -> None:
         cfg = request.config
         t0 = time.perf_counter()
+        # the queue wait ends HERE on both admission paths: the slot is this
+        # request's from now on (the split path claims its pages below)
+        request.slot_claimed_at = t0
+        if self._obs_on:
+            self._obs.async_instant(self._span_cat, request.request_id,
+                                    "slot_claimed", slot=slot)
         n = int(request.prompt_ids.size)
         bucket = self._bucket_for(n)
         pages: Optional[int] = None
@@ -1606,10 +1685,10 @@ class ServingEngine:
             table_row = np.zeros((self._pages_per_slot,), np.int32)
             table_row[: len(page_ids)] = page_ids  # trash-padded reservation
         self._tick_programs += 2  # classic path: prefill + install programs
-        with self._obs.span(self._span_prefill):
+        with self._obs.span(self._span_prefill, request_id=request.request_id):
             ids, pad_mask = self._bucket_prompt(request, bucket)
             req_logits, req_cache = self._jit_prefill(self.params, ids, pad_mask, bucket=bucket)
-        with self._obs.span(self._span_install):
+        with self._obs.span(self._span_install, request_id=request.request_id):
             # greedy requests ignore temperature/top_k/top_p (argmax survives
             # scaling and filtering): install the neutral encodings so any
             # user value — including temperature <= 0 — shares the one
@@ -1663,9 +1742,10 @@ class ServingEngine:
             self._replay_slots[slot] = request
         request.admitted_at = now
         self.metrics.record_admit(
-            request.request_id, slot, wait_s=now - request.enqueued_at,
+            request.request_id, slot, wait_s=t0 - request.enqueued_at,
             prefill_s=now - t0, bucket=bucket, pages=pages,
             priority=request.priority, preempted_replay=resumed,
+            prompt_tokens=n,
         )
         if self.paged:
             self.metrics.set_page_pool(
@@ -1730,8 +1810,6 @@ class ServingEngine:
         if shared:
             self.metrics.record_prefix_hit(request.request_id, shared,
                                            shared_tokens)
-            if self._obs_on:
-                self._obs.counter_inc(f"{self._obs_ns}.prefix_hits")
         self.metrics.set_page_pool(
             self._pool.num_pages - self._pool.reserved, self._pool.pages_in_use
         )
@@ -1755,24 +1833,26 @@ class ServingEngine:
             c = min(task.chunk_budget, remaining)
             self._tick_chunk_items += 1
             t0 = time.perf_counter()
-            if self.ragged:
-                # descriptor lane, FIXED row capacity — chunk shapes stop
-                # riding the bucket ladder (chunk math is row-independent and
-                # write_rows routes pad rows to the trash page, so cap-vs-
-                # ladder padding is value-identical on real rows)
-                ids = np.full((self._ragged_chunk_cap,),
-                              request.config.pad_token_id, np.int32)
-                ids[:c] = request.prompt_ids[task.next_pos: task.next_pos + c]
-                self._tick_chunks.append(
-                    (slot, ids, task.next_pos, c,
-                     task.n - self._latents, task.table_row)
-                )
-            else:
-                cb = self._bucket_for(c)  # chunk program shapes ride the ladder
-                ids = np.full((1, cb), request.config.pad_token_id, np.int32)
-                ids[0, :c] = request.prompt_ids[task.next_pos: task.next_pos + c]
-                self._tick_programs += 1
-                with self._obs.span(self._span_chunk):
+            # the chunk's host work: packing a descriptor lane (ragged), or
+            # packing + dispatching the chunk program (composed)
+            with self._obs.span(self._span_chunk, request_id=request.request_id):
+                if self.ragged:
+                    # descriptor lane, FIXED row capacity — chunk shapes stop
+                    # riding the bucket ladder (chunk math is row-independent
+                    # and write_rows routes pad rows to the trash page, so
+                    # cap-vs-ladder padding is value-identical on real rows)
+                    ids = np.full((self._ragged_chunk_cap,),
+                                  request.config.pad_token_id, np.int32)
+                    ids[:c] = request.prompt_ids[task.next_pos: task.next_pos + c]
+                    self._tick_chunks.append(
+                        (slot, ids, task.next_pos, c,
+                         task.n - self._latents, task.table_row)
+                    )
+                else:
+                    cb = self._bucket_for(c)  # chunk program shapes ride the ladder
+                    ids = np.full((1, cb), request.config.pad_token_id, np.int32)
+                    ids[0, :c] = request.prompt_ids[task.next_pos: task.next_pos + c]
+                    self._tick_programs += 1
                     self._cache = self._jit_chunk_kv(
                         self.params, self._cache, jnp.asarray(ids),
                         jnp.asarray(task.next_pos, jnp.int32),
@@ -1811,33 +1891,35 @@ class ServingEngine:
         """The split admission's FINISH: one fixed-shape program computes the
         latents against the slot's pages, installs the page table / ring
         offset / SA cache, and activates the slot's decode state — the
-        moment this request is ADMITTED in the metrics sense (its TTFT
-        includes the chunk phase, honestly)."""
+        moment this request is DECODE-READY (``admitted_at``; its queue wait
+        ended at ``slot_claimed_at``, every chunk tick before this one)."""
         request = task.request
         cfg = request.config
-        ids_latent = np.asarray(
-            request.prompt_ids[task.n - self._latents:], np.int32
-        )[None, :]
-        sampling = (
-            float(cfg.temperature) if cfg.do_sample else 1.0,
-            int(cfg.top_k) if (cfg.do_sample and cfg.top_k) else 0,
-            float(cfg.top_p) if (cfg.do_sample and cfg.top_p is not None) else 1.0,
-            bool(cfg.do_sample),
-            int(cfg.pad_token_id),
-        )
         self._tick_finish_items += 1
-        if self.ragged:
-            # descriptor lane — the fused program's finish phase runs after
-            # every chunk lane (this slot's tail chunk included) and before
-            # decode, so the newly active slot decodes THIS tick, exactly
-            # like the composed path
-            self._tick_finishes.append(
-                (slot, task.table_row, ids_latent[0], task.n,
-                 np.asarray(request.rng), sampling)
+        # the finish's host work: packing a descriptor lane (ragged), or
+        # packing + dispatching the finish program (composed)
+        with self._obs.span(self._span_finish, request_id=request.request_id):
+            ids_latent = np.asarray(
+                request.prompt_ids[task.n - self._latents:], np.int32
+            )[None, :]
+            sampling = (
+                float(cfg.temperature) if cfg.do_sample else 1.0,
+                int(cfg.top_k) if (cfg.do_sample and cfg.top_k) else 0,
+                float(cfg.top_p) if (cfg.do_sample and cfg.top_p is not None) else 1.0,
+                bool(cfg.do_sample),
+                int(cfg.pad_token_id),
             )
-        else:
-            self._tick_programs += 1
-            with self._obs.span(self._span_finish):
+            if self.ragged:
+                # descriptor lane — the fused program's finish phase runs
+                # after every chunk lane (this slot's tail chunk included)
+                # and before decode, so the newly active slot decodes THIS
+                # tick, exactly like the composed path
+                self._tick_finishes.append(
+                    (slot, task.table_row, ids_latent[0], task.n,
+                     np.asarray(request.rng), sampling)
+                )
+            else:
+                self._tick_programs += 1
                 self._cache, self._state = self._jit_prefill_finish(
                     self.params, self._cache, self._state, slot,
                     jnp.asarray(task.table_row), jnp.asarray(ids_latent),
@@ -1858,6 +1940,7 @@ class ServingEngine:
             preempted_replay=task.resumed,
             chunks=task.chunks if self.chunked else None,
             shared_pages=task.shared_pages or None,
+            prompt_tokens=task.n,
         )
         if self._prefix_cache is not None:
             self.metrics.set_prefix_cache(
@@ -2131,7 +2214,6 @@ class ServingEngine:
             priority=request.priority,
         )
         if self._obs_on:
-            self._obs.counter_inc(f"{self._obs_ns}.preemptions")
             self._obs.async_instant(self._span_cat, request.request_id,
                                     "preempted", by=preemptor.request_id,
                                     emitted=len(request.output_ids))
@@ -2311,9 +2393,6 @@ class ServingEngine:
             truncated=state.truncated, dropped_records=state.dropped_records,
             generation=state.generation,
         )
-        if self._obs_on:
-            self._obs.counter_inc(f"{self._obs_ns}.sessions_recovered",
-                                  len(handles))
         return {
             "sessions": len(handles),
             "replayed_tokens": replayed,
@@ -2500,6 +2579,10 @@ class ServingEngine:
         single-engine tick, unchanged."""
         if self._pending_harvest is not None:
             raise RuntimeError("step_harvest() must run before the next step_dispatch()")
+        obs = self._obs
+        # the entry's clock reading: the caller's own time (between_steps)
+        # ends and the tick and its schedule phase begin here
+        t_entry = obs.now() if self._obs_on else None
         faults.fire_serving_tick_delay()  # injected stall (deadline-overrun chaos)
         if self._preempt_requested and not self._draining:
             # signal-initiated graceful drain: admission closes and the
@@ -2508,11 +2591,15 @@ class ServingEngine:
             self.preempted = True
             self._begin_drain()
         # tick span as a begin/end pair: it brackets both halves, which the
-        # obs core pairs per (thread, name) — same "X" event as the old
-        # with-block, now router-interleavable. An exception anywhere in the
-        # half must still balance the span (a dead replica's dangling begin
-        # would sit in the recorder's open-span stack forever).
-        self._obs.span_begin(self._span_tick)
+        # obs core pairs per (thread, name) — router-interleavable. An
+        # exception anywhere in the half must still balance the spans (a dead
+        # replica's dangling begin would sit in the recorder's open-span
+        # stack forever). ``schedule`` is the tick's first phase: everything
+        # from this method's entry to the start of the dispatch.
+        self._tick_no += 1
+        tick = self._tick_no
+        obs.span_begin(self._span_tick, at=t_entry, tick=tick)
+        obs.span_begin(self._span_schedule, at=t_entry, tick=tick)
         try:
             # per-tick program/work accounting (serving-metrics/v11
             # ragged_tick block). Buffers are re-cleared defensively: they
@@ -2548,7 +2635,7 @@ class ServingEngine:
                 # mid-generation work, so they re-admit as capacity frees and
                 # FINISH — drain's "in-flight work is finished, not dropped"
                 # contract covers a victim parked by preemption
-                with self._obs.span(self._span_admit):
+                with obs.span(self._span_admit, tick=tick):
                     can_admit = self._can_admit_paged if self.paged else None
                     # chunk-aware admission bound: a chunked engine schedules
                     # at most max_prefill_slots concurrent chunk streams, so
@@ -2566,10 +2653,10 @@ class ServingEngine:
             self._maybe_inject_nan()
             occupied = list(self.scheduler.occupied())
             if self._obs_on:
-                self._obs.gauge_set(f"{self._obs_ns}.active_slots", len(occupied))
-                self._obs.gauge_set(f"{self._obs_ns}.queue_depth", self.scheduler.queue_depth)
+                obs.gauge_set(f"{self._obs_ns}.active_slots", len(occupied))
+                obs.gauge_set(f"{self._obs_ns}.queue_depth", self.scheduler.queue_depth)
                 if self.paged:
-                    self._obs.gauge_set(f"{self._obs_ns}.pages_in_use", self._pool.pages_in_use)
+                    obs.gauge_set(f"{self._obs_ns}.pages_in_use", self._pool.pages_in_use)
             # slots mid-split-prefill hold no decode state yet (their
             # SlotState row is inactive, their in-cache table trash): they
             # are claimed for every scheduler purpose but must not be
@@ -2583,7 +2670,8 @@ class ServingEngine:
                     # still a dispatching tick for the programs-per-tick view
                     self.metrics.record_tick_dispatch(
                         self._tick_programs, 0, 0, 0, 0.0)
-                self._obs.span_end(self._span_tick)
+                obs.span_end(self._span_tick, at=obs.span_end(self._span_schedule))
+                self._gap_from = None  # nothing dispatched: no gap to close
                 return False
 
             if self._replay_slots:
@@ -2596,21 +2684,25 @@ class ServingEngine:
             else:
                 forced, use_forced = self._forced_none, self._use_forced_none
             t0 = time.perf_counter()
+            # schedule ends where the dispatch begins: one clock reading, no seam
+            t_dispatch = obs.span_end(self._span_schedule)
+            obs.span_begin(self._span_decode_dispatch, at=t_dispatch, tick=tick)
             if self.ragged:
-                with self._obs.span(self._span_decode_dispatch):
-                    # the tick's ONE program: resets + chunks + finishes +
-                    # poison + decode, fused (docs/serving.md "Unified
-                    # ragged tick")
-                    tok, finite = self._dispatch_ragged(bool(occupied),
-                                                        forced, use_forced)
+                # the tick's ONE program: resets + chunks + finishes +
+                # poison + decode, fused (docs/serving.md "Unified
+                # ragged tick"); the span holds the descriptor build
+                tok, finite = self._dispatch_ragged(bool(occupied),
+                                                    forced, use_forced)
             else:
                 self._tick_programs += 1
-                with self._obs.span(self._span_decode_dispatch):
-                    # dispatch only — the jit call returns before the device step
-                    # finishes; the device cost lands in the sample-sync at harvest
-                    tok, finite, self._cache, self._state = self._jit_decode(
-                        self.params, self._cache, self._state, forced, use_forced
-                    )
+                # dispatch only — the jit call returns before the device step
+                # finishes; the device cost lands in the sample-sync at harvest
+                tok, finite, self._cache, self._state = self._jit_decode(
+                    self.params, self._cache, self._state, forced, use_forced
+                )
+            t_dispatched = obs.span_end(self._span_decode_dispatch)
+            if self._gap_from is not None:
+                self._book_host_gap(t_entry, t_dispatch, t_dispatched)
             self.metrics.record_tick_dispatch(
                 self._tick_programs, self._tick_chunk_items,
                 self._tick_finish_items, len(occupied), self._tick_build_s,
@@ -2619,13 +2711,39 @@ class ServingEngine:
                 # ragged tick that only carried prefill work: nothing to
                 # harvest (the finish lanes activate slots for NEXT tick's
                 # decode when the tail chunk and finish split across ticks)
-                self._obs.span_end(self._span_tick)
+                obs.span_end(self._span_tick)
                 return False
         except BaseException:
-            self._obs.span_end(self._span_tick)
+            self._end_tick_spans()
             raise
-        self._pending_harvest = (occupied, tok, finite, t0)
+        # a tick with a chunk or finish lane charges every decoding slot its
+        # prefill work: its wall time is booked apart (tick_wall.with_prefill)
+        with_prefill = bool(self._tick_chunk_items or self._tick_finish_items)
+        self._pending_harvest = (occupied, tok, finite, t0, t_dispatch, with_prefill)
         return True
+
+    def _book_host_gap(self, t_entry: float, t_dispatch: float, t_dispatched: float) -> None:
+        """The previous sync's return to this dispatch's return — the stretch
+        in which the device had nothing of this engine's to run — and its
+        four parts, which tile it: the harvest after that sync, the caller's
+        time between the two steps, this tick's schedule phase and its
+        dispatch. Booked for steady-state gaps only."""
+        obs, t_sync, t_harvested = self._obs, self._gap_from, self._harvest_end
+        self._gap_from = None
+        if self.total_compilations != self._gap_compilations:
+            return  # a program compiled inside this gap: the watchdog's time to report
+        obs.observe(self._phase_host_gap, t_dispatched - t_sync)
+        obs.observe(self._phase_gap_harvest, t_harvested - t_sync)
+        obs.observe(self._phase_between, t_entry - t_harvested)
+        obs.observe(self._phase_gap_schedule, t_dispatch - t_entry)
+        obs.observe(self._phase_gap_dispatch, t_dispatched - t_dispatch)
+
+    def _end_tick_spans(self) -> None:
+        """Balance whatever tick spans a dying half left open (an unmatched
+        end is ignored), innermost first."""
+        for name in (self._span_decode_dispatch, self._span_schedule,
+                     self._span_sample_sync, self._span_harvest, self._span_tick):
+            self._obs.span_end(name)
 
     def step_harvest(self) -> bool:
         """Second half of a tick: the tick's ONE device sync on the dispatched
@@ -2644,15 +2762,28 @@ class ServingEngine:
         except BaseException:
             # balance the tick span opened by step_dispatch even when the
             # sync/evict path dies (the replica-loss domain)
-            self._obs.span_end(self._span_tick)
+            self._end_tick_spans()
             raise
 
     def _harvest(self, pending) -> bool:
-        occupied, tok, finite, t0 = pending
-        with self._obs.span(self._span_sample_sync):
-            tok = np.asarray(tok)  # blocks: the step's ONE device sync point
-            finite = np.asarray(finite)  # already on host after the sync above
-        decode_s = time.perf_counter() - t0
+        occupied, tok, finite, t0, t_dispatch, with_prefill = pending
+        obs, tick = self._obs, self._tick_no
+        obs.span_begin(self._span_sample_sync, tick=tick)
+        tok = np.asarray(tok)  # blocks: the step's ONE device sync point
+        finite = np.asarray(finite)  # already on host after the sync above
+        t_sync = obs.span_end(self._span_sample_sync)
+        # harvest: everything from the sync's return to the end of the step's
+        # host work (the device idles from here until the next dispatch)
+        obs.span_begin(self._span_harvest, at=t_sync, tick=tick)
+        # the ONE host time of this tick's tokens: every slot's first-token
+        # and inter-token stamps below share it
+        now = time.perf_counter()
+        decode_s = now - t0
+        if self._obs_on:
+            obs.observe(self._phase_wall_prefill if with_prefill
+                        else self._phase_wall_decode, t_sync - t_dispatch)
+            self._gap_from = t_sync
+            self._gap_compilations = self.total_compilations
         # tokens_generated counts USEFUL tokens only: a quarantined slot's
         # garbage sample is never emitted, and a REPLAYED token was already
         # delivered once by the engine that originally generated it — counting
@@ -2665,7 +2796,7 @@ class ServingEngine:
         )
         self.metrics.record_decode_step(len(occupied), decode_s, tokens=useful)
 
-        with self._obs.span(self._span_evict):
+        with obs.span(self._span_evict, tick=tick):
             for slot, request in occupied:
                 if self.scheduler.occupant(slot) is not request:
                     # the request left its slot between dispatch and harvest
@@ -2734,6 +2865,20 @@ class ServingEngine:
                         del self._replay_slots[slot]
                 else:
                     request.output_ids.append(token)
+                    # a request's life, stamped where the token leaves the
+                    # engine (serving-metrics/v13)
+                    last = request.last_token_at
+                    request.last_token_at = now
+                    if last is not None:
+                        self.metrics.record_token_gap(now - last)
+                    elif request.first_token_at is None:
+                        request.first_token_at = now
+                        self.metrics.record_first_token(
+                            request.request_id, now - request.slot_claimed_at,
+                            now - request.enqueued_at)
+                        if self._obs_on:
+                            obs.async_instant(self._span_cat, request.request_id,
+                                              "first_token")
                     if self.journal is not None:
                         # only FREE-RUNNING emissions are journaled: a
                         # replayed token is already covered by its accept
@@ -2754,13 +2899,21 @@ class ServingEngine:
             # growth past the churn-never-recompiles budgets is flagged
             # (counter compile.unexpected + instant trace event), never raised
             self.watchdog.check()
-        self._obs.span_end(self._span_tick)
         # the tick's ONE journal write: admissions + emitted tokens +
         # terminal outcomes, buffered above, land together (flushed; fsynced
         # only under fsync="always" — docs/serving.md "Request journal")
         self._journal_flush()
+        has_work = self.scheduler.has_work
+        self._harvest_end = obs.span_end(self._span_harvest)
+        obs.span_end(self._span_tick, at=self._harvest_end)
+        if not has_work:
+            # the engine holds no request: whatever passes until the next
+            # one arrives is not the loop's cost
+            self._gap_from = None
+        # after the tick span: the terminal close of a signal-initiated drain
+        # writes the recorder's trace, which must hold this last tick
         self._maybe_flush_preempted()
-        return self.scheduler.has_work
+        return has_work
 
     def step(self) -> bool:
         """One scheduler tick: expire deadlines, admit queued requests into

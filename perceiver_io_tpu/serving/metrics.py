@@ -147,6 +147,23 @@ Schema history:
     ``respawn`` events (one per supervisor respawn) and ``rpc_retry``
     events (one per transport retry, with op/attempt/error/delay). The
     reader normalizes pre-v12 snapshots with ``None``.
+  * ``serving-metrics/v13`` — the request-life schema (docs/observability.md
+    "A request's life"): the ENGINE stamps when a slot was claimed and when a
+    request's first free-running token left it (``ServedRequest
+    .slot_claimed_at`` / ``.first_token_at``), and every engine snapshot
+    reports, over the latency window, ``first_token_s`` (first token minus
+    slot claim: the prefill layer's latency, chunk ticks included),
+    ``ttft_s`` (first token minus enqueue) and ``inter_token_s`` (gap
+    between one request's successive tokens; one stamp per tick, taken right
+    after the tick's sync), plus the lifetime counters
+    ``prefix_hit_tokens`` (prompt tokens served from shared prefix pages)
+    and ``prompt_tokens_admitted`` (prompt tokens of every decode-ready
+    admission). ``queue_wait_s`` now spans enqueue to SLOT CLAIM on both
+    admission paths (the one-shot path used to stamp it after its prefill
+    dispatch). The stream gains ``first_token`` events. Router snapshots
+    carry the three windows as ``None`` (measured per engine) and sum the two
+    counters over their replica sections. No reader back-fill: a pre-v13
+    snapshot simply lacks the keys.
 """
 
 from __future__ import annotations
@@ -159,7 +176,7 @@ from typing import Deque, Dict, List, Optional
 
 import numpy as np
 
-SCHEMA = "serving-metrics/v12"
+SCHEMA = "serving-metrics/v13"
 KNOWN_SCHEMAS = (
     "serving-metrics/v1",
     "serving-metrics/v2",
@@ -173,6 +190,7 @@ KNOWN_SCHEMAS = (
     "serving-metrics/v10",
     "serving-metrics/v11",
     "serving-metrics/v12",
+    "serving-metrics/v13",
 )
 _V3_COUNTERS = ("rejected", "timed_out", "failed")
 _V4_FIELDS = ("failovers", "shed_infeasible", "breaker_transitions")
@@ -425,6 +443,12 @@ class EngineMetrics(_JsonlMetrics):
     _queue_waits: Deque[float] = field(default_factory=lambda: deque(maxlen=LATENCY_WINDOW))
     _prefill_times: Deque[float] = field(default_factory=lambda: deque(maxlen=LATENCY_WINDOW))
     _decode_times: Deque[float] = field(default_factory=lambda: deque(maxlen=LATENCY_WINDOW))
+    # a request's life, stamped by the engine (serving-metrics/v13)
+    prefix_hit_tokens: int = 0  # prompt tokens served from shared prefix pages
+    prompt_tokens_admitted: int = 0  # prompt tokens of decode-ready admissions
+    _first_token_times: Deque[float] = field(default_factory=lambda: deque(maxlen=LATENCY_WINDOW))
+    _ttfts: Deque[float] = field(default_factory=lambda: deque(maxlen=LATENCY_WINDOW))
+    _inter_token_gaps: Deque[float] = field(default_factory=lambda: deque(maxlen=LATENCY_WINDOW))
     _jsonl_file: Optional[object] = field(default=None, repr=False)
     _closed: bool = field(default=False, repr=False)
 
@@ -443,8 +467,13 @@ class EngineMetrics(_JsonlMetrics):
         bucket: Optional[int] = None, pages: Optional[int] = None,
         priority: int = 0, preempted_replay: bool = False,
         chunks: Optional[int] = None, shared_pages: Optional[int] = None,
+        prompt_tokens: int = 0,
     ) -> None:
+        """One request became decode-ready. ``wait_s`` spans enqueue to SLOT
+        CLAIM, ``prefill_s`` slot claim to decode-ready (dispatch time on the
+        one-shot path; every chunk tick on the split path)."""
         self.requests_admitted += 1
+        self.prompt_tokens_admitted += prompt_tokens
         self.prefills += 1
         self.prefill_seconds += prefill_s
         self.queue_depth = max(self.queue_depth - 1, 0)
@@ -486,6 +515,7 @@ class EngineMetrics(_JsonlMetrics):
         request retained ``shared_pages`` cached pages covering
         ``shared_tokens`` prompt tokens — KV it neither recomputes nor
         re-stores."""
+        self.prefix_hit_tokens += shared_tokens
         self._emit("prefix_hit", request_id=request_id,
                    shared_pages=shared_pages, shared_tokens=shared_tokens)
 
@@ -617,6 +647,21 @@ class EngineMetrics(_JsonlMetrics):
         self._emit("decode_step", active_slots=active_slots,
                    seconds=round(seconds, 6), tokens=tokens)
 
+    def record_first_token(self, request_id: int, first_token_s: float,
+                           ttft_s: float) -> None:
+        """A request's first free-running token left the engine
+        (serving-metrics/v13): ``first_token_s`` since its slot was claimed
+        (the prefill layer's latency), ``ttft_s`` since it was enqueued."""
+        self._first_token_times.append(first_token_s)
+        self._ttfts.append(ttft_s)
+        self._emit("first_token", request_id=request_id,
+                   first_token_s=round(first_token_s, 6), ttft_s=round(ttft_s, 6))
+
+    def record_token_gap(self, seconds: float) -> None:
+        """Gap between two successive tokens of one request (no event: this
+        runs once per occupied slot per tick)."""
+        self._inter_token_gaps.append(seconds)
+
     def record_finish(
         self, request_id: int, slot: int, new_tokens: int, reason: str,
         status: str = "finished",
@@ -710,6 +755,12 @@ class EngineMetrics(_JsonlMetrics):
             "queue_wait_s": _latency_dict(self._queue_waits),
             "prefill_s": _latency_dict(self._prefill_times),
             "decode_step_s": _latency_dict(self._decode_times),
+            # v13: a request's life as the engine stamps it
+            "first_token_s": _latency_dict(self._first_token_times),
+            "ttft_s": _latency_dict(self._ttfts),
+            "inter_token_s": _latency_dict(self._inter_token_gaps),
+            "prefix_hit_tokens": self.prefix_hit_tokens,
+            "prompt_tokens_admitted": self.prompt_tokens_admitted,
             # v6 (docs/serving.md, priority section): preemption counters +
             # per-class queue-wait percentiles over the latency window
             "preemptions": self.preemptions,
@@ -1025,6 +1076,15 @@ class RouterMetrics(_JsonlMetrics):
                 s.get("preempted_replays") or 0 for s in replicas.values()
             ),
             "queue_wait_by_priority": None,
+            # v13: a request's life is stamped per engine (windows None
+            # here); the two token counters sum over the replica sections
+            "first_token_s": None,
+            "ttft_s": None,
+            "inter_token_s": None,
+            "prefix_hit_tokens": sum(s.get("prefix_hit_tokens") or 0 for s in replicas.values()),
+            "prompt_tokens_admitted": sum(
+                s.get("prompt_tokens_admitted") or 0 for s in replicas.values()
+            ),
             # pools, journals, prefix caches, chunked admission, and the
             # quantized-serving modes are per-engine: the embedded replica
             # sections carry the real gauges, the router itself truthfully

@@ -974,8 +974,6 @@ class ServingRouter:
             est = self._estimate_completion_s(config.max_new_tokens)
             if est is not None and est > routed.deadline_s:
                 self.metrics.record_shed(routed.request_id, routed.deadline_s, est)
-                if self._obs_on:
-                    self._obs.counter_inc("router.shed_infeasible")
                 return self._refuse(routed, "shed_infeasible")
         self._dispatch(routed)
         return routed
@@ -1397,7 +1395,6 @@ class ServingRouter:
                     emitted_tokens=len(routed._salvaged),
                 )
                 if self._obs_on:
-                    self._obs.counter_inc("router.migrations")
                     self._obs.async_instant("router.request",
                                             routed.request_id, "migrate",
                                             src=src, dst=dst)
@@ -1464,8 +1461,6 @@ class ServingRouter:
         if self.restart_in_progress:
             return True
         self._restart_queue = [r.rid for r in self.replicas if not r.retired]
-        if self._obs_on:
-            self._obs.counter_inc("router.rolling_restarts")
         return True
 
     def rolling_restart(self, max_steps: Optional[int] = None) -> bool:
@@ -1562,7 +1557,6 @@ class ServingRouter:
                                     leftover_sessions=leftovers,
                                     tick=self._tick)
         if self._obs_on:
-            self._obs.counter_inc("router.recycles")
             self._obs.instant("router.recycle", replica=r.rid,
                               sessions_moved=self._recycle_moved,
                               leftovers=leftovers)
@@ -1676,7 +1670,6 @@ class ServingRouter:
         self.metrics.set_fleet_gauges(len(active), self.restart_in_progress,
                                       self._primary_version)
         if self._obs_on:
-            self._obs.counter_inc("router.deploys")
             self._obs.instant("router.deploy", version=version,
                               fraction=float(fraction))
         return version
@@ -1699,7 +1692,6 @@ class ServingRouter:
         self._prune_versions()
         self.metrics.record_rollback(version, base)
         if self._obs_on:
-            self._obs.counter_inc("router.rollbacks")
             self._obs.instant("router.rollback", from_version=version,
                               to_version=base)
         return True
@@ -1868,8 +1860,6 @@ class ServingRouter:
         self.metrics.record_autoscale("up", rid,
                                       active=len(self._active_replicas()),
                                       load=load, tick=self._tick)
-        if self._obs_on:
-            self._obs.counter_inc("router.scale_ups")
 
     def _scale_down(self, load: int) -> None:
         """Shed capacity through the SAME migrate-and-drain path a recycle
@@ -1907,8 +1897,6 @@ class ServingRouter:
                 "down", r.rid, active=len(self._active_replicas()) - 1,
                 load=load, tick=self._tick,
             )
-            if self._obs_on:
-                self._obs.counter_inc("router.scale_downs")
             self._start_recycle(r, mode="retire")
             return
 
@@ -1942,7 +1930,6 @@ class ServingRouter:
         old, r.breaker = r.breaker, new
         self.metrics.record_breaker(r.rid, old, new, self._tick)
         if self._obs_on:
-            self._obs.counter_inc(f"router.breaker.{old}->{new}")
             self._obs.instant("router.breaker", replica=r.rid, transition=f"{old}->{new}")
 
     def _open_breaker(self, r: _Replica, cause: str) -> None:
@@ -2100,7 +2087,6 @@ class ServingRouter:
         self.metrics.record_respawn(r.rid, sessions=recovered,
                                     tick=self._tick)
         if self._obs_on:
-            self._obs.counter_inc("router.worker_respawns")
             self._obs.instant("router.respawn", replica=r.rid,
                               sessions=recovered)
         return True
@@ -2208,7 +2194,6 @@ class ServingRouter:
                                          emitted_tokens=len(routed._salvaged),
                                          failover_n=routed.failovers)
             if self._obs_on:
-                self._obs.counter_inc("router.failovers")
                 self._obs.async_instant("router.request", routed.request_id,
                                         "failover", from_replica=r.rid,
                                         emitted=len(routed._salvaged))
@@ -2273,8 +2258,6 @@ class ServingRouter:
             version=routed.version if self._fleet_ops else None,
         )
         if self._obs_on:
-            if status is RequestStatus.REJECTED:
-                self._obs.counter_inc("router.rejected")
             self._obs.async_end("router.request", routed.request_id,
                                 status=status.value, reason=reason,
                                 new_tokens=len(routed.output_ids),

@@ -48,12 +48,15 @@ def phase_table(phases: Dict[str, Dict], title: str) -> List[str]:
         lines.append("(no phases recorded)")
         return lines
     total_known = sum(p.get("total_s", 0.0) for p in phases.values())
-    header = f"{'phase':<28} {'count':>7} {'total_s':>9} {'mean_ms':>9} {'p50_ms':>8} {'p95_ms':>8} {'max_ms':>8} {'share':>6}"
+    # self_s: the phase's time minus what its direct child spans cover
+    # (telemetry-summary/v2; older summaries have no parents: self = total)
+    header = f"{'phase':<28} {'count':>7} {'total_s':>9} {'self_s':>9} {'mean_ms':>9} {'p50_ms':>8} {'p95_ms':>8} {'max_ms':>8} {'share':>6}"
     lines.append(header)
     for name, p in sorted(phases.items(), key=lambda kv: -kv[1].get("total_s", 0.0)):
         share = p.get("total_s", 0.0) / total_known if total_known > 0 else 0.0
         lines.append(
             f"{name:<28} {p.get('count', 0):>7} {p.get('total_s', 0.0):>9.4f} "
+            f"{p.get('self_total_s', p.get('total_s', 0.0)):>9.4f} "
             f"{p.get('mean_s', 0.0) * 1e3:>9.3f} {p.get('p50_s', 0.0) * 1e3:>8.3f} "
             f"{p.get('p95_s', 0.0) * 1e3:>8.3f} {p.get('max_s', 0.0) * 1e3:>8.3f} "
             f"{share:>6.1%}"

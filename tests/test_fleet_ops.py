@@ -95,7 +95,7 @@ def test_migrate_token_identity_greedy_and_sampled(x64):
         assert h.ok and h.failovers == 0
         assert h.result().tolist() == want, "migration must be token-invisible"
     snap = router.snapshot()
-    assert snap["schema"] == "serving-metrics/v12"
+    assert snap["schema"] == "serving-metrics/v13"
     assert snap["fleet_ops"]["migrations"] == 2
     assert snap["failovers"] == 0 and snap["breaker_transitions"] == {}
     for r in router.replicas:
@@ -567,7 +567,7 @@ def test_fleet_ops_metrics_v10_jsonl_and_reader(tmp_path):
     assert {"migrate", "recycle", "deploy", "autoscale", "rollback",
             "snapshot"} <= events
     snap = got["snapshots"][0]
-    assert snap["schema"] == "serving-metrics/v12"
+    assert snap["schema"] == "serving-metrics/v13"
     fo = snap["fleet_ops"]
     assert fo["migrations"] == 1 and fo["recycles"] == 1
     assert fo["scale_ups"] == 1 and fo["scale_downs"] == 0

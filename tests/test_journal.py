@@ -390,7 +390,7 @@ def test_metrics_v7_journal_gauges_and_recovery_event(setup, tmp_path):
     h = engine.submit([1, 2, 3], max_new_tokens=3)
     engine.run_until_drained(max_steps=100)
     snap = engine.metrics.write_snapshot()
-    assert snap["schema"] == "serving-metrics/v12"
+    assert snap["schema"] == "serving-metrics/v13"
     j = snap["journal"]
     assert j["records_appended"] >= 2 and j["bytes_written"] > 0
     assert j["fsyncs"] >= 1  # the accept fsync under the default policy

@@ -574,7 +574,7 @@ def test_metrics_v9_sections_and_reader_backcompat(setup, tmp_path):
     from perceiver_io_tpu.serving import load_metrics_jsonl
     from perceiver_io_tpu.serving.metrics import SCHEMA
 
-    assert SCHEMA == "serving-metrics/v12"
+    assert SCHEMA == "serving-metrics/v13"
     model, params = setup
     path = tmp_path / "v9.jsonl"
     engine = ServingEngine(model, params, num_slots=2, kv_page_size=PS,
@@ -586,7 +586,7 @@ def test_metrics_v9_sections_and_reader_backcompat(setup, tmp_path):
     engine.metrics.record_quant_agreement(5, 6)
     snap = engine.metrics.write_snapshot()
     engine.close()
-    assert snap["schema"] == "serving-metrics/v12"
+    assert snap["schema"] == "serving-metrics/v13"
     kvq = snap["kv_quant"]
     assert kvq["mode"] == "int8"
     assert kvq["bytes_per_token"] < kvq["bytes_per_token_fp"]

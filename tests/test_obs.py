@@ -324,6 +324,12 @@ def test_engine_disabled_telemetry_is_null_and_token_identical(x64):
     assert engine_off.telemetry_summary() is None
     engine_on, tokens_on = run(True)
     assert tokens_on == tokens_off
+    # ... THROUGH the spans that tile the tick (tests/test_tick_spans.py pins the
+    # paged ragged and composed engines the same way)
+    phases = engine_on.telemetry.summary()["phases"]
+    for name in ("serving.tick", "serving.schedule", "serving.decode_dispatch", "serving.sample_sync",
+                 "serving.harvest", "serving.evict", "serving.host_gap"):
+        assert phases[name]["count"] > 0, name
     # same compile geometry: telemetry adds host-side timers, not programs
     assert engine_on.decode_compilations == engine_off.decode_compilations == 1
 
@@ -488,7 +494,7 @@ def test_run_manifest_contents(tmp_path):
     assert manifest["versions"]["jax"] == jax.__version__
     assert manifest["devices"]["count"] >= 1 and manifest["devices"]["backend"]
     assert manifest["config"] == {"preset": "tiny", "slots": 4}
-    assert manifest["artifact_schemas"]["serving_metrics"] == "serving-metrics/v12"
+    assert manifest["artifact_schemas"]["serving_metrics"] == "serving-metrics/v13"
     assert manifest["artifact_schemas"]["train_metrics"] == "train-metrics/v1"
     # config objects that are not JSON-encodable degrade to repr, never raise
     weird = build_run_manifest(config={"fn": open})  # a builtin is unencodable

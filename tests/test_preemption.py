@@ -438,7 +438,7 @@ def test_metrics_v6_preemption_counters_and_reader(setup, tmp_path):
     engine.close()
     assert all(h.ok for h in bg) and hi.ok
 
-    assert snap["schema"] == "serving-metrics/v12"
+    assert snap["schema"] == "serving-metrics/v13"
     assert snap["preemptions"] == 1
     assert snap["preempted_replays"] == 1
     assert set(snap["queue_wait_by_priority"]) == {"0", "1"}

@@ -529,7 +529,7 @@ def test_metrics_v8_sections_and_reader_backcompat(setup, tmp_path):
     from perceiver_io_tpu.serving import load_metrics_jsonl
     from perceiver_io_tpu.serving.metrics import SCHEMA
 
-    assert SCHEMA == "serving-metrics/v12"
+    assert SCHEMA == "serving-metrics/v13"
     model, params = setup
     path = tmp_path / "v8.jsonl"
     engine = ServingEngine(model, params, num_slots=2, kv_page_size=PS,
@@ -542,7 +542,7 @@ def test_metrics_v8_sections_and_reader_backcompat(setup, tmp_path):
     engine.run_until_drained(max_steps=200)
     assert donor.ok and fork.ok and long.ok
     snap = engine.metrics.write_snapshot()
-    assert snap["schema"] == "serving-metrics/v12"
+    assert snap["schema"] == "serving-metrics/v13"
     pc = snap["prefix_cache"]
     assert pc["hits"] >= 1 and pc["cached_pages"] >= 4
     assert "shared_pages_in_use" in pc

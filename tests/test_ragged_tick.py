@@ -286,7 +286,7 @@ def test_schema_v11_and_reader_normalizes_pre_v11(tmp_path):
     ragged_tick: None onto pre-v11 snapshots (and dense engines truthfully
     report None — 'not recorded' stays indistinguishable from 'no tick
     dispatcher exists', the schema's long-standing discipline)."""
-    assert SCHEMA == "serving-metrics/v12"
+    assert SCHEMA == "serving-metrics/v13"
     path = tmp_path / "old.jsonl"
     path.write_text(json.dumps({
         "event": "snapshot", "schema": "serving-metrics/v10",

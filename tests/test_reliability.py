@@ -613,7 +613,7 @@ def test_metrics_v3_reader_normalizes_older_snapshots(tmp_path):
     from perceiver_io_tpu.serving import EngineMetrics, load_metrics_jsonl
     from perceiver_io_tpu.serving.metrics import SCHEMA
 
-    assert SCHEMA == "serving-metrics/v12"
+    assert SCHEMA == "serving-metrics/v13"
     path = tmp_path / "v3.jsonl"
     m = EngineMetrics(num_slots=2, jsonl_path=str(path))
     m.record_submit(0, prompt_len=3)

@@ -83,7 +83,7 @@ def test_router_greedy_parity_mixed_lengths(x64):
         assert handle.failovers == 0
     # load-based dispatch actually spread the work
     snap = router.snapshot()
-    assert snap["schema"] == "serving-metrics/v12"
+    assert snap["schema"] == "serving-metrics/v13"
     assert all(s["requests_admitted"] > 0 for s in snap["replicas"].values())
     assert snap["failovers"] == 0 and snap["breaker_transitions"] == {}
     router.close()
@@ -520,12 +520,12 @@ def test_router_metrics_v4_jsonl_and_reader(tmp_path):
     events = {e["event"] for e in got["events"]}
     assert {"submit", "dispatch", "failover", "breaker", "shed", "finish", "snapshot"} <= events
     snap = got["snapshots"][0]
-    assert snap["schema"] == "serving-metrics/v12"
+    assert snap["schema"] == "serving-metrics/v13"
     assert snap["failovers"] == 1 and snap["shed_infeasible"] == 1
     assert snap["breaker_transitions"] == {"closed->open": 1}
     assert snap["tokens_generated"] == 1  # aggregated over replica sections
     assert set(snap["replicas"]) == {"r0", "r1"}
-    assert snap["replicas"]["r0"]["schema"] == "serving-metrics/v12"
+    assert snap["replicas"]["r0"]["schema"] == "serving-metrics/v13"
 
     bad = tmp_path / "bad.jsonl"
     bad.write_text(json.dumps({"event": "snapshot", "schema": "serving-metrics/v99"}) + "\n")

@@ -313,7 +313,7 @@ def test_metrics_standalone_counters():
     m.record_decode_step(active_slots=2, seconds=0.2, tokens=2)
     m.record_finish(0, slot=1, new_tokens=1, reason="length")
     snap = m.snapshot()
-    assert snap["schema"] == "serving-metrics/v12"
+    assert snap["schema"] == "serving-metrics/v13"
     assert snap["rejected"] == snap["timed_out"] == snap["failed"] == 0
     assert snap["page_pool"] is None  # dense engine: no pool exists
     assert snap["mean_slot_occupancy"] == 0.5
