@@ -31,6 +31,7 @@ TPU-first design notes:
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import flax.linen as nn
@@ -39,7 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from perceiver_io_tpu.models.core.adapter import InputAdapter, TrainableQueryProvider
-from perceiver_io_tpu.ops.attention import KVCache, MultiHeadAttention
+from perceiver_io_tpu.ops.attention import KVCache, MultiHeadAttention, RingKVCache
 
 LN_EPS = 1e-5  # matches torch.nn.LayerNorm default for checkpoint-conversion parity
 
@@ -447,6 +448,19 @@ class SelfAttentionLayer(nn.Module):
         return x, kv_cache
 
 
+class _RingDecodeLayer(SelfAttentionLayer):
+    """``SelfAttentionLayer`` as the body of the serving pool's decode loop:
+    the stacked ``RingKVCache`` travels in the CARRY beside the activations
+    and the layer index is the scanned input, so no layer's cache is sliced
+    out of the stacked buffer or written back into it. Same submodules, same
+    parameter tree."""
+
+    def __call__(self, carry, rope_gate, layer, rope_q, rope_k):
+        x, kv_cache = carry
+        x, kv_cache = super().__call__(x, rope_gate, kv_cache.replace(layer=layer), rope_q, rope_k)
+        return (x, kv_cache.replace(layer=None)), None
+
+
 class SelfAttentionBlock(nn.Module):
     """Stack of ``num_layers`` self-attention layers, scanned over a stacked
     parameter axis. ``num_rotary_layers`` leading layers apply RoPE (-1 = all)."""
@@ -521,20 +535,31 @@ class SelfAttentionBlock(nn.Module):
             if plan is not None:
                 return self._pipelined(plan, x, rope_gates, rope_q, rope_k, pad_mask, policy)
 
-        layer_cls = SelfAttentionLayer
-        if self.activation_checkpointing:
-            layer_cls = nn.remat(layer_cls, policy=policy)
-
-        scanned = nn.scan(
-            layer_cls,
+        scan = functools.partial(
+            nn.scan,
             variable_axes={"params": 0},
             split_rngs={"params": True, "dropout": True},
-            in_axes=(0, 0, nn.broadcast, nn.broadcast, nn.broadcast),
             out_axes=0,
             length=self.num_layers,
             unroll=max(1, min(self.scan_unroll, self.num_layers)),
             metadata_params={nn.PARTITION_NAME: "layers"},
-        )(**self._layer_kwargs(), name="layers")
+        )
+        if isinstance(kv_cache, RingKVCache):
+            # the paged pool's decode step: the stacked ring is a carry, written
+            # in place one row a slot a layer (see _RingDecodeLayer)
+            scanned = scan(_RingDecodeLayer, in_axes=(0, 0, nn.broadcast, nn.broadcast))(
+                **self._layer_kwargs(), name="layers"
+            )
+            (x, kv_cache), _ = scanned((x, kv_cache), rope_gates, jnp.asarray(idx, jnp.int32), rope_q, rope_k)
+            return x, kv_cache.advance()
+
+        layer_cls = SelfAttentionLayer
+        if self.activation_checkpointing:
+            layer_cls = nn.remat(layer_cls, policy=policy)
+
+        scanned = scan(layer_cls, in_axes=(0, 0, nn.broadcast, nn.broadcast, nn.broadcast))(
+            **self._layer_kwargs(), name="layers"
+        )
         return scanned(x, rope_gates, kv_cache, rope_q, rope_k, pad_mask)
 
     def _layer_kwargs(self, **overrides):

@@ -43,7 +43,7 @@ from perceiver_io_tpu.models.core.adapter import (
 )
 from perceiver_io_tpu.models.core.config import CausalSequenceModelConfig
 from perceiver_io_tpu.models.core.modules import LN_EPS, CrossAttentionLayer, SelfAttentionBlock
-from perceiver_io_tpu.ops.attention import KVCache
+from perceiver_io_tpu.ops.attention import KVCache, RingKVCache
 from perceiver_io_tpu.ops.paged_decode_kernel import PagedKVCache
 from perceiver_io_tpu.ops.position import frequency_position_encoding, positions
 
@@ -136,17 +136,22 @@ class PagedPerceiverARCache(flax.struct.PyTreeNode):
     (``ca``: ops/paged_decode_kernel.PagedKVCache) addressed through per-slot
     page tables, so HBM cost scales with live tokens and admission/eviction
     are page-table edits — the paged forms of ``write_slot`` (install_slot),
-    ``rewind``, and the ``live`` bookkeeping. The small self-attention cache
-    (capacity ``max_latents``) stays dense.
+    ``rewind``, and the ``live`` bookkeeping. The self-attention cache
+    (capacity ``max_latents``, one per layer) stays dense, as a ring
+    (``sa``: ops/attention.RingKVCache): per slot an offset ``sa.start``, an
+    append of one row a slot a layer, nothing shifted.
 
     Engine-only invariants (serving/engine.py): every row sits at FULL window
     occupancy at all times (the same invariant the dense pool pins via shared
     cache lengths), so validity is fully encoded by ``live`` and the ring
     offset ``ca.start`` — there is no pad-slot buffer and no shared length.
+    The self-attention ring rests on the same invariant: every slot holds
+    ``max_latents`` latents from its install on (a prefill always yields that
+    many) and appends one per decode step, so all its rows are always visible.
     """
 
     ca: PagedKVCache
-    sa: KVCache
+    sa: RingKVCache
     shift: jax.Array  # (B, 1) left-pad position shift, as in PerceiverARCache
     live: jax.Array  # (B,) live (non-pad) entries per row
 
@@ -155,11 +160,12 @@ class PagedPerceiverARCache(flax.struct.PyTreeNode):
         recently written tokens by stepping the ring offset back (their pages
         stay allocated — pages are only returned at eviction — so the slots
         still hold the rewound values and the next append overwrites them
-        exactly, the speculative-verification contract)."""
+        exactly, the speculative-verification contract). The self-attention
+        ring steps back the same way (``RingKVCache.rewind``)."""
         k = jnp.asarray(k, jnp.int32)
         return self.replace(
             ca=self.ca.replace(start=jnp.mod(self.ca.start - k, self.ca.window)),
-            sa=self.sa.replace(length=jnp.maximum(self.sa.length - k, 0)),
+            sa=self.sa.rewind(k),
             live=jnp.maximum(self.live - k, 0),
         )
 
@@ -224,7 +230,7 @@ class PagedPerceiverARCache(flax.struct.PyTreeNode):
         )
         return self.replace(
             ca=ca,
-            sa=self.sa.write_batch_row(slot, src.sa, batch_axis=1),
+            sa=self.sa.write_batch_row(slot, src.sa),
             shift=jax.lax.dynamic_update_slice_in_dim(
                 self.shift, src.shift + (window - bucket), slot, axis=0
             ),
@@ -241,9 +247,9 @@ class PagedPerceiverARCache(flax.struct.PyTreeNode):
         not corrupt the half-built slot), so installing the slot is pure
         bookkeeping: point the table at the reservation, set the ring offset
         to ``live mod window`` (the page-aligned layout's post-prompt
-        append point), write the finish step's self-attention cache, and pin
-        shift/live exactly as ``install_slot`` would for a prompt of
-        ``live`` tokens."""
+        append point), write the finish step's self-attention cache (the
+        slot's ring restarts at 0), and pin shift/live exactly as
+        ``install_slot`` would for a prompt of ``live`` tokens."""
         window = self.ca.window
         live = jnp.asarray(live, jnp.int32)
         return self.replace(
@@ -251,7 +257,7 @@ class PagedPerceiverARCache(flax.struct.PyTreeNode):
                 page_table=self.ca.page_table.at[slot].set(table_row),
                 start=self.ca.start.at[slot].set(jnp.mod(live, window)),
             ),
-            sa=self.sa.write_batch_row(slot, sa_src, batch_axis=1),
+            sa=self.sa.write_batch_row(slot, sa_src),
             shift=self.shift.at[slot].set(window - live),
             live=self.live.at[slot].set(live),
         )
@@ -260,9 +266,11 @@ class PagedPerceiverARCache(flax.struct.PyTreeNode):
         """Reset slot ``slot`` to the free canonical form: page table entries
         all trash (page 0), ring offset 0, live pinned at the full window
         (free rows decode discarded garbage exactly like the dense pool's
-        free slots). CRITICAL for correctness, not just hygiene: a freed
-        slot keeps decoding every tick, and a stale table entry would route
-        its writes into a page since reallocated to a live request."""
+        free slots; their self-attention ring keeps turning, its rows and
+        offset replaced whole by the next install). CRITICAL for
+        correctness, not just hygiene: a freed slot keeps decoding every
+        tick, and a stale table entry would route its writes into a page
+        since reallocated to a live request."""
         p = self.ca.pages_per_slot
         return self.replace(
             ca=self.ca.replace(
@@ -302,9 +310,10 @@ def _make_paged_ar_cache(
 ) -> PagedPerceiverARCache:
     """Paged decode-pool state: a shared (num_pages, page_size, C) KV page
     pool (page 0 reserved as the trash page) + per-slot page tables over
-    ceil(max_seq_len / page_size) logical pages, dense self-attention caches
-    unchanged. ``page_size`` need not divide the window — the last logical
-    page's tail is simply never visible. ``kv_quant="int8"`` stores the page
+    ceil(max_seq_len / page_size) logical pages, and the dense self-attention
+    caches as one stacked ring (``RingKVCache``, every row full from the
+    start: free slots hold zeros). ``page_size`` need not divide the window —
+    the last logical page's tail is simply never visible. ``kv_quant="int8"`` stores the page
     pool as int8 with per-page-per-head float32 scale sidecars (the KV bytes
     per token drop ~4x vs f32; ops/paged_decode_kernel.py module docstring) —
     the self-attention caches and everything dense stay in ``dtype``.
@@ -350,7 +359,7 @@ def _make_paged_ar_cache(
             window=max_seq_len,
             **quant_fields,
         ),
-        sa=KVCache.create_stacked(num_layers, batch_size, max_latents, num_channels, num_channels, dtype),
+        sa=RingKVCache.create(num_layers, batch_size, max_latents, num_channels, num_channels, dtype),
         shift=jnp.zeros((batch_size, 1), jnp.int32),
         live=jnp.full((batch_size,), max_seq_len, jnp.int32),
     )
@@ -681,12 +690,14 @@ class PerceiverAR(nn.Module):
             kv_cache=cache.ca, kv_live=live,
         )
 
-        # dense self-attention over the latents, exactly as decode_block n=1
-        # with the window full (n_after == window)
-        sa_cap = cache.sa.k.shape[2]
-        sa_len_after = jnp.minimum(cache.sa.length[0] + 1, sa_cap)
-        sa_slot_pos = window - sa_len_after + jnp.arange(sa_cap)[None, :]
-        sa_slot_pos = jnp.maximum(sa_slot_pos - cache.shift, 0)
+        # self-attention over the latents' ring, full like the window: the
+        # append (inside self_attention) writes physical row sa.start, after
+        # which row r holds latent (r - start - 1) mod cap, whose window
+        # position is window - cap + that: decode_block's angles (n = 1,
+        # n_after == window), per PHYSICAL row as for rope_k_ca above
+        sa_cap = cache.sa.capacity
+        sa_logical = jnp.mod(jnp.arange(sa_cap)[None, :] - (cache.sa.start[:, None] + 1), sa_cap)
+        sa_slot_pos = jnp.maximum(window - sa_cap + sa_logical - cache.shift, 0)
         rope_k_sa = frequency_position_encoding(sa_slot_pos, rot)
         x_latent, sa_cache = self.self_attention(
             x_latent, rope_q=frq_q, rope_k=rope_k_sa, kv_cache=cache.sa

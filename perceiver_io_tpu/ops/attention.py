@@ -116,6 +116,78 @@ class KVCache(flax.struct.PyTreeNode):
         return KVCache(k=k, v=v, length=length)
 
 
+class RingKVCache(flax.struct.PyTreeNode):
+    """Stacked per-layer self-attention cache of the serving engine's PAGED
+    pool (``PagedPerceiverARCache.sa``): a ring that is written in place and
+    read where it lies.
+
+    ``k`` / ``v``: (num_layers, B, capacity, C) unrotated projected keys / values.
+    ``start``: (B,) int32 ring offset per slot, as ``PagedKVCache.start``: the
+        NEXT append writes physical row ``start``; physical row ``r`` holds
+        logical latent ``(r - start) mod capacity`` (0 = oldest).
+    ``layer``: the layer a view addresses; set by ``SelfAttentionBlock``'s
+        layer loop (which carries the stacked buffers), None outside it.
+
+    The pool's invariant is what makes the ring exact: every row is FULL at
+    all times and every slot appends once per decode step, free slots too.
+    The one query therefore sees all ``capacity`` rows (no validity bound, no
+    pad mask) and an append is one row a slot a layer; nothing is shifted.
+    ``KVCache`` (left-aligned, shared scalar length, rolled when full) stays
+    the cache of ``generate()``, prefill and the dense engine pool.
+    """
+
+    k: jax.Array
+    v: jax.Array
+    start: jax.Array
+    layer: Optional[jax.Array] = None
+
+    @property
+    def capacity(self) -> int:
+        return self.k.shape[2]
+
+    @staticmethod
+    def create(
+        num_layers: int, batch_size: int, capacity: int, num_qk_channels: int, num_v_channels: int, dtype=jnp.float32
+    ) -> "RingKVCache":
+        return RingKVCache(
+            k=jnp.zeros((num_layers, batch_size, capacity, num_qk_channels), dtype=dtype),
+            v=jnp.zeros((num_layers, batch_size, capacity, num_v_channels), dtype=dtype),
+            start=jnp.zeros((batch_size,), dtype=jnp.int32),
+        )
+
+    def append_row(self, k_new: jax.Array, v_new: jax.Array) -> "RingKVCache":
+        """Write one token's (B, 1, C) keys / values into layer ``layer`` at
+        each slot's ring position. ``start`` is NOT advanced: every layer of
+        one decode step writes the same position, and the layer loop's owner
+        advances once after the last (``advance``)."""
+        rows = jnp.arange(self.start.shape[0])
+        at = lambda buf, new: buf.at[self.layer, rows, self.start].set(
+            new[:, 0].astype(buf.dtype), indices_are_sorted=True, unique_indices=True
+        )
+        return self.replace(k=at(self.k, k_new), v=at(self.v, v_new))
+
+    def advance(self) -> "RingKVCache":
+        return self.replace(start=jnp.mod(self.start + 1, self.capacity))
+
+    def rewind(self, k: jax.Array) -> "RingKVCache":
+        """Step every slot's offset back by ``k`` appends. A full ring has no
+        spare row: the rewound rows keep what was appended over the ``k``
+        oldest latents, and are read in their place until appended over again.
+        So ``k = 1`` is exact (the one rewound row is rewritten before it is
+        read), and appending the same ``k`` tokens again restores the bytes."""
+        return self.replace(start=jnp.mod(self.start - k, self.capacity))
+
+    def write_batch_row(self, idx: jax.Array, src: KVCache) -> "RingKVCache":
+        """Install slot ``idx`` (traced OK) from a FULL stacked ``KVCache`` of
+        batch 1 in age order (what a prefill leaves): a plain row write, with
+        the slot's ring restarting at 0."""
+        return self.replace(
+            k=jax.lax.dynamic_update_slice_in_dim(self.k, src.k.astype(self.k.dtype), idx, axis=1),
+            v=jax.lax.dynamic_update_slice_in_dim(self.v, src.v.astype(self.v.dtype), idx, axis=1),
+            start=self.start.at[idx].set(0),
+        )
+
+
 class MultiHeadAttention(nn.Module):
     """Scaled dot-product multi-head attention (Perceiver IO appendix-E style).
 
@@ -281,6 +353,55 @@ class MultiHeadAttention(nn.Module):
         o = o.transpose(0, 2, 1, 3).reshape(o.shape[0], n_q, -1)
         return self.o_proj(o), kv_cache
 
+    def _ring_cached_attention(self, q, k, v, kv_cache, rope_q, rope_k, scale):
+        """Single-token causal decode against one layer of the paged pool's
+        self-attention ring (``RingKVCache``). ``q``/``k``/``v`` are the
+        UNSPLIT (B, 1, C) projections of the new token. The append writes one
+        row a slot into the stacked buffer; the fused kernel then reads the
+        layer where it lies (its stacked form), else the XLA formulation
+        reads it through one slice. Every row is visible: the ring is full and
+        the query is its newest entry, so there is no mask on this path and
+        ``rope_k`` carries the order (angles per PHYSICAL row)."""
+        from perceiver_io_tpu.ops.decode_kernel import decode_kernel_supported, fused_decode_attention_auto
+
+        b, n_q = q.shape[0], q.shape[1]
+        if n_q != 1 or not self.causal_attention:
+            raise ValueError("ring KV caches support single-token causal decode only")
+        if self.dropout > 0.0 and not self.deterministic:
+            raise ValueError("ring decode is inference-only (no attention dropout)")
+        num_qk, num_v, _ = self._dims()
+        with jax.named_scope("cache_append"):
+            kv_cache = kv_cache.append_row(k, v)
+        cap = kv_cache.capacity
+
+        split = lambda t: t.reshape(t.shape[0], t.shape[1], self.num_heads, -1).transpose(0, 2, 1, 3)
+        q = split(q) * scale
+        if rope_q is not None:
+            q = apply_rope(q, rope_q)
+
+        with jax.named_scope("decode_attention"):
+            if self.use_flash is not False and decode_kernel_supported(
+                n_q, cap, num_qk, num_v, self.num_heads, batch_size=b,
+                itemsize=kv_cache.k.dtype.itemsize,
+            ):
+                ang = rope_k if rope_k is not None else jnp.zeros((b, cap, 2), jnp.float32)
+                if ang.shape[0] != b:
+                    ang = jnp.broadcast_to(ang, (b, *ang.shape[1:]))
+                o = fused_decode_attention_auto(
+                    q, kv_cache.k, kv_cache.v, ang, cap - 1, jnp.zeros((b, cap), bool),
+                    layer=kv_cache.layer,
+                )
+            else:
+                take = lambda buf: jax.lax.dynamic_index_in_dim(buf, kv_cache.layer, axis=0, keepdims=False)
+                kf, vf = split(take(kv_cache.k)), split(take(kv_cache.v))
+                if rope_k is not None:
+                    kf = apply_rope(kf, rope_k)
+                attn = jnp.einsum("bhic,bhjc->bhij", q, kf, preferred_element_type=jnp.float32)
+                attn = jax.nn.softmax(attn, axis=-1).astype(vf.dtype)
+                o = jnp.einsum("bhij,bhjc->bhic", attn, vf)
+        o = o.transpose(0, 2, 1, 3).reshape(o.shape[0], n_q, -1)
+        return self.o_proj(o), kv_cache
+
     def paged_prefill_attention(
         self,
         x_q: jax.Array,
@@ -369,6 +490,9 @@ class MultiHeadAttention(nn.Module):
             q = self.q_proj(x_q)
             k = self.k_proj(x_kv)
             v = self.v_proj(x_kv)
+
+        if isinstance(kv_cache, RingKVCache):
+            return self._ring_cached_attention(q, k, v, kv_cache, rope_q, rope_k, scale)
 
         if paged:
             # Paged ring-cache decode (serving/paging.py; ops/paged_decode_kernel.py):

@@ -121,7 +121,7 @@ def _head_expander(h: int, d: int):
     return np.kron(np.eye(h, dtype=np.float32), np.ones((1, d), np.float32))
 
 
-def _kernel(qpos_ref, live_ref, qbd_ref, k_ref, v_ref, ang_ref, pad_ref, rot_ref, exp_ref, o_ref, m_ref, l_ref, acc_ref):
+def _kernel(qpos_ref, live_ref, layer_ref, qbd_ref, k_ref, v_ref, ang_ref, pad_ref, rot_ref, exp_ref, o_ref, m_ref, l_ref, acc_ref):
     """Grid (B, num_blocks); block i covers cache slots [i*blk, (i+1)*blk).
 
     qpos_ref (B,)            absolute position of the LAST query (scalar-prefetch, SMEM)
@@ -130,6 +130,8 @@ def _kernel(qpos_ref, live_ref, qbd_ref, k_ref, v_ref, ang_ref, pad_ref, rot_ref
                              entirely below it are dead: their grid steps alias the
                              first live block in the index maps (no new DMA) and
                              skip all compute — the ragged length-aware early exit.
+    layer_ref (1,)           which layer of a stacked cache the K/V blocks come
+                             from (scalar-prefetch); read by the K/V index map alone
     qbd_ref  (h*d, n_q*h)    block-diagonal scaled+rotated queries (col qi*h+head
                              holds query qi's head slice in rows [head*d, (head+1)*d))
     k_ref    (1, blk, h*d)   unrotated keys
@@ -229,6 +231,7 @@ def fused_decode_attention_auto(
     q_pos: jax.Array,
     pad_slots: jax.Array,
     live: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Mesh-aware dispatch: under an ambient mesh that shards batch axes, the
@@ -239,7 +242,9 @@ def fused_decode_attention_auto(
 
     plan = _mesh_plan()
     if plan is None or not plan[0]:
-        return fused_decode_attention(q, k_cache, v_cache, rope_k, q_pos, pad_slots, live=live, interpret=interpret)
+        return fused_decode_attention(
+            q, k_cache, v_cache, rope_k, q_pos, pad_slots, live=live, layer=layer, interpret=interpret
+        )
 
     from jax.sharding import PartitionSpec as P
 
@@ -252,23 +257,26 @@ def fused_decode_attention_auto(
         jnp.broadcast_to(jnp.asarray(live, jnp.int32).reshape(-1), (b,))
         if live is not None else q_pos_b + 1  # full live region: no skipping
     )
+    if layer is None:  # as in fused_decode_attention: one layer, stacked
+        k_cache, v_cache, layer = k_cache[None], v_cache[None], 0
     fn = _shard_map(
-        lambda q, k, v, a, pos, pad, lv: fused_decode_attention(
-            q, k, v, a, pos, pad, live=lv, interpret=interpret
+        lambda q, k, v, a, pos, pad, lv, layer: fused_decode_attention(
+            q, k, v, a, pos, pad, live=lv, layer=layer[0], interpret=interpret
         ),
         in_specs=(
             P(baxes, None, None, None),
-            P(baxes, None, None),
-            P(baxes, None, None),
+            P(None, baxes, None, None),
+            P(None, baxes, None, None),
             P(baxes, None, None),
             P(baxes),
             P(baxes, None),
             P(baxes),
+            P(None),
         ),
         out_specs=P(baxes, None, None, None),
         mesh=None,
     )
-    return fn(q, k_cache, v_cache, rope_k, q_pos_b, pad_slots, live_b)
+    return fn(q, k_cache, v_cache, rope_k, q_pos_b, pad_slots, live_b, jnp.asarray(layer, jnp.int32).reshape(1))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -280,6 +288,7 @@ def fused_decode_attention(
     q_pos: jax.Array,
     pad_slots: jax.Array,
     live: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,
     interpret: bool = False,
 ) -> jax.Array:
     """q (B, H, n_q, D) scaled (+rotated) queries, n_q <= 8; k/v_cache
@@ -289,12 +298,21 @@ def fused_decode_attention(
     live region is the tail [q_pos+1-live, q_pos+1); KV blocks entirely below
     it are skipped (no compute, no fresh DMA). Callers keep ``live``
     consistent with ``pad_slots`` (live = valid minus pad slots); None means
-    fully live. Returns (B, H, n_q, D)."""
+    fully live. Returns (B, H, n_q, D).
+
+    STACKED form: with ``layer`` (a scalar, traced OK) k/v_cache are the
+    per-layer caches stacked as (L, B, cap, H*D) and the kernel reads layer
+    ``layer`` where it lies — the index rides the scalar-prefetch path into
+    the K/V index map, so no layer-sized slice is materialised for the call
+    (the serving pool's ``RingKVCache``). Everything else is per batch row and
+    the same in both forms."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, n_q, d = q.shape
-    cap = k_cache.shape[1]
+    if layer is None:  # the 3-D form is the stacked one with a single layer
+        k_cache, v_cache, layer = k_cache[None], v_cache[None], 0
+    cap = k_cache.shape[2]
     blk = _kv_block(cap, h * d, k_cache.dtype.itemsize)
     if blk is None:
         raise ValueError(
@@ -315,22 +333,25 @@ def fused_decode_attention(
     eye = jnp.eye(h, dtype=q.dtype)
     qbd = (q.transpose(0, 1, 3, 2)[:, :, :, :, None] * eye[:, None, None, :]).reshape(b, h * d, n_q * h)
 
-    def _kv_map(bi, i, qpos_ref, live_ref):
+    def _slot_map(bi, i, qpos_ref, live_ref, layer_ref):
         # dead head blocks alias the first (possibly) live block: consecutive
         # equal indices elide the DMA, so HBM traffic scales with live tokens
         # (clamped into range — live = 0 rows have no live block at all)
         dead = jnp.maximum((qpos_ref[bi] + 1 - live_ref[bi]) // blk, 0)
         return (bi, jnp.minimum(jnp.maximum(i, dead), nblocks - 1), 0)
 
+    def _kv_map(bi, i, qpos_ref, live_ref, layer_ref):
+        return (layer_ref[0], *_slot_map(bi, i, qpos_ref, live_ref, layer_ref))
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, nblocks),
         in_specs=[
             pl.BlockSpec((None, h * d, n_q * h), lambda bi, i, *_: (bi, 0, 0)),
-            pl.BlockSpec((1, blk, h * d), _kv_map),
-            pl.BlockSpec((1, blk, h * d), _kv_map),
-            pl.BlockSpec((1, blk, r), _kv_map),
-            pl.BlockSpec((1, blk, 1), _kv_map),
+            pl.BlockSpec((None, 1, blk, h * d), _kv_map),
+            pl.BlockSpec((None, 1, blk, h * d), _kv_map),
+            pl.BlockSpec((1, blk, r), _slot_map),
+            pl.BlockSpec((1, blk, 1), _slot_map),
             pl.BlockSpec((h * d, h * d), lambda bi, i, *_: (0, 0)),
             pl.BlockSpec((h, h * d), lambda bi, i, *_: (0, 0)),
         ],
@@ -349,6 +370,7 @@ def fused_decode_attention(
     )(
         q_pos_arr,
         live_arr,
+        jnp.asarray(layer, jnp.int32).reshape(1),
         qbd,
         k_cache,
         v_cache,

@@ -699,14 +699,12 @@ class ServingEngine:
             # request id currently head-blocked on the free list, so a long
             # block reports one alloc_failure episode rather than one per tick
             self._alloc_blocked_id: Optional[int] = None
-            cache = model.init_paged_cache(
+            # the factory pins live at the window, and the self-attention
+            # ring (RingKVCache) is full by construction: the fill-level
+            # invariant the dense pool pins through its shared lengths
+            self._cache = model.init_paged_cache(
                 num_slots, pages, self.kv_page_size, dtype=self.cache_dtype,
                 kv_quant=self.kv_quant,
-            )
-            # factory pins live at the window; pin the SA lengths full too —
-            # the shared-fill-level invariant the dense pool also maintains
-            self._cache = cache.replace(
-                sa=cache.sa.replace(length=jnp.full_like(cache.sa.length, cache.sa.k.shape[2])),
             )
             self.metrics.set_page_pool(self._pool.num_pages - self._pool.reserved, 0)
         else:

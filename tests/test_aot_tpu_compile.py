@@ -91,6 +91,20 @@ def _dense(sds, heads_width, batch, capacity, n_q):
     )
 
 
+def _dense_stacked(sds, heads_width, layers, batch, capacity):
+    """The serving pool's self-attention ring: the kernel indexes the layer of
+    the stacked buffers itself (``layer`` traced, as inside the layer loop)."""
+    heads, width = heads_width
+    kv = sds((layers, batch, capacity, heads * width), jnp.bfloat16)
+    return jax.jit(
+        lambda q, k, v, ang, pad, layer: dk.fused_decode_attention(q, k, v, ang, capacity - 1, pad, layer=layer)
+    ).lower(
+        sds((batch, heads, 1, width), jnp.bfloat16), kv, kv,
+        sds((batch, capacity, width // 2), jnp.float32), sds((batch, capacity), jnp.bool_),
+        sds((), jnp.int32),
+    )
+
+
 def _splash_fwd_bwd(sds, heads_width, n_q, n_k):
     heads, width = heads_width
     q = sds((2, heads, n_q, width), jnp.bfloat16)
@@ -114,6 +128,10 @@ CASES = {
     # 512 — refused at the old fixed 512-row block (16.71M of 16M scoped VMEM)
     "dense-10x128-b8-cap512": (_dense, FLAGSHIP, 8, 512, 1),
     "dense-10x128-b8-cap1024-q8": (_dense, FLAGSHIP, 8, 1024, 8),
+    # the paged pool's stacked self-attention ring: 455M at the online cell's
+    # 64 slots, and the 30.7M configuration (8 layers, 16 slots)
+    "dense-stacked-10x128-l20-b64-cap512": (_dense_stacked, FLAGSHIP, 20, 64, 512),
+    "dense-stacked-8x64-l8-b16-cap512": (_dense_stacked, WIKITEXT, 8, 16, 512),
     "splash-fwd-bwd-512x1024x128": (_splash_fwd_bwd, FLAGSHIP, 512, 1024),
 }
 

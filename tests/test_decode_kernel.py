@@ -62,6 +62,32 @@ def test_fused_decode_attention_per_batch_positions():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_fused_decode_attention_stacked_form_equals_3d_form_per_layer(layer):
+    """The stacked, layer-indexed form (the serving pool's ring cache: the
+    kernel reads layer ``layer`` of the (L, B, cap, H*D) buffers where it lies)
+    equals the 3-D form on ``k[layer]``, ``v[layer]`` bit for bit, with the
+    index traced and with per-row live lengths skipping blocks."""
+    n_layers, b, h, d, cap, r = 3, 2, 2, 32, 256, 16
+    rng = lambda i: jax.random.PRNGKey(i)
+    q = jax.random.normal(rng(0), (b, h, 1, d)) * 0.3
+    k = jax.random.normal(rng(1), (n_layers, b, cap, h * d)) * 0.3
+    v = jax.random.normal(rng(2), (n_layers, b, cap, h * d)) * 0.3
+    ang = jnp.repeat(jax.random.normal(rng(3), (b, cap, r // 2)) * 0.5, 2, axis=-1)
+    pad = jnp.zeros((b, cap), bool)
+    q_pos = jnp.asarray(cap - 1)
+    live = jnp.asarray([cap, 90], jnp.int32)
+
+    stacked = jax.jit(
+        lambda layer: dk.fused_decode_attention(q, k, v, ang, q_pos, pad, live=live, layer=layer, interpret=True)
+    )(jnp.asarray(layer, jnp.int32))
+    flat = dk.fused_decode_attention(q, k[layer], v[layer], ang, q_pos, pad, live=live, interpret=True)
+    np.testing.assert_array_equal(np.asarray(stacked), np.asarray(flat))
+    pad_live = jnp.arange(cap)[None, :] < (cap - live)[:, None]  # the dead head as a pad mask
+    ref = xla_reference(q, k[layer], v[layer], ang, jnp.full((b,), cap - 1), pad_live)
+    np.testing.assert_allclose(np.asarray(stacked), np.asarray(ref), atol=1e-5)
+
+
 @pytest.mark.parametrize(
     "b,h,d,cap,r,n_q,q_last",
     [
@@ -297,10 +323,13 @@ def test_full_model_ragged_prompts_with_kernel_matches_plain(monkeypatch):
         np.testing.assert_allclose(f, p, atol=2e-5)
 
 
-def test_fused_decode_attention_auto_sharded_batch():
+@pytest.mark.parametrize("form", ["3d", "stacked"])
+def test_fused_decode_attention_auto_sharded_batch(form):
     """Mesh-aware dispatch: under a batch-sharded ambient mesh the kernel runs
     per-device inside shard_map (interpret mode on the 8-virtual-device CPU
-    backend) and must match the single-device reference."""
+    backend) and must match the single-device reference — in the 3-D form and
+    in the stacked one, whose batch axis is the second and whose layer index
+    is traced."""
     from perceiver_io_tpu.parallel.mesh import make_mesh
 
     b, h, d, cap, r = 8, 2, 32, 256, 16
@@ -314,9 +343,15 @@ def test_fused_decode_attention_auto_sharded_batch():
 
     mesh = make_mesh({"data": 4}, devices=jax.devices()[:4])
     with jax.sharding.set_mesh(mesh):
-        out = jax.jit(lambda *a: dk.fused_decode_attention_auto(*a, interpret=True))(
-            q, k, v, ang, q_pos, pad
-        )
+        if form == "3d":
+            out = jax.jit(lambda *a: dk.fused_decode_attention_auto(*a, interpret=True))(
+                q, k, v, ang, q_pos, pad
+            )
+        else:
+            stack = lambda t: jnp.stack([jnp.zeros_like(t), t])  # the cache is layer 1 of 2
+            out = jax.jit(lambda *a, layer: dk.fused_decode_attention_auto(*a, layer=layer, interpret=True))(
+                q, stack(k), stack(v), ang, q_pos, pad, layer=jnp.asarray(1, jnp.int32)
+            )
     ref = xla_reference(q, k, v, ang, jnp.full((b,), 200), pad)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 

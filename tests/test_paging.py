@@ -518,3 +518,224 @@ def test_paged_rewind_matches_dense_rewind_contract(setup):
     cache_rw = cache1.rewind(1)
     logits2, _ = model.apply(params, tok, cache_rw, method=CausalSequenceModel.decode_step_paged)
     np.testing.assert_array_equal(np.asarray(logits1), np.asarray(logits2))
+
+
+def test_paged_rewind_round_trip_same_bytes_same_tokens(setup):
+    """Append, rewind, append again, on two slots whose rings sit at different
+    offsets: both rings (the page pool's and the self-attention cache's) hold
+    the same bytes at the same offsets and the logits repeat. Exact for the
+    one-token rewind only, on the parent's rolled cache as on the ring: the
+    self-attention cache is always full, so a rewound row keeps the rejected
+    token until it is appended over, and with k > 1 the steps in between read
+    it as an old latent; for those, ``rewind`` steps the offsets and no more."""
+    model, params = setup
+    engine = ServingEngine(model, params, num_slots=2, kv_page_size=4)
+    engine.submit([5, 6, 7], max_new_tokens=30)
+    engine.step()
+    engine.submit(list(range(40, 45)), max_new_tokens=30)  # short: the window never saturates here
+    for _ in range(4):  # past both installs: the slots' rings sit mid-turn, apart
+        engine.step()
+    cache0 = engine._cache
+    assert len(set(np.asarray(cache0.sa.start).tolist())) == 2
+    tok = jnp.asarray([[9], [17]], jnp.int32)
+    step = lambda cache: model.apply(params, tok, cache, method=CausalSequenceModel.decode_step_paged)
+
+    logits1, cache1 = step(cache0)
+    rewound = cache1.rewind(1)
+    for ring in ("sa", "ca"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(rewound, ring).start), np.asarray(getattr(cache0, ring).start))
+    np.testing.assert_array_equal(np.asarray(rewound.live), np.asarray(cache0.live))
+    logits2, cache2 = step(rewound)
+    np.testing.assert_array_equal(np.asarray(logits1), np.asarray(logits2))
+    same = lambda a, b: np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    jax.tree_util.tree_map(same, cache2, cache1)  # every buffer and offset of the pool
+    # k > 1 steps the offsets back by k, modulo each ring's length
+    cap, window = cache1.sa.capacity, cache1.ca.window
+    back = cache1.rewind(cap + 2)
+    same(back.sa.start, (np.asarray(cache1.sa.start) - 2) % cap)
+    same(back.ca.start, (np.asarray(cache1.ca.start) - cap - 2) % window)
+
+
+# ------------------------------------------------------ self-attention ring
+# The paged pool's self-attention cache is a ring (ops/attention.RingKVCache):
+# one row a slot a layer written in place, read where it lies. Toy sizes: 8
+# latents and 19 decode steps a request turn every ring twice and more. The
+# window is 40, not 16: a request may use the prefix cache only if its prompt
+# and answer fit the window (its pages must never be appended over), and 14 +
+# 19 tokens have to.
+RING_WINDOW, RING_LATENTS, RING_PS, RING_SLOTS = 40, 8, 4, 4
+RING_NEW = 2 * RING_LATENTS + 3
+RING_PROMPTS = {
+    "prefill_install": [5, 6, 7, 8, 9],  # shorter than the latents: prefill + install
+    "chunks_finish": list(range(100, 130)),  # 30 tokens: chunks of 4, the finish; the page ring wraps too
+    "prefix_donor": list(range(40, 54)),  # 14 tokens: its first page of 4 is cacheable
+    "prefix_hit": list(range(40, 44)) + list(range(70, 80)),  # the donor's first page, then its own
+    "evict_readmit": [3, 1, 4, 1, 5, 9, 2, 6, 5, 3],  # evicted mid-decode, admitted again
+}
+_RING_RUNS: dict = {}
+
+
+def _ring_model():
+    if "model" not in _RING_RUNS:
+        config = CausalSequenceModelConfig(
+            vocab_size=VOCAB, max_seq_len=RING_WINDOW, max_latents=RING_LATENTS, num_channels=16,
+            num_heads=2, num_self_attention_layers=2, cross_attention_dropout=0.0,
+        )
+        model = CausalSequenceModel(config=config, param_dtype=jnp.float64)
+        rng = jax.random.PRNGKey(0)
+        params = jax.jit(model.init, static_argnames="prefix_len")(
+            rng, jax.random.randint(rng, (1, 12), 0, VOCAB), prefix_len=4
+        )
+        _RING_RUNS["model"] = (model, params)
+    return _RING_RUNS["model"]
+
+
+def _ring_reference(name):
+    """``generate()`` on the canonical left-padded full window."""
+    key = ("reference", name)
+    if key not in _RING_RUNS:
+        model, params = _ring_model()
+        prompt = RING_PROMPTS[name]
+        ids = np.zeros((1, RING_WINDOW), np.int64)
+        pad = np.ones((1, RING_WINDOW), bool)
+        ids[0, RING_WINDOW - len(prompt):] = prompt
+        pad[0, RING_WINDOW - len(prompt):] = False
+        out = generate(model, params, jnp.asarray(ids), num_latents=RING_LATENTS,
+                       pad_mask=jnp.asarray(pad), config=GenerationConfig(max_new_tokens=RING_NEW))
+        _RING_RUNS[key] = np.asarray(out)[0, RING_WINDOW:].tolist()
+    return _RING_RUNS[key]
+
+
+def _ring_run(tick):
+    """One engine life per tick program, shared by the cases below: requests of
+    mixed lengths admitted at different ticks, so that the slots' rings sit at
+    different offsets; every admission path; one slot evicted and admitted
+    again while its ring is mid-turn."""
+    if tick in _RING_RUNS:
+        return _RING_RUNS[tick]
+    model, params = _ring_model()
+    with pytest.MonkeyPatch.context() as env:  # the switch is read when the engine is built
+        env.setenv("PERCEIVER_IO_TPU_DISABLE_RAGGED_TICK", "1" if tick == "composed" else "0")
+        engine = ServingEngine(model, params, num_slots=RING_SLOTS, kv_page_size=RING_PS,
+                               prefill_chunk_tokens=4, prefix_cache=True)
+    assert engine.ragged is (tick == "fused")
+    submit = lambda name: engine.submit(RING_PROMPTS[name], max_new_tokens=RING_NEW)
+    handles, starts = {}, []
+
+    def steps(n):
+        for _ in range(n):
+            engine.step()
+            starts.append(np.asarray(engine._cache.sa.start).tolist())
+
+    handles["prefill_install"] = submit("prefill_install")
+    steps(2)
+    handles["chunks_finish"] = submit("chunks_finish")
+    steps(3)
+    handles["prefix_donor"] = submit("prefix_donor")
+    steps(2)
+    victim = submit("evict_readmit")
+    steps(6)
+    assert victim.status.value == "running" and 0 < len(victim.output_ids) < RING_NEW
+    victim_slot = victim.slot
+    engine.evict_request(victim.request_id)
+    steps(2)  # the freed slot decodes on: its ring keeps turning
+    mid_ring = starts[-1][victim_slot]
+    handles["evict_readmit"] = submit("evict_readmit")
+    handles["prefix_hit"] = submit("prefix_hit")
+    steps(1)
+    engine.run_until_drained(max_steps=400)
+    run = {
+        "handles": handles, "starts": starts, "mid_ring": mid_ring, "victim_slot": victim_slot,
+        "prefix_hits": engine._prefix_cache.hits, "decode_compilations": engine.decode_compilations,
+        "pages_in_use": engine._pool.pages_in_use, "cached_pages": engine._prefix_cache.cached_pages,
+    }
+    _RING_RUNS[tick] = run
+    return run
+
+
+@pytest.mark.parametrize("tick", ["fused", "composed"])
+@pytest.mark.parametrize("phase", sorted(RING_PROMPTS))
+def test_paged_sa_ring_tokens_match_generate(x64, tick, phase):
+    """Acceptance of the ring (f64, greedy): whatever a slot's ring offset and
+    however the request got there — prefill + install, chunks + finish, behind
+    a prefix-cache hit, into a slot evicted mid-turn — its tokens are
+    ``generate()``'s over ``2 * max_latents + 3`` decode steps, for the fused
+    tick and for the composed one."""
+    run = _ring_run(tick)
+    handle = run["handles"][phase]
+    assert handle.ok and len(handle.output_ids) == RING_NEW
+    assert handle.result().tolist() == _ring_reference(phase), f"{phase} diverged under the {tick} tick"
+    # the scenario did what the cases are named for
+    assert any(len(set(row)) > 1 for row in run["starts"])  # slots at different offsets
+    assert run["mid_ring"] != 0 and run["handles"]["evict_readmit"].slot is None
+    assert run["prefix_hits"] >= 1 and run["decode_compilations"] == 1
+    assert run["pages_in_use"] == run["cached_pages"]  # only the cache's own pages stay
+
+
+def _cache_sized_results(jaxpr, shapes, found):
+    """Every equation of ``jaxpr`` (sub-programs included) with a result of one
+    of ``shapes``: (primitive, shape) pairs; and for every ``scan`` the shapes
+    of its scanned inputs and stacked outputs (per iteration for the inputs)."""
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            if getattr(var.aval, "shape", None) in shapes:
+                found["results"].add((eqn.primitive.name, var.aval.shape))
+        if eqn.primitive.name == "scan":
+            carried = eqn.params["num_consts"] + eqn.params["num_carry"]
+            found["scanned"].update(v.aval.shape for v in eqn.invars[carried:])
+            found["scanned"].update(v.aval.shape for v in eqn.outvars[eqn.params["num_carry"]:])
+        for param in eqn.params.values():
+            for sub in (param if isinstance(param, (tuple, list)) else (param,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _cache_sized_results(sub, shapes, found)
+    return found
+
+
+# programs a cache-sized value may pass through, and the only two writes
+_CONTAINERS = {"scan", "pjit", "jit", "closed_call", "core_call", "cond", "while", "remat", "checkpoint", "custom_jvp_call"}
+_WRITES = {"scatter", "dynamic_update_slice"}
+
+
+@pytest.mark.parametrize("program", ["decode_step-kernel", "decode_step-xla", "ragged_tick-kernel"])
+def test_paged_decode_moves_no_cache_sized_buffer(program, monkeypatch):
+    """The guard against the roll coming back (a CPU time guards nothing): in
+    the paged decode step no equation other than the row write (a scatter; an
+    install's row update in the tick) has a result of a layer's cache shape
+    or of the stacked shape — no roll, select, concatenate or slice of it —
+    and the layer loop carries the stacked buffers: it has no scanned input
+    or output of their shape. With the fused kernel (what the chip compiles)
+    the kernel call takes the STACKED buffers; the XLA formulation (the CPU,
+    unsupported shapes) reads the layer through one ``dynamic_slice``."""
+    import perceiver_io_tpu.ops.decode_kernel as dk
+
+    name, form = program.split("-")
+    model, params = _make_model()
+    layers, slots, cap, ch = 2, 3, LATENTS, 16
+    stacked, layer = (layers, slots, cap, ch), (slots, cap, ch)
+    shapes = {stacked, layer, (1, *layer)}
+    monkeypatch.setattr(dk, "decode_kernel_supported", lambda *a, **kw: form == "kernel")
+    engine = ServingEngine(model, params, num_slots=slots, kv_page_size=4)
+    assert engine._cache.sa.k.shape == stacked
+    if name == "decode_step":
+        fn = lambda p, tok, cache: model.apply(p, tok, cache, method=CausalSequenceModel.decode_step_paged)
+        jaxpr = jax.make_jaxpr(fn)(params, jnp.zeros((slots, 1), jnp.int32), engine._cache)
+    else:
+        args = engine._ragged_args(True, engine._forced_none, engine._use_forced_none)
+        jaxpr = jax.make_jaxpr(engine._jit_ragged_tick)(*args)
+    found = _cache_sized_results(jaxpr.jaxpr, shapes, {"results": set(), "scanned": set()})
+
+    by_primitive = {}
+    for primitive, shape in found["results"]:
+        by_primitive.setdefault(primitive, set()).add(shape)
+    assert "scatter" in by_primitive and by_primitive["scatter"] == {stacked}  # the append, in the stacked buffer
+    moved = set(by_primitive) - _CONTAINERS - _WRITES
+    if form == "kernel":
+        assert not moved, f"cache-sized results besides the row write: { {p: by_primitive[p] for p in moved} }"
+        assert "pallas_call" in str(jaxpr)
+    else:
+        # the XLA formulation's one read of the layer, and its view as heads
+        assert moved <= {"dynamic_slice", "squeeze", "reshape", "transpose"}, moved
+        assert by_primitive["dynamic_slice"] == {(1, *layer)}
+    assert not (found["scanned"] & shapes), "the layer loop scans over the cache instead of carrying it"
