@@ -1,7 +1,6 @@
-"""Glue to the system under test: the only module of the benchmark that imports
-``perceiver_io_tpu``. It builds the program's model, trainer pieces and serving engine
-from a configuration file and a cell's settings, and lays the benchmark's own weights
-(``reference/weights.py``) into the program's parameter tree."""
+"""Glue to the system under test: the family's one module that imports the program's
+model. It builds the program's model and its train step from a configuration file, and
+lays the benchmark's own weights (``weights.py``) into the program's parameter tree."""
 
 from __future__ import annotations
 
@@ -22,6 +21,13 @@ def build_model(config: dict, deterministic: bool, dtype_name: str | None = None
 
     dtype = DTYPES[dtype_name or config["compute_dtype"]]
     return CausalSequenceModel(config=model_config(config), deterministic=deterministic, dtype=dtype)
+
+
+def make_program_train_step(model, tx, sizes: dict):
+    """The program's own causal-LM step over the latents of each row."""
+    from perceiver_io_tpu.training import trainer
+
+    return trainer.make_causal_lm_train_step(model, tx, max_latents=sizes["max_latents"])
 
 
 def to_program_params(weights: dict) -> dict:

@@ -5,7 +5,7 @@ long-context autoregressive modeling with Perceiver AR", and the krasserm/percei
 ``CausalSequenceModel``): straightforward ``jax.numpy``, float32, every matrix
 multiplication at ``highest`` precision, no kernels, no cache, no batching of requests.
 It imports nothing of ``perceiver_io_tpu`` and takes only what the benchmark itself made
-(weights from ``reference/weights.py``, tokens from the traffic generator).
+(weights from ``weights.py`` beside it, tokens from the traffic generator).
 
 The model (sizes from the configuration file):
 

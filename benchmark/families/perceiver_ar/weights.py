@@ -2,7 +2,7 @@
 
 The benchmark owns the weights: one jitted call makes the whole tree in the type it is
 used in, the harness lays the same arrays into the program's parameter tree
-(``harness/program.py``), and the reference reads them as they are. Nothing here comes
+(``program.py``), and the reference reads them as they are. Nothing here comes
 from the program.
 
 Every leaf is random so that a dropped bias or norm parameter shows: matrices and

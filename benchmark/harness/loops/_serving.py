@@ -1,5 +1,8 @@
 """What every serving loop driver shares (today the open loop): the engine under test, its
-warm-up, the per-tick bookkeeping, and the check of served tokens against the reference.
+warm-up, the per-tick bookkeeping, and the sample of served requests that the family's
+check compares with its reference. What depends on the model (its builder, its weights,
+which prompts warm up every program, how many cache entries a request holds) comes from
+the configuration's family (``benchmark/families/<family>/``).
 
 The harness drives the engine from one thread: it calls ``submit`` for whatever is due
 and then ``step()``, which ends in the tick's one device sync. The engine reports no time
@@ -13,11 +16,8 @@ import time
 
 import numpy as np
 
-from benchmark.harness import check, program
-from benchmark.harness.result import note
+from benchmark.harness import check, manifest, traffic
 from benchmark.harness.tracing import WindowTrace
-from benchmark.reference import perceiver_ar as ref
-from benchmark.reference import weights as ref_weights
 
 
 class Served:
@@ -41,30 +41,28 @@ class Bench:
 
     def __init__(self, cell: dict, env: dict):
         import jax
+        import jax.numpy as jnp
 
         from perceiver_io_tpu.serving import ServingEngine
 
         self.cell, self.env = cell, env
         self.config, self.mix, self.settings = cell["config"], cell["traffic"], cell["settings"]
+        self.family = family = manifest.load_family(self.config["family"])
         self.sizes = self.config["sizes"]
         engine_cfg = self.settings["engine"]
         self.slots, self.page = engine_cfg["num_slots"], engine_cfg["kv_page_size"]
-        self.dtype = program.DTYPES[self.config["compute_dtype"]]
-        self.model = program.build_model(self.config, deterministic=True)
+        self.model = family.build_model(self.config, deterministic=True)
         # weights in the type they are served in, made on the device in one call
-        self.weights = ref_weights.make_weights(self.sizes, env["seed"], self.dtype)
-        params = program.to_program_params(self.weights)
-        program.check_param_tree(self.model, params)
+        self.weights = family.make_weights(self.sizes, env["seed"], jnp.dtype(self.config["compute_dtype"]))
+        params = family.to_program_params(self.weights)
+        family.check_param_tree(self.model, params)
         self.recorder = None
         if env["trace"]:
             from perceiver_io_tpu.obs.core import TelemetryRecorder
 
             self.recorder = TelemetryRecorder()
-        self.engine = ServingEngine(
-            self.model, params, num_slots=self.slots, kv_page_size=self.page,
-            prefill_chunk_tokens=engine_cfg["prefill_chunk_tokens"], prefix_cache=engine_cfg["prefix_cache"],
-            num_kv_pages=engine_cfg.get("num_kv_pages"), kv_quant=engine_cfg.get("kv_quant"),
-            weight_dtype=engine_cfg.get("weight_dtype"), telemetry=self.recorder or False)
+        # the settings file's keys are the engine's argument names: an option is data
+        self.engine = ServingEngine(self.model, params, **engine_cfg, telemetry=self.recorder or False)
         if not self.engine.ragged and not env["rehearse"]:
             raise RuntimeError("the engine did not take the fused ragged tick")
         # EngineMetrics keeps no count of the prompt tokens a prefix hit saved: count the
@@ -94,16 +92,13 @@ class Bench:
 
     # --------------------------------------------------------------- warm-up
     def warm_up(self) -> None:
-        """Compile every program the mix's traffic uses, and nothing else: one prompt
-        under the latent count where the mix has such (prefill + install), one over it
-        (chunks and finish inside the tick), both run to their end."""
-        lengths = self.mix["prompt_tokens"]
-        latents = self.sizes["max_latents"]
-        lo = lengths.get("min", lengths.get("value"))
-        hi = lengths.get("max", lengths.get("value"))
+        """Compile every program the mix's traffic uses, and nothing else: requests of
+        the prompt lengths the family names for the mix's shortest and longest prompt,
+        run to their end."""
+        lo, hi = traffic.length_bounds(self.mix["prompt_tokens"])
         if self.mix.get("shared_prefix"):
             hi = max(hi, self.mix["shared_prefix"]["preamble_tokens"] + lo)
-        probe = [n for n in (min(lo, latents - 1) if lo < latents else None, max(hi, latents)) if n]
+        probe = self.family.warm_up_prompt_lengths(self.sizes, lo, hi)
         rng = np.random.default_rng(0)
         handles = [self.engine.submit(rng.integers(1, self.sizes["vocab_size"], size=n).astype(np.int32),
                                       max_new_tokens=4) for n in probe]
@@ -131,14 +126,14 @@ class Bench:
         if t - before > self.longest_tick[0]:
             self.longest_tick = (t - before, t)
         occupied = entries = 0
-        window = self.sizes["max_seq_len"]
+        sizes, live_entries = self.sizes, self.family.live_cache_entries
         for served in self.live.values():
             n = len(served.handle.output_ids)
             if n > len(served.times):
                 served.times.extend([t] * (n - len(served.times)))
             if not served.handle.done and served.handle.admitted_at is not None:
                 occupied += 1
-                entries += min(len(served.request["prompt"]) + n, window)
+                entries += live_entries(sizes, len(served.request["prompt"]), n)
         if self.tracer is not None:
             self.ticks.append((t, occupied, entries))
         finished = self.engine.finished
@@ -172,10 +167,9 @@ class Bench:
         gc.collect()
         return {"snapshot": snapshot, "obs": obs, "watchdog": compile_summary}
 
-    def check_tokens(self, candidates: list, checks: check.Checks) -> dict:
-        """Score a seeded sample of the finished requests, the longest in it, with the
-        reference: the widest and the mean gap by which a served token's logit lies
-        below the reference's best."""
+    def check_served(self, candidates: list, checks: check.Checks) -> dict:
+        """Hand a seeded sample of the finished requests, the longest in it, to the
+        family's comparison with its reference, under the cell's limits."""
         limits, n = self.settings["limits"], self.mix["check_requests"]
         rng = np.random.default_rng([self.env["seed"], 3])
         pool = [s for s in candidates if s.ok]
@@ -186,26 +180,5 @@ class Bench:
         rest = [s for s in pool if s is not longest]
         picks = [longest] + [rest[i] for i in rng.permutation(len(rest))[: n - 1]]
         controls = [p for p in self.env.get("reference_precision", "float32").split(",") if p != "float32"]
-        deficits, control = [], {p: [] for p in controls}
-        t0 = time.perf_counter()
-        for served in picks:
-            tokens = np.asarray(served.handle.output_ids, np.int32)
-            logits = np.asarray(ref.score_served(self.weights, self.sizes, served.request["prompt"], tokens))
-            deficits.append(check.token_deficits(logits, tokens))
-            for precision in controls:
-                # a control: at each position, the token the lower precision puts first
-                low = np.asarray(ref.score_served(self.weights, self.sizes, served.request["prompt"], tokens, precision))
-                control[precision].append(check.token_deficits(logits, low.argmax(axis=-1)))
-        deficits = np.concatenate(deficits)
-        control = {p: np.concatenate(d) for p, d in control.items()}
-        scored = control[controls[0]] if controls else deficits
-        checks.at_most("served_token_deficit_max", scored.max(), limits["served_token_deficit_max"])
-        checks.at_most("served_token_deficit_mean", scored.mean(), limits["served_token_deficit_mean"])
-        report = {"phase": "reference", "seconds": time.perf_counter() - t0, "requests": len(picks),
-                  "tokens": int(len(deficits)), "scored": controls[0] if controls else "program",
-                  "program_deficit_max": float(deficits.max()), "program_deficit_mean": float(deficits.mean()),
-                  "tokens_off_reference_argmax": int((deficits > 0).sum()),
-                  "controls": {p: {"deficit_max": float(d.max()), "deficit_mean": float(d.mean()),
-                                   "tokens_moved": int((d > 0).sum())} for p, d in control.items()}}
-        note(report)
-        return report
+        served = [(s.request["prompt"], np.asarray(s.handle.output_ids, np.int32)) for s in picks]
+        return self.family.check_served(self.weights, self.sizes, served, limits, checks, controls)

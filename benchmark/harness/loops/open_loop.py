@@ -120,7 +120,7 @@ def run(cell: dict, env: dict) -> dict:
     engine_books = bench.close()
 
     checks = check.Checks()
-    bench.check_tokens(books["good"], checks)
+    bench.check_served(books["good"], checks)
     checks.at_most("failed_requests", books["failed"], 0)
     checks.at_most("compilations_in_window", compiled_in_window, 0)
     end_to_end = {}
@@ -138,7 +138,7 @@ def run(cell: dict, env: dict) -> dict:
                              if s.due < ramp + 0.5 * seconds and s.handle.admitted_at is not None],
             "prefix_hit_tokens": hit_tokens,
             "prompt_tokens_admitted": books["prompt_tokens_admitted"], "ticks": bench.ticks, "slots": bench.slots,
-            "sizes": bench.sizes, "program_name": "ragged_tick", "window_s": seconds, "chips": cell["chips"],
+            "sizes": bench.sizes, "program_name": bench.family.TICK_PROGRAM, "window_s": seconds, "chips": cell["chips"],
             "trace_span": trace_span,
         },
     }

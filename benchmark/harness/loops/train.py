@@ -7,7 +7,8 @@ state to the window. The window is one more fit, whose step count is worked out 
 step time (``TrainerConfig`` has no time limit): it opens at that fit's first log boundary
 and closes at its last, each of which ends in a device sync, and tokens/s is all the
 steps between the two over all the time between. A traced run then profiles a few
-seconds' steps of a further fit of the same state.
+seconds' steps of a further fit of the same state. The model, its step, its weights and
+the reference's step come from the configuration's family (``benchmark/families/<family>/``).
 """
 
 from __future__ import annotations
@@ -21,11 +22,9 @@ import time
 
 import numpy as np
 
-from benchmark.harness import check, program, traffic
+from benchmark.harness import check, manifest, traffic
 from benchmark.harness.result import note
 from benchmark.harness.tracing import WindowTrace
-from benchmark.reference import perceiver_ar as ref
-from benchmark.reference import weights as ref_weights
 
 
 class Rows:
@@ -91,13 +90,14 @@ def run(cell: dict, env: dict) -> dict:
     from perceiver_io_tpu.scripts.common import run_fit
     from perceiver_io_tpu.training.fit import TrainerConfig
     from perceiver_io_tpu.training.metrics import load_metrics_jsonl
-    from perceiver_io_tpu.training.trainer import TrainState, build_optimizer, make_causal_lm_train_step
+    from perceiver_io_tpu.training.trainer import TrainState, build_optimizer
 
     config, mix, settings = cell["config"], cell["traffic"], cell["settings"]
+    family = manifest.load_family(config["family"])
     sizes, seed, seconds = config["sizes"], env["seed"], env["seconds"]
     opt, limits = settings["optimizer"], settings["limits"]
     rows_per_step, log_every = mix["rows_per_step"], mix["log_every"]
-    seq_len, latents = sizes["max_seq_len"], sizes["max_latents"]
+    seq_len, trained_tokens = family.row_tokens(sizes)
     ref_steps = mix["reference_steps"]
     mesh_axes = settings.get("mesh_axes")
     monitor = env["monitor"]
@@ -111,20 +111,20 @@ def run(cell: dict, env: dict) -> dict:
         traffic.markov_rows(mix["stream"], sizes, seed,
                             (warm_to + log_every + max_window_steps + max_traced_steps) * rows_per_step, seq_len),
         rows_per_step)
-    model = program.build_model(config, deterministic=False)
+    model = family.build_model(config, deterministic=False)
     tx = build_optimizer(opt["learning_rate"], weight_decay=opt["weight_decay"],
                          max_grad_norm=opt["max_grad_norm"], b1=opt["b1"], b2=opt["b2"])
-    train_step = make_causal_lm_train_step(model, tx, max_latents=latents)
-    key = ref_weights.seed_key(seed)
+    train_step = family.make_program_train_step(model, tx, sizes)
+    key = family.seed_key(seed)
 
     # the seed's key is an ARGUMENT of every program that uses it: closed over, it would
     # be a constant of the program, and every new seed would miss the compile cache
     def make_state(key):
-        params = program.to_program_params(ref_weights.build_weights(sizes, key, jnp.float32))
+        params = family.to_program_params(family.build_weights(sizes, key, jnp.float32))
         return TrainState.create(params, tx, rng=key)
 
-    program.check_param_tree(model, jax.eval_shape(make_state, key).params)
-    tokens_per_step = rows_per_step * latents
+    family.check_param_tree(model, jax.eval_shape(make_state, key).params)
+    tokens_per_step = rows_per_step * trained_tokens
     recorder = TelemetryRecorder() if env["trace"] else False
     tmp = tempfile.mkdtemp(prefix="bench-train-", dir=env["scratch"])
     jsonl = os.path.join(tmp, "train.jsonl")
@@ -142,14 +142,14 @@ def run(cell: dict, env: dict) -> dict:
     def logs() -> list:
         return load_metrics_jsonl(jsonl)["by_kind"]["train_log"]
 
-    leaf_norms = jax.jit(lambda tree: ref.leaf_norms(program.from_program_params(tree)))
-    update_norms = jax.jit(lambda weights, key: ref.leaf_norms(jax.tree.map(
-        lambda a, b: a - b, weights, ref_weights.build_weights(sizes, key, jnp.float32))))
+    leaf_norms = jax.jit(lambda tree: family.leaf_norms(family.from_program_params(tree)))
+    update_norms = jax.jit(lambda weights, key: family.leaf_norms(jax.tree.map(
+        lambda a, b: a - b, weights, family.build_weights(sizes, key, jnp.float32))))
 
     state = fit(jax.jit(make_state)(key), 1, 1)
     got_grad = jax.device_get(leaf_norms(jax.tree.map(lambda m: m / (1 - opt["b1"]), _adam_mu(state.opt_state))))
     state = fit(state, ref_steps, 1)
-    got_update = jax.device_get(update_norms(program.from_program_params(state.params), key))
+    got_update = jax.device_get(update_norms(family.from_program_params(state.params), key))
     got_losses = [line["loss"] for line in logs()][:ref_steps]
     # warm-up: a fit of its own up to the log boundary ``warm_to``; the shortest of its
     # whole log intervals gives the step time that sizes the window's fit (the shortest:
@@ -212,10 +212,10 @@ def run(cell: dict, env: dict) -> dict:
     def reference(precision: str):
         """(losses, first clipped gradient's leaf norms, update's leaf norms) of the
         reference's first steps from the seed's weights."""
-        weights = ref_weights.make_weights(sizes, seed, jnp.float32)
+        weights = family.make_weights(sizes, seed, jnp.float32)
         mu = jax.tree.map(jnp.zeros_like, weights)
         nu = jax.tree.map(jnp.zeros_like, weights)
-        step = ref.make_train_step(sizes, opt, mix["reference_rows_per_block"], precision)
+        step = family.make_train_step(sizes, opt, mix["reference_rows_per_block"], precision)
         losses, first_grad = [], None
         for t, batch in enumerate(batches, start=1):
             weights, mu, nu, loss, grad_norms = step(weights, mu, nu, batch, t)

@@ -4,19 +4,14 @@ traffic mix and per-layer metric is found by its name; nothing here knows one of
 from __future__ import annotations
 
 import copy
+import importlib
 import json
 import os
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 BENCH_DIR = os.path.join(ROOT, "benchmark")
 
-# the keys of a configuration file that size the model (the rest documents it)
-SIZE_KEYS = (
-    "vocab_size", "max_seq_len", "max_latents", "num_channels", "num_heads",
-    "num_self_attention_layers", "num_self_attention_rotary_layers",
-    "self_attention_widening_factor", "cross_attention_widening_factor",
-    "cross_attention_dropout", "abs_pos_emb", "output_norm", "output_bias", "init_scale",
-)
+FAMILIES = "benchmark.families"
 
 
 def _load(path: str) -> dict:
@@ -28,13 +23,36 @@ def load_manifest() -> dict:
     return _load(os.path.join(ROOT, "BENCHMARK.json"))
 
 
+def _family_dirs() -> list:
+    return list(importlib.import_module(FAMILIES).__path__)
+
+
+def load_family(name: str, named_by: str = "the configuration"):
+    """The package ``benchmark/families/<name>/``: a model family's glue to the program,
+    its weights, its reference and what the drivers ask of it (``families/__init__.py``
+    lists the names). Imported once; every later call finds it."""
+    try:
+        return importlib.import_module(f"{FAMILIES}.{name}")
+    except ModuleNotFoundError as e:
+        if e.name != f"{FAMILIES}.{name}":
+            raise  # the family is there, and something it imports is not
+        raise ValueError(f"{named_by} names the family {name!r}, and there is no {name}/__init__.py "
+                         f"under {_family_dirs()}") from e
+
+
 def load_config(config_entry: dict) -> dict:
-    """A configuration file as the harness uses it: ``sizes`` gathered from the top level."""
-    raw = _load(os.path.join(ROOT, config_entry["file"]))
-    missing = [k for k in SIZE_KEYS if k not in raw]
+    """A configuration file as the harness uses it: ``sizes`` gathered from the top level
+    under the keys its ``family`` sizes a model by (``vocab_size`` is every family's: the
+    traffic generator draws token ids from it)."""
+    file = config_entry["file"]
+    raw = _load(os.path.join(ROOT, file))
+    if not isinstance(raw.get("family"), str):
+        raise ValueError(f'{file} has no "family": it has to name a directory under {_family_dirs()}')
+    size_keys = dict.fromkeys(("vocab_size", *load_family(raw["family"], file).SIZE_KEYS))
+    missing = [k for k in size_keys if k not in raw]
     if missing:
-        raise ValueError(f"{config_entry['file']} lacks {missing}")
-    return {**raw, "sizes": {k: raw[k] for k in SIZE_KEYS}}
+        raise ValueError(f"{file} lacks {missing}, which the family {raw['family']!r} sizes its model by")
+    return {**raw, "sizes": {k: raw[k] for k in size_keys}}
 
 
 def load_traffic(name: str) -> dict:

@@ -20,8 +20,18 @@ def fail(message: str, code: int = 1) -> int:
     return code
 
 
+def say_compared(rows: list) -> None:
+    """Each number ``correct`` was decided by, beside its limit: the last lines of
+    standard error (a record of a run that is not correct keeps only the end of it)."""
+    for row in rows:
+        print(f"compared {row['check']}: value {row['value']!r} limit {row['limit']!r} {'ok' if row['ok'] else 'NOT OK'}",
+              file=sys.stderr, flush=True)
+
+
 def result_line(correct: bool, attempted: int, failed: int, metrics: dict, units: dict,
-                device: dict, breakdown: dict | None = None) -> str:
+                device: dict, breakdown: dict | None = None, compared: list = ()) -> str:
+    """The one JSON object a run prints last; ``compared`` (the checks' rows) comes last
+    in it, each number under its short name with its limit."""
     line = {
         "correct": bool(correct), "attempted": int(attempted), "failed": int(failed),
         "metrics": {name: {"value": float(value), "unit": units[name]} for name, value in metrics.items()},
@@ -29,4 +39,5 @@ def result_line(correct: bool, attempted: int, failed: int, metrics: dict, units
     }
     if breakdown is not None:
         line["breakdown"] = breakdown
+    line["compared"] = {row["check"]: {"value": row["value"], "limit": row["limit"]} for row in compared}
     return json.dumps(line)

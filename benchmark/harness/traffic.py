@@ -14,10 +14,29 @@ def _quantile_grid(n: int) -> np.ndarray:
     return (np.arange(n) + 0.5) / n
 
 
+def _part_counts(shares: list, n: int) -> list:
+    """``n`` split by ``shares`` (which sum to 1): each part's floor, and what is left
+    over to the largest remainders, the earlier part first among equals."""
+    if abs(sum(shares) - 1.0) > 1e-9 or min(shares) < 0:
+        raise ValueError(f"a mixture's shares have to be >= 0 and sum to 1, got {shares}")
+    exact = [s * n for s in shares]
+    counts = [int(x + 1e-9) for x in exact]
+    by_remainder = sorted(range(len(shares)), key=lambda i: (counts[i] - exact[i], i))
+    for i in by_remainder[: n - sum(counts)]:
+        counts[i] += 1
+    return counts
+
+
 def length_set(law: dict, n: int) -> np.ndarray:
-    """``n`` lengths: the law's quantiles at (i + 0.5) / n, clipped to its bounds."""
-    u = _quantile_grid(n)
+    """``n`` lengths: the law's quantiles at (i + 0.5) / n, clipped to its bounds. A
+    ``mixture`` gives each of its ``parts`` (a law with a ``share``) its share of ``n``
+    and that part's own quantiles and bounds, so that short and long requests sit in one
+    queue in fixed numbers."""
     kind = law["law"]
+    if kind == "mixture":
+        counts = _part_counts([part["share"] for part in law["parts"]], n)
+        return np.concatenate([length_set(part, k) for part, k in zip(law["parts"], counts)])
+    u = _quantile_grid(n)
     if kind == "fixed":
         return np.full(n, int(law["value"]), np.int64)
     if kind == "uniform":
@@ -28,6 +47,16 @@ def length_set(law: dict, n: int) -> np.ndarray:
     else:
         raise ValueError(f"unknown length law {kind!r}")
     return np.clip(np.rint(x), law["min"], law["max"]).astype(np.int64)
+
+
+def length_bounds(law: dict) -> tuple:
+    """(shortest, longest) length the law can give."""
+    if law["law"] == "mixture":
+        bounds = [length_bounds(part) for part in law["parts"]]
+        return min(lo for lo, _ in bounds), max(hi for _, hi in bounds)
+    if law["law"] == "fixed":
+        return int(law["value"]), int(law["value"])
+    return law["min"], law["max"]
 
 
 def arrival_gaps(rate_rps: float, n: int) -> np.ndarray:
@@ -43,6 +72,7 @@ def make_requests(mix: dict, sizes: dict, seed: int, n: int, rate_rps: float | N
     vocab = sizes["vocab_size"]
     prompt_len = rng.permutation(length_set(mix["prompt_tokens"], n))
     new_tokens = rng.permutation(length_set(mix["new_tokens"], n))
+    longest = length_bounds(mix["prompt_tokens"])[1]
     shared = mix.get("shared_prefix")
     behind = np.zeros(n, bool)
     preambles = None
@@ -60,7 +90,7 @@ def make_requests(mix: dict, sizes: dict, seed: int, n: int, rate_rps: float | N
         length = int(prompt_len[i])
         if behind[i]:
             pre = preambles[int(rng.integers(0, len(preambles)))]
-            length = min(len(pre) + length, mix["prompt_tokens"]["max"])
+            length = min(len(pre) + length, longest)
             prompt = np.concatenate([pre, rng.integers(1, vocab, size=length - len(pre))])
         else:
             prompt = rng.integers(1, vocab, size=length)

@@ -40,7 +40,7 @@ def _override(cell: dict, assignment: str) -> None:
 def run(args) -> int:
     from benchmark.harness import device, layers, manifest, stall
     from benchmark.harness.loops import driver_for
-    from benchmark.harness.result import fail, note, result_line
+    from benchmark.harness.result import fail, note, result_line, say_compared
 
     try:
         cell = manifest.resolve_cell(args.workload)
@@ -106,7 +106,9 @@ def run(args) -> int:
     else:
         metrics = {**outcome["end_to_end"], "setup_s": setup_s}
         metrics = {m["name"]: metrics[m["name"]] for m in cell["end_to_end"]}
-    line = result_line(outcome["checks"].ok, outcome["attempted"], outcome["failed"], metrics, units, dev, breakdown)
+    rows = outcome["checks"].rows
+    line = result_line(outcome["checks"].ok, outcome["attempted"], outcome["failed"], metrics, units, dev, breakdown, rows)
+    say_compared(rows)
     if args.rehearse:
         note({"phase": "rehearsal-result", "would_print": line})
         return fail("rehearsal: every phase ran at a toy size; not a result", 4)

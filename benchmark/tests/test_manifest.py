@@ -63,7 +63,9 @@ def test_every_cell_resolves_and_reports(cell):
     names = {m["name"] for m in resolved["end_to_end"]}
     assert "setup_s" in names and len(names) >= 2 and resolved["per_layer"]
     assert resolved["traffic"]["kind"] in ("train", "open_loop")
-    for key in ("vocab_size", "num_channels", "max_seq_len"):
+    size_keys = manifest.load_family(resolved["config"]["family"]).SIZE_KEYS
+    assert "vocab_size" in resolved["config"]["sizes"] and set(size_keys) <= set(resolved["config"]["sizes"])
+    for key in size_keys:
         assert resolved["config"]["sizes"][key] == resolved["config"][key]
 
 

@@ -8,9 +8,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark.harness import check, program
-from benchmark.reference import perceiver_ar as ref
-from benchmark.reference import weights as ref_weights
+from benchmark.families.perceiver_ar import program
+from benchmark.families.perceiver_ar import reference as ref
+from benchmark.families.perceiver_ar import weights as ref_weights
+from benchmark.harness import check
 from benchmark.tests.conftest import TOY
 
 
