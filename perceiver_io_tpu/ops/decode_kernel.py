@@ -1,13 +1,26 @@
 """Pallas fused cached-decode attention (single query over a KV ring cache).
 
-The scan-decode hot loop's per-layer attention currently materializes a rotated
-copy of the ENTIRE cached key buffer every token (the reference's torch design
-re-rotates the cache each forward, core modules.py:126-130), then runs masked
-softmax-attention over it — several full HBM round trips per token per layer.
-This kernel streams the caches once: per KV block it applies RoPE to the keys
-in-register, computes masked scores against the single query, and merges into
-flash-style running (max, sum, accumulator) scratch — no rotated-K
+The XLA formulation of the scan-decode hot loop's per-layer attention
+materializes a rotated copy of the ENTIRE cached key buffer every token (the
+reference's torch design re-rotates the cache each forward, core
+modules.py:126-130), then runs masked softmax-attention over it — several full
+HBM round trips per token per layer. This kernel streams the caches once: per
+KV block it computes masked scores of the rotated keys against the query and
+merges into flash-style running (max, sum, accumulator) scratch — no rotated-K
 materialization, no (1, cap) score tensor in HBM.
+
+Where the rotation is applied: on the QUERY side. The cached keys are stored
+unrotated and stay so inside the kernel; for k_rot = k*cos + rotate_half(k)*sin
+the score is k_rot . q = (k*cos) . q + (k*sin) . q_hat with q_hat the
+rotate-half of the query ([q1, q2] -> [q2, -q1] per rotary pair), because the
+angles are equal within a pair. q_hat is built once per query outside the
+kernel (``_blockdiag_queries``); a block costs two elementwise products and two
+thin score matmuls (``_rotary_scores``). Rotating the keys in the kernel needs
+either a pair swizzle along the lanes, which Mosaic cannot lower, or a matmul
+against an (h*d, h*d) rotate-half constant, which at width 1280 was four fifths
+of the kernel's matrix-unit work and 6.5 MB of its VMEM (PERF.md, PR 29). The
+paged and ragged kernels (ops/paged_decode_kernel.py,
+ops/ragged_paged_kernel.py) share both helpers.
 
 Forward-only (decode is inference); the training paths use the splash kernel.
 Masking: slot j is visible iff j <= q_pos (the ring cache's left-aligned
@@ -29,18 +42,20 @@ import jax.numpy as jnp
 # KV block candidates, largest first, and the VMEM the kernel may plan for.
 # The kernel's scoped VMEM at block ``blk`` and packed width ``hd = h*d`` was
 # measured by compiling for a v5e without one (binary search on
-# ``vmem_limit_bytes``; PERF.md): the (hd, hd) f32 rotate-half constant, held
-# once, plus per block element the double-buffered K and V blocks and two f32
-# temporaries — 17.0 MiB at (512, 1280) with a bf16 cache, over the 16 MiB
-# scoped default; 11.8 MiB at (256, 1280). The budget leaves the rest of the
-# 16 MiB to what XLA itself parks in VMEM around the call (it moved the
-# measured need by up to 3 MiB between batch and capacity variants).
+# ``vmem_limit_bytes``; PERF.md, PR 29): per block element the double-buffered
+# K and V blocks (4 x itemsize) and, over n_q 1-8, widths 512-1280 and batches
+# 1-64, at most 7.6 bytes of f32 temporaries — 6.9 MiB at (512, 1280) with a
+# bf16 cache and one query, 10.2 MiB with eight; 3.9 MiB at (512, 512). Since
+# the rotation moved to the query side no (hd, hd) constant is held. The budget
+# leaves the rest of the 16 MiB scoped default to what XLA itself parks in
+# VMEM around the call (batch and capacity variants moved the measured need
+# by up to 5 MiB).
 _BLOCKS = (512, 256, 128)
 _VMEM_BUDGET = 12 * 2**20
 
 
 def _vmem_estimate(blk: int, hd: int, itemsize: int) -> int:
-    return 4 * hd * hd + blk * hd * (4 * itemsize + 8) + 2**19
+    return blk * hd * (4 * itemsize + 8) + 2**19
 
 
 def _kv_block(capacity: int, hd: int, itemsize: int) -> Optional[int]:
@@ -99,18 +114,49 @@ def decode_kernel_supported(
     )
 
 
-def _rotate_half_blockdiag(h: int, d: int, r: int):
-    """Constant (h*d, h*d) block-diagonal matrix: per head, the leading (r, r)
-    corner rotates adjacent pairs [x1, x2] -> [-x2, x1]; the rest is zero.
-    (x @ M) gives rotate_half on each head's rotary dims and 0 elsewhere — a
-    matmul avoids the lane-dim pair-swizzles Mosaic cannot lower."""
-    import numpy as np
+def _blockdiag_queries(q: jax.Array, r: int) -> jax.Array:
+    """(B, H, n_q, D) scaled+rotated queries -> (B, 2, H*D, n_q*H), both planes
+    block-diagonal (column qi*H+head holds query qi's head slice in rows
+    [head*D, (head+1)*D), zeros elsewhere): plane 0 carries q, plane 1 carries
+    q_hat, the rotate-half of q on each head's ``r`` rotary dims (per pair
+    [q1, q2] -> [q2, -q1]) and zero on the rest. ``_rotary_scores`` says why."""
+    from perceiver_io_tpu.ops.position import rotate_half
 
-    rot = np.zeros((d, d), np.float32)
-    for i in range(0, r, 2):
-        rot[i + 1, i] = -1.0
-        rot[i, i + 1] = 1.0
-    return np.kron(np.eye(h, dtype=np.float32), rot)
+    b, h, n_q, d = q.shape
+    # rotate_half is [q1, q2] -> [-q2, q1], the map applied to the KEYS; its
+    # transpose, which lands on the query, is the negative
+    q_hat = jnp.pad(-rotate_half(q[..., :r]), ((0, 0), (0, 0), (0, 0), (0, d - r)))
+    planes = jnp.stack([q, q_hat], axis=1).transpose(0, 1, 2, 4, 3)  # (B, 2, H, D, n_q)
+    eye = jnp.eye(h, dtype=q.dtype)
+    return (planes[..., None] * eye[:, None, None, :]).reshape(b, 2, h * d, n_q * h)
+
+
+def _rotary_scores(k: jax.Array, ang: jax.Array, qq_ref, h: int) -> jax.Array:
+    """Scores of the ROTATED keys against every query, (blk, n_q*h), from the
+    unrotated f32 keys ``k`` (blk, h*d), their angles ``ang`` (blk, r;
+    pairwise-repeated) and the two query planes of ``_blockdiag_queries``.
+
+    The rotation is moved off the keys. With M the rotate-half map,
+    k_rot = k*cos + (k M)*sin, and sin is equal within a rotary pair, so it
+    commutes with M:  k_rot . q = (k*cos) . q + (k*sin) . (M q).  M q = q_hat
+    is built once per query outside the kernel; a block then needs two
+    elementwise products and two thin score matmuls. No pair swizzle along the
+    lanes (which Mosaic cannot lower) and no (h*d, h*d) rotate-half matmul
+    (which was four fifths of the kernel's matrix-unit work at width 1280)."""
+    blk, hd = k.shape
+    d = hd // h
+    r = ang.shape[1]
+    contract = (((1,), (0,)), ((), ()))
+    # tile the angles' cos / sin across heads -> per-channel (blk, h*d); off the
+    # rotary dims the keys pass unrotated (cos 1) and q_hat is zero (sin 0)
+    cos_fill = [jnp.ones((blk, d - r), jnp.float32)] if d > r else []
+    sin_fill = [jnp.zeros((blk, d - r), jnp.float32)] if d > r else []
+    cos = jnp.concatenate(([jnp.cos(ang)] + cos_fill) * h, -1)
+    sin = jnp.concatenate(([jnp.sin(ang)] + sin_fill) * h, -1)
+    return (
+        jax.lax.dot_general(k * cos, qq_ref[0], contract, preferred_element_type=jnp.float32)
+        + jax.lax.dot_general(k * sin, qq_ref[1], contract, preferred_element_type=jnp.float32)
+    )
 
 
 def _head_expander(h: int, d: int):
@@ -121,7 +167,7 @@ def _head_expander(h: int, d: int):
     return np.kron(np.eye(h, dtype=np.float32), np.ones((1, d), np.float32))
 
 
-def _kernel(qpos_ref, live_ref, layer_ref, qbd_ref, k_ref, v_ref, ang_ref, pad_ref, rot_ref, exp_ref, o_ref, m_ref, l_ref, acc_ref):
+def _kernel(qpos_ref, live_ref, layer_ref, qq_ref, k_ref, v_ref, ang_ref, pad_ref, exp_ref, o_ref, m_ref, l_ref, acc_ref):
     """Grid (B, num_blocks); block i covers cache slots [i*blk, (i+1)*blk).
 
     qpos_ref (B,)            absolute position of the LAST query (scalar-prefetch, SMEM)
@@ -132,21 +178,21 @@ def _kernel(qpos_ref, live_ref, layer_ref, qbd_ref, k_ref, v_ref, ang_ref, pad_r
                              skip all compute — the ragged length-aware early exit.
     layer_ref (1,)           which layer of a stacked cache the K/V blocks come
                              from (scalar-prefetch); read by the K/V index map alone
-    qbd_ref  (h*d, n_q*h)    block-diagonal scaled+rotated queries (col qi*h+head
-                             holds query qi's head slice in rows [head*d, (head+1)*d))
-    k_ref    (1, blk, h*d)   unrotated keys
+    qq_ref   (2, h*d, n_q*h) block-diagonal scaled+rotated queries q and their
+                             rotate-half q_hat (``_blockdiag_queries``)
+    k_ref    (1, blk, h*d)   unrotated keys; they stay unrotated: the rotation
+                             is applied on the query side (``_rotary_scores``)
     v_ref    (1, blk, h*d)   values
     ang_ref  (1, blk, r)     rotary angles per slot (pairwise-repeated)
     pad_ref  (1, blk, 1)     pad-slot mask (int8, 1 = pad)
-    rot_ref  (h*d, h*d)      block-diag rotate-half matrix
     exp_ref  (h, h*d)        head->channel expander
     o_ref    (1, n_q, h*d)   output
     scratch: m, l (8, 128) VMEM (query qi's per-head stats in row qi), acc (8, h*d)
                              (query qi's output accumulator in row qi)
 
-    Everything is a full-width 2D op: the rotate and score contractions are
-    single (blk, h*d) matmuls covering all heads and all queries (MXU-shaped, no
-    per-head slicing), and softmax stats live in (1, h) rows that broadcast over
+    Everything is a full-width 2D op: the score contractions are (blk, h*d)
+    matmuls covering all heads and all queries (MXU-shaped, no per-head
+    slicing), and softmax stats live in (1, h) rows that broadcast over
     sublanes — the orientations Mosaic lowers natively. The per-query loop is a
     trace-time Python unroll over static scratch rows (n_q <= 8).
 
@@ -160,11 +206,8 @@ def _kernel(qpos_ref, live_ref, layer_ref, qbd_ref, k_ref, v_ref, ang_ref, pad_r
     i = pl.program_id(1)
     nblocks = pl.num_programs(1)
     blk = k_ref.shape[1]
-    hd = k_ref.shape[2]
     h = exp_ref.shape[0]
-    n_q = qbd_ref.shape[1] // h
-    r = ang_ref.shape[2]
-    d = hd // h
+    n_q = qq_ref.shape[2] // h
     contract = (((1,), (0,)), ((), ()))
 
     @pl.when(i == 0)
@@ -179,17 +222,9 @@ def _kernel(qpos_ref, live_ref, layer_ref, qbd_ref, k_ref, v_ref, ang_ref, pad_r
 
     @pl.when(i >= dead)
     def _compute():
-        ang = ang_ref[0].astype(jnp.float32)  # (blk, r)
-        # tile [angles, identity-fill] across heads -> per-channel (blk, h*d)
-        fill = [jnp.ones((blk, d - r), jnp.float32)] if d > r else []
-        cos = jnp.concatenate(([jnp.cos(ang)] + fill) * h, -1)  # (blk, h*d)
-        sin = jnp.concatenate(([jnp.sin(ang)] + fill) * h, -1)
-
-        k = k_ref[0].astype(jnp.float32)  # (blk, h*d)
-        rot_half = jax.lax.dot_general(k, rot_ref[:], contract, preferred_element_type=jnp.float32)
-        k = k * cos + rot_half * sin
-
-        sc_all = jax.lax.dot_general(k, qbd_ref[:], contract, preferred_element_type=jnp.float32)  # (blk, n_q*h)
+        sc_all = _rotary_scores(
+            k_ref[0].astype(jnp.float32), ang_ref[0].astype(jnp.float32), qq_ref, h
+        )  # (blk, n_q*h)
         slot = i * blk + jax.lax.broadcasted_iota(jnp.int32, (blk, 1), 0)
         not_pad = (pad_ref[0].astype(jnp.int32) == 0) & (slot >= live_lo)  # (blk, 1)
         vf = v_ref[0].astype(jnp.float32)
@@ -328,10 +363,6 @@ def fused_decode_attention(
         jnp.broadcast_to(jnp.asarray(live, jnp.int32).reshape(-1), (b,))
         if live is not None else q_pos_arr + 1  # full live region: no skipping
     )
-    # block-diagonal queries: column qi*h+head carries q[:, head, qi] in rows
-    # [head*d, (head+1)*d)
-    eye = jnp.eye(h, dtype=q.dtype)
-    qbd = (q.transpose(0, 1, 3, 2)[:, :, :, :, None] * eye[:, None, None, :]).reshape(b, h * d, n_q * h)
 
     def _slot_map(bi, i, qpos_ref, live_ref, layer_ref):
         # dead head blocks alias the first (possibly) live block: consecutive
@@ -347,12 +378,11 @@ def fused_decode_attention(
         num_scalar_prefetch=3,
         grid=(b, nblocks),
         in_specs=[
-            pl.BlockSpec((None, h * d, n_q * h), lambda bi, i, *_: (bi, 0, 0)),
+            pl.BlockSpec((None, 2, h * d, n_q * h), lambda bi, i, *_: (bi, 0, 0, 0)),
             pl.BlockSpec((None, 1, blk, h * d), _kv_map),
             pl.BlockSpec((None, 1, blk, h * d), _kv_map),
             pl.BlockSpec((1, blk, r), _slot_map),
             pl.BlockSpec((1, blk, 1), _slot_map),
-            pl.BlockSpec((h * d, h * d), lambda bi, i, *_: (0, 0)),
             pl.BlockSpec((h, h * d), lambda bi, i, *_: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, n_q, h * d), lambda bi, i, *_: (bi, 0, 0)),
@@ -371,12 +401,11 @@ def fused_decode_attention(
         q_pos_arr,
         live_arr,
         jnp.asarray(layer, jnp.int32).reshape(1),
-        qbd,
+        _blockdiag_queries(q, r),
         k_cache,
         v_cache,
         rope_k,
         pad_slots.astype(jnp.int8)[:, :, None],
-        jnp.asarray(_rotate_half_blockdiag(h, d, r)),
         jnp.asarray(_head_expander(h, d)),
     )
     return out.reshape(b, n_q, h, d).transpose(0, 2, 1, 3)

@@ -81,7 +81,7 @@ import flax.struct
 import jax
 import jax.numpy as jnp
 
-from perceiver_io_tpu.ops.decode_kernel import _head_expander, _rotate_half_blockdiag
+from perceiver_io_tpu.ops.decode_kernel import _blockdiag_queries, _head_expander, _rotary_scores
 from perceiver_io_tpu.ops.flash import single_device_trace
 
 # supported quantized-page modes (serving/engine.py `kv_quant` knob)
@@ -491,12 +491,14 @@ def _paged_kernel(*refs, window, skip_dead_pages, quantized):
     start_ref (B,)        post-append ring offset (scalar prefetch, SMEM)
     live_ref  (B,)        live (non-pad) entries per row
     table_ref (B, P)      physical page ids
-    qbd_ref   (h*d, h)    block-diagonal scaled+rotated single query
-    k_ref     (1, ps, h*d) unrotated keys of ONE pool page
+    qq_ref    (2, h*d, h) block-diagonal scaled+rotated single query q and its
+                          rotate-half q_hat (decode_kernel._blockdiag_queries)
+    k_ref     (1, ps, h*d) unrotated keys of ONE pool page; they stay
+                          unrotated: the rotation is applied on the query
+                          side (decode_kernel._rotary_scores)
     v_ref     (1, ps, h*d)
     ang_ref   (1, ps, r)  rotary angles per PHYSICAL position (precomputed
                           from the ring logical positions; pairwise-repeated)
-    rot_ref   (h*d, h*d)  block-diag rotate-half matrix
     exp_ref   (h, h*d)    head->channel expander
     o_ref     (1, 1, h*d) output
     scratch: m, l (8, 128) VMEM (per-head stats in row 0), acc (8, h*d)
@@ -516,29 +518,26 @@ def _paged_kernel(*refs, window, skip_dead_pages, quantized):
     sidecars exceed it from about 1k pages. The dequant is FUSED: step i
     reads scale row i (the page the index map fetched — un-aliased whenever
     compute runs), expands it to channels through the same head expander
-    the stats use, and multiplies it into the f32 upcast before rotation —
+    the stats use, and multiplies it into the f32 upcast before the scores —
     bit-identical to feeding the XLA-dequantized f32 pool through this same
     kernel (tests pin it).
     """
     import jax.experimental.pallas as pl
 
     if quantized:
-        (start_ref, live_ref, table_ref, qbd_ref, k_ref, v_ref, ang_ref,
-         rot_ref, exp_ref, kscale_ref, vscale_ref,
+        (start_ref, live_ref, table_ref, qq_ref, k_ref, v_ref, ang_ref,
+         exp_ref, kscale_ref, vscale_ref,
          o_ref, m_ref, l_ref, acc_ref) = refs
     else:
-        (start_ref, live_ref, table_ref, qbd_ref, k_ref, v_ref, ang_ref,
-         rot_ref, exp_ref, o_ref, m_ref, l_ref, acc_ref) = refs
+        (start_ref, live_ref, table_ref, qq_ref, k_ref, v_ref, ang_ref,
+         exp_ref, o_ref, m_ref, l_ref, acc_ref) = refs
         kscale_ref = vscale_ref = None
 
     bi = pl.program_id(0)
     i = pl.program_id(1)
     nblocks = pl.num_programs(1)
     ps = k_ref.shape[1]
-    hd = k_ref.shape[2]
     h = exp_ref.shape[0]
-    r = ang_ref.shape[2]
-    d = hd // h
     contract = (((1,), (0,)), ((), ()))
 
     @pl.when(i == 0)
@@ -553,11 +552,6 @@ def _paged_kernel(*refs, window, skip_dead_pages, quantized):
 
     @pl.when(compute)
     def _compute():
-        ang = ang_ref[0].astype(jnp.float32)  # (ps, r)
-        fill = [jnp.ones((ps, d - r), jnp.float32)] if d > r else []
-        cos = jnp.concatenate(([jnp.cos(ang)] + fill) * h, -1)  # (ps, h*d)
-        sin = jnp.concatenate(([jnp.sin(ang)] + fill) * h, -1)
-
         k = k_ref[0].astype(jnp.float32)  # (ps, h*d)
         if quantized:
             # whenever compute runs, the page is live and the index map did
@@ -571,11 +565,8 @@ def _paged_kernel(*refs, window, skip_dead_pages, quantized):
                                        preferred_element_type=jnp.float32)
             vexp = jax.lax.dot_general(vscale, exp_ref[:], contract,
                                        preferred_element_type=jnp.float32)
-            k = k * kexp  # fused dequant, before rotation — the fallback's order
-        rot_half = jax.lax.dot_general(k, rot_ref[:], contract, preferred_element_type=jnp.float32)
-        k = k * cos + rot_half * sin
-
-        sc = jax.lax.dot_general(k, qbd_ref[:], contract, preferred_element_type=jnp.float32)  # (ps, h)
+            k = k * kexp  # fused dequant, before the rotary products — the fallback's order
+        sc = _rotary_scores(k, ang_ref[0].astype(jnp.float32), qq_ref, h)  # (ps, h)
         slot = i * ps + jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0)
         lp = jnp.mod(slot - start, window)
         visible = (lp >= window - live) & (slot < window)  # (ps, 1)
@@ -646,13 +637,6 @@ def fused_paged_decode_attention(
 
     start = jnp.asarray(start, jnp.int32).reshape(-1)
     live = jnp.asarray(live, jnp.int32).reshape(-1)
-    # block-diagonal query: column ``head`` carries q[:, head, 0] in rows
-    # [head*d, (head+1)*d) — one (ps, h*d) x (h*d, h) matmul scores all heads
-    eye = jnp.eye(h, dtype=q.dtype)
-    qbd = (
-        q[:, :, 0, :][:, :, None, :] * eye[None, :, :, None]
-    )  # (b, head, col, d)
-    qbd = qbd.transpose(0, 1, 3, 2).reshape(b, h * d, h)
 
     def _alias(i, start_ref, live_ref, bi):
         # dead pages alias the newest token's page — a page some step fetches
@@ -672,11 +656,10 @@ def fused_paged_decode_attention(
     page_table = jnp.asarray(page_table, jnp.int32)
     prefetch = [start, live, page_table]
     in_specs = [
-        pl.BlockSpec((None, h * d, h), lambda bi, i, *_: (bi, 0, 0)),
+        pl.BlockSpec((None, 2, h * d, h), lambda bi, i, *_: (bi, 0, 0, 0)),
         pl.BlockSpec((1, ps, hd), _kv_map),
         pl.BlockSpec((1, ps, hd), _kv_map),
         pl.BlockSpec((1, ps, r), _ang_map),
-        pl.BlockSpec((h * d, h * d), lambda bi, i, *_: (0, 0)),
         pl.BlockSpec((h, h * d), lambda bi, i, *_: (0, 0)),
     ]
     row_scales = []
@@ -705,11 +688,10 @@ def fused_paged_decode_attention(
         interpret=interpret,
     )(
         *prefetch,
-        qbd,
+        _blockdiag_queries(q, r),  # column ``head`` scores head ``head``
         kp,
         vp,
         rope_k,
-        jnp.asarray(_rotate_half_blockdiag(h, d, r)),
         jnp.asarray(_head_expander(h, d)),
         *row_scales,
     )

@@ -70,7 +70,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from perceiver_io_tpu.ops.decode_kernel import _head_expander, _rotate_half_blockdiag
+from perceiver_io_tpu.ops.decode_kernel import _blockdiag_queries, _head_expander, _rotary_scores
 from perceiver_io_tpu.ops.flash import single_device_trace
 from perceiver_io_tpu.ops.paged_decode_kernel import _page_has_live
 
@@ -132,11 +132,13 @@ def _ragged_kernel(*refs, window, skip_dead_pages, quantized, qbits):
     start_ref (W,)        EFFECTIVE ring offset (causal bound already folded)
     live_ref  (W,)        EFFECTIVE live count
     table_ref (W, P)      physical page ids per work item
-    qbd_ref   (h*d, h)    block-diagonal scaled+rotated query of item wi
-    k_ref     (1, ps, c)  one pool page (c = h*d, or h*d // 2 packed int4)
+    qq_ref    (2, h*d, h) block-diagonal scaled+rotated query of item wi and
+                          its rotate-half (decode_kernel._blockdiag_queries)
+    k_ref     (1, ps, c)  one pool page (c = h*d, or h*d // 2 packed int4),
+                          keys unrotated: the rotation is applied on the
+                          query side (decode_kernel._rotary_scores)
     v_ref     (1, ps, c)
     ang_ref   (1, ps, r)  rotary angles per PHYSICAL position of item wi
-    rot_ref   (h*d, h*d)  block-diag rotate-half matrix
     exp_ref   (h, h*d)    head->channel expander
     kscale_ref, vscale_ref (1, P, h)  quantized pools: the item's page scales
     perm_ref  (h*d, h*d)  int4 pools: nibble-plane interleave (bf16 0/1)
@@ -148,8 +150,8 @@ def _ragged_kernel(*refs, window, skip_dead_pages, quantized, qbits):
     before the fused dequant. Dead pages alias + skip exactly as there."""
     import jax.experimental.pallas as pl
 
-    (start_ref, live_ref, table_ref, qbd_ref, k_ref, v_ref, ang_ref,
-     rot_ref, exp_ref, *quant_refs, o_ref, m_ref, l_ref, acc_ref) = refs
+    (start_ref, live_ref, table_ref, qq_ref, k_ref, v_ref, ang_ref,
+     exp_ref, *quant_refs, o_ref, m_ref, l_ref, acc_ref) = refs
     kscale_ref, vscale_ref = quant_refs[:2] if quantized else (None, None)
     perm_ref = quant_refs[2] if quantized and qbits == 4 else None
 
@@ -157,10 +159,7 @@ def _ragged_kernel(*refs, window, skip_dead_pages, quantized, qbits):
     i = pl.program_id(1)
     nblocks = pl.num_programs(1)
     ps = k_ref.shape[1]
-    hd = o_ref.shape[2]
     h = exp_ref.shape[0]
-    r = ang_ref.shape[2]
-    d = hd // h
     contract = (((1,), (0,)), ((), ()))
 
     @pl.when(i == 0)
@@ -175,11 +174,6 @@ def _ragged_kernel(*refs, window, skip_dead_pages, quantized, qbits):
 
     @pl.when(compute)
     def _compute():
-        ang = ang_ref[0].astype(jnp.float32)  # (ps, r)
-        fill = [jnp.ones((ps, d - r), jnp.float32)] if d > r else []
-        cos = jnp.concatenate(([jnp.cos(ang)] + fill) * h, -1)  # (ps, h*d)
-        sin = jnp.concatenate(([jnp.sin(ang)] + fill) * h, -1)
-
         if quantized and qbits == 4:
             # in-stream nibble unpack: (ps, h*d // 2) uint8 -> (ps, h*d) f32
             # integer codes (low nibble = even logical channel, high = odd)
@@ -197,11 +191,8 @@ def _ragged_kernel(*refs, window, skip_dead_pages, quantized, qbits):
                                        preferred_element_type=jnp.float32)
             vexp = jax.lax.dot_general(vscale, exp_ref[:], contract,
                                        preferred_element_type=jnp.float32)
-            k = k * kexp  # fused dequant, before rotation — the fallback's order
-        rot_half = jax.lax.dot_general(k, rot_ref[:], contract, preferred_element_type=jnp.float32)
-        k = k * cos + rot_half * sin
-
-        sc = jax.lax.dot_general(k, qbd_ref[:], contract, preferred_element_type=jnp.float32)  # (ps, h)
+            k = k * kexp  # fused dequant, before the rotary products — the fallback's order
+        sc = _rotary_scores(k, ang_ref[0].astype(jnp.float32), qq_ref, h)  # (ps, h)
         slot = i * ps + jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0)
         lp = jnp.mod(slot - start, window)
         visible = (lp >= window - live) & (slot < window)  # (ps, 1)
@@ -296,13 +287,6 @@ def fused_ragged_paged_attention(
         assert c_phys == hd
 
     start, live = fold_causal_bound(start, live, causal_bound, window)
-    # block-diagonal query: column ``head`` carries q[:, head, 0] in rows
-    # [head*d, (head+1)*d) — one (ps, h*d) x (h*d, h) matmul scores all heads
-    eye = jnp.eye(h, dtype=q.dtype)
-    qbd = (
-        q[:, :, 0, :][:, :, None, :] * eye[None, :, :, None]
-    )  # (w, head, col, d)
-    qbd = qbd.transpose(0, 1, 3, 2).reshape(w, hd, h)
 
     def _alias(i, start_ref, live_ref, wi):
         # dead pages alias the newest live position's page — fetched anyway,
@@ -322,11 +306,10 @@ def fused_ragged_paged_attention(
     page_table = jnp.asarray(page_table, jnp.int32)
     prefetch = [start, live, page_table]
     in_specs = [
-        pl.BlockSpec((None, hd, h), lambda wi, i, *_: (wi, 0, 0)),
+        pl.BlockSpec((None, 2, hd, h), lambda wi, i, *_: (wi, 0, 0, 0)),
         pl.BlockSpec((1, ps, c_phys), _kv_map),
         pl.BlockSpec((1, ps, c_phys), _kv_map),
         pl.BlockSpec((1, ps, r), _ang_map),
-        pl.BlockSpec((hd, hd), lambda wi, i, *_: (0, 0)),
         pl.BlockSpec((h, hd), lambda wi, i, *_: (0, 0)),
     ]
     quant_operands = []
@@ -359,11 +342,10 @@ def fused_ragged_paged_attention(
         interpret=interpret,
     )(
         *prefetch,
-        qbd,
+        _blockdiag_queries(q, r),  # column ``head`` scores head ``head``
         kp,
         vp,
         rope_k,
-        jnp.asarray(_rotate_half_blockdiag(h, d, r)),
         jnp.asarray(_head_expander(h, d)),
         *quant_operands,
     )

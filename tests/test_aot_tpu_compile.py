@@ -22,7 +22,8 @@ from perceiver_io_tpu.ops import paged_decode_kernel as pdk
 from perceiver_io_tpu.ops import ragged_paged_kernel as rpk
 from perceiver_io_tpu.ops.flash import splash_mha
 
-FLAGSHIP = (10, 128)  # heads x head width: 455M C4 (and 134M GiantMIDI) Perceiver AR
+FLAGSHIP = (10, 128)  # heads x head width: 455M C4 Perceiver AR
+GIANTMIDI = (8, 96)  # 134M GiantMIDI Perceiver AR, 2048 latents
 WIKITEXT = (8, 64)  # 30.7M WikiText Perceiver AR, window 4096
 POOL_PAGES = 2048  # the (N, H) scale sidecars outgrew SMEM from about 1k pages
 
@@ -125,9 +126,14 @@ CASES = {
     "ragged-int4-10x128-w1024": (_ragged, FLAGSHIP, 1024, 128, "int4"),
     "ragged-int4-8x64-w4096": (_ragged, WIKITEXT, 4096, 64, "int4"),
     # the flagship's self-attention cache under generate(): batch 8, capacity
-    # 512 — refused at the old fixed 512-row block (16.71M of 16M scoped VMEM)
+    # 512 — with the (1280, 1280) rotate-half constant in the kernel a 512-row
+    # block was refused (16.71M of 16M scoped VMEM); without it ``_kv_block``
+    # picks 512 at every published width (tests/test_decode_kernel.py pins it)
     "dense-10x128-b8-cap512": (_dense, FLAGSHIP, 8, 512, 1),
     "dense-10x128-b8-cap1024-q8": (_dense, FLAGSHIP, 8, 1024, 8),
+    # the 512-row block's largest measured need: eight queries at batch 64
+    "dense-10x128-b64-cap2048-q8": (_dense, FLAGSHIP, 64, 2048, 8),
+    "dense-8x96-b8-cap2048": (_dense, GIANTMIDI, 8, 2048, 1),
     # the paged pool's stacked self-attention ring: 455M at the online cell's
     # 64 slots, and the 30.7M configuration (8 layers, 16 slots)
     "dense-stacked-10x128-l20-b64-cap512": (_dense_stacked, FLAGSHIP, 20, 64, 512),

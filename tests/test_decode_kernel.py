@@ -62,6 +62,140 @@ def test_fused_decode_attention_per_batch_positions():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
+def pallas_operand_shapes(fn, *args):
+    """Shapes of every operand of every ``pallas_call`` that ``fn(*args)`` traces."""
+    shapes = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                shapes.extend(tuple(v.aval.shape) for v in eqn.invars)
+                continue
+            for param in eqn.params.values():
+                inner = getattr(param, "jaxpr", param)  # ClosedJaxpr -> Jaxpr
+                if hasattr(inner, "eqns"):
+                    walk(inner)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return shapes
+
+
+def has_square_operand(shapes, hd):
+    """Does any traced kernel operand end in (h*d, h*d) — the shape of a
+    rotate-half (or any other per-channel mixing) constant?"""
+    return any(len(s) >= 2 and s[-2:] == (hd, hd) for s in shapes)
+
+
+@pytest.mark.parametrize(
+    "b,h,d,cap,r,n_q,q_last,lives,zero_angles,stacked",
+    [
+        pytest.param(2, 2, 32, 256, 8, 1, 255, None, False, False, id="partial-rotary"),
+        pytest.param(2, 2, 32, 256, 2, 1, 200, None, True, False, id="zero-angles-r2"),  # the no-rotary call
+        pytest.param(2, 2, 32, 256, 16, 8, 130, None, False, False, id="n_q8-partial-rotary"),
+        pytest.param(2, 2, 32, 256, 2, 8, 255, None, True, False, id="n_q8-zero-angles"),
+        pytest.param(2, 2, 32, 1024, 16, 1, 1023, (1024, 100), False, False, id="live-below-one-block"),  # blk 512
+        pytest.param(2, 2, 32, 256, 16, 1, 255, (256, 9), False, True, id="stacked-traced-layer"),
+        pytest.param(2, 3, 16, 256, 8, 8, 255, None, False, True, id="stacked-n_q8"),
+    ],
+)
+def test_fused_decode_attention_query_side_rotation(b, h, d, cap, r, n_q, q_last, lives, zero_angles, stacked):
+    """The rotation is applied on the query side (``_rotary_scores``): parity
+    with rotating the keys themselves where it differs most from the plain
+    cases above — rotary on part of a head (q_hat zero on the rest), the
+    no-rotary call (zero angles, r = 2), eight queries, a row whose live
+    region is under one KV block, and the stacked form with a traced layer."""
+    rng = lambda i: jax.random.PRNGKey(40 + i)
+    q = jax.random.normal(rng(0), (b, h, n_q, d)) * 0.3
+    layers = 3 if stacked else 1
+    k = jax.random.normal(rng(1), (layers, b, cap, h * d)) * 0.3
+    v = jax.random.normal(rng(2), (layers, b, cap, h * d)) * 0.3
+    ang = jnp.repeat(jax.random.normal(rng(3), (b, cap, r // 2)) * 0.5, 2, axis=-1)
+    ang = jnp.zeros_like(ang) if zero_angles else ang
+    pad = jnp.zeros((b, cap), bool)
+    live = None if lives is None else jnp.asarray(lives, jnp.int32)
+    layer = 2 if stacked else 0
+
+    if stacked:
+        out = jax.jit(
+            lambda layer: dk.fused_decode_attention(q, k, v, ang, jnp.asarray(q_last), pad, live=live, layer=layer, interpret=True)
+        )(jnp.asarray(layer, jnp.int32))
+    else:
+        out = dk.fused_decode_attention(q, k[0], v[0], ang, jnp.asarray(q_last), pad, live=live, interpret=True)
+    ref_pad = pad if lives is None else jnp.arange(cap)[None, :] < (q_last + 1 - live)[:, None]
+    ref = xla_reference(q, k[layer], v[layer], ang, jnp.full((b,), q_last), ref_pad)
+    assert out.shape == (b, h, n_q, d)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
+def test_blockdiag_queries_planes():
+    """Plane 0 is the block-diagonal query, plane 1 its rotate-half on the
+    rotary dims and zero elsewhere: (k*sin) @ plane 1 == (rotate_half(k)*sin) . q."""
+    b, h, n_q, d, r = 2, 3, 2, 8, 4
+    q = jax.random.normal(jax.random.PRNGKey(0), (b, h, n_q, d))
+    qq = np.asarray(dk._blockdiag_queries(q, r))
+    assert qq.shape == (b, 2, h * d, n_q * h)
+    qn = np.asarray(q)
+    for head in range(h):
+        for qi in range(n_q):
+            col = qq[:, :, :, qi * h + head]
+            want = qn[:, head, qi]
+            np.testing.assert_array_equal(col[:, 0, head * d : (head + 1) * d], want)
+            hat = np.zeros_like(want)
+            hat[:, 0:r:2], hat[:, 1:r:2] = want[:, 1:r:2], -want[:, 0:r:2]
+            np.testing.assert_array_equal(col[:, 1, head * d : (head + 1) * d], hat)
+            off = np.ones(h * d, bool)
+            off[head * d : (head + 1) * d] = False
+            assert not col[:, :, off].any()
+
+
+def test_fused_decode_attention_has_no_square_operand():
+    """Structure: the traced kernel holds no (h*d, h*d) operand — the
+    rotate-half constant and its matmul are gone, not merely skipped."""
+    b, h, d, cap, r = 2, 4, 32, 256, 16
+    q = jnp.zeros((b, h, 1, d))
+    kv = jnp.zeros((3, b, cap, h * d))
+    ang = jnp.zeros((b, cap, r))
+    pad = jnp.zeros((b, cap), bool)
+    shapes = pallas_operand_shapes(
+        lambda *a: dk.fused_decode_attention(*a, layer=jnp.asarray(1), interpret=True), q, kv, kv, ang, cap - 1, pad
+    )
+    assert (b, 2, h * d, h) in shapes  # the query planes reached the kernel
+    assert not has_square_operand(shapes, h * d)
+
+
+# the kernel's measured scoped-VMEM need (MiB), compiled for a described v5e
+# with vmem_limit_bytes searched by bisection (PERF.md, PR 29):
+# (block, packed width, cache itemsize, n_q, batch, need)
+MEASURED_VMEM_MIB = [
+    (512, 1280, 2, 1, 64, 6.85), (256, 1280, 2, 1, 64, 3.30), (128, 1280, 2, 1, 64, 1.56),
+    (512, 1280, 2, 8, 64, 10.21), (256, 1280, 2, 8, 64, 4.92), (512, 1280, 2, 8, 8, 5.11),
+    (512, 512, 2, 1, 16, 3.86), (512, 512, 2, 8, 8, 4.36), (512, 768, 2, 1, 8, 5.42), (512, 768, 2, 8, 8, 6.10),
+    (512, 1280, 4, 1, 8, 11.08), (256, 1280, 4, 1, 8, 5.42), (512, 512, 4, 1, 8, 5.48), (512, 768, 4, 1, 8, 7.85),
+]
+
+
+@pytest.mark.parametrize(
+    "capacity,hd,itemsize,block",
+    [
+        pytest.param(512, 1280, 2, 512, id="455m-self-attention-ring"),  # the online cell: one block a slot
+        pytest.param(1024, 1280, 2, 512, id="455m-cross-attention-window"),
+        pytest.param(512, 512, 2, 512, id="30m-latents"),
+        pytest.param(4096, 512, 2, 512, id="30m-window"),
+        pytest.param(2048, 768, 2, 512, id="134m-latents"),
+        pytest.param(6144, 768, 2, 512, id="134m-window"),
+        pytest.param(512, 1280, 4, 256, id="455m-float32-cache"),
+    ],
+)
+def test_kv_block_pins(capacity, hd, itemsize, block):
+    """The KV block the estimate picks at the published widths. The estimate
+    describes the kernel's temporaries: an edit to those re-measures
+    (MEASURED_VMEM_MIB) and restates these pins."""
+    assert dk._kv_block(capacity, hd, itemsize) == block
+    assert dk._vmem_estimate(block, hd, itemsize) <= dk._VMEM_BUDGET
+    for blk, width, size, _, _, need in MEASURED_VMEM_MIB:
+        assert dk._vmem_estimate(blk, width, size) >= need * 2**20, (blk, width, size)
+
+
 @pytest.mark.parametrize("layer", [0, 1, 2])
 def test_fused_decode_attention_stacked_form_equals_3d_form_per_layer(layer):
     """The stacked, layer-indexed form (the serving pool's ring cache: the

@@ -168,8 +168,8 @@ def test_append_ratchet_int4_zeroes_fresh_pages_and_requantizes():
     assert np.allclose(got[1], 5.0, atol=5.0 / 14 + 1e-6)
 
 
-def _quantized_kernel_inputs(window, ps, seed=0):
-    b, h, d = 3, 2, 32
+def _quantized_kernel_inputs(window, ps, seed=0, r=None, h=2):
+    b, d = 3, 32
     p = -(-window // ps)
     n_pool = 3 * p + 2
     rng = lambda i: jax.random.PRNGKey(seed + i)
@@ -178,7 +178,7 @@ def _quantized_kernel_inputs(window, ps, seed=0):
     vpf = jax.random.normal(rng(2), (n_pool, ps, h * d)) * 0.3
     perm = jax.random.permutation(rng(3), n_pool - 1)[: b * p] + 1
     table = jnp.asarray(np.asarray(perm).reshape(b, p), jnp.int32)
-    ang = jnp.repeat(jax.random.normal(rng(4), (b, p * ps, d // 2)) * 0.5, 2, axis=-1)
+    ang = jnp.repeat(jax.random.normal(rng(4), (b, p * ps, (r or d) // 2)) * 0.5, 2, axis=-1)
     base = _quant_cache(n_pool, ps, h, d, table, jnp.zeros((b,), jnp.int32), window)
     qc = base.write_pages(jnp.arange(n_pool), kpf, vpf)
     return q, qc, table, ang
@@ -223,14 +223,25 @@ def test_fused_dequant_kernel_bitwise_vs_xla_dequant_interpret(window, ps, start
     np.testing.assert_array_equal(np.asarray(fused), np.asarray(noskip))
 
 
-def test_fused_dequant_kernel_matches_gather_softmax_reference():
+@pytest.mark.parametrize(
+    "r,zero_angles",
+    [
+        pytest.param(None, False, id="full-rotary"),
+        pytest.param(8, False, id="partial-rotary"),
+        pytest.param(2, True, id="zero-angles-r2"),  # the no-rotary call
+    ],
+)
+def test_fused_dequant_kernel_matches_gather_softmax_reference(r, zero_angles):
     """The quantized kernel also matches the XLA gather + masked-softmax
     fallback formulation (the engine's CPU path) to float tolerance — the
-    same (start, live) visibility bound on the same dequantized values."""
+    same (start, live) visibility bound on the same dequantized values; the
+    dequant comes before the query-side rotary products, with rotary on all
+    of a head, on part of it, and on the no-rotary call."""
     from tests.test_paging import paged_xla_reference
 
     window, ps = 256, 32
-    q, qc, table, ang = _quantized_kernel_inputs(window, ps, seed=9)
+    q, qc, table, ang = _quantized_kernel_inputs(window, ps, seed=9, r=r)
+    ang = jnp.zeros_like(ang) if zero_angles else ang
     start = jnp.asarray([40, 200, 0], jnp.int32)
     live = jnp.asarray([40, 200, 256], jnp.int32)
     out = pdk.fused_paged_decode_attention(
@@ -245,6 +256,23 @@ def test_fused_dequant_kernel_matches_gather_softmax_reference():
         table, start, live, ang, window,
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
+def test_fused_dequant_kernel_has_no_square_operand():
+    """Structure: the int8 paged kernel holds no (h*d, h*d) operand either."""
+    from tests.test_decode_kernel import has_square_operand, pallas_operand_shapes
+
+    window, ps, h = 256, 64, 4  # h*d = 128: no page or table dimension equals it
+    q, qc, table, ang = _quantized_kernel_inputs(window, ps, h=h)
+    start, live = jnp.zeros((3,), jnp.int32), jnp.full((3,), window, jnp.int32)
+    shapes = pallas_operand_shapes(
+        lambda q, kp, vp, ks, vs: pdk.fused_paged_decode_attention(
+            q, kp, vp, table, start, live, ang, window, interpret=True, k_scale=ks, v_scale=vs
+        ),
+        q, qc.kp, qc.vp, qc.k_scale, qc.v_scale,
+    )
+    assert (3, 2, h * qc.head_dim, h) in shapes  # the query planes reached the kernel
+    assert not has_square_operand(shapes, h * qc.head_dim)
 
 
 def test_supported_gate_requires_int8_tile_alignment(monkeypatch):

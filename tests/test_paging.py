@@ -366,7 +366,7 @@ def paged_xla_reference(q, kp, vp, table, start, live, ang, window):
     return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), vh)
 
 
-def _kernel_inputs(b, h, d, window, ps, n_pool, seed=0):
+def _kernel_inputs(b, h, d, window, ps, n_pool, seed=0, r=None):
     rng = lambda i: jax.random.PRNGKey(seed + i)
     p = -(-window // ps)
     q = jax.random.normal(rng(0), (b, h, 1, d)) * 0.3
@@ -375,8 +375,45 @@ def _kernel_inputs(b, h, d, window, ps, n_pool, seed=0):
     # distinct pages per row (the allocator invariant), deliberately shuffled
     perm = jax.random.permutation(rng(3), n_pool - 1)[: b * p] + 1
     table = jnp.asarray(np.asarray(perm).reshape(b, p), jnp.int32)
-    ang = jnp.repeat(jax.random.normal(rng(4), (b, p * ps, d // 2)) * 0.5, 2, axis=-1)
+    ang = jnp.repeat(jax.random.normal(rng(4), (b, p * ps, (r or d) // 2)) * 0.5, 2, axis=-1)
     return q, kp, vp, table, ang
+
+
+@pytest.mark.parametrize(
+    "r,zero_angles",
+    [
+        pytest.param(8, False, id="partial-rotary"),
+        pytest.param(2, True, id="zero-angles-r2"),  # the no-rotary call
+    ],
+)
+def test_paged_kernel_query_side_rotation_interpret(r, zero_angles):
+    """The rotation is applied on the query side (decode_kernel._rotary_scores):
+    parity with rotating the gathered keys where rotary covers part of a head
+    (q_hat is zero on the rest) and on the no-rotary call (zero angles, r = 2),
+    over wrapped live intervals and a partial last page."""
+    window, ps, b, h, d = 200, 64, 3, 2, 32
+    q, kp, vp, table, ang = _kernel_inputs(b, h, d, window, ps, n_pool=3 * 4 + 2, seed=21, r=r)
+    ang = jnp.zeros_like(ang) if zero_angles else ang
+    start = jnp.asarray((8, 72, 199), jnp.int32)
+    live = jnp.asarray((200, 130, 64), jnp.int32)
+    out = pdk.fused_paged_decode_attention(q, kp, vp, table, start, live, ang, window, interpret=True)
+    ref = paged_xla_reference(q, kp, vp, table, start, live, ang, window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
+def test_paged_kernel_has_no_square_operand():
+    """Structure: the traced paged kernel holds no (h*d, h*d) operand — the
+    rotate-half constant and its matmul are gone."""
+    from tests.test_decode_kernel import has_square_operand, pallas_operand_shapes
+
+    window, ps, b, h, d = 256, 64, 3, 4, 32  # h*d = 128: no page or table dimension equals it
+    q, kp, vp, table, ang = _kernel_inputs(b, h, d, window, ps, n_pool=3 * 4 + 2)
+    start, live = jnp.zeros((b,), jnp.int32), jnp.full((b,), window, jnp.int32)
+    shapes = pallas_operand_shapes(
+        lambda *a: pdk.fused_paged_decode_attention(*a, window, interpret=True), q, kp, vp, table, start, live, ang
+    )
+    assert (b, 2, h * d, h) in shapes  # the query planes reached the kernel
+    assert not has_square_operand(shapes, h * d)
 
 
 @pytest.mark.parametrize(

@@ -21,7 +21,7 @@ import perceiver_io_tpu.ops.ragged_paged_kernel as rpk
 from perceiver_io_tpu.ops.position import apply_rope
 
 
-def _inputs(w, h, d, window, ps, n_pool, seed=0):
+def _inputs(w, h, d, window, ps, n_pool, seed=0, r=None):
     rng = lambda i: jax.random.PRNGKey(seed + i)
     p = -(-window // ps)
     q = jax.random.normal(rng(0), (w, h, 1, d)) * 0.3
@@ -29,7 +29,7 @@ def _inputs(w, h, d, window, ps, n_pool, seed=0):
     vp = jax.random.normal(rng(2), (n_pool, ps, h * d)) * 0.3
     perm = jax.random.permutation(rng(3), n_pool - 1)[: w * p] + 1
     table = jnp.asarray(np.asarray(perm).reshape(w, p), jnp.int32)
-    ang = jnp.repeat(jax.random.normal(rng(4), (w, p * ps, d // 2)) * 0.5, 2, axis=-1)
+    ang = jnp.repeat(jax.random.normal(rng(4), (w, p * ps, (r or d) // 2)) * 0.5, 2, axis=-1)
     return q, kp, vp, table, ang
 
 
@@ -102,6 +102,70 @@ def test_bounded_items_match_masked_softmax_oracle():
         skip_dead_pages=False, interpret=True,
     )
     np.testing.assert_array_equal(np.asarray(out), np.asarray(noskip))
+
+
+@pytest.mark.parametrize(
+    "r,zero_angles,qbits",
+    [
+        pytest.param(8, False, None, id="partial-rotary-fp"),
+        pytest.param(2, True, None, id="zero-angles-r2-fp"),  # the no-rotary call
+        pytest.param(8, False, 8, id="partial-rotary-int8"),
+        pytest.param(8, False, 4, id="partial-rotary-int4"),
+    ],
+)
+def test_query_side_rotation_matches_oracle(r, zero_angles, qbits):
+    """The rotation is applied on the query side (decode_kernel._rotary_scores):
+    parity with the oracle, which rotates the gathered keys, where rotary
+    covers part of a head and on the no-rotary call — decode and bounded
+    items, fp and quantized pools (the dequant comes before the products)."""
+    window, ps = 128, 32
+    w, h, d = 4, 2, 32
+    n_pool = 4 * 4 + 2
+    q, kp, vp, table, ang = _inputs(w, h, d, window, ps, n_pool=n_pool, seed=13, r=r)
+    ang = jnp.zeros_like(ang) if zero_angles else ang
+    start = jnp.asarray([0, 100, 9, 9], jnp.int32)
+    live = jnp.asarray([128, 40, 120, 120], jnp.int32)
+    cb = jnp.asarray([127, 127, 126, 127], jnp.int32)
+    quant = {}
+    if qbits is not None:
+        qc, kp_ref, vp_ref = _quant_pool(n_pool, ps, h, d, qbits, seed=13)
+        kp, vp = qc.kp, qc.vp
+        quant = dict(k_scale=qc.k_scale, v_scale=qc.v_scale, qbits=qbits)
+    else:
+        kp_ref, vp_ref = kp, vp
+    out = rpk.fused_ragged_paged_attention(
+        q, kp, vp, table, start, live, cb, ang, window, interpret=True, **quant
+    )
+    ref = _reference(q, kp_ref, vp_ref, table, start, live, cb, ang, window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
+@pytest.mark.parametrize("qbits", [None, 8])
+def test_ragged_kernel_has_no_square_operand(qbits):
+    """Structure: on fp and int8 pools the traced ragged kernel holds no
+    (h*d, h*d) operand — the rotate-half constant and its matmul are gone.
+    (int4 pools keep ONE such operand, the nibble-plane interleave.)"""
+    from tests.test_decode_kernel import has_square_operand, pallas_operand_shapes
+
+    window, ps = 128, 32
+    w, h, d = 3, 4, 32  # h*d = 128: kept apart from the window by the shapes below
+    n_pool = 3 * 4 + 2
+    q, kp, vp, table, ang = _inputs(w, h, d, window, ps, n_pool=n_pool)
+    quant = {}
+    if qbits is not None:
+        qc, _, _ = _quant_pool(n_pool, ps, h, d, qbits)
+        kp, vp = qc.kp, qc.vp
+        quant = dict(k_scale=qc.k_scale, v_scale=qc.v_scale, qbits=qbits)
+    start, live = jnp.zeros((w,), jnp.int32), jnp.full((w,), window, jnp.int32)
+    cb = jnp.full((w,), window - 1, jnp.int32)
+    shapes = pallas_operand_shapes(
+        lambda q, kp, vp: rpk.fused_ragged_paged_attention(
+            q, kp, vp, table, start, live, cb, ang, window, interpret=True, **quant
+        ),
+        q, kp, vp,
+    )
+    assert (w, 2, h * d, h) in shapes  # the query planes reached the kernel
+    assert not has_square_operand(shapes, h * d)
 
 
 def test_fold_causal_bound_equals_brute_force_mask():
