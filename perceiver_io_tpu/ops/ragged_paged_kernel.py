@@ -1,11 +1,9 @@
 """Unified ragged paged attention: ONE Pallas program per serving tick.
 
-The composed serving tick (serving/engine.py before the ragged rework)
-dispatches up to ladder-many chunked-prefill programs, one latent-finish
-program per finishing slot, and one fused paged decode program — prefill and
-decode serialize within the tick and chunk shapes ride the prefill bucket
-ladder. The "Ragged Paged Attention" TPU kernel recipe (PAPERS.md) collapses
-the attention side of that tick into ONE kernel launch over a host-built
+A serving tick mixes chunked-prefill writes, latent finishes of finishing
+slots, and the batched decode step (serving/engine.py ``ragged_tick``). The
+"Ragged Paged Attention" TPU kernel recipe (PAPERS.md) collapses the
+attention side of that tick into ONE kernel launch over a host-built
 ragged work descriptor: a flat list of work items, each one QUERY ROW —
 
   * a **decode step** contributes one item: the slot's single query against
@@ -57,9 +55,7 @@ finalize's l clamp turns 0/eps into 0 — so the engine can dispatch a
 fixed-width descriptor and ignore the padding lanes.
 
 Kill-switch: ``PERCEIVER_IO_TPU_DISABLE_DECODE_KERNEL`` (shared with the
-dense and legacy paged kernels) forces the XLA fallback;
-``PERCEIVER_IO_TPU_DISABLE_RAGGED_TICK`` (serving/paging.py) restores the
-composed per-program tick in the engine without touching this module.
+dense and legacy paged kernels) forces the XLA fallback.
 """
 
 from __future__ import annotations
@@ -366,8 +362,7 @@ def ragged_reference_attention(
     ROTATED keys / values in physical ring order (PagedKVCache.gather_dense
     followed by the rope the kernel fuses). Masks the identical position set
     as the kernel — ``fold_causal_bound`` + the plain decode visibility —
-    then one softmax per item. The correctness oracle tests pin against, and
-    the shape the engine's composed XLA path computes item-wise."""
+    then one softmax per item. The correctness oracle tests pin against."""
     w, h, _, d = q.shape
     n_phys = k_dense.shape[1]
     eff_start, eff_live = fold_causal_bound(start, live, causal_bound, window)

@@ -39,13 +39,9 @@ from perceiver_io_tpu.serving.metrics import (
 from perceiver_io_tpu.serving.paging import (
     PagePool,
     PrefixCache,
-    chunked_prefill_enabled,
-    kv_quant_enabled,
     page_keys_for_prompt,
-    paged_kv_enabled,
     pages_for_request,
     pages_for_tokens,
-    prefix_cache_enabled,
 )
 from perceiver_io_tpu.serving.quant import (
     dequantize_params,
@@ -83,18 +79,14 @@ __all__ = [
     "read_journal",
     "PagePool",
     "PrefixCache",
-    "chunked_prefill_enabled",
     "dequantize_params",
     "fleet_ops_enabled",
-    "kv_quant_enabled",
     "page_keys_for_prompt",
-    "paged_kv_enabled",
     "quantize_params_int8",
     "serve_params",
     "pages_for_request",
     "pages_for_tokens",
     "preemption_enabled",
-    "prefix_cache_enabled",
     "RequestStatus",
     "RoutedRequest",
     "RouterMetrics",

@@ -96,29 +96,28 @@ NOTHING new. Victim selection is a pure function of (priority, admission
 order, page count); each request survives at most ``max_preemptions``
 preemptions, then runs to completion untouchable (no livelock).
 
-Kill-switches: ``PERCEIVER_IO_TPU_DISABLE_BUCKETED_PREFILL=1`` pins the
-ladder at the single full-window bucket (the PR-1 behavior);
+Unified ragged tick (docs/serving.md "Unified ragged tick"): a PAGED engine
+buffers each tick's prefill chunks, latent finishes, scale resets, and decode
+step into ONE host-built descriptor and dispatches ONE fused program per
+tick (``ragged_tick``); the dense pool (``kv_page_size=None``) dispatches
+``decode_step``. The constructor's arguments alone decide which pool, page
+format, admission path, and prefill ladder an engine runs: ``kv_page_size``
+(None = the dense pool), ``kv_quant`` / ``weight_dtype`` (None = full
+precision pages / untouched params), ``prefill_chunk_tokens`` (None =
+unchunked admission), ``prefix_cache`` (False = no sharing),
+``prefill_buckets`` (``(window,)`` = the single full-window bucket).
+
+Kill-switches, for what no argument sets:
 ``PERCEIVER_IO_TPU_DISABLE_RAGGED_DECODE=1`` disables live-length masking
 and block skipping (pad masking alone; under paging only the kernel's
 dead-page skip — the visibility bound is load-bearing there);
 ``PERCEIVER_IO_TPU_DISABLE_DECODE_KERNEL=1`` disables the fused kernel;
-``PERCEIVER_IO_TPU_DISABLE_PAGED_KV=1`` forces the dense pool even when
-``kv_page_size`` is configured (f64 greedy parity pinned both ways);
 ``PERCEIVER_IO_TPU_DISABLE_PREEMPTION=1`` restores strict submit-order FIFO
 (priorities ignored, no aging, no preemption — behavior bit-identical to
 the pre-priority engine, pinned by the ``preempt_disabled_inert`` chaos
 scenario); ``PERCEIVER_IO_TPU_DISABLE_JOURNAL=1`` makes a configured
 request journal inert — no files touched, behavior bit-identical to
-``journal=None`` (serving/journal.py, tests/test_journal.py);
-``PERCEIVER_IO_TPU_DISABLE_KV_QUANT=1`` forces full-precision pages AND
-untouched served params regardless of ``kv_quant``/``weight_dtype`` —
-f64 token-identical to the pre-quantization engine (tests/test_kv_quant.py);
-``PERCEIVER_IO_TPU_DISABLE_RAGGED_TICK=1`` restores the composed
-per-program tick (per-rung chunk programs, per-slot finish programs, a
-separate decode dispatch) bit-identically — the unified ragged tick
-(docs/serving.md "Unified ragged tick") buffers each tick's prefill
-chunks, latent finishes, scale resets, and decode step into ONE host-built
-descriptor and dispatches ONE fused program per tick.
+``journal=None`` (serving/journal.py, tests/test_journal.py).
 
 Quantized serving (docs/serving.md "Quantized KV pages & weight serving"):
 ``kv_quant="int8"`` stores the paged KV pools as int8 with per-page-per-head
@@ -180,13 +179,8 @@ from perceiver_io_tpu.serving.metrics import EngineMetrics
 from perceiver_io_tpu.serving.paging import (
     PagePool,
     PrefixCache,
-    chunked_prefill_enabled,
-    kv_quant_enabled,
     page_keys_for_prompt,
-    paged_kv_enabled,
     pages_for_request,
-    prefix_cache_enabled,
-    ragged_tick_enabled,
 )
 from perceiver_io_tpu.serving.quant import (
     WEIGHT_DTYPES,
@@ -408,13 +402,13 @@ class _PrefillTask:
 _ENGINE_IDS = itertools.count()
 
 # Stable ``jax.named_scope`` names of the phases INSIDE the tick program (the
-# fused ``ragged_tick`` and its composed twins): they ride every operation's
-# ``op_name`` into a profiler trace, where device time is summed per phase
-# (docs/observability.md "Named scopes"). Inside ``tick.decode`` the model's
-# own scopes split further: ``cache_append`` and ``decode_attention``
-# (ops/attention.py), the flax ``mlp`` modules, and ``head``
-# (models/core/perceiver_ar.py). Renaming one is a change to what the trace
-# tools read.
+# fused ``ragged_tick``; the dense pool's ``decode_step`` has the last two):
+# they ride every operation's ``op_name`` into a profiler trace, where device
+# time is summed per phase (docs/observability.md "Named scopes"). Inside
+# ``tick.decode`` the model's own scopes split further: ``cache_append`` and
+# ``decode_attention`` (ops/attention.py), the flax ``mlp`` modules, and
+# ``head`` (models/core/perceiver_ar.py). Renaming one is a change to what the
+# trace tools read.
 TICK_SCOPES = {phase: f"tick.{phase}" for phase in (
     "resets", "chunk_lanes", "finish_lanes", "poison", "sample", "decode")}
 
@@ -470,14 +464,13 @@ class ServingEngine:
         # "Quantized KV pages & weight serving"): bf16 casts float leaves,
         # int8 stores matmul-grade leaves as int8 + per-tensor scale and the
         # compiled programs dequantize on entry — resident param HBM drops
-        # alongside the KV pool's. weight_dtype=None (and the
-        # PERCEIVER_IO_TPU_DISABLE_KV_QUANT kill-switch) pass the tree
+        # alongside the KV pool's. weight_dtype=None passes the tree
         # through UNTOUCHED: the f64 parity pins run the identity path.
         if weight_dtype is not None and weight_dtype not in WEIGHT_DTYPES:
             raise ValueError(
                 f"weight_dtype must be one of {WEIGHT_DTYPES} or None, got {weight_dtype!r}"
             )
-        self.weight_dtype = weight_dtype if kv_quant_enabled() else None
+        self.weight_dtype = weight_dtype
         (self.params, self._dequant_params,
          self._param_bytes, self._param_bytes_fp) = serve_params(
             params, self.weight_dtype
@@ -632,9 +625,6 @@ class ServingEngine:
         # prefilled at the smallest covering bucket — cost O(bucket) — and
         # write_slot widens the bucket rows into the slot's tail. One compiled
         # prefill program per bucket, ever.
-        disable = os.environ.get(
-            "PERCEIVER_IO_TPU_DISABLE_BUCKETED_PREFILL", "0"
-        ).lower() not in ("0", "false", "")
         if prefill_buckets is None:
             ladder = default_prefill_buckets(self._window, model.max_latents)
         else:
@@ -645,12 +635,11 @@ class ServingEngine:
                     f"prefill_buckets must lie in [max_latents={model.max_latents}.."
                     f"window={self._window}], got {bad}"
                 )
-        self.prefill_buckets: tuple = (self._window,) if disable else ladder
+        self.prefill_buckets: tuple = ladder
 
         # Paged KV mode (serving/paging.py; module docstring): kv_page_size
-        # opts in, the kill-switch env forces dense regardless — the f64
-        # parity pins run both ways.
-        self.paged = kv_page_size is not None and paged_kv_enabled()
+        # opts in; None is the dense pool.
+        self.paged = kv_page_size is not None
         self.kv_page_size: Optional[int] = None
         self._pool: Optional[PagePool] = None
         if kv_page_size is not None and not 1 <= int(kv_page_size) <= self._window:
@@ -659,10 +648,8 @@ class ServingEngine:
             )
         # Quantized KV pages (docs/serving.md "Quantized KV pages & weight
         # serving"): int8 pool + per-page-per-head scale sidecars. Requires
-        # paging (quantization is a PAGE layout); configuring it on a
-        # dense-by-construction engine is a caller bug, while the paged/quant
-        # kill-switches forcing fp silently disable it (a rollback lever
-        # must never crash the engine it rolls back).
+        # paging (quantization is a PAGE layout); configuring it on a dense
+        # engine is a caller bug.
         from perceiver_io_tpu.ops.paged_decode_kernel import KV_QUANT_MODES
 
         if kv_quant is not None and kv_quant not in KV_QUANT_MODES:
@@ -672,10 +659,7 @@ class ServingEngine:
         if kv_quant is not None and kv_page_size is None:
             raise ValueError("kv_quant requires kv_page_size (quantization is "
                              "a page layout)")
-        self.kv_quant: Optional[str] = (
-            kv_quant if (kv_quant is not None and self.paged and kv_quant_enabled())
-            else None
-        )
+        self.kv_quant: Optional[str] = kv_quant
         if self.paged:
             self.kv_page_size = int(kv_page_size)
             self._pages_per_slot = -(-self._window // self.kv_page_size)
@@ -722,9 +706,7 @@ class ServingEngine:
         # Chunked admission prefill + cross-request radix prefix cache
         # (docs/serving.md "Chunked prefill" / "Prefix cache"). Both compose
         # over the PAGED pool (chunks write pages, the cache shares them):
-        # configuring either on a dense-by-construction engine is a caller
-        # bug, while the PAGED kill-switch forcing dense silently disables
-        # them (a rollback lever must never crash the engine it rolls back).
+        # configuring either on a dense engine is a caller bug.
         if prefill_chunk_tokens is not None:
             if kv_page_size is None:
                 raise ValueError("prefill_chunk_tokens requires kv_page_size "
@@ -737,8 +719,7 @@ class ServingEngine:
                              "shares pool pages)")
         if max_prefill_slots is not None and max_prefill_slots < 1:
             raise ValueError(f"max_prefill_slots must be >= 1, got {max_prefill_slots}")
-        self.chunked = (prefill_chunk_tokens is not None and self.paged
-                        and chunked_prefill_enabled())
+        self.chunked = prefill_chunk_tokens is not None and self.paged
         self.prefill_chunk_tokens = (int(prefill_chunk_tokens)
                                      if self.chunked else None)
         if (self.kv_quant is not None and self.chunked
@@ -753,12 +734,11 @@ class ServingEngine:
         self.max_prefill_slots = (int(max_prefill_slots)
                                   if max_prefill_slots is not None else num_slots)
         # Unified ragged tick (docs/serving.md "Unified ragged tick"; module
-        # docstring): buffer the tick's prefill chunks / latent finishes /
-        # scale resets / decode into ONE host-built descriptor and dispatch
-        # ONE fused program. Paged-only (the descriptor is page-table work);
-        # the kill-switch restores the composed per-program tick bitwise.
-        self.ragged = self.paged and ragged_tick_enabled()
-        if self.ragged:
+        # docstring): a paged engine buffers the tick's prefill chunks /
+        # latent finishes / scale resets / decode into ONE host-built
+        # descriptor and dispatches ONE fused program (the descriptor is
+        # page-table work: the dense pool has none and runs decode_step).
+        if self.paged:
             # lane counts are STATIC program shapes. At most one chunk and
             # one finish lane per slot per tick; chunked engines are further
             # bounded by 2 x max_prefill_slots (advancing tasks plus the
@@ -771,7 +751,7 @@ class ServingEngine:
             self._ragged_chunk_cap = (self.prefill_chunk_tokens
                                       if self.chunked else self._window)
         # per-tick ragged work buffers (host side of the descriptor); always
-        # present so _drop_tick_work and the program counters are mode-blind
+        # present so _drop_tick_work and the program counters are pool-blind
         self._tick_chunks: List[tuple] = []
         self._tick_finishes: List[tuple] = []
         self._tick_resets: List[tuple] = []
@@ -781,7 +761,7 @@ class ServingEngine:
         self._tick_finish_items = 0
         self._tick_build_s = 0.0
         self._prefix_cache: Optional[PrefixCache] = None
-        if prefix_cache and self.paged and prefix_cache_enabled():
+        if prefix_cache and self.paged:
             # the cache is keyed on the pool's byte layout: its mode is fixed
             # at construction. A cache built HERE trivially matches this
             # engine, so this ensure_mode cannot fire today — it stands as
@@ -798,9 +778,8 @@ class ServingEngine:
         if self.chunked:
             self.metrics.set_chunked_prefill(self.prefill_chunk_tokens)
         if self.paged:
-            # serving-metrics/v11: which tick dispatcher this engine runs
-            # (ragged one-program vs composed per-phase under kill-switch)
-            self.metrics.set_ragged_tick(self.ragged)
+            # serving-metrics/v11: the fused tick's block (None on dense pools)
+            self.metrics.set_ragged_tick(True)
         if self._prefix_cache is not None:
             self.metrics.set_prefix_cache(self._prefix_cache.stats(), 0)
         # serving-metrics/v9 gauges: quantized-page byte economics and the
@@ -827,13 +806,10 @@ class ServingEngine:
             # the engine's own compile-count pins, as runtime budgets: one
             # decode/install/release/quarantine program ever, <= one prefill
             # program per ladder bucket (tests/test_serving.py churn test)
-            if self.ragged:
+            if self.paged:
                 # the whole steady-state tick — chunks, finishes, poison,
                 # decode — is ONE program whatever the tick mix (every phase
-                # gates on traced flags, lanes are fixed-shape). The composed
-                # per-phase jits stay built (kill-switch fallback + oracles)
-                # but are never dispatched steady-state, so they are not
-                # watched; budgets under the kill-switch are unchanged.
+                # gates on traced flags, lanes are fixed-shape)
                 self.watchdog.watch(f"{obs_ns}.ragged_tick",
                                     self._jit_ragged_tick, budget=1)
             else:
@@ -849,17 +825,6 @@ class ServingEngine:
             self.watchdog.watch(f"{obs_ns}.quarantine", self._jit_quarantine, budget=1)
             if self._jit_release_pages is not None:
                 self.watchdog.watch(f"{obs_ns}.release_pages", self._jit_release_pages, budget=1)
-            if self._jit_chunk_kv is not None and not self.ragged:
-                # chunk programs are keyed on the chunk's covering ladder
-                # bucket; the finish consumes fixed shapes (L queries, the
-                # window's page run) so it owns exactly one program
-                self.watchdog.watch(f"{obs_ns}.prefill_chunk", self._jit_chunk_kv,
-                                    budget=len(self.prefill_buckets))
-                self.watchdog.watch(f"{obs_ns}.prefill_finish",
-                                    self._jit_prefill_finish, budget=1)
-            if self._jit_reset_scales is not None and not self.ragged:
-                self.watchdog.watch(f"{obs_ns}.reset_scales",
-                                    self._jit_reset_scales, budget=1)
 
     # ------------------------------------------------------------------- jits
     def _build_jits(self):
@@ -959,8 +924,9 @@ class ServingEngine:
             type(model).decode_step_paged if self.paged else type(model).decode_step
         )
 
-        @partial(jax.jit, donate_argnums=(1, 2))
-        def decode_step(params, cache, state, forced, use_forced):
+        def decode_body(params, cache, state, forced, use_forced):
+            # THE decode step, traced by the dense pool's ``decode_step`` and
+            # by the fused tick's decode phase (``params`` already dequantized).
             # Mirrors _generate_single's loop body per row: process logits ->
             # sample -> one cached model step. Inactive rows decode their pad
             # token; their outputs are never harvested.
@@ -977,16 +943,17 @@ class ServingEngine:
                 keys = jax.vmap(jax.random.split)(state.rng)  # (B, 2, 2)
                 tok = sample_token_batched(keys[:, 1], processed, state.do_sample)
                 tok = jnp.where(state.active, tok, state.pad_id).astype(jnp.int32)
-            # deterministic replay mux (router failover): a replaying slot's
-            # token is FORCED to the known stream while the rng chain, cache
-            # appends, and logits advance exactly as in the original run —
-            # so free-running continuation is bit-identical. With use_forced
-            # all-False (every ordinary tick) this is a no-op select and the
-            # f64 parity pins run through it.
-            tok = jnp.where(use_forced, forced, tok).astype(jnp.int32)
+                # deterministic replay mux (router failover): a replaying
+                # slot's token is FORCED to the known stream while the rng
+                # chain, cache appends, and logits advance exactly as in the
+                # original run — so free-running continuation is
+                # bit-identical. With use_forced all-False (every ordinary
+                # tick) this is a no-op select and the f64 parity pins run
+                # through it.
+                tok = jnp.where(use_forced, forced, tok).astype(jnp.int32)
             with jax.named_scope(TICK_SCOPES["decode"]):
                 logits_t, cache = model.apply(
-                    dq(params), tok[:, None], cache, method=decode_method
+                    params, tok[:, None], cache, method=decode_method
                 )
             # inactive rows keep their (zeroed-at-release) rng/logits frozen:
             # freed-slot state stays canonical across steps, so pool dumps are
@@ -1041,58 +1008,10 @@ class ServingEngine:
                 ),
             )
 
-        @partial(jax.jit, donate_argnums=(0,))
-        def reset_scales(cache, ids):
-            # quantized split admission: zero the PRIVATE reservation's scale
-            # sidecars before any chunk writes, so a page's first ratcheted
-            # append starts from scale 0 and zeroes stale tenant bytes
-            # (ops/paged_decode_kernel.reset_page_scales). Shared prefix
-            # pages are never in ``ids`` — their scales belong to the cache.
-            with jax.named_scope(TICK_SCOPES["resets"]):
-                return cache.replace(ca=cache.ca.reset_page_scales(ids))
-
-        @partial(jax.jit, donate_argnums=(1,))
-        def chunk_kv(params_, cache, ids, offset, count, latent_start, table_row):
-            params = dq(params_)
-            # one SPLIT-prefill chunk (docs/serving.md "Chunked prefill"):
-            # position-wise KV for prompt tokens [offset, offset + count)
-            # scattered page-wise through table_row — the slot's IN-CACHE
-            # table stays trash until the finish, so interleaved decode
-            # ticks cannot write into the half-built reservation. ids is
-            # padded to a ladder bucket (programs keyed on that shape, <=
-            # one per rung); padded rows write zero payloads to the trash
-            # page (PagedKVCache.write_rows).
-            cb = ids.shape[1]
-            with jax.named_scope(TICK_SCOPES["chunk_lanes"]):
-                j = jnp.arange(cb)
-                pos = jnp.clip(offset + j, 0, model.max_seq_len - 1)[None, :]
-                latent_mask = ((offset + j) >= latent_start)[None, :]
-                k, v = model.apply(params, ids, pos, latent_mask,
-                                   method=type(model).prefill_chunk_kv)
-                return cache.replace(
-                    ca=cache.ca.write_rows(table_row, offset, count, k[0], v[0])
-                )
-
-        @partial(jax.jit, donate_argnums=(1, 2))
-        def prefill_finish(params_, cache, state, slot, table_row, ids, n, rng,
-                           temperature, top_k, top_p, do_sample, pad_id):
-            params = dq(params_)
-            # the SPLIT prefill's finish: latents for the last max_latents
-            # prompt tokens against the slot's already-written pages, then
-            # the install bookkeeping (table, ring offset, SA cache, slot
-            # state activation). Fixed shapes throughout — ONE program ever.
-            with jax.named_scope(TICK_SCOPES["finish_lanes"]):
-                req_logits, sa_src = model.apply(
-                    params, ids, n, cache.ca, table_row,
-                    method=type(model).prefill_finish_paged,
-                )
-                cache = cache.install_finish(slot, table_row, sa_src, n)
-                state = _install_state(state, slot, req_logits, rng,
-                                       temperature, top_k, top_p, do_sample, pad_id)
-            return cache, state
-
-        self._jit_ragged_tick = None
-        if self.ragged:
+        # the tick program: the fused ragged tick on a paged engine, the
+        # decode step on the dense pool — exactly one of the two is built
+        self._jit_ragged_tick = self._jit_decode = None
+        if self.paged:
             cap = self._ragged_chunk_cap
             quantized = self.kv_quant is not None
 
@@ -1105,15 +1024,13 @@ class ServingEngine:
                             fin_rng, fin_temp, fin_tk, fin_tp, fin_ds,
                             fin_pad, any_finish,
                             poison_slot, any_decode, forced, use_forced):
-                # ONE program per tick: the composed tick's phases — scale
-                # resets, prefill chunks, latent finishes, fault poison,
-                # batched decode — fused in the composed dispatch order.
-                # Every phase is gated by a TRACED any-flag (lax.cond), so
-                # one compiled program covers every tick mix and the
-                # watchdog budget is exactly 1. Per-slot state is disjoint
-                # across phases' lanes, so batching lanes that the composed
-                # path dispatched serially is f64-identical (the parity
-                # tests pin it).
+                # ONE program per tick, its phases in dependency order:
+                # scale resets, prefill chunks, latent finishes, fault
+                # poison, batched decode. Every phase is gated by a TRACED
+                # any-flag (lax.cond), so one compiled program covers every
+                # tick mix and the watchdog budget is exactly 1. Per-slot
+                # state is disjoint across a phase's lanes, so the lanes of
+                # one scan do not interact (f64-pinned against generate()).
                 # The phases carry STABLE jax.named_scope names (TICK_SCOPES;
                 # metadata only — the program's instructions are unchanged):
                 # a profiler trace's device time is read per phase from the
@@ -1121,6 +1038,13 @@ class ServingEngine:
                 params = dq(params_)
 
                 if quantized:
+                    # quantized split admission: zero the PRIVATE
+                    # reservations' scale sidecars before any chunk writes,
+                    # so a page's first ratcheted append starts from scale 0
+                    # and zeroes stale tenant bytes
+                    # (ops/paged_decode_kernel.reset_page_scales). Shared
+                    # prefix pages are never in ``reset_ids`` — their scales
+                    # belong to the cache.
                     with jax.named_scope(TICK_SCOPES["resets"]):
                         cache = jax.lax.cond(
                             any_reset,
@@ -1129,6 +1053,12 @@ class ServingEngine:
                         )
 
                 def chunk_phase(cache):
+                    # one SPLIT-prefill chunk a lane (docs/serving.md
+                    # "Chunked prefill"): position-wise KV for prompt tokens
+                    # [offset, offset + count) scattered page-wise through
+                    # the lane's table row — the slot's IN-CACHE table stays
+                    # trash until the finish, so the decode phase cannot
+                    # write into the half-built reservation
                     def body(cache, lane):
                         ids, offset, count, lstart, trow = lane
                         j = jnp.arange(cap)
@@ -1159,6 +1089,11 @@ class ServingEngine:
                          temp, tk, tp, ds, pad) = lane
 
                         def fin(args):
+                            # the SPLIT prefill's finish: latents for the
+                            # last max_latents prompt tokens against the
+                            # slot's already-written pages, then the install
+                            # bookkeeping (table, ring offset, SA cache, slot
+                            # state activation)
                             cache, state = args
                             req_logits, sa_src = model.apply(
                                 params, ids[None, :], n, cache.ca, trow,
@@ -1182,8 +1117,8 @@ class ServingEngine:
                     cache, state = jax.lax.cond(
                         any_finish, finish_phase, lambda a: a, (cache, state)
                     )
-                # serving.nan fault point, fused in the composed position
-                # (after finishes activate their logits, before decode reads)
+                # serving.nan fault point: after finishes activate their
+                # logits, before decode reads them
                 with jax.named_scope(TICK_SCOPES["poison"]):
                     state = jax.lax.cond(
                         poison_slot >= 0,
@@ -1193,27 +1128,7 @@ class ServingEngine:
                     )
 
                 def decode_phase(args):
-                    cache, state = args
-                    # verbatim decode_step body (the composed oracle)
-                    with jax.named_scope(TICK_SCOPES["sample"]):
-                        finite = jnp.all(jnp.isfinite(state.next_logits), axis=-1) | ~state.active
-                        processed = process_logits_batched(
-                            state.next_logits, state.temperature, state.top_k, state.top_p
-                        )
-                        keys = jax.vmap(jax.random.split)(state.rng)
-                        tok = sample_token_batched(keys[:, 1], processed, state.do_sample)
-                        tok = jnp.where(state.active, tok, state.pad_id).astype(jnp.int32)
-                        tok = jnp.where(use_forced, forced, tok).astype(jnp.int32)
-                    with jax.named_scope(TICK_SCOPES["decode"]):
-                        logits_t, cache = model.apply(
-                            params, tok[:, None], cache, method=decode_method
-                        )
-                    state = state.replace(
-                        next_logits=jnp.where(state.active[:, None], logits_t[:, -1],
-                                              state.next_logits),
-                        rng=jnp.where(state.active[:, None], keys[:, 0], state.rng),
-                    )
-                    return tok, finite, cache, state
+                    return decode_body(params, *args, forced, use_forced)
 
                 def no_decode(args):
                     cache, state = args
@@ -1224,26 +1139,33 @@ class ServingEngine:
                                     (cache, state))
 
             self._jit_ragged_tick = ragged_tick
+        else:
+            @partial(jax.jit, donate_argnums=(1, 2))
+            def decode_step(params, cache, state, forced, use_forced):
+                return decode_body(dq(params), cache, state, forced, use_forced)
+
+            self._jit_decode = decode_step
 
         self._jit_prefill = prefill_one
         self._jit_install = install_paged if self.paged else install
         self._jit_release = release
         self._jit_release_pages = release_pages if self.paged else None
-        self._jit_decode = decode_step
         self._jit_quarantine = quarantine_paged if self.paged else quarantine
-        self._jit_chunk_kv = chunk_kv if self.paged else None
-        self._jit_prefill_finish = prefill_finish if self.paged else None
-        self._jit_reset_scales = reset_scales if self.paged and self.kv_quant else None
+
+    @property
+    def ragged(self) -> bool:
+        """True exactly when the engine is paged: its tick is the fused
+        ``ragged_tick`` program (the dense pool's is ``decode_step``)."""
+        return self.paged
 
     @property
     def decode_compilations(self) -> int:
         """Number of programs compiled for the steady-state tick step
-        (target: 1). Under the ragged tick THE tick program is the fused
-        one — chunks, finishes, and decode in a single launch — so it is the
-        program this invariant pins; composed engines pin the decode jit."""
-        if self.ragged:
-            return self._jit_ragged_tick._cache_size()
-        return self._jit_decode._cache_size()
+        (target: 1): the fused tick — chunks, finishes, and decode in a
+        single launch — on a paged engine, the decode step on the dense
+        pool."""
+        tick = self._jit_ragged_tick if self.paged else self._jit_decode
+        return tick._cache_size()
 
     @property
     def prefill_compilations(self) -> int:
@@ -1254,30 +1176,24 @@ class ServingEngine:
     def total_compilations(self) -> int:
         """Total compiled programs across every engine jit — the router's
         compile-tick detector: a tick whose count moved paid a compile, so
-        its duration must not count as a stall strike (five int reads,
-        cheap enough per tick)."""
+        its duration must not count as a stall strike (a handful of int
+        reads, cheap enough per tick)."""
         jits = [
-            self._jit_prefill, self._jit_install, self._jit_decode,
-            self._jit_release, self._jit_quarantine,
+            self._jit_prefill, self._jit_install, self._jit_release,
+            self._jit_quarantine,
         ]
         if self._jit_release_pages is not None:
             jits.append(self._jit_release_pages)
-        if self._jit_chunk_kv is not None:
-            jits.extend((self._jit_chunk_kv, self._jit_prefill_finish))
-        if self._jit_reset_scales is not None:
-            jits.append(self._jit_reset_scales)
-        if self._jit_ragged_tick is not None:
-            jits.append(self._jit_ragged_tick)
-        return sum(f._cache_size() for f in jits)
+        return self.decode_compilations + sum(f._cache_size() for f in jits)
 
     def lower_tick(self):
         """The steady-state tick program — the fused ragged tick, or the
-        decode step on composed and dense engines — lowered at this engine's
-        shapes (a ``jax.stages.Lowered``). ``.compile().as_text()`` shows
-        which attention path the program holds: a Pallas kernel is a
+        decode step on the dense pool — lowered at this engine's shapes (a
+        ``jax.stages.Lowered``). ``.compile().as_text()`` shows which
+        attention path the program holds: a Pallas kernel is a
         ``tpu_custom_call``. Lowers an idle descriptor; dispatches nothing."""
         idle = (self._forced_none, self._use_forced_none)
-        if self.ragged:
+        if self.paged:
             return self._jit_ragged_tick.lower(*self._ragged_args(True, *idle))
         return self._jit_decode.lower(self.params, self._cache, self._state, *idle)
 
@@ -1642,35 +1558,24 @@ class ServingEngine:
         pages: Optional[int] = None
         if self.paged:
             # SPLIT admission (docs/serving.md "Chunked prefill" / "Prefix
-            # cache"): a prompt extending a cached prefix retains those
-            # pages and chunk-prefills only the uncached tail; a long
-            # prompt on a chunked engine spreads its KV writes one chunk
-            # per tick. Everything else takes the classic one-shot path
-            # below, bit-identical to the pre-chunking engine.
-            shared_run: List[int] = []
-            if self._prefix_cache is not None and request.page_keys:
-                shared_run = self._prefix_cache.probe(request.page_keys)
-            # QUANTIZED pools route every prompt that fits the finish step
-            # (n >= max_latents) through the split path, cold or cache-hit:
-            # the finish computes its latents against the slot's QUANTIZED
-            # pages (gather_slot dequant), so a cache-hit fork and a cold
-            # admission of the same prompt see byte-identical KV — the
-            # cache-on == cache-off token identity the fp engine pins
-            # survives quantization. (The classic one-shot path computes
-            # latents inside the prefill program, BEFORE quantization —
-            # fp-exact KV a fork could never reproduce from shared pages.)
-            # Shorter prompts (n < max_latents) keep the classic path: they
-            # have no cacheable pages, so no identity is at stake.
-            # Under the ragged tick every admission that CAN ride the
-            # descriptor does (n >= latents — the split path's floor, since
-            # the finish consumes the last L prompt tokens): its chunk and
-            # finish fuse into the tick program. Shorter prompts keep the
-            # classic prefill+install programs — the documented exception
-            # (docs/serving.md "Unified ragged tick").
-            if shared_run or (self.chunked and n >= self._latents
-                              and n > self.prefill_chunk_tokens) or (
-                                  self.kv_quant is not None and n >= self._latents
-                              ) or (self.ragged and n >= self._latents):
+            # cache" / "Unified ragged tick"): every prompt the finish step
+            # fits (n >= max_latents: the finish consumes the last L prompt
+            # tokens) rides the tick's descriptor — a prompt extending a
+            # cached prefix retains those pages and chunk-prefills only the
+            # uncached tail; a long prompt on a chunked engine spreads its
+            # KV writes one chunk per tick; the chunks and the finish fuse
+            # into the tick program. The finish computes its latents against
+            # the slot's pages AS STORED (gather_slot dequant on a quantized
+            # pool), so a cache-hit fork and a cold admission of the same
+            # prompt see byte-identical KV — the cache-on == cache-off token
+            # identity survives quantization. Shorter prompts keep the
+            # classic prefill + install programs below, the documented
+            # exception: they have no cacheable pages (page keys lie below
+            # the latent boundary), so no identity is at stake.
+            if n >= self._latents:
+                shared_run: List[int] = []
+                if self._prefix_cache is not None and request.page_keys:
+                    shared_run = self._prefix_cache.probe(request.page_keys)
                 self._admit_split(slot, request, bucket, shared_run, t0)
                 return
             # the ONLY allocation point (serving/paging.py): the whole
@@ -1709,19 +1614,6 @@ class ServingEngine:
                     self._cache, self._state, slot, req_cache, req_logits,
                     request.rng, *sampling,
                 )
-        if self.paged and self._prefix_cache is not None and request.page_keys:
-            # the page-aligned install makes this prompt's pages cache-grade:
-            # insert the cacheable run (full pages below the latent
-            # boundary) so later prompts sharing the prefix fork instead of
-            # recomputing — the donor's pages gain the cache's reference and
-            # outlive this session
-            self._prefix_cache.insert(
-                request.page_keys,
-                [int(p) for p in table_row[: len(request.page_keys)]],
-            )
-            self.metrics.set_prefix_cache(
-                self._prefix_cache.stats(), self._shared_pages_in_use()
-            )
         # NON-BLOCKING: no device sync here — the prefill/install dispatch
         # overlaps the decode stream, and step() syncs once per tick (its
         # np.asarray on the decoded tokens). prefill_s is therefore dispatch
@@ -1773,21 +1665,17 @@ class ServingEngine:
         self._slot_pages[slot] = page_ids
         table_row = np.zeros((self._pages_per_slot,), np.int32)
         table_row[: len(page_ids)] = page_ids  # trash-padded reservation
-        if self._jit_reset_scales is not None:
+        if self.kv_quant is not None:
             # quantized pools: zero the PRIVATE pages' scale sidecars before
             # any chunk writes them — a fresh page must start from scale 0 so
             # its first ratcheted append zeroes stale tenant bytes; shared
             # prefix pages keep theirs (the scales ARE part of the cached
             # bytes). Trash-padded tail entries re-zero page 0 harmlessly.
+            # Rides the tick descriptor: the fused program's reset phase
+            # runs before any chunk lane.
             ids_row = np.zeros((self._pages_per_slot,), np.int32)
             ids_row[: len(private)] = private
-            if self.ragged:
-                # rides the tick descriptor: the fused program's reset phase
-                # runs before any chunk lane, preserving composed order
-                self._tick_resets.append((slot, ids_row))
-            else:
-                self._tick_programs += 1
-                self._cache = self._jit_reset_scales(self._cache, jnp.asarray(ids_row))
+            self._tick_resets.append((slot, ids_row))
         shared_tokens = shared * self.kv_page_size
         budget = (self.prefill_chunk_tokens if self.chunked
                   else max(n - shared_tokens, 1))
@@ -1831,33 +1719,19 @@ class ServingEngine:
             c = min(task.chunk_budget, remaining)
             self._tick_chunk_items += 1
             t0 = time.perf_counter()
-            # the chunk's host work: packing a descriptor lane (ragged), or
-            # packing + dispatching the chunk program (composed)
+            # the chunk's host work: packing a descriptor lane
             with self._obs.span(self._span_chunk, request_id=request.request_id):
-                if self.ragged:
-                    # descriptor lane, FIXED row capacity — chunk shapes stop
-                    # riding the bucket ladder (chunk math is row-independent
-                    # and write_rows routes pad rows to the trash page, so
-                    # cap-vs-ladder padding is value-identical on real rows)
-                    ids = np.full((self._ragged_chunk_cap,),
-                                  request.config.pad_token_id, np.int32)
-                    ids[:c] = request.prompt_ids[task.next_pos: task.next_pos + c]
-                    self._tick_chunks.append(
-                        (slot, ids, task.next_pos, c,
-                         task.n - self._latents, task.table_row)
-                    )
-                else:
-                    cb = self._bucket_for(c)  # chunk program shapes ride the ladder
-                    ids = np.full((1, cb), request.config.pad_token_id, np.int32)
-                    ids[0, :c] = request.prompt_ids[task.next_pos: task.next_pos + c]
-                    self._tick_programs += 1
-                    self._cache = self._jit_chunk_kv(
-                        self.params, self._cache, jnp.asarray(ids),
-                        jnp.asarray(task.next_pos, jnp.int32),
-                        jnp.asarray(c, jnp.int32),
-                        jnp.asarray(task.n - self._latents, jnp.int32),
-                        jnp.asarray(task.table_row),
-                    )
+                # FIXED row capacity — chunk shapes do not ride the bucket
+                # ladder (chunk math is row-independent and write_rows routes
+                # pad rows to the trash page, so the padding never reaches a
+                # real row)
+                ids = np.full((self._ragged_chunk_cap,),
+                              request.config.pad_token_id, np.int32)
+                ids[:c] = request.prompt_ids[task.next_pos: task.next_pos + c]
+                self._tick_chunks.append(
+                    (slot, ids, task.next_pos, c,
+                     task.n - self._latents, task.table_row)
+                )
             task.next_pos += c
             task.chunks += 1
             if self.chunked:
@@ -1886,20 +1760,20 @@ class ServingEngine:
             self._finish_prefill(slot, task)
 
     def _finish_prefill(self, slot: int, task: _PrefillTask) -> None:
-        """The split admission's FINISH: one fixed-shape program computes the
-        latents against the slot's pages, installs the page table / ring
-        offset / SA cache, and activates the slot's decode state — the
-        moment this request is DECODE-READY (``admitted_at``; its queue wait
-        ended at ``slot_claimed_at``, every chunk tick before this one)."""
+        """The split admission's FINISH: a fixed-shape lane of the tick
+        program computes the latents against the slot's pages, installs the
+        page table / ring offset / SA cache, and activates the slot's decode
+        state — the moment this request is DECODE-READY (``admitted_at``; its
+        queue wait ended at ``slot_claimed_at``, every chunk tick before this
+        one)."""
         request = task.request
         cfg = request.config
         self._tick_finish_items += 1
-        # the finish's host work: packing a descriptor lane (ragged), or
-        # packing + dispatching the finish program (composed)
+        # the finish's host work: packing a descriptor lane
         with self._obs.span(self._span_finish, request_id=request.request_id):
             ids_latent = np.asarray(
                 request.prompt_ids[task.n - self._latents:], np.int32
-            )[None, :]
+            )
             sampling = (
                 float(cfg.temperature) if cfg.do_sample else 1.0,
                 int(cfg.top_k) if (cfg.do_sample and cfg.top_k) else 0,
@@ -1907,22 +1781,13 @@ class ServingEngine:
                 bool(cfg.do_sample),
                 int(cfg.pad_token_id),
             )
-            if self.ragged:
-                # descriptor lane — the fused program's finish phase runs
-                # after every chunk lane (this slot's tail chunk included)
-                # and before decode, so the newly active slot decodes THIS
-                # tick, exactly like the composed path
-                self._tick_finishes.append(
-                    (slot, task.table_row, ids_latent[0], task.n,
-                     np.asarray(request.rng), sampling)
-                )
-            else:
-                self._tick_programs += 1
-                self._cache, self._state = self._jit_prefill_finish(
-                    self.params, self._cache, self._state, slot,
-                    jnp.asarray(task.table_row), jnp.asarray(ids_latent),
-                    jnp.asarray(task.n, jnp.int32), request.rng, *sampling,
-                )
+            # the fused program's finish phase runs after every chunk lane
+            # (this slot's tail chunk included) and before decode, so the
+            # newly active slot decodes THIS tick
+            self._tick_finishes.append(
+                (slot, task.table_row, ids_latent, task.n,
+                 np.asarray(request.rng), sampling)
+            )
         del self._prefilling[slot]
         # (donor insert already happened incrementally, chunk by chunk, in
         # _advance_prefill — by the last chunk it covered every cacheable key)
@@ -2471,12 +2336,13 @@ class ServingEngine:
             if occupied is None:
                 return
             slot = occupied[0]
-        if self.ragged:
+        if self.paged:
             # stash for the fused program's poison phase — applied between
-            # the finish lanes (which activate logits) and decode, the same
-            # composed ordering, without an eager host-side device op
+            # the finish lanes (which activate logits) and decode, without
+            # an eager host-side device op
             self._tick_poison = slot
             return
+        # the dense pool has no descriptor: poke the logits eagerly
         self._state = self._state.replace(
             next_logits=self._state.next_logits.at[slot].set(jnp.nan)
         )
@@ -2685,13 +2551,14 @@ class ServingEngine:
             # schedule ends where the dispatch begins: one clock reading, no seam
             t_dispatch = obs.span_end(self._span_schedule)
             obs.span_begin(self._span_decode_dispatch, at=t_dispatch, tick=tick)
-            if self.ragged:
+            if self.paged:
                 # the tick's ONE program: resets + chunks + finishes +
                 # poison + decode, fused (docs/serving.md "Unified
                 # ragged tick"); the span holds the descriptor build
                 tok, finite = self._dispatch_ragged(bool(occupied),
                                                     forced, use_forced)
             else:
+                # the dense pool's tick is the decode step alone
                 self._tick_programs += 1
                 # dispatch only — the jit call returns before the device step
                 # finishes; the device cost lands in the sample-sync at harvest

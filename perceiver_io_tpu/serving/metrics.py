@@ -125,14 +125,13 @@ Schema history:
   * ``serving-metrics/v11`` — the unified-ragged-tick schema (docs/serving.md
     "Unified ragged tick"): every snapshot carries a ``ragged_tick`` field —
     ``None`` on dense engines and on router snapshots (tick dispatch is
-    per-engine), else ``enabled`` (False under the
-    ``PERCEIVER_IO_TPU_DISABLE_RAGGED_TICK`` kill-switch — the composed
-    per-phase dispatcher), ``ticks`` (dispatching ticks recorded),
-    ``programs_per_tick`` p50/p95 (the headline gauge: 1 steady-state when
-    ragged, the per-phase sum when composed), ``chunk_items`` /
-    ``finish_items`` / ``decode_items`` p50/p95 (the mixed-batch
-    composition per tick), and ``descriptor_build_s`` p50/p95 (host-side
-    lane packing; 0 when composed). The stream is unchanged — the block is
+    per-engine), else ``enabled`` (True on every paged engine: the fused
+    tick is its one dispatcher), ``ticks`` (dispatching ticks recorded),
+    ``programs_per_tick`` p50/p95 (the headline gauge: 1 steady-state;
+    short prompts' prefill + install and evictions add theirs),
+    ``chunk_items`` / ``finish_items`` / ``decode_items`` p50/p95 (the
+    mixed-batch composition per tick), and ``descriptor_build_s`` p50/p95
+    (host-side lane packing). The stream is unchanged — the block is
     windowed gauges only. The reader normalizes pre-v11 snapshots with
     ``None``.
   * ``serving-metrics/v12`` — the out-of-process-replica schema
@@ -426,9 +425,8 @@ class EngineMetrics(_JsonlMetrics):
     # weight-serving gauges (serving-metrics/v9): None <=> params untouched
     weight_serving: Optional[Dict] = None
     # unified-ragged-tick gauges (serving-metrics/v11): ragged_enabled None
-    # <=> dense engine (no tick dispatcher) and snapshots report
-    # ragged_tick: None; False <=> paged engine running the composed
-    # per-phase dispatcher (the kill-switch comparison arm)
+    # <=> dense engine (no tick descriptor) and snapshots report
+    # ragged_tick: None; True on every paged engine
     ragged_enabled: Optional[bool] = None
     ragged_ticks: int = 0
     _tick_program_counts: Deque[int] = field(default_factory=lambda: deque(maxlen=LATENCY_WINDOW))
@@ -559,11 +557,8 @@ class EngineMetrics(_JsonlMetrics):
 
     def set_ragged_tick(self, enabled: bool) -> None:
         """Mark a paged engine's tick dispatcher (serving-metrics/v11):
-        snapshots report the ragged_tick section instead of None. ``enabled``
-        False means the composed per-phase dispatcher is live (the
-        ``PERCEIVER_IO_TPU_DISABLE_RAGGED_TICK`` kill-switch) — its
-        per-tick program counts are recorded through the same gauges, which
-        is exactly the 1-vs-N comparison the bench reads."""
+        snapshots report the ragged_tick section instead of None. Every
+        paged engine passes True (the fused tick is its one dispatcher)."""
         self.ragged_enabled = bool(enabled)
 
     def record_tick_dispatch(self, programs: int, chunk_items: int,
@@ -573,7 +568,7 @@ class EngineMetrics(_JsonlMetrics):
         compiled programs the tick launched (ragged steady-state: exactly 1),
         the tick's mixed-batch composition (prefill chunk lanes, latent
         finish lanes, decoding slots), and the host-side descriptor build
-        time (0 when composed — there is no descriptor). Windowed, no JSONL
+        time (0 on the dense pool — there is no descriptor). Windowed, no JSONL
         event: this fires every tick, and the stream already carries
         decode_step/chunk events for per-tick forensics."""
         self.ragged_ticks += 1

@@ -40,71 +40,17 @@ them into its page table (O(page-table copy), zero KV duplication or
 recompute), and the cache itself holds one reference per cached page so a
 cached run outlives the request that built it.
 
-Kill-switches: ``PERCEIVER_IO_TPU_DISABLE_PAGED_KV=1`` forces the dense pool
-even when an engine was configured with a page size (``paged_kv_enabled``),
-f64 greedy parity pinned both ways (tests/test_paging.py);
-``PERCEIVER_IO_TPU_DISABLE_PREFIX_CACHE=1`` forces every probe to miss and
-every insert to no-op (outputs bit-identical to a cold cache — which is
-itself pinned bit-identical to cache-off);
-``PERCEIVER_IO_TPU_DISABLE_CHUNKED_PREFILL=1`` pins admission to the
-one-shot bucket prefill (serving/engine.py).
+The engine's arguments alone turn these on (``kv_page_size``,
+``prefix_cache``, ``prefill_chunk_tokens``; serving/engine.py): this module
+reads no environment.
 """
 
 from __future__ import annotations
 
 import itertools
-import os
 from collections import Counter
 from heapq import heapify, heappop, heappush
 from typing import Dict, List, Optional, Sequence, Set, Tuple
-
-
-def paged_kv_enabled() -> bool:
-    """Kill-switch for the paged KV cache: PERCEIVER_IO_TPU_DISABLE_PAGED_KV=1
-    pins engines to the dense full-window slot pool (the pre-paging layout)
-    regardless of their ``kv_page_size`` knob. Checked at engine construction,
-    like the bucketed-prefill switch."""
-    return os.environ.get("PERCEIVER_IO_TPU_DISABLE_PAGED_KV", "0").lower() in ("0", "false", "")
-
-
-def prefix_cache_enabled() -> bool:
-    """Kill-switch for the cross-request radix prefix cache:
-    ``PERCEIVER_IO_TPU_DISABLE_PREFIX_CACHE=1`` forces every probe to miss
-    and every insert to drop — behavior bit-identical to running with the
-    cache cold, which is itself pinned bit-identical to ``prefix_cache=False``
-    (tests/test_prefix_cache.py). Checked at engine construction."""
-    return os.environ.get("PERCEIVER_IO_TPU_DISABLE_PREFIX_CACHE", "0").lower() in ("0", "false", "")
-
-
-def kv_quant_enabled() -> bool:
-    """Kill-switch for quantized serving (docs/serving.md "Quantized KV
-    pages & weight serving"): ``PERCEIVER_IO_TPU_DISABLE_KV_QUANT=1`` forces
-    full-precision pages AND full-precision served weights regardless of the
-    engine's ``kv_quant``/``weight_dtype`` knobs — behavior exactly the
-    pre-quantization engine's (f64 parity pinned, tests/test_kv_quant.py).
-    Checked at engine construction, like the paged-KV switch; a rollback
-    lever must never crash the engine it rolls back."""
-    return os.environ.get("PERCEIVER_IO_TPU_DISABLE_KV_QUANT", "0").lower() in ("0", "false", "")
-
-
-def ragged_tick_enabled() -> bool:
-    """Kill-switch for the unified ragged tick (docs/serving.md "Unified
-    ragged tick"): ``PERCEIVER_IO_TPU_DISABLE_RAGGED_TICK=1`` restores the
-    composed per-program tick — per-rung chunk programs, per-slot finish
-    programs, a separate decode dispatch — BIT-identically (the composed
-    path stays compiled-in as the fallback and correctness oracle;
-    tests/test_ragged_tick.py pins tokens both ways). Checked at engine
-    construction, like the paged-KV switch."""
-    return os.environ.get("PERCEIVER_IO_TPU_DISABLE_RAGGED_TICK", "0").lower() in ("0", "false", "")
-
-
-def chunked_prefill_enabled() -> bool:
-    """Kill-switch for chunked admission prefill:
-    ``PERCEIVER_IO_TPU_DISABLE_CHUNKED_PREFILL=1`` pins every admission to
-    the one-shot covering-bucket prefill regardless of the engine's
-    ``prefill_chunk_tokens`` knob (outputs token-identical either way —
-    pinned). Checked at engine construction, like the paged-KV switch."""
-    return os.environ.get("PERCEIVER_IO_TPU_DISABLE_CHUNKED_PREFILL", "0").lower() in ("0", "false", "")
 
 
 def page_keys_for_prompt(prompt, page_size: int, max_latents: int) -> Tuple[Tuple[int, ...], ...]:

@@ -20,10 +20,9 @@ construction:
     the resident tree is int8 (~4x smaller than f32), the dequantized copy is
     a per-execution transient XLA schedules in and out of scratch.
 
-Both transforms are applied ONCE at engine construction and are behind the
-``PERCEIVER_IO_TPU_DISABLE_KV_QUANT`` kill-switch + ``weight_dtype=None``
-default — off means the params object is passed through UNTOUCHED (the f64
-parity pins run through the identity path). This module is deliberately
+Both transforms are applied ONCE at engine construction and are off by
+default (``weight_dtype=None``) — off means the params object is passed
+through UNTOUCHED (the f64 parity pins run through the identity path). This module is deliberately
 jax-light and model-agnostic: it walks pytree leaves, never module code.
 """
 

@@ -75,8 +75,8 @@ exercised on every change, not just when production finds them:
                            fused ragged tick under page pressure: the
                            poisoned slot's buffered descriptor lanes drop
                            with it, survivors finish f64 token-identical
-                           to the COMPOSED kill-switch engine running
-                           uncontended, repeat runs pin statuses/tokens/
+                           to the same engine running uncontended (ample
+                           pool, no fault), repeat runs pin statuses/tokens/
                            victims, and the drain leaves the free list
                            whole and the tick buffers empty
 
@@ -972,16 +972,15 @@ def check_chunked_prefill_recovery() -> dict:
 
 def check_ragged_tick_churn() -> dict:
     """Fault churn INSIDE the unified ragged tick (docs/serving.md "Unified
-    ragged tick"): with the fused one-program tick live (the paged default),
-    a poisoned slot is quarantined out of a MIXED tick — its buffered
-    descriptor lanes dropped with it — while chunked prefill lanes are still
-    streaming, and a high-priority request then admits via preemption under
-    page pressure. Every survivor finishes f64 token-identical to the
-    COMPOSED per-program engine running uncontended (the kill-switch arm is
-    the correctness oracle, not a convenience), repeat runs pin statuses,
-    tokens AND victim identity, and the drain leaves the free list whole
-    and the tick buffers empty — a dropped lane leaks no page."""
-    kill = "PERCEIVER_IO_TPU_DISABLE_RAGGED_TICK"
+    ragged tick"): a poisoned slot is quarantined out of a MIXED tick — its
+    buffered descriptor lanes dropped with it — while chunked prefill lanes
+    are still streaming, and a high-priority request then admits via
+    preemption under page pressure. Every survivor finishes f64
+    token-identical to the same engine running uncontended (ample pool, no
+    fault armed: what the scenario claims is that faults leave the
+    survivors' tokens untouched), repeat runs pin statuses, tokens AND
+    victim identity, and the drain leaves the free list whole and the tick
+    buffers empty — a dropped lane leaks no page."""
     with _x64():
         model, params = _serving_setup(param_dtype=jnp.float64)
         # short (classic path, n < latents), window-length chunk-streamed,
@@ -993,22 +992,12 @@ def check_ragged_tick_churn() -> dict:
         # has no decode state to poison yet
         poisoned_prompt = [20, 21, 22]
 
-        def build(composed, **kw):
-            prev = os.environ.pop(kill, None)
-            if composed:
-                os.environ[kill] = "1"
-            try:
-                return _engine(model, params, num_slots=3, kv_page_size=2, **kw)
-            finally:
-                if prev is None:
-                    os.environ.pop(kill, None)
-                else:
-                    os.environ[kill] = prev
+        def build(**kw):
+            return _engine(model, params, num_slots=3, kv_page_size=2, **kw)
 
         def reference():
-            # composed per-program engine, ample pool, no faults: the oracle
-            engine = build(True)
-            assert not engine.ragged
+            # the same engine, ample pool, no faults: the oracle
+            engine = build()
             hs = [engine.submit(p, max_new_tokens=m, rng=jax.random.PRNGKey(i))
                   for i, (p, m) in enumerate(zip(survivor_prompts, new))]
             engine.run_until_drained(max_steps=300)
@@ -1023,9 +1012,8 @@ def check_ragged_tick_churn() -> dict:
             # exactly; the quarantine hands 5 back, one short of the hi
             # head's 6 — the head page-blocks and must preempt, all while
             # chunk lanes are still streaming
-            engine = build(False, num_kv_pages=17,
+            engine = build(num_kv_pages=17,
                            prefill_chunk_tokens=4, max_prefill_slots=2)
-            assert engine.ragged
             short = engine.submit(survivor_prompts[0], max_new_tokens=new[0],
                                   rng=jax.random.PRNGKey(0))
             long = engine.submit(survivor_prompts[1], max_new_tokens=new[1],
@@ -1076,7 +1064,7 @@ def check_ragged_tick_churn() -> dict:
             and r1["buffers_empty"]
         ),
         "statuses": r1["statuses"],
-        "survivors_identical_to_composed_uncontended": survivors_identical,
+        "survivors_identical_to_uncontended": survivors_identical,
         "deterministic_repeat": r1 == r2,
         "victims": r1["victims"],
         "preemptions": r1["preemptions"],

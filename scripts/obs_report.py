@@ -438,7 +438,6 @@ def main(argv=None) -> Dict:
             ppt = rt.get("programs_per_tick") or {}
             build = rt.get("descriptor_build_s") or {}
             print("ragged tick: "
-                  f"{'ragged' if rt.get('enabled') else 'composed (kill-switch)'}, "
                   f"{rt.get('ticks')} dispatching ticks, "
                   f"programs/tick p50={ppt.get('p50')} p95={ppt.get('p95')}, "
                   f"descriptor build p95={build.get('p95')}s")

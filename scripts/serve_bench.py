@@ -1332,20 +1332,15 @@ def run_chunked_interference(model, config, params, num_slots: int, seed: int,
 def run_ragged_tick_bench(model, config, params, num_slots: int, seed: int,
                           repeats: int = 7) -> dict:
     """``--ragged`` arm (docs/serving.md "Unified ragged tick"): the fused
-    ONE-program tick vs the composed per-program tick the
-    PERCEIVER_IO_TPU_DISABLE_RAGGED_TICK kill-switch restores, on a
-    sustained MIXED workload — background decode streams plus recurring
-    window-length prompt bursts admitted through chunked prefill, so steady
-    ticks genuinely carry chunk lanes, latent finishes AND batched decode at
-    once (the shape class the ragged tick exists for; a decode-only
-    workload would show nothing). Reported per arm, interleaved
+    ONE-program tick on a sustained MIXED workload — background decode
+    streams plus recurring window-length prompt bursts admitted through
+    chunked prefill, so steady ticks genuinely carry chunk lanes, latent
+    finishes AND batched decode at once (the shape class the ragged tick
+    exists for; a decode-only workload would show nothing). Reported,
     median-of-``repeats``: decode tokens/s, running-slot inter-token
-    p50/p95, and the headline 1-vs-N contrast — programs per dispatching
-    tick from the v11 ``ragged_tick`` metrics block (plus descriptor build
-    time on the ragged arm; the host-side cost the single dispatch buys).
-    Greedy tokens must be IDENTICAL across the arms on the bench workload
-    (the f64 engine-level pin lives in tests/test_ragged_tick — this
-    re-checks at serving dtype under timing pressure).
+    p50/p95, and the counts — programs per dispatching tick from the v11
+    ``ragged_tick`` metrics block, descriptor build time (the host-side
+    cost the single dispatch buys), compilations of the tick program.
 
     A second section prices the new int4 pages: CONCURRENT SESSIONS PER
     FIXED POOL BYTE BUDGET, int4 vs int8 vs full-precision pages — the same
@@ -1357,7 +1352,6 @@ def run_ragged_tick_bench(model, config, params, num_slots: int, seed: int,
     from perceiver_io_tpu.serving import ServingEngine, pages_for_request
     from perceiver_io_tpu.serving.engine import default_prefill_buckets
 
-    KILL = "PERCEIVER_IO_TPU_DISABLE_RAGGED_TICK"
     window = config.max_seq_len
     page_size = max(window // 16, 2)
     chunk = max(window // 8, 1)
@@ -1377,27 +1371,15 @@ def run_ragged_tick_bench(model, config, params, num_slots: int, seed: int,
     burst_prompts = [rng.randint(1, config.vocab_size, size=window).tolist()
                      for _ in range(burst_size * n_bursts)]
 
-    def build(composed: bool) -> ServingEngine:
-        # the mode knob is read at construction: toggle the kill-switch
-        # around the ctor only, and restore the ambient env either way
-        prev = os.environ.pop(KILL, None)
-        if composed:
-            os.environ[KILL] = "1"
-        try:
-            # telemetry=False: ambient env must not record inside a TIMED arm
-            return ServingEngine(
-                model, params, num_slots=slots, kv_page_size=page_size,
-                num_kv_pages=num_pages,
-                max_queue_depth=4 * len(burst_prompts),
-                prefill_chunk_tokens=chunk, max_prefill_slots=2,
-                telemetry=False)
-        finally:
-            if prev is None:
-                os.environ.pop(KILL, None)
-            else:
-                os.environ[KILL] = prev
+    # telemetry=False: ambient env must not record inside a TIMED arm
+    engine = ServingEngine(
+        model, params, num_slots=slots, kv_page_size=page_size,
+        num_kv_pages=num_pages,
+        max_queue_depth=4 * len(burst_prompts),
+        prefill_chunk_tokens=chunk, max_prefill_slots=2,
+        telemetry=False)
 
-    def one_pass(engine):
+    def one_pass():
         bg = [engine.submit(p, max_new_tokens=bg_max_new,
                             rng=jax.random.PRNGKey(i))
               for i, p in enumerate(bg_prompts)]
@@ -1422,38 +1404,25 @@ def run_ragged_tick_bench(model, config, params, num_slots: int, seed: int,
         drain = time.perf_counter() - t0
         assert all(h.ok for h in bg) and all(h.ok for h in lhs)
         engine.finished.clear()
-        return sorted(gaps), drain, [h.result().tolist() for h in bg + lhs]
+        return sorted(gaps), drain
 
-    engines = {"ragged": build(False), "composed": build(True)}
-    assert engines["ragged"].ragged and not engines["composed"].ragged
-    for engine in engines.values():  # warmup compiles every program
-        one_pass(engine)
-    samples = {n: [] for n in engines}
-    tokens_by_arm = {}
-    for _ in range(repeats):
-        for name, engine in engines.items():  # interleaved A/B
-            gaps, drain, toks = one_pass(engine)
-            samples[name].append((gaps, drain))
-            tokens_by_arm[name] = toks
+    one_pass()  # warmup compiles every program
+    samples = [one_pass() for _ in range(repeats)]
 
     new_tokens = bg_max_new * len(bg_prompts) + burst_max_new * len(burst_prompts)
-    arms = {}
-    for name, engine in engines.items():
-        drain = _median([s[1] for s in samples[name]])
-        rt = engine.metrics.snapshot()["ragged_tick"]
-        arms[name] = {
-            "tokens_per_s": round(new_tokens / drain, 2) if drain > 0 else 0.0,
-            "drain_wall_seconds": round(drain, 4),
-            "inter_token_p50_s": round(
-                _median([_pct(s[0], 0.50) for s in samples[name]]), 4),
-            "inter_token_p95_s": round(
-                _median([_pct(s[0], 0.95) for s in samples[name]]), 4),
-            "dispatching_ticks": rt["ticks"],
-            "programs_per_tick": rt["programs_per_tick"],
-            "descriptor_build_s": rt["descriptor_build_s"],
-            "tick_compilations": engine.decode_compilations,
-        }
-        engine.close()
+    drain = _median([d for _, d in samples])
+    rt = engine.metrics.snapshot()["ragged_tick"]
+    ragged = {
+        "tokens_per_s": round(new_tokens / drain, 2) if drain > 0 else 0.0,
+        "drain_wall_seconds": round(drain, 4),
+        "inter_token_p50_s": round(_median([_pct(g, 0.50) for g, _ in samples]), 4),
+        "inter_token_p95_s": round(_median([_pct(g, 0.95) for g, _ in samples]), 4),
+        "dispatching_ticks": rt["ticks"],
+        "programs_per_tick": rt["programs_per_tick"],
+        "descriptor_build_s": rt["descriptor_build_s"],
+        "tick_compilations": engine.decode_compilations,
+    }
+    engine.close()
 
     # --- int4 capacity: sessions per fixed pool BYTE budget, three arms.
     # The budget is the fp arm's pool bytes; every arm spends the same
@@ -1531,7 +1500,6 @@ def run_ragged_tick_bench(model, config, params, num_slots: int, seed: int,
     i4_peak = cap_arms["int4"]["peak_concurrent_sessions"]
     int4_vs_fp = round(i4_peak / fp_peak, 3) if fp_peak else 0.0
 
-    ra, co = arms["ragged"], arms["composed"]
     return {
         "workload": {
             "background_sessions": len(bg_prompts),
@@ -1545,20 +1513,9 @@ def run_ragged_tick_bench(model, config, params, num_slots: int, seed: int,
             "page_size": page_size,
             "slots": slots,
         },
-        **{f"{n}_arm": a for n, a in arms.items()},
-        "tokens_per_s_ratio": round(
-            ra["tokens_per_s"] / co["tokens_per_s"], 3)
-        if co["tokens_per_s"] > 0 else 0.0,
-        "inter_token_p95_ratio": round(
-            co["inter_token_p95_s"] / ra["inter_token_p95_s"], 3)
-        if ra["inter_token_p95_s"] > 0 else 0.0,
-        # the structural win the arm exists to record: 1 vs N
-        "programs_per_tick_p50": {
-            "ragged": ra["programs_per_tick"]["p50"],
-            "composed": co["programs_per_tick"]["p50"],
-        },
-        "greedy_tokens_identical": (
-            tokens_by_arm["ragged"] == tokens_by_arm["composed"]),
+        # programs_per_tick p50 is the structural count the arm exists to
+        # record: one program a tick
+        "ragged_arm": ragged,
         "int4_capacity": {
             "pool_byte_budget": budget_bytes,
             "page_bytes": page_bytes,
@@ -1861,11 +1818,10 @@ def main(argv=None) -> dict:
                          "--profile-out artifact (BENCH_serving.json)")
     ap.add_argument("--chunked-repeats", type=int, default=5)
     ap.add_argument("--ragged", action="store_true",
-                    help="run the unified-ragged-tick arm: fused one-program "
-                         "tick vs the composed kill-switch arm on a mixed "
-                         "prefill+decode workload (tokens/s, inter-token "
-                         "p95, programs-per-tick 1-vs-N), interleaved "
-                         "median-of --ragged-repeats, plus the int4-page "
+                    help="run the unified-ragged-tick arm: the fused "
+                         "one-program tick on a mixed prefill+decode "
+                         "workload (tokens/s, inter-token p95, programs per "
+                         "tick), median-of --ragged-repeats, plus the int4-page "
                          "capacity section (sessions at fixed HBM vs "
                          "int8/fp, greedy agreement); the block lands in "
                          "the --profile-out artifact (BENCH_serving.json)")

@@ -29,7 +29,7 @@ def test_every_package_env_var_is_documented():
         f"docs/reliability.md / docs/observability.md)"
     )
     # the guard is not vacuous: the known switches are actually found
-    for var in ("PERCEIVER_IO_TPU_DISABLE_PAGED_KV",
+    for var in ("PERCEIVER_IO_TPU_DISABLE_DECODE_KERNEL",
                 "PERCEIVER_IO_TPU_DISABLE_PREEMPTION",
                 "PERCEIVER_IO_TPU_TELEMETRY"):
         assert var in result["package_vars"]

@@ -6,9 +6,8 @@ is bounded by half an LSB of the page-head scale; the fused-dequant paged
 kernel is BITWISE identical (interpret mode) to feeding the XLA-dequantized
 f32 pool through the same kernel — across ring-wrapped live intervals and
 partial last pages — and the engine's kernel-forced tokens match its XLA
-fallback exactly. The rollback contract: ``kv_quant=None`` (and the
-``PERCEIVER_IO_TPU_DISABLE_KV_QUANT`` kill-switch) is exact f64 parity to
-the pre-quantization engine (generate()'s canonical form). The determinism
+fallback exactly. The rollback contract: ``kv_quant=None`` is exact f64
+parity to the pre-quantization engine (generate()'s canonical form). The determinism
 contract: quantized runs are repeat-identical, cache-on == cache-off, and a
 preempted/quarantined slot leaves slot-mates bit-identical with the
 condemned pages' bytes AND scales zeroed.
@@ -307,30 +306,27 @@ def test_kv_quant_none_is_exact_f64_parity_to_pre_quant_engine(x64):
         assert handle.result().tolist() == expected, f"len {len(prompt)} diverged"
 
 
-def test_kill_switch_forces_fp_and_matches_quant_none(x64, monkeypatch):
-    """PERCEIVER_IO_TPU_DISABLE_KV_QUANT pins fp pages + untouched params
-    even with both knobs set — tokens f64-identical to kv_quant=None."""
+def test_off_values_are_fp_and_match_the_default_engine(x64):
+    """``kv_quant=None, weight_dtype=None`` is fp pages + untouched params —
+    tokens f64-identical to the engine built without either argument."""
     model, params = _make_model(param_dtype=jnp.float64)
     prompts = [[5, 6, 7], list(range(3, 12))]
 
-    def run(disable, **kw):
-        if disable:
-            monkeypatch.setenv("PERCEIVER_IO_TPU_DISABLE_KV_QUANT", "1")
-        else:
-            monkeypatch.delenv("PERCEIVER_IO_TPU_DISABLE_KV_QUANT", raising=False)
+    def run(**kw):
         engine = ServingEngine(model, params, num_slots=2, kv_page_size=PS, **kw)
         handles = [engine.submit(p, max_new_tokens=4) for p in prompts]
         engine.run_until_drained(max_steps=200)
         return [h.result().tolist() for h in handles], engine
 
-    base, _ = run(False)
-    killed, ek = run(True, kv_quant="int8", weight_dtype="int8")
-    assert killed == base
-    assert ek.kv_quant is None and ek.weight_dtype is None
-    assert ek.metrics.snapshot()["kv_quant"] is None
-    assert ek.metrics.snapshot()["weight_serving"] is None
-    # and with the switch clear, the knobs actually engage
-    _, eq = run(False, kv_quant="int8")
+    base, _ = run()
+    off, eo = run(kv_quant=None, weight_dtype=None)
+    assert off == base
+    assert eo.kv_quant is None and eo.weight_dtype is None
+    assert eo._cache.ca.kp.dtype == jnp.float64 and eo.params is params
+    assert eo.metrics.snapshot()["kv_quant"] is None
+    assert eo.metrics.snapshot()["weight_serving"] is None
+    # and set, the knobs actually engage
+    _, eq = run(kv_quant="int8")
     assert eq.kv_quant == "int8" and eq._cache.ca.kp.dtype == jnp.int8
 
 
@@ -360,9 +356,7 @@ def test_quant_engine_deterministic_and_compiles_decode_once(setup):
     assert toks1 == toks2  # deterministic under churn
     assert engine.decode_compilations == 1  # THE invariant, quant included
     assert engine.prefill_compilations <= len(engine.prefill_buckets)
-    assert engine._jit_chunk_kv._cache_size() <= len(engine.prefill_buckets)
-    assert engine._jit_prefill_finish._cache_size() <= 1
-    assert engine._jit_reset_scales._cache_size() <= 1
+    assert engine._jit_ragged_tick._cache_size() == 1  # resets, chunks and finishes ride it
     assert engine._pool.pages_in_use == 0
     assert all(p is None for p in engine._slot_pages)
 
@@ -583,15 +577,6 @@ def test_constructor_validation(setup):
     with pytest.raises(ValueError, match="multiple of kv_page_size"):
         ServingEngine(model, params, num_slots=2, kv_page_size=PS,
                       kv_quant="int8", prefill_chunk_tokens=6)
-    # the PAGED kill-switch silently disables quant too (rollback lever must
-    # never crash): dense-forced engine with kv_quant configured runs dense fp
-    os.environ["PERCEIVER_IO_TPU_DISABLE_PAGED_KV"] = "1"
-    try:
-        engine = ServingEngine(model, params, num_slots=2, kv_page_size=PS,
-                               kv_quant="int8")
-        assert not engine.paged and engine.kv_quant is None
-    finally:
-        del os.environ["PERCEIVER_IO_TPU_DISABLE_PAGED_KV"]
 
 
 # ----------------------------------------------------------------- metrics

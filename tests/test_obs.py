@@ -325,7 +325,7 @@ def test_engine_disabled_telemetry_is_null_and_token_identical(x64):
     engine_on, tokens_on = run(True)
     assert tokens_on == tokens_off
     # ... THROUGH the spans that tile the tick (tests/test_tick_spans.py pins the
-    # paged ragged and composed engines the same way)
+    # paged engine the same way)
     phases = engine_on.telemetry.summary()["phases"]
     for name in ("serving.tick", "serving.schedule", "serving.decode_dispatch", "serving.sample_sync",
                  "serving.harvest", "serving.evict", "serving.host_gap"):

@@ -3,8 +3,8 @@
 The parity contract: a paged engine's greedy output is token-identical to
 ``generate()``'s canonical full-window form — pinned in float64 across page
 sizes straddling every prefill-ladder rung (page < bucket, page = bucket,
-page not dividing the window) and with the kill-switch forcing the dense
-pool. The kernel contract: the paged Pallas kernel's dead-page skipping is
+page not dividing the window) and against the dense pool
+(``kv_page_size=None``). The kernel contract: the paged Pallas kernel's dead-page skipping is
 BIT-identical to the skip-off kernel, and both match the XLA gather + masked
 softmax fallback applying the same (start, live) visibility bound. The churn
 contract: paging never adds decode programs (1, pinned) and every page
@@ -183,23 +183,19 @@ def test_paged_engine_matches_generate_across_page_sizes(x64, page_size):
     assert engine._pool.pages_in_use == 0  # eviction returned every page
 
 
-def test_paged_kill_switch_forces_dense_and_matches(x64, monkeypatch):
-    """PERCEIVER_IO_TPU_DISABLE_PAGED_KV pins the dense pool even with
-    kv_page_size configured, and (greedy, float64) produces the same tokens."""
+def test_paged_off_value_is_the_dense_pool_and_matches(x64):
+    """``kv_page_size=None`` is the dense pool, and (greedy, float64) it
+    produces the same tokens as the paged one."""
     model, params = _make_model(param_dtype=jnp.float64)
 
-    def run(disable):
-        if disable:
-            monkeypatch.setenv("PERCEIVER_IO_TPU_DISABLE_PAGED_KV", "1")
-        else:
-            monkeypatch.delenv("PERCEIVER_IO_TPU_DISABLE_PAGED_KV", raising=False)
-        engine = ServingEngine(model, params, num_slots=2, kv_page_size=4)
+    def run(kv_page_size):
+        engine = ServingEngine(model, params, num_slots=2, kv_page_size=kv_page_size)
         handles = [engine.submit(p, max_new_tokens=4) for p in ([5, 6, 7], list(range(40, 49)))]
         engine.run_until_drained(max_steps=100)
         return [h.result().tolist() for h in handles], engine.paged
 
-    toks_paged, paged_on = run(False)
-    toks_dense, paged_off = run(True)
+    toks_paged, paged_on = run(4)
+    toks_dense, paged_off = run(None)
     assert paged_on and not paged_off
     assert toks_paged == toks_dense
 
@@ -644,19 +640,16 @@ def _ring_reference(name):
     return _RING_RUNS[key]
 
 
-def _ring_run(tick):
-    """One engine life per tick program, shared by the cases below: requests of
-    mixed lengths admitted at different ticks, so that the slots' rings sit at
-    different offsets; every admission path; one slot evicted and admitted
-    again while its ring is mid-turn."""
-    if tick in _RING_RUNS:
-        return _RING_RUNS[tick]
+def _ring_run():
+    """One engine life, shared by the cases below: requests of mixed lengths
+    admitted at different ticks, so that the slots' rings sit at different
+    offsets; every admission path; one slot evicted and admitted again while
+    its ring is mid-turn."""
+    if "run" in _RING_RUNS:
+        return _RING_RUNS["run"]
     model, params = _ring_model()
-    with pytest.MonkeyPatch.context() as env:  # the switch is read when the engine is built
-        env.setenv("PERCEIVER_IO_TPU_DISABLE_RAGGED_TICK", "1" if tick == "composed" else "0")
-        engine = ServingEngine(model, params, num_slots=RING_SLOTS, kv_page_size=RING_PS,
-                               prefill_chunk_tokens=4, prefix_cache=True)
-    assert engine.ragged is (tick == "fused")
+    engine = ServingEngine(model, params, num_slots=RING_SLOTS, kv_page_size=RING_PS,
+                           prefill_chunk_tokens=4, prefix_cache=True)
     submit = lambda name: engine.submit(RING_PROMPTS[name], max_new_tokens=RING_NEW)
     handles, starts = {}, []
 
@@ -687,22 +680,20 @@ def _ring_run(tick):
         "prefix_hits": engine._prefix_cache.hits, "decode_compilations": engine.decode_compilations,
         "pages_in_use": engine._pool.pages_in_use, "cached_pages": engine._prefix_cache.cached_pages,
     }
-    _RING_RUNS[tick] = run
+    _RING_RUNS["run"] = run
     return run
 
 
-@pytest.mark.parametrize("tick", ["fused", "composed"])
 @pytest.mark.parametrize("phase", sorted(RING_PROMPTS))
-def test_paged_sa_ring_tokens_match_generate(x64, tick, phase):
+def test_paged_sa_ring_tokens_match_generate(x64, phase):
     """Acceptance of the ring (f64, greedy): whatever a slot's ring offset and
     however the request got there — prefill + install, chunks + finish, behind
     a prefix-cache hit, into a slot evicted mid-turn — its tokens are
-    ``generate()``'s over ``2 * max_latents + 3`` decode steps, for the fused
-    tick and for the composed one."""
-    run = _ring_run(tick)
+    ``generate()``'s over ``2 * max_latents + 3`` decode steps."""
+    run = _ring_run()
     handle = run["handles"][phase]
     assert handle.ok and len(handle.output_ids) == RING_NEW
-    assert handle.result().tolist() == _ring_reference(phase), f"{phase} diverged under the {tick} tick"
+    assert handle.result().tolist() == _ring_reference(phase), f"{phase} diverged"
     # the scenario did what the cases are named for
     assert any(len(set(row)) > 1 for row in run["starts"])  # slots at different offsets
     assert run["mid_ring"] != 0 and run["handles"]["evict_readmit"].slot is None

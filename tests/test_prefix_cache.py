@@ -2,7 +2,7 @@
 (docs/serving.md "Chunked prefill" / "Prefix cache").
 
 The parity contract: engine output is f64 token-identical with the cache
-warm, cold, disabled (knob off or kill-switch), or mid-evicted, and with
+warm, cold, off (``prefix_cache=False``), or mid-evicted, and with
 admission chunked or one-shot — across prompt lengths straddling every
 prefill-ladder rung, greedy and sampled. The sharing contract:
 ``PagePool.retain()`` finally has its second caller — a fork's pages outlive
@@ -244,11 +244,11 @@ def test_preemption_victim_releases_fork_sharer_pages_intact(setup):
 
 
 # ------------------------------------------------------------------ parity
-def test_prefix_cache_parity_warm_cold_off_killswitch(setup64, monkeypatch):
-    """Acceptance: cache-on output is f64 token-identical to cache-off —
-    cold (first pass), warm (every prompt extends a cached prefix),
-    mid-evicted, and under the kill-switch — greedy and sampled, across
-    ladder-straddling prompt lengths."""
+def test_prefix_cache_parity_warm_cold_off(setup64):
+    """Acceptance: cache-on output is f64 token-identical to cache-off
+    (``prefix_cache=False``: no trie built) — cold (first pass), warm (every
+    prompt extends a cached prefix), mid-evicted — greedy and sampled,
+    across ladder-straddling prompt lengths."""
     model, params = setup64
     preamble = [11] * 18
     prompts = [list(range(3, 3 + n)) for n in PARITY_LENGTHS]
@@ -264,7 +264,9 @@ def test_prefix_cache_parity_warm_cold_off_killswitch(setup64, monkeypatch):
         engine.run_until_drained(max_steps=500)
         return [h.result().tolist() for h in handles]
 
-    off_engine = ServingEngine(model, params, num_slots=3, kv_page_size=PS)
+    off_engine = ServingEngine(model, params, num_slots=3, kv_page_size=PS,
+                               prefix_cache=False)
+    assert off_engine._prefix_cache is None
     expected = submit_all(off_engine)
     # greedy rows are additionally anchored to generate()'s canonical form
     for toks, prompt in zip(expected[: len(PARITY_LENGTHS)], prompts):
@@ -289,18 +291,11 @@ def test_prefix_cache_parity_warm_cold_off_killswitch(setup64, monkeypatch):
     assert engine._pool.pages_in_use == 0
     engine.close()
 
-    monkeypatch.setenv("PERCEIVER_IO_TPU_DISABLE_PREFIX_CACHE", "1")
-    killed = ServingEngine(model, params, num_slots=3, kv_page_size=PS,
-                           prefix_cache=True)
-    assert killed._prefix_cache is None  # the switch wins over the knob
-    assert submit_all(killed) == expected
-    killed.close()
 
-
-def test_chunked_prefill_parity_and_killswitch(setup64, monkeypatch):
-    """Acceptance: chunked admission is f64 token-identical to one-shot —
-    chunk sizes straddling the ladder, greedy and sampled — and the
-    kill-switch pins the one-shot path."""
+def test_chunked_prefill_parity_with_unchunked(setup64):
+    """Acceptance: chunked admission is f64 token-identical to unchunked
+    (``prefill_chunk_tokens=None``) — chunk sizes straddling the ladder,
+    greedy and sampled."""
     model, params = setup64
     prompts = [list(range(3, 3 + n)) for n in PARITY_LENGTHS]
 
@@ -315,8 +310,11 @@ def test_chunked_prefill_parity_and_killswitch(setup64, monkeypatch):
         engine.run_until_drained(max_steps=500)
         return [h.result().tolist() for h in handles]
 
-    baseline = ServingEngine(model, params, num_slots=3, kv_page_size=PS)
+    baseline = ServingEngine(model, params, num_slots=3, kv_page_size=PS,
+                             prefill_chunk_tokens=None)
+    assert not baseline.chunked
     expected = submit_all(baseline)
+    assert baseline.metrics.chunks_dispatched == 0
     baseline.close()
 
     for chunk in (4, 6, 11):  # < rung, = rung, straddling
@@ -327,14 +325,6 @@ def test_chunked_prefill_parity_and_killswitch(setup64, monkeypatch):
         assert engine.metrics.chunks_dispatched > 0
         assert engine._pool.pages_in_use == 0
         engine.close()
-
-    monkeypatch.setenv("PERCEIVER_IO_TPU_DISABLE_CHUNKED_PREFILL", "1")
-    killed = ServingEngine(model, params, num_slots=3, kv_page_size=PS,
-                           prefill_chunk_tokens=4)
-    assert not killed.chunked
-    assert submit_all(killed) == expected
-    assert killed.metrics.chunks_dispatched == 0
-    killed.close()
 
 
 def test_chunked_prefill_interleaves_running_decode(setup):
@@ -493,9 +483,9 @@ def test_quarantine_zeroes_cache_shared_pages_before_free(setup):
 
 # ------------------------------------------------------------------- churn
 def test_churn_compile_counts_with_chunking_and_cache(setup):
-    """Compile-geometry acceptance: chunked + cached churn keeps decode at
-    ONE program, prefill/install/chunk programs each bounded by the ladder
-    length, and the finish at one program ever."""
+    """Compile-geometry acceptance: chunked + cached churn keeps the tick —
+    chunks and finishes inside it — at ONE program, prefill/install programs
+    each bounded by the ladder length."""
     model, params = setup
     engine = ServingEngine(model, params, num_slots=2, kv_page_size=PS,
                            prefix_cache=True, prefill_chunk_tokens=5)
@@ -513,8 +503,7 @@ def test_churn_compile_counts_with_chunking_and_cache(setup):
     assert engine.decode_compilations == 1  # THE invariant, unchanged
     assert engine.prefill_compilations <= ladder
     assert engine._jit_install._cache_size() <= ladder
-    assert engine._jit_chunk_kv._cache_size() <= ladder
-    assert engine._jit_prefill_finish._cache_size() <= 1
+    assert engine._jit_ragged_tick._cache_size() == 1
     engine._prefix_cache.clear()
     assert engine._pool.pages_in_use == 0
     assert all(p is None for p in engine._slot_pages)

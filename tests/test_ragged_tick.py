@@ -1,14 +1,12 @@
 """Unified ragged tick: one fused program per steady-state tick (ISSUE 19).
 
-The contract: with the ragged tick live (the default on paged engines), every
-steady-state tick — prefill chunks, latent finishes, fault poison, batched
-decode, quantized-page scale resets — dispatches as ONE compiled program
-whose lanes are a host-built fixed-shape work descriptor, and the emitted
-token streams are IDENTICAL to the composed per-program tick the
-``PERCEIVER_IO_TPU_DISABLE_RAGGED_TICK`` kill-switch restores: f64-exact on
-fp engines (near-tie argmax flips cannot mask a real bug), exact token
-equality on int8/int4 engines. The compile-count invariant tightens to
-"the tick program compiles exactly once, ever" and the serving-metrics/v12
+The contract: on a paged engine every steady-state tick — prefill chunks,
+latent finishes, fault poison, batched decode, quantized-page scale resets —
+dispatches as ONE compiled program whose lanes are a host-built fixed-shape
+work descriptor, and the emitted token streams are ``generate()``'s in
+float64 (near-tie argmax flips cannot mask a real bug; int8 / int4 pools
+under the same tick: tests/test_kv_quant.py). The compile-count invariant is
+"the tick program compiles exactly once, ever" and the serving-metrics
 ``ragged_tick`` block pins programs-per-tick at 1.
 """
 
@@ -20,17 +18,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from perceiver_io_tpu.generation.generate import GenerationConfig
 from perceiver_io_tpu.models.core.config import CausalSequenceModelConfig
 from perceiver_io_tpu.models.core.perceiver_ar import CausalSequenceModel
 from perceiver_io_tpu.serving import ServingEngine
 from perceiver_io_tpu.serving.metrics import SCHEMA, load_metrics_jsonl
+from tests.test_paging import _reference_tokens  # the same toy model: window 12 over 6 latents
 
 VOCAB = 262
 WINDOW = 12
 LATENTS = 6
 PS = 4
-
-KILL = "PERCEIVER_IO_TPU_DISABLE_RAGGED_TICK"
 
 
 def _make_model(param_dtype=jnp.float32):
@@ -65,14 +63,10 @@ CHURN_PROMPTS = [[5, 6, 7], [2] * 5, list(range(3, 12)), [9] * WINDOW,
 CHURN_NEW = [6, 3, 5, 8, 4, 7]
 
 
-def _run_churn(model, params, monkeypatch, *, composed, **engine_kw):
-    if composed:
-        monkeypatch.setenv(KILL, "1")
-    else:
-        monkeypatch.delenv(KILL, raising=False)
+def _run_churn(model, params, **engine_kw):
     engine = ServingEngine(model, params, num_slots=3, kv_page_size=PS,
                            **engine_kw)
-    assert engine.ragged is (not composed)
+    assert engine.ragged
     handles = []
     for i, (p, m) in enumerate(zip(CHURN_PROMPTS, CHURN_NEW)):
         handles.append(engine.submit(p, max_new_tokens=m,
@@ -84,43 +78,32 @@ def _run_churn(model, params, monkeypatch, *, composed, **engine_kw):
     return [h.result().tolist() for h in handles], engine
 
 
-def test_ragged_tick_f64_identical_to_composed(setup64, monkeypatch):
-    """The headline parity: fused-tick tokens == composed-tick tokens in
-    float64, across ladder-straddling lengths, ring wraps, partial tail
-    pages, interleaved admissions — with and without chunked admission."""
+CHURN_ADMISSION = {"one_shot": {}, "chunked": {"prefill_chunk_tokens": 4, "max_prefill_slots": 2}}
+_CHURN_RUNS: dict = {}  # admission -> the churn's tokens (one engine life a mode)
+
+
+@pytest.mark.parametrize("admission", sorted(CHURN_ADMISSION))
+@pytest.mark.parametrize("request_no", range(len(CHURN_PROMPTS)))
+def test_ragged_tick_f64_matches_generate(setup64, request_no, admission):
+    """The headline parity: fused-tick tokens == ``generate()``'s in float64,
+    across ladder-straddling lengths, ring wraps, partial tail pages,
+    interleaved admissions — with and without chunked admission."""
     model, params = setup64
-    for kw in ({}, {"prefill_chunk_tokens": 4, "max_prefill_slots": 2}):
-        ragged, er = _run_churn(model, params, monkeypatch, composed=False, **kw)
-        composed, ec = _run_churn(model, params, monkeypatch, composed=True, **kw)
-        assert ragged == composed, f"ragged tick diverged under {kw or 'unchunked'}"
-        assert er.ragged and not ec.ragged
+    if admission not in _CHURN_RUNS:
+        _CHURN_RUNS[admission], _ = _run_churn(model, params, **CHURN_ADMISSION[admission])
+    expected = _reference_tokens(model, params, CHURN_PROMPTS[request_no],
+                                 GenerationConfig(max_new_tokens=CHURN_NEW[request_no]))
+    assert _CHURN_RUNS[admission][request_no] == expected
 
 
-@pytest.mark.parametrize("kv_quant", ["int8", "int4"])
-def test_ragged_tick_quant_identical_to_composed(setup, monkeypatch, kv_quant):
-    """Quantized pages ride the same descriptor: int8 and int4 engines emit
-    exactly the composed path's tokens (scale resets and ratcheted appends
-    fold into the fused program without reordering any write)."""
-    model, params = setup
-    ragged, er = _run_churn(model, params, monkeypatch, composed=False,
-                            kv_quant=kv_quant)
-    composed, _ = _run_churn(model, params, monkeypatch, composed=True,
-                             kv_quant=kv_quant)
-    assert ragged == composed
-    assert er._cache.ca.qbits == (4 if kv_quant == "int4" else 8)
-
-
-def test_ragged_tick_sampled_rng_chain_identical(setup, monkeypatch):
+def test_ragged_tick_sampled_rng_chain_identical(setup):
     """Sampling: the per-slot rng split chain is part of the fused decode
-    phase — sampled streams must match the composed path seed-for-seed."""
+    phase, and a finish lane installs its request's key — sampled streams
+    match the dense pool's seed for seed, on both admission paths."""
     model, params = setup
 
-    def run(composed):
-        if composed:
-            monkeypatch.setenv(KILL, "1")
-        else:
-            monkeypatch.delenv(KILL, raising=False)
-        engine = ServingEngine(model, params, num_slots=2, kv_page_size=PS)
+    def run(**pool):
+        engine = ServingEngine(model, params, num_slots=2, **pool)
         handles = [
             engine.submit(p, max_new_tokens=6, do_sample=True, temperature=0.8,
                           top_k=20, rng=jax.random.PRNGKey(7 + i))
@@ -129,27 +112,23 @@ def test_ragged_tick_sampled_rng_chain_identical(setup, monkeypatch):
         engine.run_until_drained(max_steps=200)
         return [h.result().tolist() for h in handles]
 
-    assert run(False) == run(True)
+    assert run(kv_page_size=PS) == run()
 
 
-def test_ragged_tick_one_program_ever(setup, monkeypatch):
+def test_ragged_tick_one_program_ever(setup):
     """THE perf invariant: steady-state churn — mixed admissions, chunked
     prefill, evictions — compiles the fused tick program exactly once, the
     watchdog budget of 1 holds, and the v11 metrics pin programs-per-tick
     at 1 for decode-carrying ticks."""
     model, params = setup
-    monkeypatch.delenv(KILL, raising=False)
-    toks, engine = _run_churn(model, params, monkeypatch, composed=False,
+    toks, engine = _run_churn(model, params,
                               prefill_chunk_tokens=4, max_prefill_slots=2)
-    assert engine.ragged
     assert engine._jit_ragged_tick._cache_size() == 1
     assert engine.decode_compilations == 1  # the property pins the fused jit
     if engine.watchdog is not None:
         engine.watchdog.check()  # ragged_tick budget=1 holds after churn
-    # the composed phase jits never dispatched (no stray per-phase programs)
-    assert engine._jit_decode._cache_size() == 0
-    assert engine._jit_chunk_kv._cache_size() == 0
-    assert engine._jit_prefill_finish._cache_size() == 0
+    # no per-phase program exists beside it: the tick is the one dispatcher
+    assert engine._jit_decode is None and not hasattr(engine, "_jit_chunk_kv")
     snap = engine.metrics.snapshot()
     assert snap["ragged_tick"]["enabled"] is True
     assert snap["ragged_tick"]["ticks"] > 0
@@ -161,34 +140,11 @@ def test_ragged_tick_one_program_ever(setup, monkeypatch):
     assert not engine._tick_chunks and not engine._tick_finishes
 
 
-def test_killswitch_restores_composed_budgets(setup, monkeypatch):
-    """Under the kill-switch the engine is the pre-PR composed engine:
-    per-phase programs within their historical budgets, fused jit absent,
-    and the metrics block reports enabled=False (the 1-vs-N comparison's
-    other arm)."""
-    model, params = setup
-    toks, engine = _run_churn(model, params, monkeypatch, composed=True,
-                              prefill_chunk_tokens=4, max_prefill_slots=2)
-    assert engine._jit_ragged_tick is None
-    assert engine.decode_compilations == 1
-    assert engine._jit_chunk_kv._cache_size() <= len(engine.prefill_buckets)
-    assert engine._jit_prefill_finish._cache_size() <= 1
-    if engine.watchdog is not None:
-        engine.watchdog.check()
-    snap = engine.metrics.snapshot()
-    assert snap["ragged_tick"]["enabled"] is False
-    # composed mixed ticks dispatch MORE than one program — the contrast
-    # the ragged tick exists to remove
-    assert snap["ragged_tick"]["programs_per_tick"]["p95"] > 1.0
-    assert snap["ragged_tick"]["descriptor_build_s"]["p95"] == 0.0
-
-
-def test_ragged_preempt_and_quarantine_drop_buffered_lanes(setup, monkeypatch):
+def test_ragged_preempt_and_quarantine_drop_buffered_lanes(setup):
     """An admission evicted the same tick it buffered descriptor lanes must
     take those lanes with it (its pages return to the pool mid-tick): churn
     with deadline-expired work stays deterministic and drains whole."""
     model, params = setup
-    monkeypatch.delenv(KILL, raising=False)
 
     def run():
         engine = ServingEngine(model, params, num_slots=2, kv_page_size=PS,
@@ -220,7 +176,7 @@ def test_chaos_ragged_tick_churn_scenario():
     """The ragged_tick_churn scenario is registered (the matrix smoke in
     test_reliability covers it in CI) and green standalone: quarantine +
     preemption inside the fused tick, survivors f64-identical to the
-    composed uncontended oracle, free list whole at drain."""
+    same engine run uncontended, free list whole at drain."""
     import importlib.util
 
     spec = importlib.util.spec_from_file_location(
@@ -237,10 +193,9 @@ def test_chaos_ragged_tick_churn_scenario():
 # -------------------------------------------------------------- serve_bench
 def test_serve_bench_ragged_arm_smoke(tmp_path):
     """CI satellite: ``serve_bench --ragged`` writes the ragged_tick section
-    — tokens/s + inter-token p95 ragged vs composed, the programs-per-tick
-    1-vs-N contrast, greedy identity, and the int4 sessions-at-fixed-HBM
-    comparison with its >= 1.8x-vs-fp acceptance — into the
-    BENCH_serving.json artifact."""
+    — tokens/s + inter-token p95 under the fused tick, its one program a
+    tick, and the int4 sessions-at-fixed-HBM comparison with its
+    >= 1.8x-vs-fp acceptance — into the BENCH_serving.json artifact."""
     import importlib.util
 
     spec = importlib.util.spec_from_file_location(
@@ -258,14 +213,10 @@ def test_serve_bench_ragged_arm_smoke(tmp_path):
         "--out", str(out), "--profile-out", str(profile_out),
     ])
     block = result["ragged_tick"]
-    # the structural headline: ONE program per steady ragged tick, N composed
-    assert block["programs_per_tick_p50"]["ragged"] == 1.0
-    assert block["programs_per_tick_p50"]["composed"] > 1.0
+    # the structural headline: ONE program per steady ragged tick
+    assert block["ragged_arm"]["programs_per_tick"]["p50"] == 1.0
     assert block["ragged_arm"]["tick_compilations"] == 1
-    assert block["composed_arm"]["tick_compilations"] == 1
     assert block["ragged_arm"]["descriptor_build_s"]["p95"] >= 0.0
-    assert block["composed_arm"]["descriptor_build_s"]["p95"] == 0.0
-    assert block["greedy_tokens_identical"] is True
     cap = block["int4_capacity"]
     for arm in ("fp", "int8", "int4"):
         assert cap[f"{arm}_arm"]["pool_bytes"] <= cap["pool_byte_budget"]
@@ -277,7 +228,7 @@ def test_serve_bench_ragged_arm_smoke(tmp_path):
     assert cap["quality"]["greedy_token_agreement_vs_fp"] is not None
     assert cap["quality"]["compared_tokens"] > 0
     on_disk = json.loads(profile_out.read_text())
-    assert on_disk["ragged_tick"]["programs_per_tick_p50"]["ragged"] == 1.0
+    assert on_disk["ragged_tick"]["ragged_arm"]["programs_per_tick"]["p50"] == 1.0
     assert (tmp_path / "BENCH_serving.manifest.json").exists()
 
 

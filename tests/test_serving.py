@@ -94,23 +94,19 @@ def test_bucketed_prefill_parity_at_bucket_boundaries(x64):
     assert engine.prefill_compilations <= len(engine.prefill_buckets)
 
 
-def test_bucketed_prefill_kill_switch_matches_bucketed(x64, monkeypatch):
-    """PERCEIVER_IO_TPU_DISABLE_BUCKETED_PREFILL pins the single-window ladder
-    and (greedy, float64) produces the same tokens as the bucketed engine."""
+def test_single_window_ladder_matches_bucketed(x64):
+    """``prefill_buckets=(window,)`` is the single-window ladder and (greedy,
+    float64) produces the same tokens as the bucketed engine."""
     model, params = _make_model(param_dtype=jnp.float64)
 
-    def run(disable):
-        if disable:
-            monkeypatch.setenv("PERCEIVER_IO_TPU_DISABLE_BUCKETED_PREFILL", "1")
-        else:
-            monkeypatch.delenv("PERCEIVER_IO_TPU_DISABLE_BUCKETED_PREFILL", raising=False)
-        engine = ServingEngine(model, params, num_slots=2)
+    def run(prefill_buckets):
+        engine = ServingEngine(model, params, num_slots=2, prefill_buckets=prefill_buckets)
         handles = [engine.submit(p, max_new_tokens=4) for p in ([5, 6, 7], list(range(40, 49)))]
         engine.run_until_drained(max_steps=100)
         return [h.result().tolist() for h in handles], engine.prefill_buckets
 
-    bucketed, ladder = run(False)
-    pinned, single = run(True)
+    bucketed, ladder = run(None)
+    pinned, single = run((WINDOW,))
     assert bucketed == pinned
     assert len(ladder) > 1 and single == (WINDOW,)
 

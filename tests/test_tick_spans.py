@@ -1,7 +1,7 @@
 """The serving tick measured from inside the engine (docs/observability.md "The tick,
 tiled" / "A request's life"): span parents and self time in the recorder core, the
-phases that tile the tick and the host gap under a fake clock (ragged and composed
-engines), the same spans on a ``jax.profiler`` trace's clock, the engine's own stamps
+phases that tile the tick and the host gap under a fake clock (the paged engine's
+fused tick and the dense pool's decode step), the same spans on a ``jax.profiler`` trace's clock, the engine's own stamps
 of a request's life, the documented table of emitted names, and the bitwise inertness
 of all of it (served tokens, compile counts) recorder on vs off."""
 
@@ -22,7 +22,6 @@ VOCAB = 262
 WINDOW = 16
 LATENTS = 6
 PAGE = 4
-KILL = "PERCEIVER_IO_TPU_DISABLE_RAGGED_TICK"
 DOCS = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "docs", "observability.md")
 
 # a one-shot admission (shorter than the latent floor), two split admissions (chunked:
@@ -54,14 +53,12 @@ def setup64(x64):
     return _make_model(param_dtype=jnp.float64)
 
 
-def _engine(model, params, monkeypatch, composed, telemetry, **kw):
-    if composed:
-        monkeypatch.setenv(KILL, "1")
-    else:
-        monkeypatch.delenv(KILL, raising=False)
-    engine = ServingEngine(model, params, num_slots=3, kv_page_size=PAGE, prefill_chunk_tokens=4,
-                           max_prefill_slots=2, prefix_cache=True, telemetry=telemetry, **kw)
-    assert engine.ragged is (not composed)
+def _engine(model, params, telemetry, paged=True):
+    """The paged engine (chunked admission, prefix cache: the fused tick), or the dense
+    pool, whose tick is the decode step and whose every admission is one-shot."""
+    pool = dict(kv_page_size=PAGE, prefill_chunk_tokens=4, max_prefill_slots=2, prefix_cache=True) if paged else {}
+    engine = ServingEngine(model, params, num_slots=3, telemetry=telemetry, **pool)
+    assert engine.ragged is paged
     return engine
 
 
@@ -218,8 +215,8 @@ def _x_events(rec, ns="serving"):
     return [e for e in rec.chrome_trace()["traceEvents"] if e["ph"] == "X" and e["name"].startswith(ns + ".")]
 
 
-@pytest.mark.parametrize("composed", [False, True], ids=["ragged", "composed"])
-def test_phases_tile_the_tick_and_the_host_gap_under_a_fake_clock(setup, monkeypatch, composed):
+@pytest.mark.parametrize("paged", [True, False], ids=["ragged", "dense"])
+def test_phases_tile_the_tick_and_the_host_gap_under_a_fake_clock(setup, paged):
     """Every clock reading advances the fake clock by one second, so any stretch the
     phases do not cover shows up as whole seconds. In microseconds (the trace's unit)
     every number below is an integer, hence exact. The prompts are served twice: the
@@ -233,7 +230,7 @@ def test_phases_tile_the_tick_and_the_host_gap_under_a_fake_clock(setup, monkeyp
         return t[0]
 
     rec = TelemetryRecorder(clock=clock)
-    engine = _engine(model, params, monkeypatch, composed, rec)
+    engine = _engine(model, params, rec, paged)
     _serve(engine, upfront=True)
     warm = rec.summary()["phases"]
     warm_ticks = warm["serving.tick"]["count"]
@@ -292,7 +289,8 @@ def test_phases_tile_the_tick_and_the_host_gap_under_a_fake_clock(setup, monkeyp
     # each tick's wall time (dispatch's start to sync's return) went to one of the two books
     walls = "serving.tick_wall.decode_only", "serving.tick_wall.with_prefill"
     assert grew(walls[0], "count") + grew(walls[1], "count") == len(ticks)
-    assert grew(walls[0], "count") >= 3 and grew(walls[1], "count") >= 3
+    # chunk and finish lanes exist in the fused tick alone
+    assert grew(walls[0], "count") >= 3 and (grew(walls[1], "count") >= 3 if paged else grew(walls[1], "count") == 0)
     assert grew(walls[0]) + grew(walls[1]) == pytest.approx(
         sum(end(children[n]["serving.sample_sync"]) - children[n]["serving.decode_dispatch"]["ts"] for n in numbers), abs=0.5)
     # nesting below the tiles, and the ids the per-admission spans carry
@@ -301,17 +299,22 @@ def test_phases_tile_the_tick_and_the_host_gap_under_a_fake_clock(setup, monkeyp
         by_parent.setdefault(e["name"], set()).add(e.get("parent"))
     assert by_parent["serving.admit"] == {"serving.schedule"} and by_parent["serving.evict"] == {"serving.harvest"}
     assert by_parent["serving.prefill_dispatch"] == {"serving.admit"} == by_parent["serving.install"]
-    assert by_parent["serving.prefill_chunk"] == {"serving.admit", "serving.schedule"}
-    assert by_parent["serving.prefill_finish"] <= {"serving.admit", "serving.schedule"}
-    for name in ("serving.prefill_dispatch", "serving.install", "serving.prefill_chunk", "serving.prefill_finish"):
+    per_admission = ["serving.prefill_dispatch", "serving.install"]
+    if paged:
+        assert by_parent["serving.prefill_chunk"] == {"serving.admit", "serving.schedule"}
+        assert by_parent["serving.prefill_finish"] <= {"serving.admit", "serving.schedule"}
+        per_admission += ["serving.prefill_chunk", "serving.prefill_finish"]
+    else:
+        assert "serving.prefill_chunk" not in by_parent and "serving.prefill_finish" not in by_parent
+    for name in per_admission:
         ids = {e["args"]["request_id"] for e in events if e["name"] == name}
         assert ids and ids <= set(range(2 * len(PROMPTS)))
 
 
-def test_no_gap_is_booked_across_an_empty_engine(setup, monkeypatch):
+def test_no_gap_is_booked_across_an_empty_engine(setup):
     model, params = setup
     rec = TelemetryRecorder()
-    engine = _engine(model, params, monkeypatch, False, rec)
+    engine = _engine(model, params, rec)
     engine.submit([5, 6, 7], max_new_tokens=3)
     engine.run_until_drained(max_steps=50)  # compiles the programs this prompt uses
     before = rec.summary()["phases"]
@@ -330,7 +333,7 @@ def test_no_gap_is_booked_across_an_empty_engine(setup, monkeypatch):
 # ------------------------------------------------- the profiler's clock
 
 
-def test_spans_reach_a_jax_profiler_trace(setup, monkeypatch, tmp_path):
+def test_spans_reach_a_jax_profiler_trace(setup, tmp_path):
     """A default-constructed recorder handed to the engine puts the program's spans into
     the /host:CPU plane of any jax.profiler trace."""
     profile_data = pytest.importorskip("jax.profiler").__dict__.get("ProfileData")
@@ -339,7 +342,7 @@ def test_spans_reach_a_jax_profiler_trace(setup, monkeypatch, tmp_path):
     import glob
 
     model, params = setup
-    engine = _engine(model, params, monkeypatch, False, TelemetryRecorder())
+    engine = _engine(model, params, TelemetryRecorder())
     engine.submit([5, 6, 7], max_new_tokens=6)
     engine.step()  # compile outside the trace
     jax.profiler.start_trace(str(tmp_path))
@@ -366,10 +369,10 @@ def test_spans_reach_a_jax_profiler_trace(setup, monkeypatch, tmp_path):
 # ------------------------------------------------------ a request's life
 
 
-@pytest.mark.parametrize("composed", [False, True], ids=["ragged", "composed"])
-def test_the_engine_stamps_a_requests_life(setup, monkeypatch, composed):
+@pytest.mark.parametrize("paged", [True, False], ids=["ragged", "dense"])
+def test_the_engine_stamps_a_requests_life(setup, paged):
     model, params = setup
-    engine = _engine(model, params, monkeypatch, composed, False)
+    engine = _engine(model, params, False, paged)
     admits, hits = {}, []
     record_admit, record_hit = engine.metrics.record_admit, engine.metrics.record_prefix_hit
 
@@ -383,9 +386,7 @@ def test_the_engine_stamps_a_requests_life(setup, monkeypatch, composed):
 
     engine.metrics.record_admit, engine.metrics.record_prefix_hit = spy_admit, spy_hit
     handles = _serve(engine, upfront=False)
-    split = [h for h in handles if len(h.prompt_ids) >= LATENTS]
-    one_shot = [h for h in handles if len(h.prompt_ids) < LATENTS]
-    assert split and one_shot  # both admission paths ran
+    assert {len(h.prompt_ids) >= LATENTS for h in handles} == {True, False}  # either side of the latent floor: on the paged engine both admission paths ran
     for h in handles:
         assert h.enqueued_at <= h.slot_claimed_at <= h.admitted_at <= h.first_token_at <= h.finished_at
         assert h.first_token_at <= h.last_token_at <= h.finished_at
@@ -395,7 +396,7 @@ def test_the_engine_stamps_a_requests_life(setup, monkeypatch, composed):
         assert kw["prompt_tokens"] == len(h.prompt_ids)
     snap = engine.metrics.snapshot()
     assert snap["schema"] == "serving-metrics/v13"
-    assert hits and snap["prefix_hit_tokens"] == sum(hits)  # the repeated 11-token prompt forked a cached page
+    assert bool(hits) is paged and snap["prefix_hit_tokens"] == sum(hits)  # the repeated 11-token prompt forked a cached page
     assert snap["prompt_tokens_admitted"] == sum(len(p) for p in PROMPTS)
     first = sorted(h.first_token_at - h.slot_claimed_at for h in handles)
     assert snap["first_token_s"]["max"] == pytest.approx(first[-1], abs=1e-6)
@@ -407,10 +408,10 @@ def test_the_engine_stamps_a_requests_life(setup, monkeypatch, composed):
     engine.close()
 
 
-def test_lifecycle_span_marks_slot_claim_and_first_token(setup, monkeypatch):
+def test_lifecycle_span_marks_slot_claim_and_first_token(setup):
     model, params = setup
     rec = TelemetryRecorder()
-    engine = _engine(model, params, monkeypatch, False, rec)
+    engine = _engine(model, params, rec)
     _serve(engine)
     engine.close()
     instants = {}
@@ -436,24 +437,20 @@ def _documented(kinds):
     return names
 
 
-def test_emitted_serving_names_are_exactly_the_documented_table(setup, monkeypatch):
+def test_emitted_serving_names_are_exactly_the_documented_table(setup):
     model, params = setup
-    emitted_phases, gauges, counters = set(), set(), set()
-    for composed in (False, True):
-        rec = TelemetryRecorder()
-        engine = _engine(model, params, monkeypatch, composed, rec)
-        _serve(engine, upfront=False)
-        engine.close()
-        summary = rec.summary()
-        declared = {name for name in summary["phases"] if name.startswith("serving.")}
-        ran = {name for name, p in summary["phases"].items() if p["count"] and name.startswith("serving.")}
-        assert {e["name"] for e in _x_events(rec)} <= declared
-        emitted_phases |= declared
-        gauges |= {g for g in summary["gauges"] if g.startswith("serving.")}
-        counters |= {c for c in summary["counters"] if c.startswith("serving.")}
-        # everything but the one-shot path's spans runs in both modes
-        assert declared - ran <= set()
-    assert emitted_phases == {n for n in _documented({"span", "interval"}) if n.startswith("serving.")}
+    rec = TelemetryRecorder()
+    engine = _engine(model, params, rec)
+    _serve(engine, upfront=False)
+    engine.close()
+    summary = rec.summary()
+    declared = {name for name in summary["phases"] if name.startswith("serving.")}
+    ran = {name for name, p in summary["phases"].items() if p["count"] and name.startswith("serving.")}
+    assert {e["name"] for e in _x_events(rec)} <= declared
+    gauges = {g for g in summary["gauges"] if g.startswith("serving.")}
+    counters = {c for c in summary["counters"] if c.startswith("serving.")}
+    assert declared == ran  # the paged engine under this traffic runs every span it declares
+    assert declared == {n for n in _documented({"span", "interval"}) if n.startswith("serving.")}
     assert gauges == {n for n in _documented({"gauge"}) if n.startswith("serving.")}
     assert counters == set()  # the engine's counts live in EngineMetrics, not in the recorder
 
@@ -461,14 +458,14 @@ def test_emitted_serving_names_are_exactly_the_documented_table(setup, monkeypat
 # ------------------------------------------------------------- inertness
 
 
-@pytest.mark.parametrize("composed", [False, True], ids=["ragged", "composed"])
-def test_tokens_and_compile_counts_are_the_same_recorder_on_and_off(setup64, monkeypatch, composed):
-    """f64 bitwise pin over the paged engines and their new spans, stamps and named
-    scopes: telemetry times host calls and never touches a device value."""
+def test_tokens_and_compile_counts_are_the_same_recorder_on_and_off(setup64):
+    """f64 bitwise pin over the paged engine and its spans, stamps and named scopes:
+    telemetry times host calls and never touches a device value (the dense pool's pin is
+    tests/test_obs.py::test_engine_disabled_telemetry_is_null_and_token_identical)."""
     model, params = setup64
 
     def run(telemetry):
-        engine = _engine(model, params, monkeypatch, composed, telemetry)
+        engine = _engine(model, params, telemetry)
         tokens = [h.result().tolist() for h in _serve(engine, upfront=False)]
         counts = (engine.decode_compilations, engine.prefill_compilations, engine.total_compilations)
         engine.close()
@@ -477,4 +474,4 @@ def test_tokens_and_compile_counts_are_the_same_recorder_on_and_off(setup64, mon
     tokens_off, counts_off = run(False)
     tokens_on, counts_on = run(TelemetryRecorder())
     assert tokens_on == tokens_off
-    assert counts_on == counts_off and counts_on[0] == 1  # the tick program compiles once in both modes
+    assert counts_on == counts_off and counts_on[0] == 1  # the tick program compiles once, recorder on or off
