@@ -98,7 +98,9 @@ preemptions, then runs to completion untouchable (no livelock).
 
 Unified ragged tick (docs/serving.md "Unified ragged tick"): a PAGED engine
 buffers each tick's prefill chunks, latent finishes, scale resets, and decode
-step into ONE host-built descriptor and dispatches ONE fused program per
+step into ONE host-built descriptor — one int32 array, sent in one
+transfer, or not at all when the tick carries nothing but decode
+(serving/tick_descriptor.py) — and dispatches ONE fused program per
 tick (``ragged_tick``); the dense pool (``kv_page_size=None``) dispatches
 ``decode_step``. The constructor's arguments alone decide which pool, page
 format, admission path, and prefill ladder an engine runs: ``kv_page_size``
@@ -189,6 +191,7 @@ from perceiver_io_tpu.serving.quant import (
     tree_layout_mismatch,
 )
 from perceiver_io_tpu.serving.scheduler import SlotScheduler, preemption_enabled
+from perceiver_io_tpu.serving.tick_descriptor import TickDescriptorLayout
 
 
 class SlotState(flax.struct.PyTreeNode):
@@ -760,6 +763,9 @@ class ServingEngine:
         self._tick_chunk_items = 0
         self._tick_finish_items = 0
         self._tick_build_s = 0.0
+        # descriptor transfers of the tick's fused dispatch (0 or 1); None
+        # while the tick has dispatched no fused program
+        self._tick_transfers: Optional[int] = None
         self._prefix_cache: Optional[PrefixCache] = None
         if prefix_cache and self.paged:
             # the cache is keyed on the pool's byte layout: its mode is fixed
@@ -801,6 +807,26 @@ class ServingEngine:
         # mux costs no host->device transfer on ordinary ticks
         self._forced_none = jnp.zeros((num_slots,), jnp.int32)
         self._use_forced_none = jnp.zeros((num_slots,), bool)
+        if self.paged:
+            # the fused tick's descriptor is ONE int32 array (its layout:
+            # serving/tick_descriptor.py). A tick that carries a lane, a
+            # reset or poison packs a copy of the idle template
+            # ``_desc_idle_host`` and sends it in one transfer; a tick that
+            # carries nothing but decode passes ``_desc_decode_only``, built
+            # here once and resident on the device — the program only reads
+            # its descriptor and it is never donated, so it stays valid.
+            self._desc_layout = TickDescriptorLayout(
+                self._ragged_lanes, self._ragged_chunk_cap,
+                self._pages_per_slot, self._latents)
+            self._desc_idle_host = self._desc_layout.idle(any_decode=False)
+            # both paths hand the jit a device array placed as the pool's
+            # own cache and state are (uncommitted, default device): one call
+            # signature, one compiled program. A COMMITTED descriptor would
+            # commit the tick's outputs, so the donated cache and state
+            # would change signature after the first call (a second entry
+            # in the jit's cache, a second compile).
+            self._desc_decode_only = jax.device_put(
+                self._desc_layout.idle(any_decode=True))
         self._build_jits()
         if self.watchdog is not None:
             # the engine's own compile-count pins, as runtime budgets: one
@@ -1014,16 +1040,10 @@ class ServingEngine:
         if self.paged:
             cap = self._ragged_chunk_cap
             quantized = self.kv_quant is not None
+            unpack = self._desc_layout.unpack
 
             @partial(jax.jit, donate_argnums=(1, 2))
-            def ragged_tick(params_, cache, state,
-                            reset_ids, any_reset,
-                            ch_ids, ch_offset, ch_count, ch_latent_start,
-                            ch_tables, any_chunk,
-                            fin_active, fin_slot, fin_tables, fin_ids, fin_n,
-                            fin_rng, fin_temp, fin_tk, fin_tp, fin_ds,
-                            fin_pad, any_finish,
-                            poison_slot, any_decode, forced, use_forced):
+            def ragged_tick(params_, cache, state, descriptor, forced, use_forced):
                 # ONE program per tick, its phases in dependency order:
                 # scale resets, prefill chunks, latent finishes, fault
                 # poison, batched decode. Every phase is gated by a TRACED
@@ -1036,6 +1056,13 @@ class ServingEngine:
                 # a profiler trace's device time is read per phase from the
                 # operations' op_name (benchmark/trace/gaps.py).
                 params = dq(params_)
+                # the tick's work arrives as ONE int32 array: static slices
+                # and bitcasts name its fields (serving/tick_descriptor.py)
+                (any_reset, any_chunk, any_finish, poison_slot, any_decode,
+                 reset_ids, ch_ids, ch_offset, ch_count, ch_latent_start,
+                 ch_tables, fin_active, fin_slot, fin_tables, fin_ids, fin_n,
+                 fin_rng, fin_temp, fin_tk, fin_tp, fin_ds,
+                 fin_pad) = unpack(descriptor)
 
                 if quantized:
                     # quantized split admission: zero the PRIVATE
@@ -2348,80 +2375,75 @@ class ServingEngine:
         )
 
     def _ragged_args(self, any_decode: bool, forced, use_forced) -> tuple:
-        """The fused tick program's arguments: the tick's buffered work —
+        """The fused tick program's arguments. The tick's buffered work —
         scale resets, prefill chunks, latent finishes, fault poison, the
-        decode flag — packed into the FIXED-SHAPE ragged descriptor. Lane
-        packing is pure host-side numpy; idle lanes carry trash tables / zero
-        counts and are either value-inert (chunk lanes write only the trash
-        page) or skipped outright (finish lanes gate on ``fin_active``).
-        Reads the buffers, changes nothing."""
-        lanes, cap = self._ragged_lanes, self._ragged_chunk_cap
-        P = self._pages_per_slot
+        decode flag — travels as ONE int32 descriptor
+        (serving/tick_descriptor.py). A tick that carries nothing but decode
+        passes the device-resident ``_desc_decode_only``: nothing is packed
+        and nothing is sent. Any other tick packs a copy of the idle
+        template (pure numpy; idle lanes keep the template's trash tables /
+        zero counts and are either value-inert — chunk lanes write only the
+        trash page — or skipped outright, finish lanes gating on
+        ``fin_active``) and sends it in one explicit transfer. Leaves the
+        tick's buffers as they are."""
+        lanes, P = self._ragged_lanes, self._pages_per_slot
         n_ch, n_fin = len(self._tick_chunks), len(self._tick_finishes)
-        if n_ch > lanes or n_fin > lanes or len(self._tick_resets) > lanes:
+        n_reset = len(self._tick_resets)
+        if n_ch > lanes or n_fin > lanes or n_reset > lanes:
             # the lane bound is structural (one chunk + one finish per
             # distinct slot per tick, admission-capped) — exceeding it is a
             # scheduling bug, not load
             raise RuntimeError(
                 f"ragged tick overflow: {n_ch} chunks / {n_fin} finishes / "
-                f"{len(self._tick_resets)} resets into {lanes} lanes"
+                f"{n_reset} resets into {lanes} lanes"
             )
-        reset_ids = np.zeros((lanes * P,), np.int32)
-        for i, (_slot, ids_row) in enumerate(self._tick_resets):
-            reset_ids[i * P:(i + 1) * P] = ids_row
-        ch_ids = np.zeros((lanes, cap), np.int32)
-        ch_offset = np.zeros((lanes,), np.int32)
-        ch_count = np.zeros((lanes,), np.int32)
-        # idle-lane latent_start far beyond any position: latent mask all
-        # False, so the lane's (trash-bound) payload takes the cheap path
-        ch_lstart = np.full((lanes,), 2 ** 30, np.int32)
-        ch_tables = np.zeros((lanes, P), np.int32)  # all-trash tables
-        for i, (_slot, ids, off, c, lstart, trow) in enumerate(self._tick_chunks):
-            ch_ids[i] = ids
-            ch_offset[i] = off
-            ch_count[i] = c
-            ch_lstart[i] = lstart
-            ch_tables[i] = trow
-        fin_active = np.zeros((lanes,), bool)
-        fin_slot = np.zeros((lanes,), np.int32)
-        fin_tables = np.zeros((lanes, P), np.int32)
-        fin_ids = np.zeros((lanes, self._latents), np.int32)
-        fin_n = np.zeros((lanes,), np.int32)
-        fin_rng = np.zeros((lanes, 2), np.uint32)
-        fin_temp = np.ones((lanes,), np.float32)
-        fin_tk = np.zeros((lanes,), np.int32)
-        fin_tp = np.ones((lanes,), np.float32)
-        fin_ds = np.zeros((lanes,), bool)
-        fin_pad = np.zeros((lanes,), np.int32)
-        for i, (slot, trow, ids_latent, n, rng, sampling) in enumerate(self._tick_finishes):
-            fin_active[i] = True
-            fin_slot[i] = slot
-            fin_tables[i] = trow
-            fin_ids[i] = ids_latent
-            fin_n[i] = n
-            fin_rng[i] = rng
-            fin_temp[i], fin_tk[i], fin_tp[i], fin_ds[i], fin_pad[i] = sampling
-        poison = -1 if self._tick_poison is None else int(self._tick_poison)
-        return (
-            self.params, self._cache, self._state,
-            jnp.asarray(reset_ids), bool(self._tick_resets),
-            jnp.asarray(ch_ids), jnp.asarray(ch_offset), jnp.asarray(ch_count),
-            jnp.asarray(ch_lstart), jnp.asarray(ch_tables), bool(n_ch),
-            jnp.asarray(fin_active), jnp.asarray(fin_slot),
-            jnp.asarray(fin_tables), jnp.asarray(fin_ids), jnp.asarray(fin_n),
-            jnp.asarray(fin_rng), jnp.asarray(fin_temp), jnp.asarray(fin_tk),
-            jnp.asarray(fin_tp), jnp.asarray(fin_ds), jnp.asarray(fin_pad),
-            bool(n_fin), poison, bool(any_decode), forced, use_forced,
-        )
+        if any_decode and not (n_ch or n_fin or n_reset) and self._tick_poison is None:
+            descriptor = self._desc_decode_only
+        else:
+            # a host array of the tick's own, never written after it is
+            # sent: the runtime may still read it once device_put has
+            # returned (a prefill-only tick has no sync before the next tick
+            # packs, and ONE buffer packed tick after tick had its chunk
+            # lanes overwritten under the transfer)
+            buf = self._desc_idle_host.copy()
+            v = self._desc_layout.views(buf)
+            v.any_reset[...] = bool(n_reset)
+            v.any_chunk[...] = bool(n_ch)
+            v.any_finish[...] = bool(n_fin)
+            if self._tick_poison is not None:
+                v.poison[...] = int(self._tick_poison)
+            v.any_decode[...] = bool(any_decode)
+            for i, (_slot, ids_row) in enumerate(self._tick_resets):
+                v.reset_ids[i * P:(i + 1) * P] = ids_row
+            for i, (_slot, ids, off, c, lstart, trow) in enumerate(self._tick_chunks):
+                v.ch_ids[i] = ids
+                v.ch_offset[i] = off
+                v.ch_count[i] = c
+                v.ch_latent_start[i] = lstart
+                v.ch_tables[i] = trow
+            for i, (slot, trow, ids_latent, n, rng, sampling) in enumerate(self._tick_finishes):
+                v.fin_active[i] = True
+                v.fin_slot[i] = slot
+                v.fin_tables[i] = trow
+                v.fin_ids[i] = ids_latent
+                v.fin_n[i] = n
+                v.fin_rng[i] = rng
+                (v.fin_temp[i], v.fin_tk[i], v.fin_tp[i], v.fin_ds[i],
+                 v.fin_pad[i]) = sampling
+            descriptor = jax.device_put(buf)
+        return (self.params, self._cache, self._state, descriptor,
+                forced, use_forced)
 
     def _dispatch_ragged(self, any_decode: bool, forced, use_forced):
         """Dispatch the tick's ONE fused program over the descriptor
-        ``_ragged_args`` packs (its build time is what the v11 metrics
-        report). Returns the decode outputs; when ``any_decode`` is False
+        ``_ragged_args`` hands it (its wall time is the metrics'
+        ``descriptor_build_s``; a resident descriptor counts as no
+        transfer). Returns the decode outputs; when ``any_decode`` is False
         they are the no-decode sentinels and the caller discards them."""
         t0 = time.perf_counter()
         args = self._ragged_args(any_decode, forced, use_forced)
         self._tick_build_s = time.perf_counter() - t0
+        self._tick_transfers = int(args[3] is not self._desc_decode_only)
         self._tick_programs += 1
         tok, finite, self._cache, self._state = self._jit_ragged_tick(*args)
         self._tick_chunks.clear()
@@ -2474,6 +2496,7 @@ class ServingEngine:
             self._tick_chunk_items = 0
             self._tick_finish_items = 0
             self._tick_build_s = 0.0
+            self._tick_transfers = None
             self._tick_chunks.clear()
             self._tick_finishes.clear()
             self._tick_resets.clear()
@@ -2571,6 +2594,7 @@ class ServingEngine:
             self.metrics.record_tick_dispatch(
                 self._tick_programs, self._tick_chunk_items,
                 self._tick_finish_items, len(occupied), self._tick_build_s,
+                self._tick_transfers,
             )
             if not occupied:
                 # ragged tick that only carried prefill work: nothing to

@@ -133,7 +133,11 @@ Schema history:
     mixed-batch composition per tick), and ``descriptor_build_s`` p50/p95
     (host-side lane packing). The stream is unchanged — the block is
     windowed gauges only. The reader normalizes pre-v11 snapshots with
-    ``None``.
+    ``None``. Added since, without a version of their own (additive keys):
+    ``resident_descriptor_tick_pct`` (share of the fused tick's dispatches,
+    over the latency window, that passed the device-resident decode-only
+    descriptor; ``None`` before the first) and ``descriptor_transfers``
+    p50/p95 (host-to-device transfers of the descriptor a tick: 0 or 1).
   * ``serving-metrics/v12`` — the out-of-process-replica schema
     (docs/serving.md "Out-of-process replicas"): every snapshot carries a
     ``transport`` field — ``None`` on plain engines and on in-process
@@ -434,6 +438,9 @@ class EngineMetrics(_JsonlMetrics):
     _tick_finish_counts: Deque[int] = field(default_factory=lambda: deque(maxlen=LATENCY_WINDOW))
     _tick_decode_counts: Deque[int] = field(default_factory=lambda: deque(maxlen=LATENCY_WINDOW))
     _tick_build_times: Deque[float] = field(default_factory=lambda: deque(maxlen=LATENCY_WINDOW))
+    # host-to-device transfers of the fused tick's descriptor, per dispatch
+    # of the tick program: 0 (the resident decode-only descriptor) or 1
+    _tick_transfer_counts: Deque[int] = field(default_factory=lambda: deque(maxlen=LATENCY_WINDOW))
     _start_time: Optional[float] = None
     _occupancy_sum: float = 0.0  # sum over steps of active_slots / num_slots
     _pages_per_request: Deque[int] = field(default_factory=lambda: deque(maxlen=LATENCY_WINDOW))
@@ -563,12 +570,16 @@ class EngineMetrics(_JsonlMetrics):
 
     def record_tick_dispatch(self, programs: int, chunk_items: int,
                              finish_items: int, decode_items: int,
-                             build_s: float) -> None:
+                             build_s: float,
+                             descriptor_transfers: Optional[int] = None) -> None:
         """One DISPATCHING tick's program/work accounting (v11): how many
         compiled programs the tick launched (ragged steady-state: exactly 1),
         the tick's mixed-batch composition (prefill chunk lanes, latent
-        finish lanes, decoding slots), and the host-side descriptor build
-        time (0 on the dense pool — there is no descriptor). Windowed, no JSONL
+        finish lanes, decoding slots), the host-side descriptor build
+        time (0 on the dense pool — there is no descriptor), and how many
+        host-to-device transfers the fused tick's descriptor cost (0: the
+        resident decode-only descriptor; 1: a packed one; None: the tick
+        dispatched no fused program). Windowed, no JSONL
         event: this fires every tick, and the stream already carries
         decode_step/chunk events for per-tick forensics."""
         self.ragged_ticks += 1
@@ -577,6 +588,8 @@ class EngineMetrics(_JsonlMetrics):
         self._tick_finish_counts.append(int(finish_items))
         self._tick_decode_counts.append(int(decode_items))
         self._tick_build_times.append(float(build_s))
+        if descriptor_transfers is not None:
+            self._tick_transfer_counts.append(int(descriptor_transfers))
 
     def set_weight_serving(self, dtype: str, param_bytes: int,
                            param_bytes_fp: int) -> None:
@@ -823,6 +836,18 @@ class EngineMetrics(_JsonlMetrics):
                 },
                 "descriptor_build_s": {
                     k: v for k, v in _latency_dict(self._tick_build_times).items()
+                    if k in _PERCENTILE_KEYS
+                },
+                # share of the fused tick's dispatches (latency window) that
+                # passed the device-resident decode-only descriptor: nothing
+                # packed, nothing sent
+                "resident_descriptor_tick_pct": (
+                    100.0 * self._tick_transfer_counts.count(0)
+                    / len(self._tick_transfer_counts)
+                    if self._tick_transfer_counts else None
+                ),
+                "descriptor_transfers": {
+                    k: v for k, v in _latency_dict(self._tick_transfer_counts).items()
                     if k in _PERCENTILE_KEYS
                 },
             },

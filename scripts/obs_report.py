@@ -441,6 +441,11 @@ def main(argv=None) -> Dict:
                   f"{rt.get('ticks')} dispatching ticks, "
                   f"programs/tick p50={ppt.get('p50')} p95={ppt.get('p95')}, "
                   f"descriptor build p95={build.get('p95')}s")
+            if "resident_descriptor_tick_pct" in rt:
+                sent = rt.get("descriptor_transfers") or {}
+                print("  descriptor: resident (nothing sent) on "
+                      f"{rt['resident_descriptor_tick_pct']}% of ticks, "
+                      f"transfers/tick p50={sent.get('p50')} p95={sent.get('p95')}")
             for key in ("chunk_items", "finish_items", "decode_items"):
                 stats = rt.get(key) or {}
                 print(f"  {key}: p50={stats.get('p50')} p95={stats.get('p95')}")

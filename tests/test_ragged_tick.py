@@ -21,8 +21,11 @@ import pytest
 from perceiver_io_tpu.generation.generate import GenerationConfig
 from perceiver_io_tpu.models.core.config import CausalSequenceModelConfig
 from perceiver_io_tpu.models.core.perceiver_ar import CausalSequenceModel
+from perceiver_io_tpu.obs.core import TelemetryRecorder
+from perceiver_io_tpu.reliability import armed
 from perceiver_io_tpu.serving import ServingEngine
 from perceiver_io_tpu.serving.metrics import SCHEMA, load_metrics_jsonl
+from perceiver_io_tpu.serving.tick_descriptor import TickFields
 from tests.test_paging import _reference_tokens  # the same toy model: window 12 over 6 latents
 
 VOCAB = 262
@@ -169,6 +172,220 @@ def test_ragged_preempt_and_quarantine_drop_buffered_lanes(setup):
     assert e1._tick_resets and e1._tick_poison is None
     e1._drop_tick_work(0)
     assert not e1._tick_resets
+
+
+# --------------------------------------------------------------- descriptor
+def _seventeen_arrays(engine, any_decode):
+    """The descriptor as the seventeen-array signature built it (the loops
+    the packed layout replaced, kept as the reference): numpy arrays in
+    their own dtypes plus the five scalars, by ``TickFields`` name."""
+    lanes, cap = engine._ragged_lanes, engine._ragged_chunk_cap
+    P = engine._pages_per_slot
+    reset_ids = np.zeros((lanes * P,), np.int32)
+    for i, (_slot, ids_row) in enumerate(engine._tick_resets):
+        reset_ids[i * P:(i + 1) * P] = ids_row
+    ch_ids = np.zeros((lanes, cap), np.int32)
+    ch_offset = np.zeros((lanes,), np.int32)
+    ch_count = np.zeros((lanes,), np.int32)
+    ch_lstart = np.full((lanes,), 2 ** 30, np.int32)
+    ch_tables = np.zeros((lanes, P), np.int32)
+    for i, (_slot, ids, off, c, lstart, trow) in enumerate(engine._tick_chunks):
+        ch_ids[i], ch_offset[i], ch_count[i] = ids, off, c
+        ch_lstart[i], ch_tables[i] = lstart, trow
+    fin_active = np.zeros((lanes,), bool)
+    fin_slot = np.zeros((lanes,), np.int32)
+    fin_tables = np.zeros((lanes, P), np.int32)
+    fin_ids = np.zeros((lanes, engine._latents), np.int32)
+    fin_n = np.zeros((lanes,), np.int32)
+    fin_rng = np.zeros((lanes, 2), np.uint32)
+    fin_temp = np.ones((lanes,), np.float32)
+    fin_tk = np.zeros((lanes,), np.int32)
+    fin_tp = np.ones((lanes,), np.float32)
+    fin_ds = np.zeros((lanes,), bool)
+    fin_pad = np.zeros((lanes,), np.int32)
+    for i, (slot, trow, ids_latent, n, rng, sampling) in enumerate(engine._tick_finishes):
+        fin_active[i], fin_slot[i], fin_tables[i] = True, slot, trow
+        fin_ids[i], fin_n[i], fin_rng[i] = ids_latent, n, rng
+        fin_temp[i], fin_tk[i], fin_tp[i], fin_ds[i], fin_pad[i] = sampling
+    poison = -1 if engine._tick_poison is None else int(engine._tick_poison)
+    return TickFields(
+        any_reset=np.bool_(bool(engine._tick_resets)),
+        any_chunk=np.bool_(bool(engine._tick_chunks)),
+        any_finish=np.bool_(bool(engine._tick_finishes)), poison=np.int32(poison),
+        any_decode=np.bool_(any_decode), reset_ids=reset_ids, ch_ids=ch_ids,
+        ch_offset=ch_offset, ch_count=ch_count, ch_latent_start=ch_lstart,
+        ch_tables=ch_tables, fin_active=fin_active, fin_slot=fin_slot,
+        fin_tables=fin_tables, fin_ids=fin_ids, fin_n=fin_n, fin_rng=fin_rng,
+        fin_temp=fin_temp, fin_tk=fin_tk, fin_tp=fin_tp, fin_ds=fin_ds, fin_pad=fin_pad,
+    )
+
+
+def _buffer_tick(engine, kind, rs):
+    """Fill the engine's tick buffers, as admission would, with one tick of
+    ``kind``; returns the tick's ``any_decode``."""
+    P, cap, L = engine._pages_per_slot, engine._ragged_chunk_cap, engine._latents
+    table = lambda: rs.randint(1, 7, size=(P,)).astype(np.int32)
+    if kind in ("chunk_lanes", "prefill_only"):
+        for slot in (2, 0):
+            engine._tick_chunks.append(
+                (slot, rs.randint(0, VOCAB, size=(cap,)).astype(np.int32),
+                 int(rs.randint(0, WINDOW)), int(rs.randint(1, cap + 1)),
+                 int(rs.randint(0, WINDOW)), table()))
+    if kind == "finish_lanes":
+        # a sampled and a greedy request: full-range rng words, temperature
+        # and top_p that are no round binary fractions
+        for slot, sampling in ((1, (0.7300000190734863, 40, 0.9100000262260437, True, 3)),
+                               (2, (1.0, 0, 1.0, False, 0))):
+            rng = rs.randint(0, 2 ** 32, size=(2,), dtype=np.uint64).astype(np.uint32)
+            engine._tick_finishes.append(
+                (slot, table(), rs.randint(0, VOCAB, size=(L,)).astype(np.int32),
+                 int(rs.randint(L, WINDOW + 1)), rng, sampling))
+    if kind == "resets_int8":
+        for slot in (0, 2):
+            engine._tick_resets.append((slot, table()))
+    if kind == "poison":
+        engine._tick_poison = 1
+    return kind != "prefill_only"
+
+
+@pytest.mark.parametrize("kind", ["idle", "chunk_lanes", "finish_lanes",
+                                  "resets_int8", "poison", "prefill_only"])
+def test_descriptor_pack_unpack_round_trip(setup, kind, monkeypatch):
+    """One int32 array carries what the seventeen arrays and five scalars
+    carried: packed on the host, unpacked in a program, every field comes
+    back in its dtype and shape, BIT-exact (float32 sampling parameters and
+    uint32 rng words travel by bit pattern through the int32 view)."""
+    model, params = setup
+    engine = ServingEngine(model, params, num_slots=3, kv_page_size=PS,
+                           prefill_chunk_tokens=4, max_prefill_slots=2,
+                           kv_quant="int8" if kind == "resets_int8" else None)
+    layout = engine._desc_layout
+    assert tuple(layout.fields) == TickFields._fields
+    any_decode = _buffer_tick(engine, kind, np.random.RandomState(31))
+    want = _seventeen_arrays(engine, any_decode)
+    sent, device_put = [], jax.device_put
+    monkeypatch.setattr(jax, "device_put",
+                        lambda x, *a, **kw: sent.append(x) or device_put(x, *a, **kw))
+    for round_no in range(2):
+        args = engine._ragged_args(any_decode, engine._forced_none,
+                                   engine._use_forced_none)
+        # the runtime may read what it is handed after device_put returns:
+        # every packed tick sends a host array of its own, never the template
+        assert len(sent) == (kind != "idle") * (round_no + 1)
+        owned = [engine._desc_idle_host] + sent
+        assert not any(np.shares_memory(x, y) for i, x in enumerate(owned)
+                       for y in owned[:i])
+        assert len(args) == 6 and args[0] is engine.params
+        desc = args[3]
+        assert isinstance(desc, jax.Array) and desc.dtype == jnp.int32
+        assert desc.shape == (layout.words,)
+        # only the tick that carries nothing but decode rides the resident one
+        assert (desc is engine._desc_decode_only) == (kind == "idle")
+        got = jax.jit(layout.unpack)(desc)
+        for name, expected in want._asdict().items():
+            value = np.asarray(getattr(got, name))
+            assert value.dtype == expected.dtype and value.shape == expected.shape, name
+            assert value.tobytes() == expected.tobytes(), name
+    # a packed tick leaves nothing behind in the template
+    engine._tick_chunks.clear(), engine._tick_finishes.clear(), engine._tick_resets.clear()
+    engine._tick_poison = None
+    again = engine._ragged_args(False, engine._forced_none, engine._use_forced_none)[3]
+    assert np.array_equal(np.asarray(again), layout.idle(any_decode=False))
+
+
+class _Transfers:
+    """Counts the engine's explicit host-to-device calls (``jax.device_put``,
+    ``jnp.asarray``, ``jnp.array``) while installed; the implicit ones (a
+    numpy array or a Python scalar handed to a jit) are the transfer guard's
+    to refuse."""
+
+    def __init__(self, monkeypatch):
+        self.calls = []
+        for module, name in ((jax, "device_put"), (jnp, "asarray"), (jnp, "array")):
+            monkeypatch.setattr(module, name, self._counting(name, getattr(module, name)))
+
+    def _counting(self, name, fn):
+        def counted(*args, **kwargs):
+            self.calls.append(name)
+            return fn(*args, **kwargs)
+        return counted
+
+
+def test_descriptor_transfers_zero_on_decode_only_one_on_lane_ticks(setup, monkeypatch):
+    """The tick's host-to-device traffic: NOTHING on a decode-only tick (the
+    resident descriptor: refused outright by the guard otherwise), exactly
+    ONE explicit transfer on a tick with a lane, and never an implicit one."""
+    model, params = setup
+    engine = ServingEngine(model, params, num_slots=3, kv_page_size=PS,
+                           prefill_chunk_tokens=4, max_prefill_slots=2)
+    first = engine.submit(list(range(3, 12)), max_new_tokens=12)
+    for _ in range(4):  # chunks, the finish, then decode: everything compiled
+        engine.step()
+    assert first.slot is not None and not engine._prefilling
+
+    transfers = _Transfers(monkeypatch)
+    with jax.transfer_guard_host_to_device("disallow_explicit"):
+        assert engine.step_dispatch()  # decode-only
+    assert transfers.calls == []
+    engine.step_harvest()
+
+    engine.submit(list(range(20, 28)), max_new_tokens=2)  # split admission: chunk lanes
+    kinds = []
+    while engine._prefilling or not kinds:
+        del transfers.calls[:]
+        with jax.transfer_guard_host_to_device("disallow"):
+            assert engine.step_dispatch()
+        kinds.append((engine._tick_chunk_items, engine._tick_finish_items))
+        assert transfers.calls == ["device_put"], kinds
+        engine.step_harvest()
+    assert any(ch for ch, _ in kinds) and any(fin for _, fin in kinds)
+    assert engine.decode_compilations == 1
+
+
+def test_descriptor_counters_over_every_tick_kind(setup):
+    """A run that mixes every kind of tick — resets (int8 pool), chunk
+    lanes, finish lanes, poison, prefill-only, decode-only — compiles the
+    tick once, leaves the watchdog silent, and the snapshot's two keys read
+    what the run did (counted here at the dispatcher's door)."""
+    model, params = setup
+    engine = ServingEngine(model, params, num_slots=3, kv_page_size=PS,
+                           prefill_chunk_tokens=4, max_prefill_slots=2,
+                           kv_quant="int8", telemetry=TelemetryRecorder())
+    seen = []  # per fused dispatch: (resets, chunks, finishes, poison, any_decode)
+    pack = engine._ragged_args
+
+    def watched(any_decode, forced, use_forced):
+        seen.append((len(engine._tick_resets), len(engine._tick_chunks),
+                     len(engine._tick_finishes), engine._tick_poison is not None,
+                     bool(any_decode)))
+        return pack(any_decode, forced, use_forced)
+
+    engine._ragged_args = watched
+    handles = [engine.submit(list(range(3, 12)), max_new_tokens=6)]
+    engine.step()  # the first chunk with nothing decoding: prefill-only
+    for prompt in ([9] * WINDOW, list(range(60, 67))):
+        handles.append(engine.submit(prompt, max_new_tokens=5))
+        engine.step()
+    with armed("serving.nan", slot=handles[0].slot):
+        engine.step()
+    engine.run_until_drained(max_steps=200)
+    assert all(h.done for h in handles) and handles[0].status.value == "failed"
+
+    carried = [any(work[:4]) or not work[4] for work in seen]
+    for column, what in enumerate(("resets", "chunks", "finishes", "poison")):
+        assert any(work[column] for work in seen), f"no tick carried {what}"
+    assert any(not work[4] for work in seen), "no prefill-only tick"
+    assert not all(carried), "no decode-only tick"
+    assert engine.decode_compilations == 1
+    assert engine.watchdog.check() == [] and engine.watchdog.violations == []
+    block = engine.metrics.snapshot()["ragged_tick"]
+    assert block["resident_descriptor_tick_pct"] == pytest.approx(
+        100.0 * carried.count(False) / len(carried))
+    p50, p95 = np.percentile([int(c) for c in carried], [50, 95])
+    assert block["descriptor_transfers"] == {"p50": round(float(p50), 6),
+                                             "p95": round(float(p95), 6)}
+    assert block["ticks"] >= len(seen)
+    engine.close()
 
 
 # -------------------------------------------------------------------- chaos
