@@ -166,3 +166,69 @@ def flagship_455m_config() -> "CausalSequenceModelConfig":
         remat_policy="dots_with_no_batch_dims_saveable",
         scan_unroll=20,
     )
+
+
+@dataclass(frozen=True)
+class FalconH1Config:
+    """A Falcon-H1 decoder (``models/core/falcon_h1.py``) under the keys of its
+    published ``config.json``: every block runs a Mamba-2 mixer and grouped-
+    query attention in parallel on one normed input, then a gated MLP, with the
+    muP multipliers of the release. ``max_seq_len`` is this program's own: the
+    most tokens (prompt plus answer) a serving slot can hold, which sizes a
+    slot's page-table row; the published ``max_position_embeddings`` only
+    bounds it."""
+
+    vocab_size: int = 261120
+    hidden_size: int = 5120
+    intermediate_size: int = 21504
+    num_hidden_layers: int = 72
+    num_attention_heads: int = 20
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    mamba_d_ssm: int = 4096
+    mamba_n_heads: int = 32
+    mamba_d_head: int = 128
+    mamba_d_state: int = 256
+    mamba_n_groups: int = 2
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 128
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 1e11
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    mlp_multipliers: Tuple[float, ...] = (1.0, 1.0)
+    max_position_embeddings: int = 262144
+    max_seq_len: int = 2048
+    init_scale: float = 0.02
+
+    def __post_init__(self):
+        if self.mamba_n_heads * self.mamba_d_head != self.mamba_d_ssm:
+            raise ValueError(
+                f"mamba_n_heads x mamba_d_head ({self.mamba_n_heads} x {self.mamba_d_head}) "
+                f"must equal mamba_d_ssm ({self.mamba_d_ssm})")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError(
+                f"num_attention_heads ({self.num_attention_heads}) must be a multiple of "
+                f"num_key_value_heads ({self.num_key_value_heads})")
+        if self.mamba_n_heads % self.mamba_n_groups or self.mamba_d_ssm % self.mamba_n_groups:
+            raise ValueError(f"mamba_n_groups ({self.mamba_n_groups}) must divide the mixer's heads and width")
+        if not 1 <= self.max_seq_len <= self.max_position_embeddings:
+            raise ValueError(
+                f"max_seq_len ({self.max_seq_len}) must lie in [1..max_position_embeddings="
+                f"{self.max_position_embeddings}]")
+
+    @property
+    def conv_dim(self) -> int:
+        return self.mamba_d_ssm + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    @classmethod
+    def create(cls, **kwargs):
+        known = {f.name for f in fields(cls)}
+        picked = {k: (tuple(v) if isinstance(v, list) else v) for k, v in kwargs.items() if k in known}
+        return cls(**picked)

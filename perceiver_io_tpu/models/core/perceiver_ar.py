@@ -43,6 +43,7 @@ from perceiver_io_tpu.models.core.adapter import (
 )
 from perceiver_io_tpu.models.core.config import CausalSequenceModelConfig
 from perceiver_io_tpu.models.core.modules import LN_EPS, CrossAttentionLayer, SelfAttentionBlock
+from perceiver_io_tpu.models.core.serving_api import ServingTraits
 from perceiver_io_tpu.ops.attention import KVCache, RingKVCache
 from perceiver_io_tpu.ops.paged_decode_kernel import PagedKVCache
 from perceiver_io_tpu.ops.position import frequency_position_encoding, positions
@@ -260,6 +261,24 @@ class PagedPerceiverARCache(flax.struct.PyTreeNode):
             sa=self.sa.write_batch_row(slot, sa_src),
             shift=self.shift.at[slot].set(window - live),
             live=self.live.at[slot].set(live),
+        )
+
+    def quarantine_slot(self, slot: jax.Array, table_row: jax.Array) -> "PagedPerceiverARCache":
+        """Containment: zero the slot's self-attention rows and every page
+        ``table_row`` names (trash-padding entries re-zero the trash page —
+        duplicate scatter indices with identical zero payloads, deterministic),
+        and on a quantized pool their scale sidecars too (a NaN that reached
+        the quantizer lands in the scale, and dequant multiplies every byte of
+        the page by it)."""
+        ca = self.ca
+        ca = ca.replace(
+            kp=ca.kp.at[table_row].set(0), vp=ca.vp.at[table_row].set(0)
+        ).reset_page_scales(table_row)
+        return self.replace(
+            ca=ca,
+            sa=self.sa.replace(
+                k=self.sa.k.at[:, slot].set(0), v=self.sa.v.at[:, slot].set(0)
+            ),
         )
 
     def release_slot(self, slot: jax.Array) -> "PagedPerceiverARCache":
@@ -944,6 +963,74 @@ class CausalSequenceModel(nn.Module):
         prefill's ``logits[:, -1]`` exactly."""
         hidden, sa_cache = self.ar.prefill_latents_paged(x, n_live, ca, table_row)
         return self._head(hidden)[:, -1], sa_cache
+
+    # ---- what the serving engine asks of a model (models/core/serving_api.py)
+    def serving_traits(self) -> ServingTraits:
+        cfg = self.config
+        return ServingTraits(vocab_size=cfg.vocab_size, window=cfg.max_seq_len, finish_ids=cfg.max_latents)
+
+    def serving_pages(self, prompt_tokens: int, max_new_tokens: int, page_size: int, bucket: int) -> int:
+        """The covering prefill bucket plus the whole generation budget, capped
+        at the window (``serving/paging.pages_for_request``; imported here, at
+        the engine's call, because ``serving`` imports this module)."""
+        from perceiver_io_tpu.serving.paging import pages_for_request
+
+        return pages_for_request(bucket, max_new_tokens, self.config.max_seq_len, page_size)
+
+    def serving_chunk_phase(self, params, cache: PagedPerceiverARCache, lanes) -> PagedPerceiverARCache:
+        """One SPLIT-prefill chunk a lane (docs/serving.md "Chunked prefill"):
+        position-wise KV for prompt tokens [offset, offset + count) scattered
+        page-wise through the lane's table row — the slot's IN-CACHE table
+        stays trash until the finish, so the decode phase cannot write into
+        the half-built reservation."""
+        cap = lanes.ch_ids.shape[1]
+
+        def body(cache, lane):
+            ids, offset, count, lstart, trow = lane
+            j = jnp.arange(cap)
+            pos = jnp.clip(offset + j, 0, self.max_seq_len - 1)[None, :]
+            latent_mask = ((offset + j) >= lstart)[None, :]
+            k, v = self.apply(params, ids[None, :], pos, latent_mask,
+                              method=type(self).prefill_chunk_kv)
+            # inactive lanes (count 0, trash table) deposit zero payloads on
+            # the trash page — write_rows' padding discipline, deterministic
+            cache = cache.replace(
+                ca=cache.ca.write_rows(trow, offset, count, k[0], v[0])
+            )
+            return cache, None
+
+        cache, _ = jax.lax.scan(
+            body, cache,
+            (lanes.ch_ids, lanes.ch_offset, lanes.ch_count, lanes.ch_latent_start, lanes.ch_tables),
+        )
+        return cache
+
+    def serving_finish_phase(self, params, cache: PagedPerceiverARCache, state, lanes, install_state):
+        """The SPLIT prefill's finish, a lane a slot: latents for the last
+        ``max_latents`` prompt tokens against the slot's already-written
+        pages, then the install bookkeeping (table, ring offset, SA cache,
+        slot state activation)."""
+        def body(carry, lane):
+            (active, slot, trow, ids, n, rng, temp, tk, tp, ds, pad) = lane
+
+            def fin(args):
+                cache, state = args
+                req_logits, sa_src = self.apply(
+                    params, ids[None, :], n, cache.ca, trow,
+                    method=type(self).prefill_finish_paged,
+                )
+                cache = cache.install_finish(slot, trow, sa_src, n)
+                state = install_state(state, slot, req_logits, rng, temp, tk, tp, ds, pad)
+                return cache, state
+
+            return jax.lax.cond(active, fin, lambda a: a, carry), None
+
+        carry, _ = jax.lax.scan(
+            body, (cache, state),
+            (lanes.fin_active, lanes.fin_slot, lanes.fin_tables, lanes.fin_ids, lanes.fin_n,
+             lanes.fin_rng, lanes.fin_temp, lanes.fin_tk, lanes.fin_tp, lanes.fin_ds, lanes.fin_pad),
+        )
+        return carry
 
     def decode_step_paged(
         self, x: jax.Array, cache: PagedPerceiverARCache

@@ -1,0 +1,70 @@
+"""What ``ServingEngine`` asks of a model it serves from the paged pool.
+
+The engine owns slots, pages, the tick loop and the descriptor; it knows no
+architecture. A model class answers four questions, by methods the engine
+calls on the (unbound) module:
+
+(a) its cache: ``init_paged_cache(num_slots, num_pages, page_size, dtype,
+    kv_quant)`` returns ONE pytree holding everything a slot keeps between
+    ticks (donated to the tick program like the pool it contains), with
+    ``release_slot(slot)`` and ``quarantine_slot(slot, table_row)``;
+(b) a request's reservation: ``serving_pages(prompt_tokens, max_new_tokens,
+    page_size, bucket)`` pages, claimed whole at admission;
+(c) its chunk step, ``serving_chunk_phase(params, cache, lanes)``, and what
+    ends a prompt, ``serving_finish_phase(params, cache, state, lanes,
+    install_state)`` — the tick program's two prefill phases, traced into the
+    one fused program;
+(d) its decode step: ``decode_step_paged(ids (B, 1), cache)`` under
+    ``model.apply``.
+
+``serving_traits()`` says, in plain data, what else differs: which prompts
+take the split admission, what the descriptor's lanes carry, and which engine
+options the model does not carry yet (each with the piece that is missing, so
+the engine can refuse it at construction).
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass, field
+from typing import Dict
+
+
+@dataclass(frozen=True)
+class ServingTraits:
+    vocab_size: int
+    # the most tokens a slot holds: longer prompts are rejected at submit, and
+    # a slot's page-table row has ceil(window / page_size) entries
+    window: int
+    # ids a finish lane carries: the tail of the prompt that is prefilled at
+    # the finish, not by chunk lanes (Perceiver AR's latents). 0: none, the
+    # last chunk lane ends the prompt
+    finish_ids: int
+    # bytes of recurrent state one slot holds beside its pages (0: none). With
+    # such a state, a claimed slot's first chunk lane carries ``reset`` (the
+    # state is zeroed inside the tick) and every chunk lane names its slot
+    recurrent_bytes_per_slot: int = 0
+    # engine option -> why this model cannot be served with it yet
+    unsupported: Dict[str, str] = field(default_factory=dict)
+
+    @property
+    def recurrent_state(self) -> bool:
+        return self.recurrent_bytes_per_slot > 0
+
+    @property
+    def split_from(self) -> int:
+        """Prompts of at least this many tokens take the split admission (chunk
+        lanes then a finish lane inside the tick): the finish consumes
+        ``finish_ids`` tokens, so shorter ones take the one-shot prefill +
+        install programs; with no finish ids, every prompt is split."""
+        return max(self.finish_ids, 1)
+
+    @property
+    def prefill_floor(self) -> int:
+        """The smallest rung of the one-shot prefill ladder: a rung holds the
+        finish ids; a model that never takes the one-shot path has one rung."""
+        return self.finish_ids or self.window
+
+    def latent_start(self, prompt_tokens: int) -> int:
+        """The first position a chunk lane treats as a latent (Perceiver AR's
+        boundary); far past any position for a model that has none."""
+        return prompt_tokens - self.finish_ids if self.finish_ids else 2 ** 30

@@ -696,3 +696,182 @@ def fused_paged_decode_attention(
         *row_scales,
     )
     return out.reshape(b, 1, h, d).transpose(0, 2, 1, 3)
+
+
+# ---------------------------------------------------------------------------
+# Grouped-query form: fewer K/V heads than query heads, absolute positions
+# ---------------------------------------------------------------------------
+#
+# A decoder whose every layer is full causal attention keeps no ring: a slot's
+# token ``t`` sits at physical position ``t`` of its page-table row for the
+# slot's whole life, keys are stored ROTATED (a key's angle never changes), and
+# ``length`` tokens are visible. Its ``h_q`` query heads share ``h_kv`` K/V
+# heads (``n_rep = h_q // h_kv`` each), so one K/V page must be read ONCE for
+# all of a slot's query heads: the query planes widen from the single-query
+# kernel's ``(h*d, h)`` to ``(h_kv*d, n_rep*h_kv)`` — block-diagonal, one row
+# of the block per (repeat, K/V head) — and a page's scores for every query
+# head are one ``Q K^T`` product. The pool stacks the layers,
+# ``(layers, num_pages, page_size, h_kv*d)``, under ONE page table. A grid step
+# holds ``pages_per_step`` pages of a slot (the pool is passed that many times,
+# each with its own index map), so the grid is short where slots are many and
+# pages small; pages past a slot's length map to the trash page and are skipped.
+
+
+def gqa_pages_per_step(pages_per_slot: int, most: int = 8) -> int:
+    """The largest divisor of ``pages_per_slot`` up to ``most``."""
+    return max(g for g in range(1, most + 1) if pages_per_slot % g == 0)
+
+
+def paged_gqa_decode_supported(page_size: int, head_dim: int) -> bool:
+    """The grouped-query paged kernel on one TPU device: lane-aligned heads and
+    sublane-aligned pages; the XLA fallback (a dense gather through the page
+    table) serves the CPU suite and sharded pools."""
+    if jax.default_backend() != "tpu" or not single_device_trace():
+        return False
+    return head_dim % 128 == 0 and page_size % 8 == 0
+
+
+def _blockdiag_gqa_queries(q: jax.Array, kv_heads: int, rows: int) -> jax.Array:
+    """(B, h_q, d) scaled+rotated queries -> (B, rows, h_kv*d): row
+    ``g*h_kv + kv`` holds query head ``kv*n_rep + g`` in the columns of K/V
+    head ``kv`` and zeros elsewhere; rows past ``h_q`` are zero padding."""
+    b, hq, d = q.shape
+    n_rep = hq // kv_heads
+    per = q.reshape(b, kv_heads, n_rep, d).transpose(0, 2, 1, 3)  # (B, g, kv, d)
+    eye = jnp.eye(kv_heads, dtype=q.dtype)
+    planes = (per[:, :, :, None, :] * eye[None, None, :, :, None]).reshape(b, hq, kv_heads * d)
+    return jnp.pad(planes, ((0, 0), (0, rows - hq), (0, 0)))
+
+
+def _gqa_kernel(len_ref, table_ref, q_ref, *refs, page_size, group):
+    """Grid (B, P // group); step (bi, i) covers pages [i*group, (i+1)*group)
+    of slot bi.
+
+    len_ref (B,), table_ref (B, P)   scalar prefetch
+    q_ref (rows, h_kv*d)             block-diagonal queries of slot bi
+    k_refs / v_refs (ps, h_kv*d)     ``group`` pages each, rotated keys
+    diag_ref (rows, h_kv*d)          1 where a row's K/V head owns the column
+    o_ref (rows, d)                  row ``g*h_kv + kv``: query head ``kv*n_rep + g``
+    scratch m, l (rows, 128), acc (rows, h_kv*d), float32
+    """
+    import jax.experimental.pallas as pl
+
+    k_refs, v_refs = refs[:group], refs[group:2 * group]
+    diag_ref, o_ref, m_ref, l_ref, acc_ref = refs[2 * group:]
+    bi, i = pl.program_id(0), pl.program_id(1)
+    n = len_ref[bi]
+    nt = (((1,), (1,)), ((), ()))
+
+    @pl.when(i == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    for g in range(group):
+        first = (i * group + g) * page_size
+
+        @pl.when(first < n)
+        def _page(g=g, first=first):
+            k, v = k_refs[g][...], v_refs[g][...]
+            s = jax.lax.dot_general(q_ref[...].astype(k.dtype), k, nt,
+                                    preferred_element_type=jnp.float32)  # (rows, ps)
+            pos = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(pos < n, s, -jnp.inf)
+            m_prev = m_ref[:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))  # finite: the page has a visible key
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_ref[...] = jnp.broadcast_to(alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
+            acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+
+    @pl.when(i == pl.num_programs(1) - 1)
+    def _finalize():
+        d = o_ref.shape[-1]
+        # a slot of length 0 never accumulated: 0 / eps is an exact zero row
+        own = acc_ref[...] * diag_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
+        out = own[:, :d]
+        for kv in range(1, own.shape[-1] // d):
+            out = out + own[:, kv * d:(kv + 1) * d]
+        o_ref[...] = out.astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("layer", "interpret"))
+def fused_paged_decode_attention_gqa(
+    q: jax.Array,
+    kp: jax.Array,
+    vp: jax.Array,
+    page_table: jax.Array,
+    length: jax.Array,
+    layer: int,
+    interpret: bool = False,
+) -> jax.Array:
+    """q (B, h_q, d) scaled+rotated single queries; kp / vp (layers, N, ps,
+    h_kv*d) pools of ROTATED keys and values; page_table (B, P); length (B,)
+    visible tokens per slot, the one just appended included (0: the slot is
+    skipped and its row comes back zero). Returns (B, h_q, d) in q's dtype."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, hq, d = q.shape
+    _, _, ps, c = kp.shape
+    kv_heads = c // d
+    n_rep = hq // kv_heads
+    p = page_table.shape[1]
+    group = gqa_pages_per_step(p)
+    rows = -(-hq // 16) * 16  # whole bf16 sublane tiles
+    length = jnp.asarray(length, jnp.int32).reshape(-1)
+    page_table = jnp.asarray(page_table, jnp.int32)
+
+    def page_map(g):
+        def index(bi, i, len_ref, table_ref):
+            page = i * group + g
+            return layer, jnp.where(page * ps < len_ref[bi], table_ref[bi, page], 0), 0, 0
+        return index
+
+    row = jnp.arange(rows)
+    diag = ((row[:, None] % kv_heads) == (jnp.arange(c)[None, :] // d)) & (row[:, None] < hq)
+    pools = [pl.BlockSpec((None, None, ps, c), page_map(g)) for g in range(group)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, p // group),
+        in_specs=[pl.BlockSpec((None, rows, c), lambda bi, i, *_: (bi, 0, 0)), *pools, *pools,
+                  pl.BlockSpec((rows, c), lambda bi, i, *_: (0, 0))],
+        out_specs=pl.BlockSpec((None, rows, d), lambda bi, i, *_: (bi, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((rows, 128), jnp.float32), pltpu.VMEM((rows, 128), jnp.float32),
+                        pltpu.VMEM((rows, c), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        functools.partial(_gqa_kernel, page_size=ps, group=group),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, rows, d), q.dtype),
+        interpret=interpret,
+        name="fused_paged_decode_attention_gqa",
+    )(length, page_table, _blockdiag_gqa_queries(q, kv_heads, rows),
+      *([kp] * group), *([vp] * group), diag.astype(jnp.float32))
+    # row g*h_kv + kv -> query head kv*n_rep + g
+    return out[:, :hq].reshape(b, n_rep, kv_heads, d).transpose(0, 2, 1, 3).reshape(b, hq, d)
+
+
+def paged_gqa_reference_attention(
+    q: jax.Array, kp: jax.Array, vp: jax.Array, page_table: jax.Array, length: jax.Array, layer: int,
+) -> jax.Array:
+    """The XLA form: each slot's pages gathered dense through its table row,
+    masked at ``length``, one softmax per query head (float32). Same arguments
+    and result as the kernel; a slot of length 0 comes back zero."""
+    b, hq, d = q.shape
+    c = kp.shape[-1]
+    kv_heads = c // d
+    k = kp[layer][page_table].reshape(b, -1, kv_heads, d).astype(jnp.float32)
+    v = vp[layer][page_table].reshape(b, -1, kv_heads, d).astype(jnp.float32)
+    qg = q.astype(jnp.float32).reshape(b, kv_heads, hq // kv_heads, d)
+    hi = jax.lax.Precision.HIGHEST
+    s = jnp.einsum("bkgd,bnkd->bkgn", qg, k, precision=hi)
+    visible = (jnp.arange(k.shape[1])[None, :] < jnp.asarray(length)[:, None])[:, None, None, :]
+    s = jnp.where(visible, s, -jnp.inf)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    prob = jnp.exp(jnp.where(visible, s - jnp.where(jnp.isfinite(m), m, 0.0), -jnp.inf))
+    prob = prob / jnp.maximum(jnp.sum(prob, axis=-1, keepdims=True), 1e-30)
+    return jnp.einsum("bkgn,bnkd->bkgd", prob, v, precision=hi).reshape(b, hq, d).astype(q.dtype)

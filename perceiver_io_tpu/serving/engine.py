@@ -182,7 +182,6 @@ from perceiver_io_tpu.serving.paging import (
     PagePool,
     PrefixCache,
     page_keys_for_prompt,
-    pages_for_request,
 )
 from perceiver_io_tpu.serving.quant import (
     WEIGHT_DTYPES,
@@ -414,6 +413,12 @@ _ENGINE_IDS = itertools.count()
 # trace tools read.
 TICK_SCOPES = {phase: f"tick.{phase}" for phase in (
     "resets", "chunk_lanes", "finish_lanes", "poison", "sample", "decode")}
+# what a model with a recurrent state (models/core/falcon_h1.py) names inside
+# two of those phases: the state update, attention and MLP of a decode step,
+# the chunked scan and attention of a chunk lane
+TICK_SCOPES.update({part: f"tick.{part}" for part in (
+    "decode/ssm_update", "decode/attention", "decode/mlp",
+    "chunk_lanes/ssd_scan", "chunk_lanes/attention")})
 
 
 def default_prefill_buckets(window: int, max_latents: int) -> tuple:
@@ -463,6 +468,19 @@ class ServingEngine:
         weight_dtype: Optional[str] = None,
     ):
         self.model = model
+        # what the engine asks of the model instead of knowing its
+        # architecture (models/core/serving_api.py): the window a slot holds,
+        # which prompts take the split admission, what a lane carries, and
+        # which options the model cannot be served with yet — refused here,
+        # before anything is built or touched on disk, never run wrong
+        traits = self._traits = model.serving_traits()
+        options = {"prefix_cache": prefix_cache, "kv_quant": kv_quant,
+                   "handle_preemption": handle_preemption, "journal": journal,
+                   "dense pool (kv_page_size=None)": kv_page_size is None}
+        for option, reason in traits.unsupported.items():
+            if options.get(option):
+                raise ValueError(
+                    f"{type(model).__name__} cannot be served with {option} yet: {reason}")
         # Weight-serving transform (serving/quant.py; docs/serving.md
         # "Quantized KV pages & weight serving"): bf16 casts float leaves,
         # int8 stores matmul-grade leaves as int8 + per-tensor scale and the
@@ -618,24 +636,22 @@ class ServingEngine:
                 install_preemption_handler(_request_preempt)
             )
 
-        cfg = model.config
-        self._vocab = cfg.vocab_size
-        self._window = model.max_seq_len
-        self._prefix_len = model.max_prefix_len
-        self._latents = model.max_latents
+        self._vocab = traits.vocab_size
+        self._window = traits.window
+        self._latents = traits.finish_ids
 
         # Prefill bucket ladder (ascending, ends at the window): a prompt is
         # prefilled at the smallest covering bucket — cost O(bucket) — and
         # write_slot widens the bucket rows into the slot's tail. One compiled
         # prefill program per bucket, ever.
         if prefill_buckets is None:
-            ladder = default_prefill_buckets(self._window, model.max_latents)
+            ladder = default_prefill_buckets(self._window, traits.prefill_floor)
         else:
             ladder = tuple(sorted({int(b) for b in prefill_buckets} | {self._window}))
-            bad = [b for b in ladder if not model.max_latents <= b <= self._window]
+            bad = [b for b in ladder if not traits.prefill_floor <= b <= self._window]
             if bad:
                 raise ValueError(
-                    f"prefill_buckets must lie in [max_latents={model.max_latents}.."
+                    f"prefill_buckets must lie in [max_latents={traits.prefill_floor}.."
                     f"window={self._window}], got {bad}"
                 )
         self.prefill_buckets: tuple = ladder
@@ -790,7 +806,10 @@ class ServingEngine:
             self.metrics.set_prefix_cache(self._prefix_cache.stats(), 0)
         # serving-metrics/v9 gauges: quantized-page byte economics and the
         # weight-serving dtype/bytes — None (off) on fp engines
+        if traits.recurrent_state:
+            self.metrics.set_recurrent_state(num_slots * traits.recurrent_bytes_per_slot)
         if self.kv_quant is not None:
+            cfg = model.config
             fp_b, served_b = kv_bytes_per_token(
                 cfg.num_channels, self.cache_dtype, self.kv_quant,
                 self.kv_page_size, cfg.num_heads,
@@ -817,7 +836,8 @@ class ServingEngine:
             # its descriptor and it is never donated, so it stays valid.
             self._desc_layout = TickDescriptorLayout(
                 self._ragged_lanes, self._ragged_chunk_cap,
-                self._pages_per_slot, self._latents)
+                self._pages_per_slot, self._latents,
+                recurrent=traits.recurrent_state)
             self._desc_idle_host = self._desc_layout.idle(any_decode=False)
             # both paths hand the jit a device array placed as the pool's
             # own cache and state are (uncommitted, default device): one call
@@ -858,7 +878,7 @@ class ServingEngine:
         compilations (the churn test asserts decode compiles exactly once and
         prefill compiles at most once per bucket)."""
         model, dtype = self.model, self.cache_dtype
-        n_latents = model.max_latents
+        n_latents = self._latents
         # weight serving (serving/quant.py): int8 trees dequantize as the
         # FIRST op of every params-consuming program — the resident tree
         # stays int8, the dequantized copy is a per-execution transient.
@@ -1011,34 +1031,19 @@ class ServingEngine:
 
         @partial(jax.jit, donate_argnums=(0,))
         def quarantine_paged(cache, slot, table_row):
-            # paged containment: zero the condemned slot's SA rows and every
-            # page its table references (trash-padding entries re-zero the
-            # trash page — duplicate scatter indices with identical zero
-            # payloads, deterministic) BEFORE the pages return to the free
-            # list. A normally-evicted page's stale FINITE garbage is safe
-            # for the next tenant (gathered at softmax weight 0), but a NaN
-            # would poison the sum through 0 * NaN — the same reason the
-            # dense quarantine zeroes its rows. O(pages), not O(window *
-            # slots), and only on the containment path. Quantized pools zero
-            # the SCALE sidecars too (reset_page_scales): a NaN that reached
-            # the quantizer lands in the scale, and dequant multiplies every
-            # byte of the page by it — int8 bytes alone are not the poison.
-            ca = cache.ca
-            ca = ca.replace(
-                kp=ca.kp.at[table_row].set(0), vp=ca.vp.at[table_row].set(0)
-            ).reset_page_scales(table_row)
-            return cache.replace(
-                ca=ca,
-                sa=cache.sa.replace(
-                    k=cache.sa.k.at[:, slot].set(0), v=cache.sa.v.at[:, slot].set(0)
-                ),
-            )
+            # paged containment: the cache zeroes the condemned slot's own
+            # rows and every page its table references BEFORE the pages
+            # return to the free list. A normally-evicted page's stale FINITE
+            # garbage is safe for the next tenant (gathered at softmax weight
+            # 0), but a NaN would poison the sum through 0 * NaN — the same
+            # reason the dense quarantine zeroes its rows. O(pages), not
+            # O(window * slots), and only on the containment path.
+            return cache.quarantine_slot(slot, table_row)
 
         # the tick program: the fused ragged tick on a paged engine, the
         # decode step on the dense pool — exactly one of the two is built
         self._jit_ragged_tick = self._jit_decode = None
         if self.paged:
-            cap = self._ragged_chunk_cap
             quantized = self.kv_quant is not None
             unpack = self._desc_layout.unpack
 
@@ -1058,11 +1063,8 @@ class ServingEngine:
                 params = dq(params_)
                 # the tick's work arrives as ONE int32 array: static slices
                 # and bitcasts name its fields (serving/tick_descriptor.py)
-                (any_reset, any_chunk, any_finish, poison_slot, any_decode,
-                 reset_ids, ch_ids, ch_offset, ch_count, ch_latent_start,
-                 ch_tables, fin_active, fin_slot, fin_tables, fin_ids, fin_n,
-                 fin_rng, fin_temp, fin_tk, fin_tp, fin_ds,
-                 fin_pad) = unpack(descriptor)
+                d = unpack(descriptor)
+                poison_slot, any_decode = d.poison, d.any_decode
 
                 if quantized:
                     # quantized split admission: zero the PRIVATE
@@ -1074,75 +1076,25 @@ class ServingEngine:
                     # belong to the cache.
                     with jax.named_scope(TICK_SCOPES["resets"]):
                         cache = jax.lax.cond(
-                            any_reset,
-                            lambda c: c.replace(ca=c.ca.reset_page_scales(reset_ids)),
+                            d.any_reset,
+                            lambda c: c.replace(ca=c.ca.reset_page_scales(d.reset_ids)),
                             lambda c: c, cache,
                         )
 
-                def chunk_phase(cache):
-                    # one SPLIT-prefill chunk a lane (docs/serving.md
-                    # "Chunked prefill"): position-wise KV for prompt tokens
-                    # [offset, offset + count) scattered page-wise through
-                    # the lane's table row — the slot's IN-CACHE table stays
-                    # trash until the finish, so the decode phase cannot
-                    # write into the half-built reservation
-                    def body(cache, lane):
-                        ids, offset, count, lstart, trow = lane
-                        j = jnp.arange(cap)
-                        pos = jnp.clip(offset + j, 0, model.max_seq_len - 1)[None, :]
-                        latent_mask = ((offset + j) >= lstart)[None, :]
-                        k, v = model.apply(params, ids[None, :], pos, latent_mask,
-                                           method=type(model).prefill_chunk_kv)
-                        # inactive lanes (count 0, trash table) deposit zero
-                        # payloads on the trash page — write_rows' padding
-                        # discipline, deterministic
-                        cache = cache.replace(
-                            ca=cache.ca.write_rows(trow, offset, count, k[0], v[0])
-                        )
-                        return cache, None
-
-                    cache, _ = jax.lax.scan(
-                        body, cache,
-                        (ch_ids, ch_offset, ch_count, ch_latent_start, ch_tables),
-                    )
-                    return cache
-
+                # the two prefill phases are the MODEL's (its chunk step and
+                # what ends a prompt: models/core/serving_api.py); the engine
+                # gates each on the tick's flags and hands it the lanes
                 with jax.named_scope(TICK_SCOPES["chunk_lanes"]):
-                    cache = jax.lax.cond(any_chunk, chunk_phase, lambda c: c, cache)
-
-                def finish_phase(carry):
-                    def body(carry, lane):
-                        (active, slot, trow, ids, n, rng,
-                         temp, tk, tp, ds, pad) = lane
-
-                        def fin(args):
-                            # the SPLIT prefill's finish: latents for the
-                            # last max_latents prompt tokens against the
-                            # slot's already-written pages, then the install
-                            # bookkeeping (table, ring offset, SA cache, slot
-                            # state activation)
-                            cache, state = args
-                            req_logits, sa_src = model.apply(
-                                params, ids[None, :], n, cache.ca, trow,
-                                method=type(model).prefill_finish_paged,
-                            )
-                            cache = cache.install_finish(slot, trow, sa_src, n)
-                            state = _install_state(state, slot, req_logits,
-                                                   rng, temp, tk, tp, ds, pad)
-                            return cache, state
-
-                        return jax.lax.cond(active, fin, lambda a: a, carry), None
-
-                    carry, _ = jax.lax.scan(
-                        body, carry,
-                        (fin_active, fin_slot, fin_tables, fin_ids, fin_n,
-                         fin_rng, fin_temp, fin_tk, fin_tp, fin_ds, fin_pad),
-                    )
-                    return carry
+                    cache = jax.lax.cond(
+                        d.any_chunk,
+                        lambda c: model.serving_chunk_phase(params, c, d),
+                        lambda c: c, cache)
 
                 with jax.named_scope(TICK_SCOPES["finish_lanes"]):
                     cache, state = jax.lax.cond(
-                        any_finish, finish_phase, lambda a: a, (cache, state)
+                        d.any_finish,
+                        lambda a: model.serving_finish_phase(params, a[0], a[1], d, _install_state),
+                        lambda a: a, (cache, state)
                     )
                 # serving.nan fault point: after finishes activate their
                 # logits, before decode reads them
@@ -1316,9 +1268,9 @@ class ServingEngine:
         Computed once per request (at submit) and cached on the handle —
         ``load`` walks the queue with it per tick."""
         if request.pages_reserved is None:
-            bucket = self._bucket_for(request.prompt_ids.size)
-            request.pages_reserved = pages_for_request(
-                bucket, request.config.max_new_tokens, self._window, self.kv_page_size
+            n = int(request.prompt_ids.size)
+            request.pages_reserved = self.model.serving_pages(
+                n, request.config.max_new_tokens, self.kv_page_size, self._bucket_for(n)
             )
         return request.pages_reserved
 
@@ -1599,7 +1551,7 @@ class ServingEngine:
             # classic prefill + install programs below, the documented
             # exception: they have no cacheable pages (page keys lie below
             # the latent boundary), so no identity is at stake.
-            if n >= self._latents:
+            if n >= self._traits.split_from:
                 shared_run: List[int] = []
                 if self._prefix_cache is not None and request.page_keys:
                     shared_run = self._prefix_cache.probe(request.page_keys)
@@ -1757,8 +1709,12 @@ class ServingEngine:
                 ids[:c] = request.prompt_ids[task.next_pos: task.next_pos + c]
                 self._tick_chunks.append(
                     (slot, ids, task.next_pos, c,
-                     task.n - self._latents, task.table_row)
+                     self._traits.latent_start(task.n), task.table_row)
                 )
+                if self._traits.recurrent_state:
+                    # the lane starts the slot's state from zero (the first
+                    # chunk after the claim) or carries it on
+                    self.metrics.record_recurrent_chunk(reset=task.next_pos == 0)
             task.next_pos += c
             task.chunks += 1
             if self.chunked:
@@ -2415,12 +2371,20 @@ class ServingEngine:
             v.any_decode[...] = bool(any_decode)
             for i, (_slot, ids_row) in enumerate(self._tick_resets):
                 v.reset_ids[i * P:(i + 1) * P] = ids_row
-            for i, (_slot, ids, off, c, lstart, trow) in enumerate(self._tick_chunks):
+            recurrent = self._traits.recurrent_state
+            for i, (slot, ids, off, c, lstart, trow) in enumerate(self._tick_chunks):
                 v.ch_ids[i] = ids
                 v.ch_offset[i] = off
                 v.ch_count[i] = c
                 v.ch_latent_start[i] = lstart
                 v.ch_tables[i] = trow
+                if recurrent:
+                    # a recurrent model's lane also names the slot whose
+                    # state it carries, zeroed where the prompt starts (such a
+                    # model shares no prefix, so a claimed slot's first chunk
+                    # is the one at position 0)
+                    v.ch_slot[i] = slot
+                    v.ch_reset[i] = off == 0
             for i, (slot, trow, ids_latent, n, rng, sampling) in enumerate(self._tick_finishes):
                 v.fin_active[i] = True
                 v.fin_slot[i] = slot

@@ -441,6 +441,11 @@ class EngineMetrics(_JsonlMetrics):
     # host-to-device transfers of the fused tick's descriptor, per dispatch
     # of the tick program: 0 (the resident decode-only descriptor) or 1
     _tick_transfer_counts: Deque[int] = field(default_factory=lambda: deque(maxlen=LATENCY_WINDOW))
+    # recurrent-state gauges: None <=> the served model keeps no recurrent
+    # state beside its pages and snapshots report recurrent_state: None
+    recurrent_state_bytes: Optional[int] = None
+    recurrent_resets: int = 0
+    recurrent_chunks_carried: int = 0
     _start_time: Optional[float] = None
     _occupancy_sum: float = 0.0  # sum over steps of active_slots / num_slots
     _pages_per_request: Deque[int] = field(default_factory=lambda: deque(maxlen=LATENCY_WINDOW))
@@ -561,6 +566,22 @@ class EngineMetrics(_JsonlMetrics):
         self.agreement_matched += int(matched)
         self.agreement_tokens += int(total)
         self._emit("quant_agreement", matched=int(matched), total=int(total))
+
+    def set_recurrent_state(self, state_bytes: int) -> None:
+        """Mark a model whose every slot holds a recurrent state of fixed size
+        beside its pages (docs/serving.md "A slot's two kinds of state"):
+        snapshots report the recurrent_state section instead of None.
+        ``state_bytes``: what the pool's recurrent states take on the device."""
+        self.recurrent_state_bytes = int(state_bytes)
+
+    def record_recurrent_chunk(self, reset: bool) -> None:
+        """One chunk lane of a recurrent model: it either starts its slot's
+        state from zero (the slot was just claimed) or carries it on from the
+        chunk before. Counters only: this fires a lane, not a request."""
+        if reset:
+            self.recurrent_resets += 1
+        else:
+            self.recurrent_chunks_carried += 1
 
     def set_ragged_tick(self, enabled: bool) -> None:
         """Mark a paged engine's tick dispatcher (serving-metrics/v11):
@@ -812,6 +833,21 @@ class EngineMetrics(_JsonlMetrics):
             # engine truthfully has no process boundary (same reading as a
             # pre-v12 snapshot)
             "transport": None,
+            # None unless the served model keeps a recurrent state in each
+            # slot: the pool's state bytes, how many slots hold one, how many
+            # chunk lanes zeroed a just-claimed slot's state (resets) or
+            # carried one on across a chunk boundary, and how many slots a
+            # tick decodes (each has its state read and written whole)
+            "recurrent_state": None if self.recurrent_state_bytes is None else {
+                "bytes": self.recurrent_state_bytes,
+                "slots": self.num_slots,
+                "resets": self.recurrent_resets,
+                "chunks_carried": self.recurrent_chunks_carried,
+                "decoding_slots": {
+                    k: v for k, v in _latency_dict(self._tick_decode_counts).items()
+                    if k in ("mean",) + _PERCENTILE_KEYS
+                },
+            },
             # v11: None on dense engines (no tick dispatcher exists — same
             # reading as a pre-v11 snapshot); on paged engines the per-tick
             # program/work gauges, whichever dispatcher is live

@@ -55,20 +55,32 @@ _FIELDS = (
     ("fin_pad", ("lanes",), _I32),
 )
 
+# what a chunk lane carries besides, for a model whose slots hold a recurrent
+# state (models/core/serving_api.py): the slot whose state the lane carries on,
+# and whether it starts from zero (the slot was just claimed). They follow the
+# fields above, so a model without them keeps its layout word for word
+_RECURRENT_FIELDS = (
+    ("ch_slot", ("lanes",), _I32),
+    ("ch_reset", ("lanes",), bool),
+)
+
 # the descriptor's fields by name, in word order (header first)
 TickFields = namedtuple("TickFields", [name for name, _, _ in _FIELDS])
+RecurrentTickFields = namedtuple("RecurrentTickFields", [name for name, _, _ in _FIELDS + _RECURRENT_FIELDS])
 
 
 class TickDescriptorLayout:
     """Offsets, shapes and dtypes of one engine's descriptor."""
 
-    def __init__(self, lanes: int, cap: int, pages_per_slot: int, latents: int):
+    def __init__(self, lanes: int, cap: int, pages_per_slot: int, latents: int,
+                 recurrent: bool = False):
         sizes = {"lanes": lanes, "cap": cap, "pages": pages_per_slot,
                  "latents": latents, "lanes*pages": lanes * pages_per_slot}
+        self._tuple = RecurrentTickFields if recurrent else TickFields
         # name -> (its words, shape, dtype the program sees)
         self.fields: Dict[str, Tuple[slice, tuple, type]] = {}
         offset = 0
-        for name, dims, dtype in _FIELDS:
+        for name, dims, dtype in _FIELDS + (_RECURRENT_FIELDS if recurrent else ()):
             shape = tuple(sizes.get(d, d) for d in dims)
             words = int(np.prod(shape, dtype=np.int64))
             self.fields[name] = (slice(offset, offset + words), shape, dtype)
@@ -79,7 +91,7 @@ class TickDescriptorLayout:
         """Writable numpy views of ``buf``'s fields, each in its own dtype
         and shape (scalars 0-d: assign through ``[...]``; bool fields are
         int32 words holding 0 / 1)."""
-        return TickFields(**{
+        return self._tuple(**{
             name: buf[words].view(np.int32 if dtype is bool else dtype).reshape(shape)
             for name, (words, shape, dtype) in self.fields.items()
         })
@@ -106,4 +118,4 @@ class TickDescriptorLayout:
             elif dtype is not np.int32:
                 field = jax.lax.bitcast_convert_type(field, jnp.dtype(dtype))
             out[name] = field
-        return TickFields(**out)
+        return self._tuple(**out)

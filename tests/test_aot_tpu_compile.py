@@ -20,6 +20,7 @@ import pytest
 from perceiver_io_tpu.ops import decode_kernel as dk
 from perceiver_io_tpu.ops import paged_decode_kernel as pdk
 from perceiver_io_tpu.ops import ragged_paged_kernel as rpk
+from perceiver_io_tpu.ops import ssm
 from perceiver_io_tpu.ops.flash import splash_mha
 
 FLAGSHIP = (10, 128)  # heads x head width: 455M C4 Perceiver AR
@@ -114,7 +115,35 @@ def _splash_fwd_bwd(sds, heads_width, n_q, n_k):
     return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv)
 
 
+def _gqa(sds, q_heads, kv_heads, width, layers, slots, pages, page, window):
+    """The hybrid decoder's grouped-query paged decode: the pool stacks the
+    layers under one page table, eight pages of a slot a grid step."""
+    pool = sds((layers, pages, page, kv_heads * width), jnp.bfloat16)
+    return jax.jit(lambda q, kp, vp, table, length: pdk.fused_paged_decode_attention_gqa(
+        q, kp, vp, table, length, layers - 1)).lower(
+        sds((slots, q_heads, width), jnp.bfloat16), pool, pool,
+        sds((slots, -(-window // page)), jnp.int32), sds((slots,), jnp.int32))
+
+
+def _ssm_update(sds, heads, groups, head_dim, state, layers, slots):
+    """The decode step's state update over the whole pool's float32 state,
+    aliased in place (2.15 GB at the hybrid cell's 128 slots)."""
+    f32 = jnp.float32
+    return jax.jit(
+        lambda s, x, dt, a, b, c, active: ssm.ssm_decode_update(s, layers - 1, x, dt, a, b, c, active),
+        donate_argnums=(0,),
+    ).lower(
+        sds((layers, slots, heads, head_dim, state), f32), sds((slots, heads, head_dim), f32),
+        sds((slots, heads), f32), sds((heads,), f32), sds((slots, groups, state), f32),
+        sds((slots, groups, state), f32), sds((slots,), jnp.bool_))
+
+
 CASES = {
+    # Falcon-H1-34B's widths at the serving cell's sizes: 20 query heads over 4
+    # K/V heads of 128, 128 slots, 3072 pages of 64 under a 1536-token row; 32
+    # mixer heads of 128 x state 256 in 2 groups, 4 layers
+    "gqa-paged-20over4x128-l4-b128-w1536": (_gqa, 20, 4, 128, 4, 128, 3072, 64, 1536),
+    "ssm-update-32x128x256-g2-l4-b128": (_ssm_update, 32, 2, 128, 256, 4, 128),
     "paged-fp-10x128-w1024": (_paged, FLAGSHIP, 1024, 64, "fp"),
     "paged-fp-10x128-w512": (_paged, FLAGSHIP, 512, 64, "fp"),
     "paged-int8-10x128-w1024": (_paged, FLAGSHIP, 1024, 64, "int8"),
