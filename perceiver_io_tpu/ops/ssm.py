@@ -22,8 +22,9 @@ with ``x_t`` (P,) the head's slice of the convolved input and ``B_t``, ``C_t``
     a Pallas kernel that reads each decoding slot's state once and writes it
     once IN PLACE (the pool array is aliased to the output), and leaves every
     other slot's state as it is through a scalar-prefetched live list: free
-    slots and slots in the middle of their prefill are not computed on (what
-    their grid steps still cost: ``live_list``). ``ssm_decode_
+    slots and slots in the middle of their prefill are not computed on, and
+    their grid steps move no bytes (``_step_block``), so the kernel's time
+    follows the slots that decode, not the pool's size. ``ssm_decode_
     update_xla`` is the same arithmetic in ``jax.numpy`` for backends without
     Mosaic (the CPU suite); ``ssm_kernel_supported`` picks between them from
     the platform and the shapes, never from a switch.
@@ -115,13 +116,9 @@ def ssm_recurrence(
 def live_list(active: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """(live (S,), n_live (1,)) of a pool's ``active`` (S,) flags: the decoding
     slots first, in slot order, then the first of the others repeated: a grid
-    step past ``n_live`` rests on that one idle slot, whose state is passed
-    through unchanged (the kernel copies it at step ``n_live``), so the pool is
-    sound whatever ``n_live`` is, zero included. MEASURED (PERF.md 7.12, PR
-    32): the idle steps do not come free. The head-block index still alternates
-    on them, so the idle slot's two blocks are fetched and written back in
-    turn, step after step, and the kernel moves as many bytes as if every slot
-    decoded; pinning that index where ``w >= n_live`` is the known cure."""
+    step past ``n_live`` rests on that one idle slot, whose first head block is
+    passed through unchanged (the kernel copies it once, at step ``n_live``),
+    so the pool is sound whatever ``n_live`` is, zero included."""
     s = active.shape[0]
     n_live = jnp.sum(active.astype(jnp.int32))
     order = jnp.argsort(~active, stable=True).astype(jnp.int32)
@@ -165,8 +162,20 @@ def _head_block(num_heads: int, num_groups: int) -> int:
     return block
 
 
+def _step_block(w, j, live, n_live):
+    """(slot, head block) that grid step ``(w, j)`` holds: block ``j`` of slot
+    ``live[w]`` while ``w < n_live``, and ONE block, the idle slot's first,
+    on every step after, whatever ``j`` is. The pipeline fetches a block and
+    writes the last one back only where this index differs from the step
+    before, so the steps past the live list move nothing; were ``j`` let
+    through on them, the idle slot's blocks would be fetched and written back
+    in turn and the kernel would move the whole pool's bytes whatever decoded
+    (measured: PERF.md 6, PR 33)."""
+    return live[w], jnp.where(w < n_live[0], j, 0)
+
+
 def _ssm_kernel(live_ref, n_live_ref, s_ref, scale_ref, b_ref, c_ref, eye_ref, so_ref, y_ref):
-    """Grid (S, H // hb); step (w, j) holds block j of slot ``live[w]``.
+    """Grid (S, H // hb); step (w, j) holds the block ``_step_block`` names.
 
     s_ref / so_ref (hb, P, N)  the slot's state, in and (aliased) out
     scale_ref (2, hb, P)       row 0: exp(dt A), row 1: dt x, per head, P on lanes
@@ -187,9 +196,10 @@ def _ssm_kernel(live_ref, n_live_ref, s_ref, scale_ref, b_ref, c_ref, eye_ref, s
     nt = (((1,), (1,)), ((), ()))
     hb = s_ref.shape[0]
 
-    @pl.when(pl.program_id(0) == n_live_ref[0])
+    @pl.when((pl.program_id(0) == n_live_ref[0]) & (pl.program_id(1) == 0))
     def _pass_through():
-        # the one idle slot the steps past the live list rest on
+        # the one block the steps past the live list rest on: no later step
+        # writes the output buffer, so this copy is what the last write-back holds
         so_ref[...] = s_ref[...]
 
     @pl.when(pl.program_id(0) < n_live_ref[0])
@@ -231,20 +241,22 @@ def ssm_decode_update(
                        dt[..., None] * x], axis=1)  # (S, 2, H, P)
     live, n_live = live_list(active)
 
+    def at(place):  # the index map that puts ``place(slot, block)`` at the step's block
+        return lambda *step: place(*_step_block(*step))
+
+    state_spec = pl.BlockSpec((None, None, hb, p, n), at(lambda slot, blk: (layer, slot, blk, 0, 0)))
+    group_spec = pl.BlockSpec((None, None, 1, n), at(lambda slot, blk: (slot, blk * hb // per_group, 0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(s, h // hb),
         in_specs=[
-            pl.BlockSpec((None, None, hb, p, n), lambda w, j, lr, nr: (layer, lr[w], j, 0, 0)),
-            pl.BlockSpec((None, 2, hb, p), lambda w, j, lr, nr: (lr[w], 0, j, 0)),
-            pl.BlockSpec((None, None, 1, n), lambda w, j, lr, nr: (lr[w], j * hb // per_group, 0, 0)),
-            pl.BlockSpec((None, None, 1, n), lambda w, j, lr, nr: (lr[w], j * hb // per_group, 0, 0)),
+            state_spec,
+            pl.BlockSpec((None, 2, hb, p), at(lambda slot, blk: (slot, 0, blk, 0))),
+            group_spec,
+            group_spec,
             pl.BlockSpec((p, p), lambda w, j, lr, nr: (0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((None, None, hb, p, n), lambda w, j, lr, nr: (layer, lr[w], j, 0, 0)),
-            pl.BlockSpec((None, hb, p), lambda w, j, lr, nr: (lr[w], j, 0)),
-        ],
+        out_specs=[state_spec, pl.BlockSpec((None, hb, p), at(lambda slot, blk: (slot, blk, 0)))],
     )
     new_state, y = pl.pallas_call(
         _ssm_kernel,

@@ -109,14 +109,20 @@ def test_a_row_with_zero_dt_leaves_the_state_alone():
     np.testing.assert_allclose(np.asarray(padded), np.asarray(short), atol=TOL, rtol=0)
 
 
-@pytest.mark.parametrize("active", [[True, False, True, True, False], [False] * 5, [True] * 5, [False] * 4 + [True]])
-def test_ssm_decode_update_kernel_equals_its_xla_form(active):
-    layers, slots, h, p, g, n = 2, 5, 4, 16, 2, 16
+ACTIVE = {"mixed": [True, False, True, True, False], "none": [False] * 5, "all": [True] * 5,
+          "only-last": [False] * 4 + [True], "all-but-last": [True] * 4 + [False]}
+
+
+@pytest.mark.parametrize("active", sorted(ACTIVE))
+@pytest.mark.parametrize("g", [1, 2, 4])  # 4 heads in g groups: one, two and four head blocks a slot
+def test_ssm_decode_update_kernel_equals_its_xla_form(g, active):
+    layers, slots, h, p, n = 2, 5, 4, 16, 16
+    assert h // ssm._head_block(h, g) == g
     ks = jax.random.split(jax.random.PRNGKey(7), 6)
     state = jax.random.normal(ks[0], (layers, slots, h, p, n))
     x, b, c = (jax.random.normal(k, s) for k, s in zip(ks[1:], [(slots, h, p), (slots, g, n), (slots, g, n)]))
     dt, a = jax.nn.softplus(jax.random.normal(ks[4], (slots, h))), -jnp.exp(jax.random.normal(ks[5], (h,)))
-    active = jnp.asarray(active)
+    active = jnp.asarray(ACTIVE[active])
     s_want, y_want = ssm.ssm_decode_update_xla(state, 1, x, dt, a, b, c, active)
     s_got, y_got = ssm.ssm_decode_update(state, 1, x, dt, a, b, c, active, interpret=True)
     np.testing.assert_allclose(np.asarray(s_got), np.asarray(s_want), atol=TOL, rtol=0)
@@ -126,6 +132,37 @@ def test_ssm_decode_update_kernel_equals_its_xla_form(active):
     assert np.array_equal(np.asarray(s_got[0]), np.asarray(state[0]))
     assert np.array_equal(np.asarray(s_got[1])[idle], np.asarray(state[1])[idle])
     assert not np.asarray(y_got)[idle].any()
+
+
+@pytest.mark.parametrize("blocks", [1, 2, 4])
+@pytest.mark.parametrize("n_live", [0, 1, 3, 15, 16])  # of S = 16 slots
+def test_ssm_decode_update_fetches_a_block_only_for_a_slot_that_decodes(n_live, blocks):
+    """A COUNT of what the kernel's pipeline will move, never a time: walk the grid
+    (S, blocks) in its order through the kernel's own index map (``ssm._step_block``) with
+    ``live_list``'s output, and count the steps whose state block differs from the step
+    before; only those fetch a block and write one back. Every (decoding slot, head block)
+    is held exactly once, and the steps past the live list add at most ONE block, the one
+    they rest on. On the parent's maps ``(live[w], j)`` the same walk counts ``S x blocks``
+    whenever a slot is idle and there is more than one head block, whatever ``n_live`` is:
+    ``j`` went on alternating over the idle slot's blocks."""
+    slots = 16
+    active = np.zeros(slots, bool)
+    active[np.random.default_rng(n_live).permutation(slots)[:n_live]] = True
+    live, count = ssm.live_list(jnp.asarray(active))
+    assert int(count[0]) == n_live
+    w, j = np.repeat(np.arange(slots), blocks), np.tile(np.arange(blocks), slots)
+    slot, block = (np.asarray(v) for v in ssm._step_block(w, j, live, count))
+
+    def fetched(held):  # the steps whose block differs from the step before
+        return [held[0]] + [now for before, now in zip(held, held[1:]) if now != before]
+
+    moved = fetched(list(zip(slot.tolist(), block.tolist())))
+    decoding = [(s, k) for s in np.flatnonzero(active).tolist() for k in range(blocks)]
+    assert moved[:len(decoding)] == decoding  # in slot order, each block once
+    rest = moved[len(decoding):]
+    assert len(rest) == (n_live < slots) and all(not active[s] and k == 0 for s, k in rest)
+    if blocks > 1 and n_live < slots:  # what the parent's maps would have moved
+        assert len(fetched(list(zip(np.asarray(live)[w].tolist(), j.tolist())))) == slots * blocks
 
 
 def test_grouped_query_paged_decode_equals_plain_attention():
