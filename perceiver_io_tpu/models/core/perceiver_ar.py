@@ -148,7 +148,9 @@ class PagedPerceiverARCache(flax.struct.PyTreeNode):
     offset ``ca.start`` — there is no pad-slot buffer and no shared length.
     The self-attention ring rests on the same invariant: every slot holds
     ``max_latents`` latents from its install on (a prefill always yields that
-    many) and appends one per decode step, so all its rows are always visible.
+    many) and appends one per decode step, so all its rows are always visible;
+    a slot that holds no installed request is not read at all (``sa.active``,
+    equal to the engine's ``SlotState.active``).
     """
 
     ca: PagedKVCache
@@ -283,19 +285,21 @@ class PagedPerceiverARCache(flax.struct.PyTreeNode):
 
     def release_slot(self, slot: jax.Array) -> "PagedPerceiverARCache":
         """Reset slot ``slot`` to the free canonical form: page table entries
-        all trash (page 0), ring offset 0, live pinned at the full window
-        (free rows decode discarded garbage exactly like the dense pool's
-        free slots; their self-attention ring keeps turning, its rows and
-        offset replaced whole by the next install). CRITICAL for
-        correctness, not just hygiene: a freed slot keeps decoding every
-        tick, and a stale table entry would route its writes into a page
-        since reallocated to a live request."""
+        all trash (page 0), ring offset 0, live pinned at the full window,
+        the self-attention ring no longer read (``RingKVCache.active``; it
+        keeps turning, its rows and offset replaced whole by the next
+        install). CRITICAL for correctness, not just hygiene: a freed slot
+        stays a row of every decode step — it embeds its pad token, appends
+        to the trash page and to its ring, and its output is discarded — and
+        a stale table entry would route its writes into a page since
+        reallocated to a live request."""
         p = self.ca.pages_per_slot
         return self.replace(
             ca=self.ca.replace(
                 page_table=self.ca.page_table.at[slot].set(jnp.zeros((p,), jnp.int32)),
                 start=self.ca.start.at[slot].set(0),
             ),
+            sa=self.sa.replace(active=self.sa.active.at[slot].set(False)),
             shift=self.shift.at[slot].set(0),
             live=self.live.at[slot].set(self.ca.window),
         )

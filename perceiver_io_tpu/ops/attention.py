@@ -125,13 +125,18 @@ class RingKVCache(flax.struct.PyTreeNode):
     ``start``: (B,) int32 ring offset per slot, as ``PagedKVCache.start``: the
         NEXT append writes physical row ``start``; physical row ``r`` holds
         logical latent ``(r - start) mod capacity`` (0 = oldest).
+    ``active``: (B,) bool, the slot holds an installed request: set by
+        ``write_batch_row``, cleared by the pool's ``release_slot``, so it
+        equals the engine's ``SlotState.active`` after every program that
+        changes either. Only an active slot's ring is READ.
     ``layer``: the layer a view addresses; set by ``SelfAttentionBlock``'s
         layer loop (which carries the stacked buffers), None outside it.
 
     The pool's invariant is what makes the ring exact: every row is FULL at
-    all times and every slot appends once per decode step, free slots too.
-    The one query therefore sees all ``capacity`` rows (no validity bound, no
-    pad mask) and an append is one row a slot a layer; nothing is shifted.
+    all times and every slot appends once per decode step, free slots too
+    (their rings turn; nothing reads them). An active slot's one query
+    therefore sees all ``capacity`` rows (no validity bound, no pad mask) and
+    an append is one row a slot a layer; nothing is shifted.
     ``KVCache`` (left-aligned, shared scalar length, rolled when full) stays
     the cache of ``generate()``, prefill and the dense engine pool.
     """
@@ -139,6 +144,7 @@ class RingKVCache(flax.struct.PyTreeNode):
     k: jax.Array
     v: jax.Array
     start: jax.Array
+    active: jax.Array
     layer: Optional[jax.Array] = None
 
     @property
@@ -153,6 +159,7 @@ class RingKVCache(flax.struct.PyTreeNode):
             k=jnp.zeros((num_layers, batch_size, capacity, num_qk_channels), dtype=dtype),
             v=jnp.zeros((num_layers, batch_size, capacity, num_v_channels), dtype=dtype),
             start=jnp.zeros((batch_size,), dtype=jnp.int32),
+            active=jnp.zeros((batch_size,), dtype=bool),
         )
 
     def append_row(self, k_new: jax.Array, v_new: jax.Array) -> "RingKVCache":
@@ -180,11 +187,12 @@ class RingKVCache(flax.struct.PyTreeNode):
     def write_batch_row(self, idx: jax.Array, src: KVCache) -> "RingKVCache":
         """Install slot ``idx`` (traced OK) from a FULL stacked ``KVCache`` of
         batch 1 in age order (what a prefill leaves): a plain row write, with
-        the slot's ring restarting at 0."""
+        the slot's ring restarting at 0 and read from now on (``active``)."""
         return self.replace(
             k=jax.lax.dynamic_update_slice_in_dim(self.k, src.k.astype(self.k.dtype), idx, axis=1),
             v=jax.lax.dynamic_update_slice_in_dim(self.v, src.v.astype(self.v.dtype), idx, axis=1),
             start=self.start.at[idx].set(0),
+            active=self.active.at[idx].set(True),
         )
 
 
@@ -359,9 +367,14 @@ class MultiHeadAttention(nn.Module):
         UNSPLIT (B, 1, C) projections of the new token. The append writes one
         row a slot into the stacked buffer; the fused kernel then reads the
         layer where it lies (its stacked form), else the XLA formulation
-        reads it through one slice. Every row is visible: the ring is full and
-        the query is its newest entry, so there is no mask on this path and
-        ``rope_k`` carries the order (angles per PHYSICAL row)."""
+        reads it through one slice. In an ACTIVE slot every row is visible:
+        the ring is full and the query is its newest entry, so there is no
+        mask on this path and ``rope_k`` carries the order (angles per
+        PHYSICAL row). A slot that is not active (free, or in the middle of
+        its prefill) has its row appended like the others and its ring left
+        unread: the kernel is told ``live = 0`` for it, moves no bytes and
+        returns zeros (ops/decode_kernel.py ``_step_block``). The XLA
+        formulation computes every slot; nothing harvests the others' rows."""
         from perceiver_io_tpu.ops.decode_kernel import decode_kernel_supported, fused_decode_attention_auto
 
         b, n_q = q.shape[0], q.shape[1]
@@ -389,7 +402,7 @@ class MultiHeadAttention(nn.Module):
                     ang = jnp.broadcast_to(ang, (b, *ang.shape[1:]))
                 o = fused_decode_attention_auto(
                     q, kv_cache.k, kv_cache.v, ang, cap - 1, jnp.zeros((b, cap), bool),
-                    layer=kv_cache.layer,
+                    live=jnp.where(kv_cache.active, cap, 0), layer=kv_cache.layer,
                 )
             else:
                 take = lambda buf: jax.lax.dynamic_index_in_dim(buf, kv_cache.layer, axis=0, keepdims=False)

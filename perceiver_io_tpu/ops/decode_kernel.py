@@ -167,7 +167,43 @@ def _head_expander(h: int, d: int):
     return np.kron(np.eye(h, dtype=np.float32), np.ones((1, d), np.float32))
 
 
-def _kernel(qpos_ref, live_ref, layer_ref, qq_ref, k_ref, v_ref, ang_ref, pad_ref, exp_ref, o_ref, m_ref, l_ref, acc_ref):
+def _fetch_rows(live: jax.Array) -> jax.Array:
+    """(B,) int32: for each row, the row whose blocks its grid steps hold — the
+    row itself where ``live > 0``; for a row that reads nothing (``live == 0``)
+    the NEXT row that reads, so that the pipeline fetches that row's first
+    block while the reading row before is still being computed (resting on the
+    row before instead leaves the fetch to the one short idle step ahead of the
+    next reading row, where nothing hides it: measured, PERF.md 6, PR 37);
+    past the last reading row, that last one (row 0 when no row reads at all)."""
+    reads = live > 0
+    rows = jnp.arange(live.shape[0])
+    ahead = jax.lax.cummin(jnp.where(reads, rows, live.shape[0]), reverse=True)
+    behind = jax.lax.cummax(jnp.where(reads, rows, 0))
+    return jnp.where(ahead < live.shape[0], ahead, behind).astype(jnp.int32)
+
+
+def _step_block(bi, i, qpos, live, fetch_row, nblocks: int, blk: int):
+    """(row, KV block) whose keys, values, angles and pad mask grid step
+    ``(bi, i)`` holds (the query planes follow the row). The pipeline fetches
+    an operand's block only where its index differs from the step before, so
+    what a step names here is what it pays for:
+
+    * a row with ``live > 0`` names its own blocks, a dead head block (entirely
+      below the live tail) aliasing the first live one: bytes follow the live
+      entries;
+    * a row with ``live == 0`` names ONE block, whatever ``i`` is: the first
+      live block of the next reading row (fetched once, ahead of time, and
+      found in place by that row's own first step), or, past the last reading
+      row, that row's LAST block (what the step before held). Its steps add no
+      bytes to what the reading rows move.
+
+    Counted by walking the grid on the CPU (tests/test_decode_kernel.py)."""
+    row = fetch_row[bi]
+    first = jnp.clip((qpos[row] + 1 - live[row]) // blk, 0, nblocks - 1)  # first live block of ``row``
+    return row, jnp.where(row == bi, jnp.maximum(i, first), jnp.where(row < bi, nblocks - 1, first))
+
+
+def _kernel(qpos_ref, live_ref, fetch_ref, layer_ref, qq_ref, k_ref, v_ref, ang_ref, pad_ref, exp_ref, o_ref, m_ref, l_ref, acc_ref):
     """Grid (B, num_blocks); block i covers cache slots [i*blk, (i+1)*blk).
 
     qpos_ref (B,)            absolute position of the LAST query (scalar-prefetch, SMEM)
@@ -176,6 +212,12 @@ def _kernel(qpos_ref, live_ref, layer_ref, qq_ref, k_ref, v_ref, ang_ref, pad_re
                              entirely below it are dead: their grid steps alias the
                              first live block in the index maps (no new DMA) and
                              skip all compute — the ragged length-aware early exit.
+                             A row with ``live == 0`` reads NOTHING: its steps name
+                             a block some reading row needs anyway (``_step_block``),
+                             run no block, and its output row is stored as zeros — the
+                             serving pool's free and half-prefilled slots cost the
+                             kernel neither bytes nor compute.
+    fetch_ref (B,)           ``_fetch_rows(live)``; read by the index maps alone
     layer_ref (1,)           which layer of a stacked cache the K/V blocks come
                              from (scalar-prefetch); read by the K/V index map alone
     qq_ref   (2, h*d, n_q*h) block-diagonal scaled+rotated queries q and their
@@ -210,7 +252,9 @@ def _kernel(qpos_ref, live_ref, layer_ref, qq_ref, k_ref, v_ref, ang_ref, pad_re
     n_q = qq_ref.shape[2] // h
     contract = (((1,), (0,)), ((), ()))
 
-    @pl.when(i == 0)
+    reads = live_ref[bi] > 0  # else the blocks in VMEM are another row's
+
+    @pl.when(reads & (i == 0))
     def _init():
         m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[:] = jnp.zeros_like(l_ref)
@@ -220,7 +264,7 @@ def _kernel(qpos_ref, live_ref, layer_ref, qq_ref, k_ref, v_ref, ang_ref, pad_re
     live_lo = q_last + 1 - live_ref[bi]  # first live slot (== pad count for full rows)
     dead = jnp.maximum(live_lo // blk, 0)  # fully-dead head blocks
 
-    @pl.when(i >= dead)
+    @pl.when(reads & (i >= dead))
     def _compute():
         sc_all = _rotary_scores(
             k_ref[0].astype(jnp.float32), ang_ref[0].astype(jnp.float32), qq_ref, h
@@ -248,7 +292,11 @@ def _kernel(qpos_ref, live_ref, layer_ref, qq_ref, k_ref, v_ref, ang_ref, pad_re
             l_ref[qi : qi + 1, :h] = l_ref[qi : qi + 1, :h] * scale + jnp.sum(prob, axis=0, keepdims=True)
             acc_ref[qi : qi + 1, :] = acc_ref[qi : qi + 1, :] * scale_x + pv
 
-    @pl.when(i == nblocks - 1)
+    @pl.when(~reads & (i == nblocks - 1))
+    def _nothing_read():
+        o_ref[...] = jnp.zeros_like(o_ref)  # what an all-masked row finalizes to
+
+    @pl.when(reads & (i == nblocks - 1))
     def _finalize():
         rows = []
         for qi in range(n_q):
@@ -333,7 +381,9 @@ def fused_decode_attention(
     live region is the tail [q_pos+1-live, q_pos+1); KV blocks entirely below
     it are skipped (no compute, no fresh DMA). Callers keep ``live``
     consistent with ``pad_slots`` (live = valid minus pad slots); None means
-    fully live. Returns (B, H, n_q, D).
+    fully live. A row with ``live == 0`` is not read at all (no bytes, no
+    compute: ``_step_block``) and comes back zeros, so the kernel's time
+    follows the rows that read, not the batch. Returns (B, H, n_q, D).
 
     STACKED form: with ``layer`` (a scalar, traced OK) k/v_cache are the
     per-layer caches stacked as (L, B, cap, H*D) and the kernel reads layer
@@ -364,21 +414,20 @@ def fused_decode_attention(
         if live is not None else q_pos_arr + 1  # full live region: no skipping
     )
 
-    def _slot_map(bi, i, qpos_ref, live_ref, layer_ref):
-        # dead head blocks alias the first (possibly) live block: consecutive
-        # equal indices elide the DMA, so HBM traffic scales with live tokens
-        # (clamped into range — live = 0 rows have no live block at all)
-        dead = jnp.maximum((qpos_ref[bi] + 1 - live_ref[bi]) // blk, 0)
-        return (bi, jnp.minimum(jnp.maximum(i, dead), nblocks - 1), 0)
+    def _slot_map(bi, i, qpos_ref, live_ref, fetch_ref, layer_ref):
+        return (*_step_block(bi, i, qpos_ref, live_ref, fetch_ref, nblocks, blk), 0)
 
-    def _kv_map(bi, i, qpos_ref, live_ref, layer_ref):
-        return (layer_ref[0], *_slot_map(bi, i, qpos_ref, live_ref, layer_ref))
+    def _kv_map(bi, i, qpos_ref, live_ref, fetch_ref, layer_ref):
+        return (layer_ref[0], *_slot_map(bi, i, qpos_ref, live_ref, fetch_ref, layer_ref))
+
+    def _query_map(bi, i, qpos_ref, live_ref, fetch_ref, layer_ref):
+        return (fetch_ref[bi], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(b, nblocks),
         in_specs=[
-            pl.BlockSpec((None, 2, h * d, n_q * h), lambda bi, i, *_: (bi, 0, 0, 0)),
+            pl.BlockSpec((None, 2, h * d, n_q * h), _query_map),
             pl.BlockSpec((None, 1, blk, h * d), _kv_map),
             pl.BlockSpec((None, 1, blk, h * d), _kv_map),
             pl.BlockSpec((1, blk, r), _slot_map),
@@ -400,6 +449,7 @@ def fused_decode_attention(
     )(
         q_pos_arr,
         live_arr,
+        _fetch_rows(live_arr),
         jnp.asarray(layer, jnp.int32).reshape(1),
         _blockdiag_queries(q, r),
         k_cache,

@@ -959,8 +959,9 @@ class ServingEngine:
         @partial(jax.jit, donate_argnums=(0,))
         def release_pages(cache, slot):
             # paged eviction's device half: table row -> trash page, ring
-            # offset 0, live pinned full (the free-slot canonical form). NOT
-            # hygiene — a freed slot keeps decoding, and a stale table entry
+            # offset 0, live pinned full, the slot's self-attention ring no
+            # longer read (the free-slot canonical form). NOT
+            # hygiene — a freed slot goes on appending, and a stale table entry
             # would route its writes into a page since handed to a new
             # tenant. The page CONTENTS are untouched: returning ids to the
             # free list replaces the dense path's O(window) row zeroing.
@@ -1826,7 +1827,7 @@ class ServingEngine:
         self._state = self._jit_release(self._state, slot)
         if self.paged:
             # paged eviction: reset the slot's table to the trash page on
-            # device (a freed slot keeps decoding — stale entries would
+            # device (a freed slot goes on appending — stale entries would
             # corrupt reallocated pages) and return the ids to the free
             # list. No O(window) row zeroing — that is the point. A SHARED
             # page's release only drops this slot's reference: the prefix

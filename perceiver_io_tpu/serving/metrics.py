@@ -136,8 +136,10 @@ Schema history:
     ``None``. Added since, without a version of their own (additive keys):
     ``resident_descriptor_tick_pct`` (share of the fused tick's dispatches,
     over the latency window, that passed the device-resident decode-only
-    descriptor; ``None`` before the first) and ``descriptor_transfers``
-    p50/p95 (host-to-device transfers of the descriptor a tick: 0 or 1).
+    descriptor; ``None`` before the first), ``descriptor_transfers``
+    p50/p95 (host-to-device transfers of the descriptor a tick: 0 or 1),
+    ``slots`` (the pool's size) and ``decoding_slots`` mean/p50/p95 (the
+    slots a tick decodes: what the decode kernels' time follows).
   * ``serving-metrics/v12`` — the out-of-process-replica schema
     (docs/serving.md "Out-of-process replicas"): every snapshot carries a
     ``transport`` field — ``None`` on plain engines and on in-process
@@ -754,6 +756,10 @@ class EngineMetrics(_JsonlMetrics):
 
     def snapshot(self) -> Dict:
         wall = (time.perf_counter() - self._start_time) if self._start_time else 0.0
+        decoding_slots = {  # slots a dispatching tick decodes, latency window
+            k: v for k, v in _latency_dict(self._tick_decode_counts).items()
+            if k in ("mean",) + _PERCENTILE_KEYS
+        }
         snap = {
             "schema": SCHEMA,
             "num_slots": self.num_slots,
@@ -843,10 +849,7 @@ class EngineMetrics(_JsonlMetrics):
                 "slots": self.num_slots,
                 "resets": self.recurrent_resets,
                 "chunks_carried": self.recurrent_chunks_carried,
-                "decoding_slots": {
-                    k: v for k, v in _latency_dict(self._tick_decode_counts).items()
-                    if k in ("mean",) + _PERCENTILE_KEYS
-                },
+                "decoding_slots": decoding_slots,
             },
             # v11: None on dense engines (no tick dispatcher exists — same
             # reading as a pre-v11 snapshot); on paged engines the per-tick
@@ -866,10 +869,11 @@ class EngineMetrics(_JsonlMetrics):
                     k: v for k, v in _latency_dict(self._tick_finish_counts).items()
                     if k in _PERCENTILE_KEYS
                 },
-                "decode_items": {
-                    k: v for k, v in _latency_dict(self._tick_decode_counts).items()
-                    if k in _PERCENTILE_KEYS
-                },
+                "decode_items": {k: decoding_slots[k] for k in _PERCENTILE_KEYS},
+                # the pool's size and the slots a tick decodes (mean too):
+                # only those cost the decode kernels bytes or compute
+                "slots": self.num_slots,
+                "decoding_slots": decoding_slots,
                 "descriptor_build_s": {
                     k: v for k, v in _latency_dict(self._tick_build_times).items()
                     if k in _PERCENTILE_KEYS

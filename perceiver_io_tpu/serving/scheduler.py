@@ -31,7 +31,9 @@ Design constraints inherited from the device side (docs/serving.md):
     so ``pop_admissible`` yields (slot, request) pairs for the engine to
     install sequentially;
   * eviction frees the slot immediately — the engine's decode step feeds pad
-    tokens through inactive rows, so a freed slot costs compute but never
+    tokens through inactive rows, so a freed slot costs the batch-wide
+    matmuls its row (not, on the paged pool, the attention over its
+    self-attention ring: ops/attention.RingKVCache.active) but never
     correctness.
 """
 

@@ -95,15 +95,17 @@ def _dense(sds, heads_width, batch, capacity, n_q):
 
 def _dense_stacked(sds, heads_width, layers, batch, capacity):
     """The serving pool's self-attention ring: the kernel indexes the layer of
-    the stacked buffers itself (``layer`` traced, as inside the layer loop)."""
+    the stacked buffers itself (``layer`` traced, as inside the layer loop) and
+    is told which slots to read (``live``: the capacity or 0)."""
     heads, width = heads_width
     kv = sds((layers, batch, capacity, heads * width), jnp.bfloat16)
     return jax.jit(
-        lambda q, k, v, ang, pad, layer: dk.fused_decode_attention(q, k, v, ang, capacity - 1, pad, layer=layer)
+        lambda q, k, v, ang, pad, live, layer: dk.fused_decode_attention(
+            q, k, v, ang, capacity - 1, pad, live=live, layer=layer)
     ).lower(
         sds((batch, heads, 1, width), jnp.bfloat16), kv, kv,
         sds((batch, capacity, width // 2), jnp.float32), sds((batch, capacity), jnp.bool_),
-        sds((), jnp.int32),
+        sds((batch,), jnp.int32), sds((), jnp.int32),
     )
 
 

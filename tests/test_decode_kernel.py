@@ -96,6 +96,14 @@ def has_square_operand(shapes, hd):
         pytest.param(2, 2, 32, 1024, 16, 1, 1023, (1024, 100), False, False, id="live-below-one-block"),  # blk 512
         pytest.param(2, 2, 32, 256, 16, 1, 255, (256, 9), False, True, id="stacked-traced-layer"),
         pytest.param(2, 3, 16, 256, 8, 8, 255, None, False, True, id="stacked-n_q8"),
+        # the serving pool's call: rows that read their whole ring among rows that read nothing
+        pytest.param(4, 2, 32, 256, 16, 1, 255, (0, 256, 0, 256), False, False, id="free-rows-scattered"),
+        pytest.param(4, 2, 32, 256, 16, 4, 255, (256, 0, 256, 0), False, False, id="free-rows-n_q4"),
+        pytest.param(4, 2, 32, 256, 16, 1, 255, (0, 256, 256, 0), False, True, id="free-rows-stacked"),
+        pytest.param(4, 2, 32, 256, 16, 4, 255, (256, 0, 0, 256), False, True, id="free-rows-stacked-n_q4"),
+        pytest.param(3, 2, 32, 1024, 16, 1, 1023, (0, 0, 1024), False, False, id="free-rows-front-two-blocks"),
+        pytest.param(3, 2, 32, 1024, 16, 1, 1023, (1024, 100, 0), False, True, id="free-row-behind-a-dead-head-block"),
+        pytest.param(2, 2, 32, 256, 8, 1, 255, (0, 0), False, False, id="no-row-reads"),
     ],
 )
 def test_fused_decode_attention_query_side_rotation(b, h, d, cap, r, n_q, q_last, lives, zero_angles, stacked):
@@ -103,7 +111,10 @@ def test_fused_decode_attention_query_side_rotation(b, h, d, cap, r, n_q, q_last
     with rotating the keys themselves where it differs most from the plain
     cases above — rotary on part of a head (q_hat zero on the rest), the
     no-rotary call (zero angles, r = 2), eight queries, a row whose live
-    region is under one KV block, and the stacked form with a traced layer."""
+    region is under one KV block, and the stacked form with a traced layer.
+    A row with ``live == 0`` reads nothing and comes back zeros, and the rows
+    that read their whole cache beside it get, bit for bit, what the kernel
+    gives them when every row does (the call without ``live``)."""
     rng = lambda i: jax.random.PRNGKey(40 + i)
     q = jax.random.normal(rng(0), (b, h, n_q, d)) * 0.3
     layers = 3 if stacked else 1
@@ -115,16 +126,66 @@ def test_fused_decode_attention_query_side_rotation(b, h, d, cap, r, n_q, q_last
     live = None if lives is None else jnp.asarray(lives, jnp.int32)
     layer = 2 if stacked else 0
 
-    if stacked:
-        out = jax.jit(
-            lambda layer: dk.fused_decode_attention(q, k, v, ang, jnp.asarray(q_last), pad, live=live, layer=layer, interpret=True)
-        )(jnp.asarray(layer, jnp.int32))
-    else:
-        out = dk.fused_decode_attention(q, k[0], v[0], ang, jnp.asarray(q_last), pad, live=live, interpret=True)
+    def kernel(live):
+        if stacked:
+            return np.asarray(jax.jit(
+                lambda layer: dk.fused_decode_attention(q, k, v, ang, jnp.asarray(q_last), pad, live=live, layer=layer, interpret=True)
+            )(jnp.asarray(layer, jnp.int32)))
+        return np.asarray(dk.fused_decode_attention(q, k[0], v[0], ang, jnp.asarray(q_last), pad, live=live, interpret=True))
+
+    out = kernel(live)
     ref_pad = pad if lives is None else jnp.arange(cap)[None, :] < (q_last + 1 - live)[:, None]
-    ref = xla_reference(q, k[layer], v[layer], ang, jnp.full((b,), q_last), ref_pad)
+    ref = np.asarray(xla_reference(q, k[layer], v[layer], ang, jnp.full((b,), q_last), ref_pad))
     assert out.shape == (b, h, n_q, d)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+    reads = np.ones(b, bool) if lives is None else np.asarray(lives) > 0
+    np.testing.assert_allclose(out[reads], ref[reads], atol=1e-5)
+    if not reads.all():
+        assert not out[~reads].any()
+        whole = np.asarray(lives) == q_last + 1
+        np.testing.assert_array_equal(out[whole], kernel(None)[whole])
+
+
+@pytest.mark.parametrize("nblocks", [1, 2, 4])
+@pytest.mark.parametrize("free", ["front", "back", "scattered", "all", "none"])
+def test_fused_decode_attention_fetches_a_block_only_for_a_row_that_reads(free, nblocks):
+    """A COUNT of what the kernel's pipeline will move, never a time: walk the
+    grid (B, nblocks) in its order through the kernel's own index map
+    (``dk._step_block`` over ``dk._fetch_rows``) and count the steps whose
+    (row, block) differs from the step before; only those fetch. Every live
+    block of every reading row is held exactly once, in row order, and the
+    rows that read nothing add none (one block in all when NO row reads). A
+    reading row names what it named before this map existed. Without ``live``
+    (the pool's call until PR 37) the same walk counts ``B x nblocks``."""
+    b, blk = 16, 128
+    cap = nblocks * blk
+    rows = np.arange(b)
+    reads = {
+        "front": rows >= 11, "back": rows < 3, "all": np.zeros(b, bool), "none": np.ones(b, bool),
+        "scattered": np.isin(rows, np.random.default_rng(nblocks).permutation(b)[:5]),
+    }[free]
+    live = np.where(reads, cap, 0).astype(np.int32)
+    if reads.any():  # one reading row whose live tail lies in its last block alone
+        live[np.flatnonzero(reads)[-1]] = blk // 2
+    qpos = np.full(b, cap - 1, np.int32)
+    bi, i = np.repeat(rows, nblocks), np.tile(np.arange(nblocks), b)
+
+    def fetched(row, block):  # the steps whose block differs from the step before
+        held = list(zip(np.asarray(row).tolist(), np.asarray(block).tolist()))
+        return [held[0]] + [now for before, now in zip(held, held[1:]) if now != before]
+
+    def before_pr37(live):  # the maps as they were: every row names its own blocks
+        dead = np.maximum((qpos[bi] + 1 - live[bi]) // blk, 0)
+        return bi, np.minimum(np.maximum(i, dead), nblocks - 1)
+
+    fetch_row = dk._fetch_rows(jnp.asarray(live))
+    row, block = (np.asarray(v) for v in dk._step_block(bi, i, qpos, live, fetch_row, nblocks, blk))
+    first = (cap - live) // blk  # a reading row's first live block
+    wanted = [(r, k) for r in np.flatnonzero(reads).tolist() for k in range(first[r], nblocks)]
+    assert fetched(row, block) == (wanted or [(0, nblocks - 1)])
+    was_row, was_block = before_pr37(live)
+    on = reads[bi]
+    assert (row[on] == was_row[on]).all() and (block[on] == was_block[on]).all()
+    assert len(fetched(*before_pr37(np.full(b, cap, np.int32)))) == b * nblocks
 
 
 def test_blockdiag_queries_planes():

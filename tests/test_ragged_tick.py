@@ -388,6 +388,102 @@ def test_descriptor_counters_over_every_tick_kind(setup):
     engine.close()
 
 
+def test_ring_active_flag_equals_slot_state_active_after_every_step(setup):
+    """``cache.sa.active`` is what tells the dense decode kernel which slots'
+    rings to read (a slot it skips comes back zeros), and the engine harvests
+    ``state.active`` slots: the two must be equal after every ``step()`` —
+    through one-shot installs, chunked admissions (a slot in the middle of its
+    prefill is neither), finish lanes, finishes, a deadline eviction and a
+    quarantine. The snapshot reports the slots a tick decoded beside the pool's
+    size."""
+    model, params = setup
+    engine = ServingEngine(model, params, num_slots=3, kv_page_size=PS,
+                           prefill_chunk_tokens=4, max_prefill_slots=2)
+    seen = set()
+
+    def step():
+        engine.step()
+        flag, active = np.asarray(engine._cache.sa.active), np.asarray(engine._state.active)
+        np.testing.assert_array_equal(flag, active)
+        decoding = {slot for slot, _ in engine.scheduler.occupied()} - set(engine._prefilling)
+        assert set(np.flatnonzero(flag).tolist()) == decoding
+        seen.add((int(flag.sum()), len(engine._prefilling)))
+
+    handles = []
+    for i, (prompt, new) in enumerate(zip(CHURN_PROMPTS, CHURN_NEW)):
+        handles.append(engine.submit(prompt, max_new_tokens=new, rng=jax.random.PRNGKey(i)))
+        step()
+    late = engine.submit([9] * WINDOW, max_new_tokens=50, deadline_s=1e-3)
+    victim = engine.submit(list(range(3, 12)), max_new_tokens=8)
+    while victim.slot is None or victim.slot in engine._prefilling:
+        step()
+    with armed("serving.nan", slot=victim.slot):
+        step()
+    for _ in range(200):
+        if engine.scheduler.queue_depth == 0 and not list(engine.scheduler.occupied()):
+            break
+        step()
+    assert all(h.done for h in handles) and [len(h.output_ids) for h in handles] == CHURN_NEW
+    assert victim.status.value == "failed" and late.status.value == "timed_out"
+    assert not np.asarray(engine._cache.sa.active).any()
+    # some step left slots decoding beside a slot in the middle of its prefill, some a free slot
+    assert any(n and prefilling for n, prefilling in seen) and any(n < 3 and not prefilling for n, prefilling in seen)
+    snap = engine.metrics.snapshot()
+    assert snap["chunked_prefill"]["chunked_admissions"] >= 1 and engine.prefill_compilations >= 1  # both paths
+    block = snap["ragged_tick"]
+    assert block["slots"] == 3
+    assert 0 < block["decoding_slots"]["mean"] <= block["decoding_slots"]["p95"] <= 3
+    assert {k: block["decoding_slots"][k] for k in ("p50", "p95")} == block["decode_items"]
+    engine.close()
+
+
+def test_engine_tokens_with_the_dense_kernel_reading_active_slots_only(monkeypatch):
+    """The chip's path at a toy size: the tick's self-attention through
+    ``fused_decode_attention`` (interpret mode; a ring of 128 rows is the
+    smallest it takes) with ``live = 0`` for every slot that holds no installed
+    request. Tokens equal the XLA formulation's (which computes every slot),
+    request for request, through both admission paths, a free slot, a slot in
+    the middle of its prefill and a slot used twice; and the kernel really was
+    handed a live length a slot."""
+    import perceiver_io_tpu.ops.decode_kernel as dk
+
+    window, latents = 160, 128
+    config = CausalSequenceModelConfig(
+        vocab_size=VOCAB, max_seq_len=window, max_latents=latents, num_channels=16,
+        num_heads=2, num_self_attention_layers=2, cross_attention_dropout=0.0,
+    )
+    model = CausalSequenceModel(config=config)
+    rng = jax.random.PRNGKey(0)
+    params = jax.jit(model.init, static_argnames="prefix_len")(
+        rng, jax.random.randint(rng, (1, window), 0, VOCAB), prefix_len=window - latents)
+    prompts = [list(range(5, 45)), list(range(1, 151)), [7, 3, 9] * 20, list(range(100, 240))]
+    lives = []
+
+    def run(kernel):
+        if kernel:
+            real = dk.fused_decode_attention_auto
+
+            def interpreted(*args, live=None, **kw):
+                lives.append(live)
+                return real(*args, live=live, **kw, interpret=True)
+
+            monkeypatch.setattr(dk, "decode_kernel_supported", lambda n_q, cap, *a, **kw: (n_q, cap) == (1, latents))
+            monkeypatch.setattr(dk, "fused_decode_attention_auto", interpreted)
+        engine = ServingEngine(model, params, num_slots=3, kv_page_size=16, prefill_chunk_tokens=32)
+        handles = []
+        for prompt in prompts:  # the fourth waits for a slot
+            handles.append(engine.submit(prompt, max_new_tokens=4))
+            engine.step()
+        engine.run_until_drained(max_steps=100)
+        assert all(h.ok for h in handles) and engine.decode_compilations == 1
+        engine.close()
+        return [h.result().tolist() for h in handles]
+
+    plain = run(kernel=False)
+    assert run(kernel=True) == plain
+    assert lives and all(live is not None and live.shape == (3,) for live in lives)  # the layer loop's call
+
+
 # -------------------------------------------------------------------- chaos
 def test_chaos_ragged_tick_churn_scenario():
     """The ragged_tick_churn scenario is registered (the matrix smoke in
