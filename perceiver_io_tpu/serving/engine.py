@@ -154,7 +154,8 @@ import time
 from dataclasses import dataclass, field
 from enum import Enum
 from functools import partial
-from typing import Dict, List, Optional, Sequence
+from types import MappingProxyType
+from typing import Dict, List, NamedTuple, Optional, Sequence
 
 import flax.struct
 import jax
@@ -421,6 +422,29 @@ TICK_SCOPES.update({part: f"tick.{part}" for part in (
     "chunk_lanes/ssd_scan", "chunk_lanes/attention")})
 
 
+class TickRecord(NamedTuple):
+    """What one dispatching tick carried (docs/observability.md "The tick,
+    tiled"): made once where ``step_dispatch`` knows it, handed to
+    ``EngineMetrics.record_tick_dispatch`` and, with telemetry on, to the
+    tick's spans as their arguments, which is how it reaches a profiler trace
+    (``benchmark/trace/ticks.py`` joins it to the tick's device execution)."""
+
+    tick: int
+    programs: int  # compiled programs launched since the tick's entry (fused steady state: 1)
+    oneshot_admissions: int  # admissions by prefill + install programs, queued AHEAD of the tick program
+    chunk_lanes: int
+    finish_lanes: int
+    chunk_tokens: int  # prompt tokens the chunk lanes carry
+    resets: int
+    decoding: int  # slots the tick decodes
+    transfers: int  # the descriptor's host-to-device transfers: 0 = the resident one, 1 = a packed one
+    after_empty: int  # 1: the engine held no request at some point since the previous dispatch
+
+
+# span arguments of a tick with telemetry off: nothing is built
+_NO_FIELDS = MappingProxyType({})
+
+
 def default_prefill_buckets(window: int, max_latents: int) -> tuple:
     """Geometric (halving) ladder of prefill bucket lengths, from the full
     window down to the smallest bucket that still fits ``max_latents`` latents
@@ -571,6 +595,10 @@ class ServingEngine:
         # rule) — the watchdog's ``jax.compile.backend`` phase has that time.
         self._gap_from: Optional[float] = None
         self._harvest_end: Optional[float] = None
+        # the engine held no request (none queued, prefilling or decoding) at
+        # some point since the previous dispatch: the next tick's
+        # ``after_empty``. Kept with telemetry off too (one boolean)
+        self._was_empty = True
         self._gap_compilations = 0
         # per-engine async-span category: request ids restart at 0 per engine,
         # so two engines sharing one caller-owned recorder would otherwise
@@ -613,7 +641,8 @@ class ServingEngine:
         # predicate calls per generated token
         self._deadlines_seen = default_deadline_s is not None
         # dispatch/harvest split state: the in-flight (occupied, tok, finite,
-        # t0) of a dispatched-but-not-synced decode step (see step_dispatch)
+        # t0, t_dispatch, TickRecord) of a dispatched-but-not-synced decode
+        # step (see step_dispatch)
         self._pending_harvest = None
         # slots currently replaying a forced token stream (slot -> request);
         # empty on the hot path, where the cached all-zeros device arrays
@@ -777,7 +806,9 @@ class ServingEngine:
         self._tick_poison: Optional[int] = None
         self._tick_programs = 0
         self._tick_chunk_items = 0
+        self._tick_chunk_tokens = 0
         self._tick_finish_items = 0
+        self._tick_oneshot = 0
         self._tick_build_s = 0.0
         # descriptor transfers of the tick's fused dispatch (0 or 1); None
         # while the tick has dispatched no fused program
@@ -1568,6 +1599,7 @@ class ServingEngine:
             table_row = np.zeros((self._pages_per_slot,), np.int32)
             table_row[: len(page_ids)] = page_ids  # trash-padded reservation
         self._tick_programs += 2  # classic path: prefill + install programs
+        self._tick_oneshot += 1
         with self._obs.span(self._span_prefill, request_id=request.request_id):
             ids, pad_mask = self._bucket_prompt(request, bucket)
             req_logits, req_cache = self._jit_prefill(self.params, ids, pad_mask, bucket=bucket)
@@ -1698,6 +1730,7 @@ class ServingEngine:
         if remaining > 0:
             c = min(task.chunk_budget, remaining)
             self._tick_chunk_items += 1
+            self._tick_chunk_tokens += c
             t0 = time.perf_counter()
             # the chunk's host work: packing a descriptor lane
             with self._obs.span(self._span_chunk, request_id=request.request_id):
@@ -2459,7 +2492,9 @@ class ServingEngine:
             # reference pages that eviction has since recycled.
             self._tick_programs = 0
             self._tick_chunk_items = 0
+            self._tick_chunk_tokens = 0
             self._tick_finish_items = 0
+            self._tick_oneshot = 0
             self._tick_build_s = 0.0
             self._tick_transfers = None
             self._tick_chunks.clear()
@@ -2504,11 +2539,6 @@ class ServingEngine:
                         self._preempt_for_blocked_head(can_admit)
             self._maybe_inject_nan()
             occupied = list(self.scheduler.occupied())
-            if self._obs_on:
-                obs.gauge_set(f"{self._obs_ns}.active_slots", len(occupied))
-                obs.gauge_set(f"{self._obs_ns}.queue_depth", self.scheduler.queue_depth)
-                if self.paged:
-                    obs.gauge_set(f"{self._obs_ns}.pages_in_use", self._pool.pages_in_use)
             # slots mid-split-prefill hold no decode state yet (their
             # SlotState row is inactive, their in-cache table trash): they
             # are claimed for every scheduler purpose but must not be
@@ -2517,13 +2547,17 @@ class ServingEngine:
             tick_work = bool(self._tick_chunks or self._tick_finishes
                              or self._tick_resets)
             if not occupied and not tick_work:
+                t_end = obs.span_end(self._span_schedule)
                 if self._tick_programs:
                     # eviction/admission programs ran but nothing decodes:
                     # still a dispatching tick for the programs-per-tick view
-                    self.metrics.record_tick_dispatch(
-                        self._tick_programs, 0, 0, 0, 0.0)
-                obs.span_end(self._span_tick, at=obs.span_end(self._span_schedule))
+                    record = self._record_tick_dispatch(tick, 0, 0, 0, 0, 0)
+                    obs.span_end(self._span_tick, at=t_end, **self._span_fields(record))
+                else:
+                    obs.span_end(self._span_tick, at=t_end)
                 self._gap_from = None  # nothing dispatched: no gap to close
+                if not self.scheduler.has_work:
+                    self._was_empty = True  # a deadline expiry emptied the engine
                 return False
 
             if self._replay_slots:
@@ -2538,7 +2572,17 @@ class ServingEngine:
             t0 = time.perf_counter()
             # schedule ends where the dispatch begins: one clock reading, no seam
             t_dispatch = obs.span_end(self._span_schedule)
-            obs.span_begin(self._span_decode_dispatch, at=t_dispatch, tick=tick)
+            n_resets = len(self._tick_resets)  # the dispatch drains them
+            if self._obs_on:
+                # what the pack-and-send is about to carry: the lanes are
+                # buffered, the transfer is not known yet. A tick that
+                # decodes nothing has no sample_sync (the record's carrier in
+                # a profiler trace): what classes it rides here
+                obs.span_begin(self._span_decode_dispatch, at=t_dispatch, tick=tick,
+                               chunk_lanes=self._tick_chunk_items,
+                               finish_lanes=self._tick_finish_items,
+                               decoding=len(occupied),
+                               after_empty=int(self._was_empty))
             if self.paged:
                 # the tick's ONE program: resets + chunks + finishes +
                 # poison + decode, fused (docs/serving.md "Unified
@@ -2556,25 +2600,38 @@ class ServingEngine:
             t_dispatched = obs.span_end(self._span_decode_dispatch)
             if self._gap_from is not None:
                 self._book_host_gap(t_entry, t_dispatch, t_dispatched)
-            self.metrics.record_tick_dispatch(
-                self._tick_programs, self._tick_chunk_items,
-                self._tick_finish_items, len(occupied), self._tick_build_s,
-                self._tick_transfers,
-            )
+            record = self._record_tick_dispatch(
+                tick, self._tick_chunk_items, self._tick_finish_items,
+                self._tick_chunk_tokens, n_resets, len(occupied))
             if not occupied:
                 # ragged tick that only carried prefill work: nothing to
                 # harvest (the finish lanes activate slots for NEXT tick's
                 # decode when the tail chunk and finish split across ticks)
-                obs.span_end(self._span_tick)
+                obs.span_end(self._span_tick, **self._span_fields(record))
                 return False
         except BaseException:
             self._end_tick_spans()
             raise
-        # a tick with a chunk or finish lane charges every decoding slot its
-        # prefill work: its wall time is booked apart (tick_wall.with_prefill)
-        with_prefill = bool(self._tick_chunk_items or self._tick_finish_items)
-        self._pending_harvest = (occupied, tok, finite, t0, t_dispatch, with_prefill)
+        self._pending_harvest = (occupied, tok, finite, t0, t_dispatch, record)
         return True
+
+    def _record_tick_dispatch(self, tick: int, chunk_lanes: int, finish_lanes: int,
+                              chunk_tokens: int, resets: int, decoding: int) -> TickRecord:
+        """The dispatching tick's composition, made ONCE: the metrics' books
+        and the tick's spans are handed the same values."""
+        record = TickRecord(
+            tick, self._tick_programs, self._tick_oneshot, chunk_lanes,
+            finish_lanes, chunk_tokens, resets, decoding,
+            self._tick_transfers or 0, int(self._was_empty))
+        self._was_empty = False
+        self.metrics.record_tick_dispatch(
+            record.programs, record.chunk_lanes, record.finish_lanes,
+            record.decoding, self._tick_build_s, self._tick_transfers)
+        return record
+
+    def _span_fields(self, record: TickRecord):
+        """The record as span arguments; with telemetry off nothing is built."""
+        return record._asdict() if self._obs_on else _NO_FIELDS
 
     def _book_host_gap(self, t_entry: float, t_dispatch: float, t_dispatched: float) -> None:
         """The previous sync's return to this dispatch's return — the stretch
@@ -2620,11 +2677,15 @@ class ServingEngine:
             raise
 
     def _harvest(self, pending) -> bool:
-        occupied, tok, finite, t0, t_dispatch, with_prefill = pending
-        obs, tick = self._obs, self._tick_no
-        obs.span_begin(self._span_sample_sync, tick=tick)
+        occupied, tok, finite, t0, t_dispatch, record = pending
+        obs, tick, fields = self._obs, record.tick, self._span_fields(record)
+        # the span that brackets the device's run of this tick carries the
+        # whole record at its BEGIN, where the profiler's annotation is entered
+        obs.span_begin(self._span_sample_sync, **fields)
         tok = np.asarray(tok)  # blocks: the step's ONE device sync point
-        finite = np.asarray(finite)  # already on host after the sync above
+        # NOT free: the program is done, but this is a second device-to-host
+        # copy after the first (0.4 ms a tick on a TPU v5 lite, PERF.md 6 PR 38)
+        finite = np.asarray(finite)
         t_sync = obs.span_end(self._span_sample_sync)
         # harvest: everything from the sync's return to the end of the step's
         # host work (the device idles from here until the next dispatch)
@@ -2634,6 +2695,9 @@ class ServingEngine:
         now = time.perf_counter()
         decode_s = now - t0
         if self._obs_on:
+            # a tick with a chunk or finish lane charges every decoding slot
+            # its prefill work: its wall time is booked apart
+            with_prefill = record.chunk_lanes or record.finish_lanes
             obs.observe(self._phase_wall_prefill if with_prefill
                         else self._phase_wall_decode, t_sync - t_dispatch)
             self._gap_from = t_sync
@@ -2759,11 +2823,12 @@ class ServingEngine:
         self._journal_flush()
         has_work = self.scheduler.has_work
         self._harvest_end = obs.span_end(self._span_harvest)
-        obs.span_end(self._span_tick, at=self._harvest_end)
+        obs.span_end(self._span_tick, at=self._harvest_end, **fields)
         if not has_work:
             # the engine holds no request: whatever passes until the next
             # one arrives is not the loop's cost
             self._gap_from = None
+            self._was_empty = True
         # after the tick span: the terminal close of a signal-initiated drain
         # writes the recorder's trace, which must hold this last tick
         self._maybe_flush_preempted()

@@ -15,7 +15,11 @@ did the time actually go, per phase:
   * ``--train-metrics``   a train-metrics JSONL stream (training/metrics.py).
 
 Output: one phase table per source (count / total / mean / p50 / p95 / max /
-share of accounted time), the counter+gauge dump, the compile-watchdog
+share of accounted time), the counter+gauge dump (the serving engine keeps
+no gauge in the recorder: its backlog, occupancy and pool pressure are the
+metrics snapshot's ``queue_depth``, ``mean_slot_occupancy`` /
+``ragged_tick.decoding_slots`` and ``page_pool.pages_in_use``, read with
+``--serving-metrics``; per tick, ``decoding`` on ``serving.tick``), the compile-watchdog
 report (per-function compile counts vs budgets, unexpected recompiles —
 LOUD when nonzero), and per-stream summaries for the metrics logs. ``--json``
 emits the same as one machine-readable object. Validation runs before
@@ -237,7 +241,7 @@ def report_serving_metrics(path: str) -> Dict:
             k: snap.get(k)
             for k in ("schema", "requests_submitted", "requests_finished", "rejected",
                       "timed_out", "failed", "tokens_generated", "decode_tokens_per_s",
-                      "wall_tokens_per_s", "mean_slot_occupancy")
+                      "wall_tokens_per_s", "mean_slot_occupancy", "queue_depth")
         }
         # serving-metrics/v5 page pool (None: dense engine or pre-v5 stream)
         out["page_pool"] = snap.get("page_pool")

@@ -15,8 +15,9 @@ import pytest
 
 from perceiver_io_tpu.models.core.config import CausalSequenceModelConfig
 from perceiver_io_tpu.models.core.perceiver_ar import CausalSequenceModel
-from perceiver_io_tpu.obs.core import SUMMARY_SCHEMA, TelemetryRecorder
+from perceiver_io_tpu.obs.core import NULL_RECORDER, SUMMARY_SCHEMA, NullRecorder, TelemetryRecorder
 from perceiver_io_tpu.serving import ServingEngine
+from perceiver_io_tpu.serving.engine import TickRecord
 
 VOCAB = 262
 WINDOW = 16
@@ -53,11 +54,11 @@ def setup64(x64):
     return _make_model(param_dtype=jnp.float64)
 
 
-def _engine(model, params, telemetry, paged=True):
+def _engine(model, params, telemetry, paged=True, **more):
     """The paged engine (chunked admission, prefix cache: the fused tick), or the dense
     pool, whose tick is the decode step and whose every admission is one-shot."""
     pool = dict(kv_page_size=PAGE, prefill_chunk_tokens=4, max_prefill_slots=2, prefix_cache=True) if paged else {}
-    engine = ServingEngine(model, params, num_slots=3, telemetry=telemetry, **pool)
+    engine = ServingEngine(model, params, num_slots=3, telemetry=telemetry, **pool, **more)
     assert engine.ragged is paged
     return engine
 
@@ -330,6 +331,168 @@ def test_no_gap_is_booked_across_an_empty_engine(setup):
     engine.close()
 
 
+# ------------------------------------------------ what a tick carried
+
+
+FIELDS = TickRecord._fields
+DISPATCH_FIELDS = ("tick", "chunk_lanes", "finish_lanes", "decoding", "after_empty")
+
+
+@pytest.fixture(scope="module")
+def carried(setup):
+    """One run of the paged engine on int8 pages (a split admission resets its private
+    pages' scales: the tick's ``resets``) under a fake clock, with what the engine handed
+    ``record_tick_dispatch`` and the ticks at which it booked a host gap noted beside the
+    recorder's events. The engine runs empty three times: after the round that compiles
+    every program, after a one-shot admission served alone (a step with nothing to do
+    falls into that stretch), and after a 16-token prompt served alone (whose first ticks
+    carry lanes and decode nothing)."""
+    model, params = setup
+    t = [0.0]
+
+    def clock():
+        t[0] += 1.0
+        return t[0]
+
+    rec = TelemetryRecorder(clock=clock)
+    engine = _engine(model, params, rec, kv_quant="int8")
+    given, gap_ticks, chunk_tokens = [], [], []
+    record, book, chunk = engine.metrics.record_tick_dispatch, engine._book_host_gap, engine.metrics.record_chunk
+
+    def spy_record(programs, chunk_items, finish_items, decode_items, build_s, descriptor_transfers=None):
+        given.append(dict(programs=programs, chunk_lanes=chunk_items, finish_lanes=finish_items,
+                          decoding=decode_items, transfers=descriptor_transfers or 0, chunk_tokens=sum(chunk_tokens)))
+        chunk_tokens.clear()
+        return record(programs, chunk_items, finish_items, decode_items, build_s, descriptor_transfers)
+
+    def spy_chunk(request_id, slot, tokens, seconds):  # one call a chunk lane, with the prompt tokens it carries
+        chunk_tokens.append(tokens)
+        return chunk(request_id, slot, tokens, seconds)
+
+    def spy_book(*edges):
+        gap_ticks.append(engine._tick_no)
+        return book(*edges)
+
+    engine.metrics.record_tick_dispatch, engine._book_host_gap, engine.metrics.record_chunk = spy_record, spy_book, spy_chunk
+    _serve(engine, upfront=True)
+    engine.submit([5, 6, 7], max_new_tokens=3)
+    engine.run_until_drained(max_steps=50)
+    engine.step()  # nothing to do: a tick that dispatches nothing
+    engine.submit([9] * WINDOW, max_new_tokens=3)
+    engine.run_until_drained(max_steps=50)
+    _serve(engine, upfront=False)
+    walls = {name: rec.summary()["phases"][f"serving.tick_wall.{name}"]["count"] for name in ("with_prefill", "decode_only")}
+    engine.close()
+    events = sorted(_x_events(rec), key=lambda e: e["ts"])
+    named = lambda name: [e for e in events if e["name"] == name]
+    ticks = named("serving.tick")
+    return {"given": given, "gap_ticks": gap_ticks, "walls": walls, "ticks": ticks,
+            "dispatching": [e for e in ticks if len(e["args"]) > 1],
+            "syncs": {e["args"]["tick"]: e for e in named("serving.sample_sync")},
+            "dispatches": {e["args"]["tick"]: e for e in named("serving.decode_dispatch")},
+            "prefill_dispatches": named("serving.prefill_dispatch")}
+
+
+def _case_ten_fields_on_sync_and_tick(run):
+    assert len(run["dispatching"]) >= 20 and len(run["dispatching"]) < len(run["ticks"])
+    for tick in run["dispatching"]:
+        assert tuple(tick["args"]) == FIELDS and all(isinstance(v, int) for v in tick["args"].values())
+        sync = run["syncs"].get(tick["args"]["tick"])
+        # the sync carries the whole record at its begin; a tick that decodes nothing has none
+        assert (sync is None) == (tick["args"]["decoding"] == 0)
+        assert sync is None or sync["args"] == tick["args"]
+    for tick in run["ticks"]:
+        if len(tick["args"]) == 1:  # it dispatched nothing: no record, and no dispatch or sync span
+            assert tick["args"]["tick"] not in run["dispatches"] and tick["args"]["tick"] not in run["syncs"]
+
+
+def _case_dispatch_carries_what_is_known_before_the_pack(run):
+    assert len(run["dispatches"]) == len(run["dispatching"])
+    for tick in run["dispatching"]:
+        dispatch = run["dispatches"][tick["args"]["tick"]]
+        assert tuple(dispatch["args"]) == DISPATCH_FIELDS
+        assert dispatch["args"] == {f: tick["args"][f] for f in DISPATCH_FIELDS}
+
+
+def _case_fields_are_what_the_metrics_were_given(run):
+    assert len(run["given"]) == len(run["dispatching"])
+    for given, tick in zip(run["given"], run["dispatching"]):
+        assert given == {f: tick["args"][f] for f in given}
+    total = lambda f: sum(e["args"][f] for e in run["dispatching"])
+    # the traffic had every kind of work: lanes beside decoding slots and alone, a packed
+    # and a resident descriptor, scale resets (int8 pages), more than one program a tick
+    assert total("chunk_lanes") >= 10 and total("finish_lanes") >= 6 and total("resets") >= 6
+    assert 4 * total("chunk_lanes") >= total("chunk_tokens") > 3 * total("chunk_lanes")  # chunks of 4, and shorter tails
+    assert {e["args"]["transfers"] for e in run["dispatching"]} == {0, 1}
+    assert any(e["args"]["decoding"] == 0 for e in run["dispatching"]) and max(e["args"]["programs"] for e in run["dispatching"]) >= 3
+    for e in run["dispatching"]:
+        lanes = e["args"]["chunk_lanes"] or e["args"]["finish_lanes"] or e["args"]["resets"]
+        assert e["args"]["transfers"] == (1 if lanes else 0)
+
+
+def _case_oneshot_admissions_count_the_ticks_prefill_dispatches(run):
+    inside = lambda tick: sum(1 for e in run["prefill_dispatches"] if tick["ts"] <= e["ts"] < tick["ts"] + tick["dur"])
+    assert [e["args"]["oneshot_admissions"] for e in run["dispatching"]] == [inside(e) for e in run["dispatching"]]
+    assert sum(e["args"]["oneshot_admissions"] for e in run["dispatching"]) == len(run["prefill_dispatches"]) == 5
+    for e in run["dispatching"]:  # each brings a prefill and an install program ahead of the tick's own
+        assert e["args"]["programs"] >= 2 * e["args"]["oneshot_admissions"] + 1
+
+
+def _case_after_empty_marks_the_ticks_with_no_gap_for_want_of_a_request(run):
+    numbers = [e["args"]["tick"] for e in run["dispatching"]]
+    after_empty = {e["args"]["tick"] for e in run["dispatching"] if e["args"]["after_empty"]}
+    # the engine's first tick, and the first after each of the three times it ran empty
+    assert len(after_empty) == 4 and numbers[0] in after_empty
+    no_gap = set(numbers) - set(run["gap_ticks"])
+    # the one other way to have no gap to close: the tick before synced nothing
+    after_lanes_only = {b["args"]["tick"] for a, b in zip(run["dispatching"], run["dispatching"][1:]) if a["args"]["decoding"] == 0}
+    assert after_lanes_only and no_gap == after_empty | after_lanes_only
+    assert any(e["args"]["after_empty"] and e["args"]["decoding"] == 0 for e in run["dispatching"])  # lanes alone, after an empty engine
+    before = {e["args"]["tick"]: a for a, e in zip(run["ticks"], run["ticks"][1:])}
+    idle_step = next(e for e in run["ticks"] if len(e["args"]) == 1)
+    assert before[idle_step["args"]["tick"] + 1]["args"] == idle_step["args"] and idle_step["args"]["tick"] + 1 in after_empty
+
+
+def _case_tick_wall_books_count_what_they_counted(run):
+    synced = [e for e in run["dispatching"] if e["args"]["decoding"]]
+    with_lane = [e for e in synced if e["args"]["chunk_lanes"] or e["args"]["finish_lanes"]]
+    assert run["walls"] == {"with_prefill": len(with_lane), "decode_only": len(synced) - len(with_lane)}
+    assert len(with_lane) >= 6 and len(synced) - len(with_lane) >= 6
+
+
+TICK_CASES = {check.__name__[len("_case_"):]: check for check in (
+    _case_ten_fields_on_sync_and_tick, _case_dispatch_carries_what_is_known_before_the_pack,
+    _case_fields_are_what_the_metrics_were_given, _case_oneshot_admissions_count_the_ticks_prefill_dispatches,
+    _case_after_empty_marks_the_ticks_with_no_gap_for_want_of_a_request, _case_tick_wall_books_count_what_they_counted)}
+
+
+@pytest.mark.parametrize("case", [*TICK_CASES, "telemetry_off_builds_no_record_for_the_spans"])
+def test_a_tick_says_what_it_carried(setup, carried, monkeypatch, case):
+    if case in TICK_CASES:
+        return TICK_CASES[case](carried)
+    # telemetry off: the shared null recorder is handed ``tick`` (as before) and nothing of
+    # the record, whose dict is never made
+    model, params = setup
+    seen = []
+
+    def spy(method):
+        def call(self, name, at=None, **args):
+            seen.append((method, name, set(args)))
+        return call
+
+    monkeypatch.setattr(NullRecorder, "span_begin", spy("begin"))
+    monkeypatch.setattr(NullRecorder, "span_end", spy("end"))
+    monkeypatch.setattr(TickRecord, "_asdict", lambda self: pytest.fail("a record was made into span arguments with telemetry off"))
+    engine = _engine(model, params, False)
+    assert engine._obs is NULL_RECORDER and engine.watchdog is None
+    _serve(engine, upfront=False)
+    assert engine.metrics.snapshot()["ragged_tick"]["ticks"] >= 10  # the metrics still get theirs
+    engine.close()
+    assert {name for _, name, _ in seen} >= {"serving.tick", "serving.sample_sync", "serving.harvest"}
+    assert all(args <= {"tick"} for _, _, args in seen)
+    assert not any(method == "begin" and name == "serving.decode_dispatch" for method, name, _ in seen)
+
+
 # ------------------------------------------------- the profiler's clock
 
 
@@ -364,6 +527,17 @@ def test_spans_reach_a_jax_profiler_trace(setup, tmp_path):
     assert {"serving.schedule", "serving.decode_dispatch", "serving.sample_sync", "serving.evict"} <= set(spans)
     for (t0, d0), (h0, hd) in zip(sorted(spans["serving.tick"]), sorted(spans["serving.harvest"])):
         assert t0 <= h0 and h0 + hd <= t0 + d0 + 1e3  # harvest lies inside its tick, on one clock
+    # the tick's record rides the annotations' arguments: the benchmark's reader finds it as
+    # the two events' stats (the tick span's annotation was entered with ``tick`` alone)
+    from benchmark.trace import ticks
+
+    carriers, _ = ticks.read_profile(files[0])
+    assert [name for name, *_ in carriers] == ["serving.decode_dispatch", "serving.sample_sync"] * 3
+    assert ticks.carries_records(carriers)
+    for (_, d_start, d_dur, dispatch), (_, s_start, _, sync) in zip(carriers[::2], carriers[1::2]):
+        assert tuple(sync) == ticks.FIELDS == TickRecord._fields and tuple(dispatch) == ticks.DISPATCH_FIELDS
+        assert dispatch == {f: sync[f] for f in dispatch} and d_start + d_dur <= s_start
+        assert ticks.tick_class(ticks.record_of("serving.sample_sync", sync)) == "decode_only"
 
 
 # ------------------------------------------------------ a request's life
@@ -471,7 +645,11 @@ def test_tokens_and_compile_counts_are_the_same_recorder_on_and_off(setup64):
         engine.close()
         return tokens, counts
 
+    rec = TelemetryRecorder()
     tokens_off, counts_off = run(False)
-    tokens_on, counts_on = run(TelemetryRecorder())
+    tokens_on, counts_on = run(rec)
     assert tokens_on == tokens_off
     assert counts_on == counts_off and counts_on[0] == 1  # the tick program compiles once, recorder on or off
+    # the pin covers the tick's record: the recorder-on run made one a dispatching tick
+    syncs = [e for e in _x_events(rec) if e["name"] == "serving.sample_sync"]
+    assert syncs and all(tuple(e["args"]) == TickRecord._fields for e in syncs)
