@@ -982,32 +982,28 @@ class CausalSequenceModel(nn.Module):
         return pages_for_request(bucket, max_new_tokens, self.config.max_seq_len, page_size)
 
     def serving_chunk_phase(self, params, cache: PagedPerceiverARCache, lanes) -> PagedPerceiverARCache:
-        """One SPLIT-prefill chunk a lane (docs/serving.md "Chunked prefill"):
-        position-wise KV for prompt tokens [offset, offset + count) scattered
-        page-wise through the lane's table row — the slot's IN-CACHE table
-        stays trash until the finish, so the decode phase cannot write into
-        the half-built reservation."""
+        """One SPLIT-prefill chunk a CARRIED lane (packed from lane 0, so the
+        live ones are the first ``sum(ch_count > 0)``; docs/serving.md "Chunked
+        prefill"): position-wise KV for prompt tokens [offset, offset + count)
+        scattered page-wise through the lane's table row — the slot's IN-CACHE
+        table stays trash until the finish, so the decode phase cannot write
+        into the half-built reservation."""
         cap = lanes.ch_ids.shape[1]
+        j = jnp.arange(cap)
 
-        def body(cache, lane):
-            ids, offset, count, lstart, trow = lane
-            j = jnp.arange(cap)
+        def lane(i, cache):
+            offset = lanes.ch_offset[i]
             pos = jnp.clip(offset + j, 0, self.max_seq_len - 1)[None, :]
-            latent_mask = ((offset + j) >= lstart)[None, :]
-            k, v = self.apply(params, ids[None, :], pos, latent_mask,
+            latent_mask = ((offset + j) >= lanes.ch_latent_start[i])[None, :]
+            k, v = self.apply(params, lanes.ch_ids[i][None, :], pos, latent_mask,
                               method=type(self).prefill_chunk_kv)
-            # inactive lanes (count 0, trash table) deposit zero payloads on
+            # the lane's own padding rows (past count) deposit zero payloads on
             # the trash page — write_rows' padding discipline, deterministic
-            cache = cache.replace(
-                ca=cache.ca.write_rows(trow, offset, count, k[0], v[0])
+            return cache.replace(
+                ca=cache.ca.write_rows(lanes.ch_tables[i], offset, lanes.ch_count[i], k[0], v[0])
             )
-            return cache, None
 
-        cache, _ = jax.lax.scan(
-            body, cache,
-            (lanes.ch_ids, lanes.ch_offset, lanes.ch_count, lanes.ch_latent_start, lanes.ch_tables),
-        )
-        return cache
+        return jax.lax.fori_loop(0, jnp.sum((lanes.ch_count > 0).astype(jnp.int32)), lane, cache)
 
     def serving_finish_phase(self, params, cache: PagedPerceiverARCache, state, lanes, install_state):
         """The SPLIT prefill's finish, a lane a slot: latents for the last

@@ -13,7 +13,11 @@ calls on the (unbound) module:
 (c) its chunk step, ``serving_chunk_phase(params, cache, lanes)``, and what
     ends a prompt, ``serving_finish_phase(params, cache, state, lanes,
     install_state)`` — the tick program's two prefill phases, traced into the
-    one fused program;
+    one fused program. The engine packs a tick's lanes FROM LANE 0 (the
+    carried chunk lanes are the first ``sum(ch_count > 0)`` rows, the carried
+    finish lanes the first ``sum(fin_active)``; a carried chunk lane's count
+    is at least 1), and a phase runs the carried lanes only: the compiled lane
+    count sizes the descriptor and the phases' static shapes, never the work;
 (d) its decode step: ``decode_step_paged(ids (B, 1), cache)`` under
     ``model.apply``.
 

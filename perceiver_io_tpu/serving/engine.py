@@ -787,10 +787,11 @@ class ServingEngine:
         # descriptor and dispatches ONE fused program (the descriptor is
         # page-table work: the dense pool has none and runs decode_step).
         if self.paged:
-            # lane counts are STATIC program shapes. At most one chunk and
-            # one finish lane per slot per tick; chunked engines are further
-            # bounded by 2 x max_prefill_slots (advancing tasks plus the
-            # admissions their finishes just unblocked).
+            # lane counts are STATIC program shapes (the descriptor's size;
+            # the model's phases run the lanes a tick carries, not these). At
+            # most one chunk and one finish lane per slot per tick; chunked
+            # engines are further bounded by 2 x max_prefill_slots (advancing
+            # tasks plus the admissions their finishes just unblocked).
             self._ragged_lanes = (min(num_slots, 2 * self.max_prefill_slots)
                                   if self.chunked else num_slots)
             # fixed chunk row capacity — chunk shapes STOP riding the bucket
@@ -832,7 +833,7 @@ class ServingEngine:
             self.metrics.set_chunked_prefill(self.prefill_chunk_tokens)
         if self.paged:
             # serving-metrics/v11: the fused tick's block (None on dense pools)
-            self.metrics.set_ragged_tick(True)
+            self.metrics.set_ragged_tick(True, self._ragged_lanes)
         if self._prefix_cache is not None:
             self.metrics.set_prefix_cache(self._prefix_cache.stats(), 0)
         # serving-metrics/v9 gauges: quantized-page byte economics and the
@@ -1087,7 +1088,7 @@ class ServingEngine:
                 # any-flag (lax.cond), so one compiled program covers every
                 # tick mix and the watchdog budget is exactly 1. Per-slot
                 # state is disjoint across a phase's lanes, so the lanes of
-                # one scan do not interact (f64-pinned against generate()).
+                # one loop do not interact (f64-pinned against generate()).
                 # The phases carry STABLE jax.named_scope names (TICK_SCOPES;
                 # metadata only — the program's instructions are unchanged):
                 # a profiler trace's device time is read per phase from the
@@ -2371,11 +2372,11 @@ class ServingEngine:
         (serving/tick_descriptor.py). A tick that carries nothing but decode
         passes the device-resident ``_desc_decode_only``: nothing is packed
         and nothing is sent. Any other tick packs a copy of the idle
-        template (pure numpy; idle lanes keep the template's trash tables /
-        zero counts and are either value-inert — chunk lanes write only the
-        trash page — or skipped outright, finish lanes gating on
-        ``fin_active``) and sends it in one explicit transfer. Leaves the
-        tick's buffers as they are."""
+        template (pure numpy; lanes are packed FROM LANE 0, the contract of
+        models/core/serving_api.py: the model's phases run the carried ones
+        and never reach the idle rest, which keeps the template's trash
+        tables / zero counts) and sends it in one explicit transfer. Leaves
+        the tick's buffers as they are."""
         lanes, P = self._ragged_lanes, self._pages_per_slot
         n_ch, n_fin = len(self._tick_chunks), len(self._tick_finishes)
         n_reset = len(self._tick_resets)

@@ -139,7 +139,10 @@ Schema history:
     descriptor; ``None`` before the first), ``descriptor_transfers``
     p50/p95 (host-to-device transfers of the descriptor a tick: 0 or 1),
     ``slots`` (the pool's size) and ``decoding_slots`` mean/p50/p95 (the
-    slots a tick decodes: what the decode kernels' time follows).
+    slots a tick decodes: what the decode kernels' time follows), ``lanes``
+    (the tick program's compiled lane count: the descriptor's size) and
+    ``chunk_lanes`` mean/p50/p95 (the chunk lanes a tick carried, over the
+    ticks that carried one: the trips of the model's chunk phase).
   * ``serving-metrics/v12`` — the out-of-process-replica schema
     (docs/serving.md "Out-of-process replicas"): every snapshot carries a
     ``transport`` field — ``None`` on plain engines and on in-process
@@ -212,6 +215,7 @@ _PRE_V11 = KNOWN_SCHEMAS[:10]
 _PRE_V12 = KNOWN_SCHEMAS[:11]
 
 _PERCENTILE_KEYS = ("p50", "p95")
+_MEAN_AND_PERCENTILE_KEYS = ("mean",) + _PERCENTILE_KEYS
 
 # Latency histories are bounded ring buffers: a long-lived engine records one
 # decode-step sample per generated token forever, so unbounded lists would be
@@ -434,6 +438,7 @@ class EngineMetrics(_JsonlMetrics):
     # <=> dense engine (no tick descriptor) and snapshots report
     # ragged_tick: None; True on every paged engine
     ragged_enabled: Optional[bool] = None
+    ragged_lanes: Optional[int] = None  # the tick program's compiled lane count
     ragged_ticks: int = 0
     _tick_program_counts: Deque[int] = field(default_factory=lambda: deque(maxlen=LATENCY_WINDOW))
     _tick_chunk_counts: Deque[int] = field(default_factory=lambda: deque(maxlen=LATENCY_WINDOW))
@@ -585,11 +590,13 @@ class EngineMetrics(_JsonlMetrics):
         else:
             self.recurrent_chunks_carried += 1
 
-    def set_ragged_tick(self, enabled: bool) -> None:
+    def set_ragged_tick(self, enabled: bool, lanes: int) -> None:
         """Mark a paged engine's tick dispatcher (serving-metrics/v11):
         snapshots report the ragged_tick section instead of None. Every
-        paged engine passes True (the fused tick is its one dispatcher)."""
+        paged engine passes True (the fused tick is its one dispatcher) and
+        the lane count its tick program was compiled with."""
         self.ragged_enabled = bool(enabled)
+        self.ragged_lanes = int(lanes)
 
     def record_tick_dispatch(self, programs: int, chunk_items: int,
                              finish_items: int, decode_items: int,
@@ -758,7 +765,7 @@ class EngineMetrics(_JsonlMetrics):
         wall = (time.perf_counter() - self._start_time) if self._start_time else 0.0
         decoding_slots = {  # slots a dispatching tick decodes, latency window
             k: v for k, v in _latency_dict(self._tick_decode_counts).items()
-            if k in ("mean",) + _PERCENTILE_KEYS
+            if k in _MEAN_AND_PERCENTILE_KEYS
         }
         snap = {
             "schema": SCHEMA,
@@ -874,6 +881,15 @@ class EngineMetrics(_JsonlMetrics):
                 # only those cost the decode kernels bytes or compute
                 "slots": self.num_slots,
                 "decoding_slots": decoding_slots,
+                # the lanes the descriptor has room for against the chunk
+                # lanes a tick carried (over the ticks that carried one): a
+                # model's chunk phase runs the carried ones only
+                "lanes": self.ragged_lanes,
+                "chunk_lanes": {
+                    k: v for k, v in _latency_dict(
+                        [n for n in self._tick_chunk_counts if n]).items()
+                    if k in _MEAN_AND_PERCENTILE_KEYS
+                },
                 "descriptor_build_s": {
                     k: v for k, v in _latency_dict(self._tick_build_times).items()
                     if k in _PERCENTILE_KEYS

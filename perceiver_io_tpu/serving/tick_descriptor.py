@@ -24,8 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# idle-lane latent_start far beyond any position: the latent mask is all
-# False, so an idle chunk lane's (trash-bound) payload takes the cheap path
+# idle-lane latent_start far beyond any position (no position is a latent);
+# a model's chunk phase runs the carried lanes only, so nothing reads it
 IDLE_LATENT_START = 2 ** 30
 
 _I32, _U32, _F32 = np.int32, np.uint32, np.float32

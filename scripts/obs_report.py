@@ -453,6 +453,10 @@ def main(argv=None) -> Dict:
             for key in ("chunk_items", "finish_items", "decode_items"):
                 stats = rt.get(key) or {}
                 print(f"  {key}: p50={stats.get('p50')} p95={stats.get('p95')}")
+            if rt.get("lanes") is not None:
+                carried = rt.get("chunk_lanes") or {}
+                print(f"  lanes: {rt['lanes']} compiled; a tick with a chunk lane carried "
+                      f"mean={carried.get('mean')} p95={carried.get('p95')}")
         # v10 fleet-operations rendering (suppressed where the reader
         # normalized to None: plain engine or pre-v10 stream) — the
         # migration/recycle/rollout/autoscale story an operator audits

@@ -22,6 +22,7 @@ from perceiver_io_tpu.generation.generate import GenerationConfig
 from perceiver_io_tpu.models.core.config import CausalSequenceModelConfig
 from perceiver_io_tpu.models.core.perceiver_ar import CausalSequenceModel
 from perceiver_io_tpu.obs.core import TelemetryRecorder
+from perceiver_io_tpu.ops.paged_decode_kernel import PagedKVCache
 from perceiver_io_tpu.reliability import armed
 from perceiver_io_tpu.serving import ServingEngine
 from perceiver_io_tpu.serving.metrics import SCHEMA, load_metrics_jsonl
@@ -385,6 +386,120 @@ def test_descriptor_counters_over_every_tick_kind(setup):
     assert block["descriptor_transfers"] == {"p50": round(float(p50), 6),
                                              "p95": round(float(p95), 6)}
     assert block["ticks"] >= len(seen)
+    engine.close()
+
+
+# ------------------------------------------------- lanes carried, lanes compiled
+@pytest.mark.parametrize("live", [0, 1, 2, 8])
+def test_chunk_phase_runs_once_a_carried_lane(setup, live, monkeypatch):
+    """The model's chunk phase pays for the lanes the tick CARRIES (packed from
+    lane 0), not for the lanes the descriptor has room for: with jit off, count
+    the lane body's ``write_rows`` calls for ``live`` lanes of 8 compiled; the
+    jitted loop (a trip count read from the descriptor) writes the same pages."""
+    model, params = setup
+    engine = ServingEngine(model, params, num_slots=8, kv_page_size=PS, prefill_chunk_tokens=4)
+    assert engine._ragged_lanes == 8  # the default: as many lanes as slots
+    rs = np.random.RandomState(5)
+    P, cap = engine._pages_per_slot, engine._ragged_chunk_cap
+    counts = [int(rs.randint(1, cap + 1)) for _ in range(live)]
+    for slot, count in enumerate(counts):
+        engine._tick_chunks.append(
+            (slot, rs.randint(0, VOCAB, size=(cap,)).astype(np.int32), PS * int(rs.randint(0, 2)),
+             count, int(rs.randint(0, WINDOW)),
+             rs.randint(1, engine._pool.num_pages, size=(P,)).astype(np.int32)))
+    desc = engine._ragged_args(False, engine._forced_none, engine._use_forced_none)[3]
+    lanes = engine._desc_layout.unpack(desc)
+    assert int(np.sum(np.asarray(lanes.ch_count) > 0)) == live
+    jitted = jax.jit(model.serving_chunk_phase)(engine.params, engine._cache, lanes)
+    seen, write_rows = [], PagedKVCache.write_rows
+    monkeypatch.setattr(PagedKVCache, "write_rows",
+                        lambda self, trow, offset, count, k, v:
+                        seen.append(int(count)) or write_rows(self, trow, offset, count, k, v))
+    with jax.disable_jit():
+        eager = model.serving_chunk_phase(engine.params, engine._cache, lanes)
+    assert seen == counts  # one call a carried lane, in lane order; none for an idle one
+    # every page but the trash page (0: garbage by design) holds the same rows
+    for a, b in ((eager.ca.kp, jitted.ca.kp), (eager.ca.vp, jitted.ca.vp)):
+        np.testing.assert_allclose(np.asarray(a)[1:], np.asarray(b)[1:], rtol=1e-6, atol=1e-7)
+    if not live:
+        assert np.array_equal(np.asarray(eager.ca.kp), np.asarray(engine._cache.ca.kp))
+    engine.close()
+
+
+# one mixed run: one-shot admissions (under LATENTS tokens), split ones, and a
+# prompt that forks the first page of an earlier one out of the prefix cache
+# (both end inside the window: a ring that wraps neither donates nor forks)
+LANE_PROMPTS = [[5, 6, 7], list(range(3, 12)), [9] * WINDOW, [8] * PS + list(range(30, 36)), [2] * 5,
+                list(range(60, 67)), [8] * PS + list(range(20, 26)), list(range(3, 11)) + [77, 78]]
+LANE_NEW = [6, 5, 8, 2, 3, 7, 2, 6]
+LANE_SLOTS = 4
+_LANE_RUNS: dict = {}  # max_prefill_slots -> (tokens, lanes, decode_compilations, snapshot)
+
+
+def _run_lanes(model, params, max_prefill_slots):
+    if max_prefill_slots not in _LANE_RUNS:
+        # pages to spare, so the cached page is still there when its sibling comes
+        engine = ServingEngine(model, params, num_slots=LANE_SLOTS, kv_page_size=PS, prefill_chunk_tokens=4,
+                               prefix_cache=True, num_kv_pages=8 * LANE_SLOTS,
+                               max_prefill_slots=max_prefill_slots)
+        handles = [engine.submit(p, max_new_tokens=m) for p, m in zip(LANE_PROMPTS[:6], LANE_NEW)]
+        for _ in range(4):
+            engine.step()
+        handles += [engine.submit(p, max_new_tokens=m) for p, m in zip(LANE_PROMPTS[6:], LANE_NEW[6:])]
+        engine.run_until_drained(max_steps=400)
+        assert all(h.ok for h in handles)
+        _LANE_RUNS[max_prefill_slots] = ([h.result().tolist() for h in handles], engine._ragged_lanes,
+                                         engine.decode_compilations, engine.metrics.snapshot())
+        engine.close()
+    return _LANE_RUNS[max_prefill_slots]
+
+
+@pytest.mark.parametrize("request_no", range(len(LANE_PROMPTS)))
+def test_tokens_do_not_depend_on_the_compiled_lane_count(setup64, request_no):
+    """2 compiled lanes (``max_prefill_slots=1``) against ``num_slots`` of them
+    (the default): the same float64 tokens, ``generate()``'s, request by
+    request; each engine compiled its one tick program once."""
+    model, params = setup64
+    narrow, wide = _run_lanes(model, params, 1), _run_lanes(model, params, None)
+    assert (narrow[1], wide[1]) == (2, LANE_SLOTS)
+    assert narrow[2] == wide[2] == 1
+    for _, _, _, snap in (narrow, wide):
+        assert snap["prefix_cache"]["hits"] >= 1 and snap["chunked_prefill"]["chunked_admissions"] >= 4
+    assert wide[3]["ragged_tick"]["chunk_lanes"]["p95"] > 1  # some tick carried several lanes
+    expected = _reference_tokens(model, params, LANE_PROMPTS[request_no],
+                                 GenerationConfig(max_new_tokens=LANE_NEW[request_no]))
+    assert narrow[0][request_no] == wide[0][request_no] == expected
+
+
+@pytest.mark.parametrize("max_prefill_slots, lanes", [(1, 2), (2, 3), (None, 3)])
+def test_snapshot_reads_lanes_compiled_and_chunk_lanes_carried(setup, max_prefill_slots, lanes):
+    """``ragged_tick.lanes`` is the tick program's compiled lane count and
+    ``chunk_lanes`` the chunk lanes a tick carried, over the ticks that carried
+    one (the counts the dispatcher booked): how far apart the two are is
+    readable without a trace."""
+    model, params = setup
+    engine = ServingEngine(model, params, num_slots=3, kv_page_size=PS, prefill_chunk_tokens=4,
+                           max_prefill_slots=max_prefill_slots)
+    block = engine.metrics.snapshot()["ragged_tick"]
+    assert block["lanes"] == engine._ragged_lanes == lanes
+    assert block["chunk_lanes"] == {"mean": 0.0, "p50": 0.0, "p95": 0.0}  # no tick carried one yet
+    booked, book = [], engine.metrics.record_tick_dispatch
+
+    def watched(programs, chunk_items, *rest):
+        booked.append(chunk_items)
+        return book(programs, chunk_items, *rest)
+
+    engine.metrics.record_tick_dispatch = watched
+    for prompt in (list(range(3, 12)), [9] * WINDOW, [5, 6, 7], list(range(60, 67))):
+        engine.submit(prompt, max_new_tokens=5)
+    engine.run_until_drained(max_steps=200)
+    carried = [n for n in booked if n]
+    assert carried and 0 in booked and max(carried) <= lanes
+    block = engine.metrics.snapshot()["ragged_tick"]
+    p50, p95 = np.percentile(carried, [50, 95])
+    assert block["chunk_lanes"] == {"mean": round(sum(carried) / len(carried), 6),
+                                    "p50": round(float(p50), 6), "p95": round(float(p95), 6)}
+    assert block["lanes"] == lanes
     engine.close()
 
 
