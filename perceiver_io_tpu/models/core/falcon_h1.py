@@ -16,8 +16,9 @@ grow a row a token (paged, ``ops/paged_decode_kernel.py``'s grouped-query form)
 and a recurrent state of fixed size that every token rewrites whole, plus the
 mixer's short convolution tail (``ops/ssm.py``). ``FalconH1Cache`` is the one
 pytree that holds both for a pool of slots; ``prefill_chunk_paged`` and
-``decode_step_paged`` are the two steps the serving engine's tick program is
-built from (``models/core/serving_api.py`` lists what the engine asks).
+``decode_rows_paged`` are the two steps the serving engine's tick program is
+built from, with one pass of ``_head`` over the slots' rows a tick
+(``models/core/serving_api.py`` lists what the engine asks).
 
 Arithmetic: matrix products in ``dtype`` (bfloat16 when served) accumulated in
 float32; norms, the convolution, the state-space recurrence and the softmax in
@@ -91,7 +92,8 @@ class FalconH1Cache(flax.struct.PyTreeNode):
         ``d_conv - 1`` inputs, oldest first, laid flat (a slot's tail is one
         lane-dense row, not three rows of a tile);
     ``last_hidden``: (B, hidden) the residual stream at a slot's newest prompt
-        token, from which the finish lane reads the first token's logits.
+        token: the row the finish lane installs, whose head gives the first
+        token's logits.
     """
 
     kp: jax.Array
@@ -327,7 +329,7 @@ class FalconH1ForCausalLM(nn.Module):
         state = 4 * cfg.mamba_n_heads * cfg.mamba_d_head * cfg.mamba_d_state
         missing = "a slot's recurrent state is not snapshotted"
         return ServingTraits(
-            vocab_size=cfg.vocab_size, window=cfg.max_seq_len, finish_ids=0,
+            vocab_size=cfg.vocab_size, hidden_size=cfg.hidden_size, window=cfg.max_seq_len, finish_ids=0,
             recurrent_bytes_per_slot=cfg.num_hidden_layers * state,
             unsupported={
                 "prefix_cache": f"{missing} at page boundaries, so a shared prefix's pages would come "
@@ -355,16 +357,14 @@ class FalconH1ForCausalLM(nn.Module):
 
     def serving_finish_phase(self, params, cache: FalconH1Cache, state, lanes, install_state: Callable):
         """(c, the end of a prompt) the last chunk left the slot's newest hidden
-        row behind: one pass of the head over the finishing slots gives each its
-        first token's logits, then the slot's row, length and sampling state go
-        live."""
-        logits = self.apply(params, cache.last_hidden[lanes.fin_slot], method=type(self)._head)
-
+        row behind: it becomes the row the slot carries, from which the tick's
+        one head pass reads its first token's logits, and the slot's table
+        row, length and sampling state go live. No head runs here."""
         def lane(i, carry):
             cache, state = carry
             slot = lanes.fin_slot[i]
             cache = cache.install_slot(slot, lanes.fin_tables[i], lanes.fin_n[i])
-            state = install_state(state, slot, logits[i][None], lanes.fin_rng[i], lanes.fin_temp[i],
+            state = install_state(state, slot, cache.last_hidden[slot], lanes.fin_rng[i], lanes.fin_temp[i],
                                   lanes.fin_tk[i], lanes.fin_tp[i], lanes.fin_ds[i], lanes.fin_pad[i])
             return cache, state
 
@@ -418,11 +418,12 @@ class FalconH1ForCausalLM(nn.Module):
         return cache.replace(kp=kp, vp=vp, ssm_state=ssm_state, conv_state=conv_state,
                              last_hidden=cache.last_hidden.at[slot].set(last.astype(cache.last_hidden.dtype)))
 
-    def decode_step_paged(self, ids: jax.Array, cache: FalconH1Cache) -> Tuple[jax.Array, FalconH1Cache]:
-        """(d) one token for every decoding slot: ids (B, 1) -> logits (B, 1,
-        vocab). A slot that is not ``active`` (free, or in the middle of its
-        prefill) computes a discarded row: its key and value go to the trash
-        page, its length, recurrent state and convolution tail stay as they are."""
+    def decode_rows_paged(self, ids: jax.Array, cache: FalconH1Cache) -> Tuple[jax.Array, FalconH1Cache]:
+        """(d) one token for every decoding slot: ids (B, 1) -> the residual
+        stream's new last rows (B, hidden), the head's input. A slot that is not
+        ``active`` (free, or in the middle of its prefill) computes a discarded
+        row: its key and value go to the trash page, its length, recurrent state
+        and convolution tail stay as they are."""
         cfg = self.config
         b, ps = ids.shape[0], cache.page_size
         active = cache.active
@@ -466,4 +467,9 @@ class FalconH1ForCausalLM(nn.Module):
                 h = h + self._mlp(p, h)
         cache = cache.replace(kp=kp, vp=vp, ssm_state=ssm_state, conv_state=conv_state,
                               length=cache.length + active.astype(jnp.int32))
-        return self._head(h)[:, None], cache
+        return h, cache
+
+    def decode_step_paged(self, ids: jax.Array, cache: FalconH1Cache) -> Tuple[jax.Array, FalconH1Cache]:
+        """ids (B, 1) -> logits (B, 1, vocab): the head of ``decode_rows_paged``'s rows."""
+        rows, cache = self.decode_rows_paged(ids, cache)
+        return self._head(rows)[:, None], cache

@@ -925,6 +925,20 @@ class CausalSequenceModel(nn.Module):
         logits, _, cache = self.decode_step_with_hidden(x, cache)
         return logits, cache
 
+    def prefill_rows(
+        self, x: jax.Array, prefix_len: int, cache: PerceiverARCache, pad_mask: Optional[jax.Array] = None
+    ) -> Tuple[jax.Array, PerceiverARCache]:
+        """prefill without the head: (the last position's hidden row (B, C),
+        cache) — what the serving engine installs as a slot's row."""
+        hidden, cache = self.ar.prefill(x, prefix_len=prefix_len, cache=cache, pad_mask=pad_mask)
+        return hidden[:, -1], cache
+
+    def decode_rows(self, x: jax.Array, cache: PerceiverARCache) -> Tuple[jax.Array, PerceiverARCache]:
+        """decode_step without the head: (hidden rows (B, C), cache) — the
+        dense pool's form of ``decode_rows_paged``."""
+        hidden, cache = self.ar.decode_step(x, cache)
+        return hidden[:, -1], cache
+
     def decode_block(self, x: jax.Array, cache: PerceiverARCache) -> Tuple[jax.Array, PerceiverARCache]:
         """Decode ``n`` tokens at once (chunked/speculative verification); see
         ``PerceiverAR.decode_block`` for the n > 1 no-roll contract. Returns
@@ -960,18 +974,18 @@ class CausalSequenceModel(nn.Module):
     def prefill_finish_paged(
         self, x: jax.Array, n_live: jax.Array, ca: PagedKVCache, table_row: jax.Array
     ) -> Tuple[jax.Array, KVCache]:
-        """Chunked prefill's finish: latents over the slot's pages, through
-        the head. Returns (last-position logits (1, V), the batch-1 SA cache
-        to install); see ``PerceiverAR.prefill_latents_paged``. The head runs
-        over the full latent block and slices, mirroring the one-shot
-        prefill's ``logits[:, -1]`` exactly."""
+        """Chunked prefill's finish: latents over the slot's pages. Returns
+        (the last position's hidden row (1, C) — the row the slot carries,
+        the one-shot ``prefill_rows``' exactly — and the batch-1 SA cache to
+        install); see ``PerceiverAR.prefill_latents_paged``. No head runs."""
         hidden, sa_cache = self.ar.prefill_latents_paged(x, n_live, ca, table_row)
-        return self._head(hidden)[:, -1], sa_cache
+        return hidden[:, -1], sa_cache
 
     # ---- what the serving engine asks of a model (models/core/serving_api.py)
     def serving_traits(self) -> ServingTraits:
         cfg = self.config
-        return ServingTraits(vocab_size=cfg.vocab_size, window=cfg.max_seq_len, finish_ids=cfg.max_latents)
+        return ServingTraits(vocab_size=cfg.vocab_size, hidden_size=cfg.num_channels, window=cfg.max_seq_len,
+                             finish_ids=cfg.max_latents)
 
     def serving_pages(self, prompt_tokens: int, max_new_tokens: int, page_size: int, bucket: int) -> int:
         """The covering prefill bucket plus the whole generation budget, capped
@@ -1015,12 +1029,12 @@ class CausalSequenceModel(nn.Module):
 
             def fin(args):
                 cache, state = args
-                req_logits, sa_src = self.apply(
+                rows, sa_src = self.apply(
                     params, ids[None, :], n, cache.ca, trow,
                     method=type(self).prefill_finish_paged,
                 )
                 cache = cache.install_finish(slot, trow, sa_src, n)
-                state = install_state(state, slot, req_logits, rng, temp, tk, tp, ds, pad)
+                state = install_state(state, slot, rows[0], rng, temp, tk, tp, ds, pad)
                 return cache, state
 
             return jax.lax.cond(active, fin, lambda a: a, carry), None
@@ -1032,10 +1046,17 @@ class CausalSequenceModel(nn.Module):
         )
         return carry
 
+    def decode_rows_paged(
+        self, x: jax.Array, cache: PagedPerceiverARCache
+    ) -> Tuple[jax.Array, PagedPerceiverARCache]:
+        """One decode token against the paged pool, without the head: (hidden
+        rows (B, C), cache); see ``PerceiverAR.decode_step_paged``."""
+        hidden, cache = self.ar.decode_step_paged(x, cache)
+        return hidden[:, -1], cache
+
     def decode_step_paged(
         self, x: jax.Array, cache: PagedPerceiverARCache
     ) -> Tuple[jax.Array, PagedPerceiverARCache]:
-        """One decode token against the paged pool; see
-        ``PerceiverAR.decode_step_paged``."""
-        hidden, cache = self.ar.decode_step_paged(x, cache)
-        return self._head(hidden), cache
+        """x (B, 1) -> logits (B, 1, vocab): the head of ``decode_rows_paged``'s rows."""
+        rows, cache = self.decode_rows_paged(x, cache)
+        return self._head(rows)[:, None], cache

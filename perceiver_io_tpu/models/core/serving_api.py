@@ -1,7 +1,7 @@
 """What ``ServingEngine`` asks of a model it serves from the paged pool.
 
 The engine owns slots, pages, the tick loop and the descriptor; it knows no
-architecture. A model class answers four questions, by methods the engine
+architecture. A model class answers five questions, by methods the engine
 calls on the (unbound) module:
 
 (a) its cache: ``init_paged_cache(num_slots, num_pages, page_size, dtype,
@@ -17,9 +17,22 @@ calls on the (unbound) module:
     carried chunk lanes are the first ``sum(ch_count > 0)`` rows, the carried
     finish lanes the first ``sum(fin_active)``; a carried chunk lane's count
     is at least 1), and a phase runs the carried lanes only: the compiled lane
-    count sizes the descriptor and the phases' static shapes, never the work;
-(d) its decode step: ``decode_step_paged(ids (B, 1), cache)`` under
-    ``model.apply``.
+    count sizes the descriptor and the phases' static shapes, never the work.
+    A finish lane hands ``install_state`` the slot's ROW: the last hidden row
+    of its prompt, ``(hidden_size,)``, as the head would receive it. The
+    phase runs no head;
+(d) its decode step: ``decode_rows_paged(ids (B, 1), cache)`` under
+    ``model.apply`` returns ``(rows (B, hidden_size), cache)``: every slot's
+    new last hidden row, again as the head would receive it
+    (``decode_step_paged`` is the head of those rows, for callers that want
+    logits);
+(e) its head over rows: ``_head(rows (B, hidden_size))`` under
+    ``model.apply`` returns logits ``(B, vocab_size)``, row by row.
+
+Between two ticks a slot carries its row, never its logits: the tick's decode
+phase begins with ONE pass of (e) over every slot's row, samples from it, runs
+(d) on the sampled tokens and stores the new rows. A slot whose prompt ended
+in this tick's finish lane is sampled in the same tick, through the same pass.
 
 ``serving_traits()`` says, in plain data, what else differs: which prompts
 take the split admission, what the descriptor's lanes carry, and which engine
@@ -36,6 +49,8 @@ from typing import Dict
 @dataclass(frozen=True)
 class ServingTraits:
     vocab_size: int
+    # the width of the row a slot carries between ticks: the head's input
+    hidden_size: int
     # the most tokens a slot holds: longer prompts are rejected at submit, and
     # a slot's page-table row has ceil(window / page_size) entries
     window: int

@@ -197,8 +197,10 @@ from perceiver_io_tpu.serving.tick_descriptor import TickDescriptorLayout
 class SlotState(flax.struct.PyTreeNode):
     """Per-slot device state advanced by the compiled decode step.
 
-    ``next_logits``: (B, V) last-position logits (sampling input of the next
-        step — written by prefill at admission, by decode afterwards).
+    ``next_hidden``: (B, C) each slot's last hidden row, the head's input:
+        the next step's head pass turns it into the logits that step samples
+        from — written by prefill at admission, by decode afterwards. The
+        (B, V) logits themselves live inside one step and are never state.
     ``rng``: (B, 2) per-slot PRNG keys, split once per step.
     ``active``: (B,) bool; inactive rows decode their pad token.
     ``temperature``/``top_k``/``top_p``/``do_sample``: per-slot sampling
@@ -206,7 +208,7 @@ class SlotState(flax.struct.PyTreeNode):
     ``pad_id``: (B,) token fed through inactive rows.
     """
 
-    next_logits: jax.Array
+    next_hidden: jax.Array
     rng: jax.Array
     active: jax.Array
     temperature: jax.Array
@@ -216,9 +218,9 @@ class SlotState(flax.struct.PyTreeNode):
     pad_id: jax.Array
 
     @staticmethod
-    def create(num_slots: int, vocab_size: int, logits_dtype=jnp.float32) -> "SlotState":
+    def create(num_slots: int, hidden_size: int, dtype=jnp.float32) -> "SlotState":
         return SlotState(
-            next_logits=jnp.zeros((num_slots, vocab_size), logits_dtype),
+            next_hidden=jnp.zeros((num_slots, hidden_size), dtype),
             rng=jnp.zeros((num_slots, 2), jnp.uint32),
             active=jnp.zeros((num_slots,), bool),
             temperature=jnp.ones((num_slots,), jnp.float32),
@@ -410,8 +412,9 @@ _ENGINE_IDS = itertools.count()
 # time is summed per phase (docs/observability.md "Named scopes"). Inside
 # ``tick.decode`` the model's own scopes split further: ``cache_append`` and
 # ``decode_attention`` (ops/attention.py), the flax ``mlp`` modules, and
-# ``head`` (models/core/perceiver_ar.py). Renaming one is a change to what the
-# trace tools read.
+# ``head`` (models/core/perceiver_ar.py): the step's one pass of the head over
+# the slots' carried rows, the first thing under ``tick.decode``; no other
+# phase runs a head. Renaming one is a change to what the trace tools read.
 TICK_SCOPES = {phase: f"tick.{phase}" for phase in (
     "resets", "chunk_lanes", "finish_lanes", "poison", "sample", "decode")}
 # what a model with a recurrent state (models/core/falcon_h1.py) names inside
@@ -665,7 +668,6 @@ class ServingEngine:
                 install_preemption_handler(_request_preempt)
             )
 
-        self._vocab = traits.vocab_size
         self._window = traits.window
         self._latents = traits.finish_ids
 
@@ -851,9 +853,10 @@ class ServingEngine:
             self.metrics.set_weight_serving(
                 self.weight_dtype, self._param_bytes, self._param_bytes_fp
             )
-        # logits carry the cache/compute dtype (f64 parity tests, bf16 TPU
-        # serving); storing them narrower would silently cast at install
-        self._state = SlotState.create(num_slots, self._vocab, logits_dtype=self.cache_dtype)
+        # a slot's row carries the cache/compute dtype (f64 parity tests, bf16
+        # TPU serving): the dtype the head receives it in, so carrying it
+        # between ticks rounds nothing
+        self._state = SlotState.create(num_slots, traits.hidden_size, dtype=self.cache_dtype)
         # device-resident constants for the no-replay case: the forced-token
         # mux costs no host->device transfer on ordinary ticks
         self._forced_none = jnp.zeros((num_slots,), jnp.int32)
@@ -925,15 +928,16 @@ class ServingEngine:
             # shared self-attention length stays uniform
             params = dq(params)
             cache = model.init_cache(batch_size=1, dtype=dtype, max_seq_len=bucket)
-            logits, cache = model.apply(
-                params, ids, bucket - n_latents, cache, pad_mask=pad_mask, method=type(model).prefill
+            rows, cache = model.apply(
+                params, ids, bucket - n_latents, cache, pad_mask=pad_mask, method=type(model).prefill_rows
             )
-            return logits[:, -1], cache
+            return rows[0], cache
 
-        def _install_state(state, slot, req_logits, rng,
+        def _install_state(state, slot, row, rng,
                            temperature, top_k, top_p, do_sample, pad_id):
+            # ``row`` (C,): the last hidden row of the slot's prompt
             return state.replace(
-                next_logits=state.next_logits.at[slot].set(req_logits[0]),
+                next_hidden=state.next_hidden.at[slot].set(row),
                 rng=state.rng.at[slot].set(rng),
                 active=state.active.at[slot].set(True),
                 temperature=state.temperature.at[slot].set(temperature),
@@ -949,15 +953,15 @@ class ServingEngine:
         # instead of updating it in place. (CPU jax warns donation is
         # unsupported and falls back to copies — correct either way.)
         @partial(jax.jit, donate_argnums=(0, 1))
-        def install(cache, state, slot, req_cache, req_logits, rng,
+        def install(cache, state, slot, req_cache, row, rng,
                     temperature, top_k, top_p, do_sample, pad_id):
             cache = cache.write_slot(slot, req_cache)
-            state = _install_state(state, slot, req_logits, rng,
+            state = _install_state(state, slot, row, rng,
                                    temperature, top_k, top_p, do_sample, pad_id)
             return cache, state
 
         @partial(jax.jit, donate_argnums=(0, 1))
-        def install_paged(cache, state, slot, table_row, req_cache, req_logits, rng,
+        def install_paged(cache, state, slot, table_row, req_cache, row, rng,
                           temperature, top_k, top_p, do_sample, pad_id):
             # paged admission: scatter the BUCKET-shaped prefill cache into
             # the freshly allocated pages and write the slot's page-table row
@@ -966,7 +970,7 @@ class ServingEngine:
             # program per ladder bucket — table_row is a fixed (P,) array,
             # so varying reservations never add programs.
             cache = cache.install_slot(slot, table_row, req_cache)
-            state = _install_state(state, slot, req_logits, rng,
+            state = _install_state(state, slot, row, rng,
                                    temperature, top_k, top_p, do_sample, pad_id)
             return cache, state
 
@@ -975,7 +979,7 @@ class ServingEngine:
             # reset sampling fields to their neutral encodings: a stale
             # do_sample/top_k/top_p on a freed row would keep the decode
             # step's any-row lax.cond branches (sampling.py) live and make
-            # all-greedy batches pay the vocab sorts forever. rng/next_logits
+            # all-greedy batches pay the vocab sorts forever. rng/next_hidden
             # are zeroed too so freed-slot state is canonical and pool dumps
             # are reproducible (they never feed a harvested output).
             return state.replace(
@@ -985,7 +989,7 @@ class ServingEngine:
                 top_k=state.top_k.at[slot].set(0),
                 top_p=state.top_p.at[slot].set(1.0),
                 rng=state.rng.at[slot].set(0),
-                next_logits=state.next_logits.at[slot].set(0),
+                next_hidden=state.next_hidden.at[slot].set(0),
             )
 
         @partial(jax.jit, donate_argnums=(0,))
@@ -1000,45 +1004,59 @@ class ServingEngine:
             return cache.release_slot(slot)
 
         decode_method = (
-            type(model).decode_step_paged if self.paged else type(model).decode_step
+            type(model).decode_rows_paged if self.paged else type(model).decode_rows
         )
 
         def decode_body(params, cache, state, forced, use_forced):
             # THE decode step, traced by the dense pool's ``decode_step`` and
             # by the fused tick's decode phase (``params`` already dequantized).
             # Mirrors _generate_single's loop body per row: process logits ->
-            # sample -> one cached model step. Inactive rows decode their pad
-            # token; their outputs are never harvested.
+            # sample -> one cached model step. The logits are the head of the
+            # row each slot carries (models/core/serving_api.py (e)): ONE pass
+            # of the head a step, over every slot's row — a decoding slot's
+            # from the step before, a just-finished prompt's from this tick's
+            # finish lane — and the (B, V) logits die in the sampler: never
+            # state, never a cond operand or a loop carry. Inactive rows decode
+            # their pad token; their outputs are never harvested.
             # ``finite`` is the containment probe (docs/reliability.md): per
             # ACTIVE slot, were the logits this step sampled from all finite?
             # Computed in the same program, harvested with the same device
             # sync as the tokens — detection costs no extra host round-trip,
             # and the token math is untouched (parity pins unaffected).
+            with jax.named_scope(TICK_SCOPES["decode"]):
+                logits = model.apply(params, state.next_hidden, method=type(model)._head)
+                # the sampler reads values of the logits' dtype and no wider:
+                # fused into the head, XLA would hand it the matmul's float32
+                # (excess precision), which breaks ties that logits of the
+                # served dtype hold — another token stream than the same
+                # logits give once they have been stored. Identity in float32.
+                width = jnp.finfo(logits.dtype)
+                logits = jax.lax.reduce_precision(logits, width.nexp, width.nmant)
             with jax.named_scope(TICK_SCOPES["sample"]):
-                finite = jnp.all(jnp.isfinite(state.next_logits), axis=-1) | ~state.active
+                finite = jnp.all(jnp.isfinite(logits), axis=-1) | ~state.active
                 processed = process_logits_batched(
-                    state.next_logits, state.temperature, state.top_k, state.top_p
+                    logits, state.temperature, state.top_k, state.top_p
                 )
                 keys = jax.vmap(jax.random.split)(state.rng)  # (B, 2, 2)
                 tok = sample_token_batched(keys[:, 1], processed, state.do_sample)
                 tok = jnp.where(state.active, tok, state.pad_id).astype(jnp.int32)
                 # deterministic replay mux (router failover): a replaying
                 # slot's token is FORCED to the known stream while the rng
-                # chain, cache appends, and logits advance exactly as in the
+                # chain, cache appends, and rows advance exactly as in the
                 # original run — so free-running continuation is
                 # bit-identical. With use_forced all-False (every ordinary
                 # tick) this is a no-op select and the f64 parity pins run
                 # through it.
                 tok = jnp.where(use_forced, forced, tok).astype(jnp.int32)
             with jax.named_scope(TICK_SCOPES["decode"]):
-                logits_t, cache = model.apply(
+                rows, cache = model.apply(
                     params, tok[:, None], cache, method=decode_method
                 )
-            # inactive rows keep their (zeroed-at-release) rng/logits frozen:
+            # inactive rows keep their (zeroed-at-release) rng/row frozen:
             # freed-slot state stays canonical across steps, so pool dumps are
             # reproducible regardless of how long slots idle between requests
             state = state.replace(
-                next_logits=jnp.where(state.active[:, None], logits_t[:, -1], state.next_logits),
+                next_hidden=jnp.where(state.active[:, None], rows, state.next_hidden),
                 rng=jnp.where(state.active[:, None], keys[:, 0], state.rng),
             )
             return tok, finite, cache, state
@@ -1129,12 +1147,12 @@ class ServingEngine:
                         lambda a: model.serving_finish_phase(params, a[0], a[1], d, _install_state),
                         lambda a: a, (cache, state)
                     )
-                # serving.nan fault point: after finishes activate their
-                # logits, before decode reads them
+                # serving.nan fault point: after finishes install their rows,
+                # before decode's head reads them (a NaN row gives NaN logits)
                 with jax.named_scope(TICK_SCOPES["poison"]):
                     state = jax.lax.cond(
                         poison_slot >= 0,
-                        lambda s: s.replace(next_logits=s.next_logits.at[
+                        lambda s: s.replace(next_hidden=s.next_hidden.at[
                             jnp.maximum(poison_slot, 0)].set(jnp.nan)),
                         lambda s: s, state,
                     )
@@ -1603,7 +1621,7 @@ class ServingEngine:
         self._tick_oneshot += 1
         with self._obs.span(self._span_prefill, request_id=request.request_id):
             ids, pad_mask = self._bucket_prompt(request, bucket)
-            req_logits, req_cache = self._jit_prefill(self.params, ids, pad_mask, bucket=bucket)
+            req_row, req_cache = self._jit_prefill(self.params, ids, pad_mask, bucket=bucket)
         with self._obs.span(self._span_install, request_id=request.request_id):
             # greedy requests ignore temperature/top_k/top_p (argmax survives
             # scaling and filtering): install the neutral encodings so any
@@ -1620,11 +1638,11 @@ class ServingEngine:
             if self.paged:
                 self._cache, self._state = self._jit_install(
                     self._cache, self._state, slot, jnp.asarray(table_row),
-                    req_cache, req_logits, request.rng, *sampling,
+                    req_cache, req_row, request.rng, *sampling,
                 )
             else:
                 self._cache, self._state = self._jit_install(
-                    self._cache, self._state, slot, req_cache, req_logits,
+                    self._cache, self._state, slot, req_cache, req_row,
                     request.rng, *sampling,
                 )
         # NON-BLOCKING: no device sync here — the prefill/install dispatch
@@ -2343,8 +2361,9 @@ class ServingEngine:
 
     def _maybe_inject_nan(self) -> None:
         """serving.nan fault point (reliability/faults.py): poison one slot's
-        next-step logits — the containment path must then evict exactly that
-        slot as FAILED while slot-mates decode on untouched."""
+        row, so the next step's logits for it are NaN — the containment path
+        must then evict exactly that slot as FAILED while slot-mates decode on
+        untouched."""
         spec = faults.fire_serving_nan()
         if spec is None:
             return
@@ -2356,13 +2375,13 @@ class ServingEngine:
             slot = occupied[0]
         if self.paged:
             # stash for the fused program's poison phase — applied between
-            # the finish lanes (which activate logits) and decode, without
+            # the finish lanes (which install rows) and decode, without
             # an eager host-side device op
             self._tick_poison = slot
             return
-        # the dense pool has no descriptor: poke the logits eagerly
+        # the dense pool has no descriptor: poke the row eagerly
         self._state = self._state.replace(
-            next_logits=self._state.next_logits.at[slot].set(jnp.nan)
+            next_hidden=self._state.next_hidden.at[slot].set(jnp.nan)
         )
 
     def _ragged_args(self, any_decode: bool, forced, use_forced) -> tuple:

@@ -406,7 +406,7 @@ def check_serving_nan() -> dict:
         engine.step()
     engine.run_until_drained(max_steps=100)
     snap = engine.metrics.snapshot()
-    pool_finite = bool(np.isfinite(np.asarray(engine._state.next_logits)).all())
+    pool_finite = bool(np.isfinite(np.asarray(engine._state.next_hidden)).all())
     return {
         "ok": (
             poisoned.status.value == "failed"

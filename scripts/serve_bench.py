@@ -1604,7 +1604,7 @@ def _admission_engine(model, params, prompts, buckets):
     for slot, req in engine.scheduler.pop_admissible():
         engine._admit(slot, req)
         engine._evict(slot, req, "warmup")
-    jax.block_until_ready(engine._state.next_logits)
+    jax.block_until_ready(engine._state.next_hidden)
     return engine
 
 
@@ -1618,7 +1618,7 @@ def _measure_admission(engine, prompts) -> float:
     t0 = time.perf_counter()
     for slot, req in engine.scheduler.pop_admissible():
         engine._admit(slot, req)
-    jax.block_until_ready(engine._state.next_logits)
+    jax.block_until_ready(engine._state.next_hidden)
     wall = time.perf_counter() - t0
     for slot, req in list(engine.scheduler.occupied()):
         engine._evict(slot, req, "measured")

@@ -596,7 +596,7 @@ def test_nan_containment_failed_eviction_and_survivor_parity(x64):
     assert len(poisoned.output_ids) == tokens_before  # garbage token not emitted
     assert survivor.ok and survivor.result().tolist() == ref.result().tolist()
     # quarantine: nothing non-finite survives anywhere in the pool
-    assert np.isfinite(np.asarray(engine._state.next_logits)).all()
+    assert np.isfinite(np.asarray(engine._state.next_hidden)).all()
     assert np.isfinite(np.asarray(engine._cache.ca.k)).all()
     snap = engine.metrics.snapshot()
     assert snap["failed"] == 1 and snap["requests_finished"] == 1

@@ -256,8 +256,8 @@ def test_greedy_temperature_zero_served_and_neutral(setup):
 
 
 def test_release_zeroes_freed_slot_state(setup):
-    """Satellite: a freed slot's rng and next_logits rows are zeroed (with the
-    sampling fields already neutral) so pool dumps are reproducible."""
+    """Satellite: a freed slot's rng and its carried hidden row are zeroed (with
+    the sampling fields already neutral) so pool dumps are reproducible."""
     model, params = setup
     engine = ServingEngine(model, params, num_slots=2)
     h = engine.submit([3, 1, 4], config=GenerationConfig(max_new_tokens=3, do_sample=True,
@@ -268,7 +268,8 @@ def test_release_zeroes_freed_slot_state(setup):
     state = engine._state
     assert not bool(state.active.any())
     assert np.asarray(state.rng).sum() == 0
-    assert np.asarray(state.next_logits).sum() == 0
+    assert state.next_hidden.shape == (2, model.config.num_channels)
+    assert np.abs(np.asarray(state.next_hidden)).sum() == 0
     assert np.asarray(state.do_sample).sum() == 0
     np.testing.assert_array_equal(np.asarray(state.temperature), 1.0)
     np.testing.assert_array_equal(np.asarray(state.top_k), 0)
