@@ -232,3 +232,81 @@ class FalconH1Config:
         known = {f.name for f in fields(cls)}
         picked = {k: (tuple(v) if isinstance(v, list) else v) for k, v in kwargs.items() if k in known}
         return cls(**picked)
+
+
+@dataclass(frozen=True)
+class Lfm2MoeConfig:
+    """An LFM2 mixture-of-experts decoder (``models/core/lfm2_moe.py``) under
+    the keys of its published ``config.json`` (``model_type`` ``lfm2_moe``):
+    layer ``l`` mixes tokens by a gated short convolution (``layer_types[l]``
+    ``"conv"``) or by grouped-query attention (``"full_attention"``); the first
+    ``num_dense_layers`` layers have a dense gated MLP of
+    ``intermediate_size``, the others ``num_experts`` routed experts of
+    ``moe_intermediate_size``, ``num_experts_per_tok`` a token. Every expert
+    of a layer lies here (``ops/moe.py``'s layer takes the range it holds as an
+    argument; a model cut across chips waits for the exchange that completes
+    its sum).
+
+    This program's own: ``max_seq_len``, the most tokens a serving slot can
+    hold."""
+
+    vocab_size: int = 65536
+    hidden_size: int = 2048
+    intermediate_size: int = 7168
+    moe_intermediate_size: int = 1792
+    num_hidden_layers: int = 24
+    num_dense_layers: int = 2
+    layer_types: Tuple[str, ...] = (
+        "conv", "conv", "full_attention", "conv", "conv", "conv", "full_attention", "conv", "conv", "conv",
+        "full_attention", "conv", "conv", "conv", "full_attention", "conv", "conv", "conv", "full_attention",
+        "conv", "conv", "full_attention", "conv", "conv")
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    head_dim: int = 64
+    conv_L_cache: int = 3
+    num_experts: int = 32
+    num_experts_per_tok: int = 4
+    norm_topk_prob: bool = True
+    use_expert_bias: bool = True
+    routed_scaling_factor: float = 1.0
+    norm_eps: float = 1e-5
+    rope_theta: float = 1e6
+    max_position_embeddings: int = 128000
+    max_seq_len: int = 2048
+    init_scale: float = 0.02
+
+    def __post_init__(self):
+        if len(self.layer_types) != self.num_hidden_layers:
+            raise ValueError(f"layer_types names {len(self.layer_types)} layers, num_hidden_layers is "
+                             f"{self.num_hidden_layers}")
+        unknown = set(self.layer_types) - {"conv", "full_attention"}
+        if unknown:
+            raise ValueError(f"layer_types holds {sorted(unknown)}: a layer is 'conv' or 'full_attention'")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError(
+                f"num_attention_heads ({self.num_attention_heads}) must be a multiple of "
+                f"num_key_value_heads ({self.num_key_value_heads})")
+        if self.num_experts_per_tok > self.num_experts:
+            raise ValueError("num_experts_per_tok exceeds num_experts")
+        if not 1 <= self.max_seq_len <= self.max_position_embeddings:
+            raise ValueError(
+                f"max_seq_len ({self.max_seq_len}) must lie in [1..max_position_embeddings="
+                f"{self.max_position_embeddings}]")
+
+    @property
+    def attention_layers(self) -> Tuple[int, ...]:
+        return tuple(l for l, kind in enumerate(self.layer_types) if kind == "full_attention")
+
+    @property
+    def conv_layers(self) -> Tuple[int, ...]:
+        return tuple(l for l, kind in enumerate(self.layer_types) if kind == "conv")
+
+    @property
+    def expert_layers(self) -> Tuple[int, ...]:
+        return tuple(range(self.num_dense_layers, self.num_hidden_layers))
+
+    @classmethod
+    def create(cls, **kwargs):
+        known = {f.name for f in fields(cls)}
+        picked = {k: (tuple(v) if isinstance(v, list) else v) for k, v in kwargs.items() if k in known}
+        return cls(**picked)

@@ -434,7 +434,7 @@ class FalconH1ForCausalLM(nn.Module):
         visible = jnp.where(active, pos + 1, 0)
         use_ssm_kernel = ssm.ssm_kernel_supported(cfg.mamba_n_heads, cfg.mamba_n_groups, cfg.mamba_d_head,
                                                   cfg.mamba_d_state)
-        use_gqa_kernel = paged.paged_gqa_decode_supported(ps, cfg.head_dim)
+        use_gqa_kernel = paged.paged_gqa_decode_supported(ps, cfg.head_dim, cfg.num_key_value_heads)
         kp, vp, ssm_state, conv_state = cache.kp, cache.vp, cache.ssm_state, cache.conv_state
         h = self._embed(ids[:, 0])
         for l, p in enumerate(self.layers):

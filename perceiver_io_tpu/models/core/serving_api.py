@@ -34,6 +34,19 @@ phase begins with ONE pass of (e) over every slot's row, samples from it, runs
 (d) on the sampled tokens and stores the new rows. A slot whose prompt ended
 in this tick's finish lane is sampled in the same tick, through the same pass.
 
+A model with routed expert layers MAY also count, on the device, what only
+the device knows, which experts its tokens were routed to:
+
+(f) ``ServingTraits.expert_counters`` = (expert layers, experts), with
+    ``take_expert_counts(taken)`` on its cache: ``(counts (2, layers, experts)
+    int32, cache)``, the assignments each expert received in the decode steps
+    (row 0) and in the chunk lanes (row 1) since the counters were last taken;
+    they start again from zero where the traced flag ``taken`` holds. The tick
+    program appends them to its token output, so they reach the host in the
+    tick's ONE readback of the tokens (no copy of their own), and the engine
+    books them (``EngineMetrics.record_expert_counts``, the tick's record). A
+    model without the field counts nothing and its tick returns what it did.
+
 ``serving_traits()`` says, in plain data, what else differs: which prompts
 take the split admission, what the descriptor's lanes carry, and which engine
 options the model does not carry yet (each with the piece that is missing, so
@@ -43,7 +56,7 @@ the engine can refuse it at construction).
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict
+from typing import Dict, Optional, Tuple
 
 
 @dataclass(frozen=True)
@@ -62,7 +75,14 @@ class ServingTraits:
     # such a state, a claimed slot's first chunk lane carries ``reset`` (the
     # state is zeroed inside the tick) and every chunk lane names its slot
     recurrent_bytes_per_slot: int = 0
-    # engine option -> why this model cannot be served with it yet
+    # (f): (expert layers, experts) of the assignment counters the model's cache
+    # keeps on the device and the tick returns with its tokens; None: the model
+    # has no routed experts, counts nothing and pays nothing
+    expert_counters: Optional[Tuple[int, int]] = None
+    # engine option -> why this model cannot be served with it yet. (What the
+    # grouped-query paged decode kernel takes is no option: full-precision
+    # pages whose row ``kv_heads * head_dim`` is lane-aligned: heads of 128, or
+    # of 64 where the row is a multiple of 128, ``paged_gqa_decode_supported``.)
     unsupported: Dict[str, str] = field(default_factory=dict)
 
     @property

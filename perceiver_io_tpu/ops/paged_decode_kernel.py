@@ -722,13 +722,17 @@ def gqa_pages_per_step(pages_per_slot: int, most: int = 8) -> int:
     return max(g for g in range(1, most + 1) if pages_per_slot % g == 0)
 
 
-def paged_gqa_decode_supported(page_size: int, head_dim: int) -> bool:
-    """The grouped-query paged kernel on one TPU device: lane-aligned heads and
-    sublane-aligned pages; the XLA fallback (a dense gather through the page
-    table) serves the CPU suite and sharded pools."""
+def paged_gqa_decode_supported(page_size: int, head_dim: int, kv_heads: int) -> bool:
+    """The grouped-query paged kernel on one TPU device: sublane-aligned pages
+    and a lane-aligned pool row ``kv_heads * head_dim``: heads that are whole
+    lane tiles (``head_dim`` a multiple of 128), or narrower ones whose row is
+    (8 heads of 64): such a slot's result leaves the kernel as whole pool rows
+    and its heads are picked out after it
+    (``fused_paged_decode_attention_gqa``). The XLA fallback (a dense gather
+    through the page table) serves the CPU suite and sharded pools."""
     if jax.default_backend() != "tpu" or not single_device_trace():
         return False
-    return head_dim % 128 == 0 and page_size % 8 == 0
+    return (kv_heads * head_dim) % 128 == 0 and page_size % 8 == 0
 
 
 def _blockdiag_gqa_queries(q: jax.Array, kv_heads: int, rows: int) -> jax.Array:
@@ -751,7 +755,9 @@ def _gqa_kernel(len_ref, table_ref, q_ref, *refs, page_size, group):
     q_ref (rows, h_kv*d)             block-diagonal queries of slot bi
     k_refs / v_refs (ps, h_kv*d)     ``group`` pages each, rotated keys
     diag_ref (rows, h_kv*d)          1 where a row's K/V head owns the column
-    o_ref (rows, d)                  row ``g*h_kv + kv``: query head ``kv*n_rep + g``
+    o_ref (rows, d)                  row ``g*h_kv + kv``: query head ``kv*n_rep + g``;
+                                     or (rows, h_kv*d) where ``d`` is no whole lane
+                                     tile: the row's own head's columns, zeros elsewhere
     scratch m, l (rows, 128), acc (rows, h_kv*d), float32
     """
     import jax.experimental.pallas as pl
@@ -792,6 +798,10 @@ def _gqa_kernel(len_ref, table_ref, q_ref, *refs, page_size, group):
         d = o_ref.shape[-1]
         # a slot of length 0 never accumulated: 0 / eps is an exact zero row
         own = acc_ref[...] * diag_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
+        if d == own.shape[-1]:
+            # heads narrower than a lane tile: the whole row goes out, lane-dense
+            o_ref[...] = own.astype(o_ref.dtype)
+            return
         out = own[:, :d]
         for kv in range(1, own.shape[-1] // d):
             out = out + own[:, kv * d:(kv + 1) * d]
@@ -811,7 +821,13 @@ def fused_paged_decode_attention_gqa(
     """q (B, h_q, d) scaled+rotated single queries; kp / vp (layers, N, ps,
     h_kv*d) pools of ROTATED keys and values; page_table (B, P); length (B,)
     visible tokens per slot, the one just appended included (0: the slot is
-    skipped and its row comes back zero). Returns (B, h_q, d) in q's dtype."""
+    skipped and its row comes back zero). Returns (B, h_q, d) in q's dtype.
+
+    Heads of a whole lane tile (``d`` a multiple of 128) leave the kernel as
+    ``(rows, d)`` blocks. Narrower ones (64) would make that block half a tile
+    wide and its stores masked, so the kernel writes each row at the pool's
+    width ``h_kv*d``, zeros outside the row's own head, and the head's
+    columns are picked out here."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -819,6 +835,7 @@ def fused_paged_decode_attention_gqa(
     _, _, ps, c = kp.shape
     kv_heads = c // d
     n_rep = hq // kv_heads
+    out_d = d if d % 128 == 0 else c
     p = page_table.shape[1]
     group = gqa_pages_per_step(p)
     rows = -(-hq // 16) * 16  # whole bf16 sublane tiles
@@ -839,18 +856,22 @@ def fused_paged_decode_attention_gqa(
         grid=(b, p // group),
         in_specs=[pl.BlockSpec((None, rows, c), lambda bi, i, *_: (bi, 0, 0)), *pools, *pools,
                   pl.BlockSpec((rows, c), lambda bi, i, *_: (0, 0))],
-        out_specs=pl.BlockSpec((None, rows, d), lambda bi, i, *_: (bi, 0, 0)),
+        out_specs=pl.BlockSpec((None, rows, out_d), lambda bi, i, *_: (bi, 0, 0)),
         scratch_shapes=[pltpu.VMEM((rows, 128), jnp.float32), pltpu.VMEM((rows, 128), jnp.float32),
                         pltpu.VMEM((rows, c), jnp.float32)],
     )
     out = pl.pallas_call(
         functools.partial(_gqa_kernel, page_size=ps, group=group),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, rows, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, rows, out_d), q.dtype),
         interpret=interpret,
         name="fused_paged_decode_attention_gqa",
     )(length, page_table, _blockdiag_gqa_queries(q, kv_heads, rows),
       *([kp] * group), *([vp] * group), diag.astype(jnp.float32))
+    if out_d != d:
+        # row g*h_kv + kv holds its head in the columns of K/V head kv: the diagonal of the two K/V axes
+        out = jnp.einsum("bgkkd->bgkd", out[:, :hq].reshape(b, n_rep, kv_heads, kv_heads, d))
+        return out.transpose(0, 2, 1, 3).reshape(b, hq, d)
     # row g*h_kv + kv -> query head kv*n_rep + g
     return out[:, :hq].reshape(b, n_rep, kv_heads, d).transpose(0, 2, 1, 3).reshape(b, hq, d)
 

@@ -423,6 +423,11 @@ TICK_SCOPES = {phase: f"tick.{phase}" for phase in (
 TICK_SCOPES.update({part: f"tick.{part}" for part in (
     "decode/ssm_update", "decode/attention", "decode/mlp",
     "chunk_lanes/ssd_scan", "chunk_lanes/attention")})
+# and a model of short-convolution, attention and routed expert layers
+# (models/core/lfm2_moe.py), in both phases alike: the router and the grouped
+# expert products under ``moe``, the gated short convolution
+TICK_SCOPES.update({f"{phase}/{part}": f"tick.{phase}/{part}" for phase in ("decode", "chunk_lanes")
+                    for part in ("moe/route", "moe/experts", "short_conv", "attention", "mlp")})
 
 
 class TickRecord(NamedTuple):
@@ -842,6 +847,8 @@ class ServingEngine:
         # weight-serving dtype/bytes — None (off) on fp engines
         if traits.recurrent_state:
             self.metrics.set_recurrent_state(num_slots * traits.recurrent_bytes_per_slot)
+        if traits.expert_counters is not None:
+            self.metrics.set_expert_counters(*traits.expert_counters)
         if self.kv_quant is not None:
             cfg = model.config
             fp_b, served_b = kv_bytes_per_token(
@@ -1165,8 +1172,16 @@ class ServingEngine:
                     return (jnp.zeros((self.num_slots,), jnp.int32),
                             jnp.ones((self.num_slots,), bool), cache, state)
 
-                return jax.lax.cond(any_decode, decode_phase, no_decode,
-                                    (cache, state))
+                tok, finite, cache, state = jax.lax.cond(
+                    any_decode, decode_phase, no_decode, (cache, state))
+                if self._traits.expert_counters is not None:
+                    # serving_api.py (f): the experts' counters ride the token
+                    # output, so the host's one readback of the tokens brings
+                    # them; a tick that decodes nothing is not read, and its
+                    # chunk lanes' counts wait in the cache for the next that is
+                    counts, cache = cache.take_expert_counts(any_decode)
+                    tok = jnp.concatenate([tok, counts.reshape(-1)])
+                return tok, finite, cache, state
 
             self._jit_ragged_tick = ragged_tick
         else:
@@ -2703,13 +2718,22 @@ class ServingEngine:
         # whole record at its BEGIN, where the profiler's annotation is entered
         obs.span_begin(self._span_sample_sync, **fields)
         tok = np.asarray(tok)  # blocks: the step's ONE device sync point
+        expert_fields = _NO_FIELDS
+        if self._traits.expert_counters is not None:
+            # the experts' counters came with the tokens (serving_api.py (f)):
+            # what they say rides, beside the record, the spans that begin or
+            # end after this readback (harvest, tick)
+            tok, counts = tok[:self.num_slots], tok[self.num_slots:].reshape(2, *self._traits.expert_counters)
+            assignments, touched = self.metrics.record_expert_counts(counts)
+            if self._obs_on:
+                expert_fields = {"expert_assignments": assignments, "experts_touched": touched}
         # NOT free: the program is done, but this is a second device-to-host
         # copy after the first (0.4 ms a tick on a TPU v5 lite, PERF.md 6 PR 38)
         finite = np.asarray(finite)
         t_sync = obs.span_end(self._span_sample_sync)
         # harvest: everything from the sync's return to the end of the step's
         # host work (the device idles from here until the next dispatch)
-        obs.span_begin(self._span_harvest, at=t_sync, tick=tick)
+        obs.span_begin(self._span_harvest, at=t_sync, tick=tick, **expert_fields)
         # the ONE host time of this tick's tokens: every slot's first-token
         # and inter-token stamps below share it
         now = time.perf_counter()
@@ -2843,7 +2867,7 @@ class ServingEngine:
         self._journal_flush()
         has_work = self.scheduler.has_work
         self._harvest_end = obs.span_end(self._span_harvest)
-        obs.span_end(self._span_tick, at=self._harvest_end, **fields)
+        obs.span_end(self._span_tick, at=self._harvest_end, **fields, **expert_fields)
         if not has_work:
             # the engine holds no request: whatever passes until the next
             # one arrives is not the loop's cost

@@ -180,7 +180,7 @@ import json
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Deque, Dict, List, Optional
+from typing import Deque, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -237,6 +237,15 @@ def _latency_dict(xs) -> Dict[str, float]:
         "p50": round(float(p50), 6),
         "p95": round(float(p95), 6),
     }
+
+
+def _load_max_over_mean(assignments: np.ndarray) -> float:
+    """The busiest expert's assignments over its layer's mean, the worst layer's
+    (1.0: perfectly even; 0.0 before any assignment)."""
+    mean = assignments.mean(axis=-1)
+    if not (mean > 0).any():
+        return 0.0
+    return round(float((assignments.max(axis=-1)[mean > 0] / mean[mean > 0]).max()), 6)
 
 
 def load_metrics_jsonl(path: str) -> Dict:
@@ -453,6 +462,12 @@ class EngineMetrics(_JsonlMetrics):
     recurrent_state_bytes: Optional[int] = None
     recurrent_resets: int = 0
     recurrent_chunks_carried: int = 0
+    # routed-expert gauges: None <=> the served model has no expert layer (or
+    # counts nothing) and snapshots report experts: None. (expert layers,
+    # experts) int64 sums of what the ticks' counters returned, and per
+    # decoding tick the mean number of experts a layer that received a row
+    expert_assignments: Optional[np.ndarray] = None
+    _experts_touched: Deque[float] = field(default_factory=lambda: deque(maxlen=LATENCY_WINDOW))
     _start_time: Optional[float] = None
     _occupancy_sum: float = 0.0  # sum over steps of active_slots / num_slots
     _pages_per_request: Deque[int] = field(default_factory=lambda: deque(maxlen=LATENCY_WINDOW))
@@ -580,6 +595,25 @@ class EngineMetrics(_JsonlMetrics):
         snapshots report the recurrent_state section instead of None.
         ``state_bytes``: what the pool's recurrent states take on the device."""
         self.recurrent_state_bytes = int(state_bytes)
+
+    def set_expert_counters(self, layers: int, experts: int) -> None:
+        """Mark a model whose routed expert layers count their assignments on
+        the device (models/core/serving_api.py (f)): snapshots report the
+        experts section instead of None."""
+        self.expert_assignments = np.zeros((int(layers), int(experts)), np.int64)
+
+    def record_expert_counts(self, counts: np.ndarray) -> Tuple[int, float]:
+        """One harvested tick's counters, ``counts`` (2, expert layers, experts):
+        the assignments each expert received in the tick's decode step (row 0)
+        and in chunk lanes since the last harvested tick (row 1). Returns what
+        goes on the tick's record: (assignments, both rows; the mean number of
+        experts a layer with a row in the DECODE step, the call whose weight
+        stream bounds a decoding tick). Windowed, no JSONL event: it fires
+        every tick."""
+        self.expert_assignments += counts.sum(axis=0)
+        touched = float((counts[0] > 0).sum(axis=-1).mean())
+        self._experts_touched.append(touched)
+        return int(counts.sum()), touched
 
     def record_recurrent_chunk(self, reset: bool) -> None:
         """One chunk lane of a recurrent model: it either starts its slot's
@@ -857,6 +891,21 @@ class EngineMetrics(_JsonlMetrics):
                 "resets": self.recurrent_resets,
                 "chunks_carried": self.recurrent_chunks_carried,
                 "decoding_slots": decoding_slots,
+            },
+            # None unless the served model has routed expert layers that count
+            # on the device: assignments per layer and expert since the start
+            # (prefill and decode alike), per decoding tick the experts a layer
+            # that received a row in the decode step, and the busiest expert's
+            # assignments over the mean of its layer's, the worst layer's
+            "experts": None if self.expert_assignments is None else {
+                "layers": int(self.expert_assignments.shape[0]),
+                "experts": int(self.expert_assignments.shape[1]),
+                "assignments": self.expert_assignments.tolist(),
+                "touched_per_step": {
+                    k: v for k, v in _latency_dict(self._experts_touched).items()
+                    if k in _MEAN_AND_PERCENTILE_KEYS
+                },
+                "load_max_over_mean": _load_max_over_mean(self.expert_assignments),
             },
             # v11: None on dense engines (no tick dispatcher exists — same
             # reading as a pre-v11 snapshot); on paged engines the per-tick

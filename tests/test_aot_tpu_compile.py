@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import pytest
 
 from perceiver_io_tpu.ops import decode_kernel as dk
+from perceiver_io_tpu.ops import moe
 from perceiver_io_tpu.ops import paged_decode_kernel as pdk
 from perceiver_io_tpu.ops import ragged_paged_kernel as rpk
 from perceiver_io_tpu.ops import ssm
@@ -140,7 +141,26 @@ def _ssm_update(sds, heads, groups, head_dim, state, layers, slots):
         sds((slots, groups, state), f32), sds((slots,), jnp.bool_))
 
 
+def _experts(sds, experts, hidden, width, top_k, rows):
+    """A routed expert layer's two grouped products, through ``expert_layer``: the layout
+    from the router's choices (a dynamic grid bound), the gated product over the stacked
+    gate-and-up matrices, the down product."""
+    bf16 = jnp.bfloat16
+    weights = moe.ExpertWeights(sds((hidden, experts), bf16), sds((experts,), bf16),
+                                sds((experts, hidden, 2 * width), bf16), sds((experts, width, hidden), bf16))
+    return jax.jit(lambda x, w, valid: moe.expert_layer(x, w, (0, experts), top_k, valid=valid, use_kernel=True)).lower(
+        sds((rows, hidden), bf16), weights, sds((rows,), jnp.bool_))
+
+
 CASES = {
+    # LFM2-8B-A1B's widths at the serving cell's sizes: 32 query heads over 8 K/V
+    # heads of 64 (the pool's row is 512 wide; a slot's result leaves the kernel at
+    # that width), 3 attention layers, 128 slots, 2049 pages of 64 under a 1024-token
+    # row; 32 experts of 2048 x 1792, 4 a token, a decode step's 128 rows and a
+    # chunk lane's 256
+    "gqa-paged-32over8x64-l3-b128-w1024": (_gqa, 32, 8, 64, 3, 128, 2049, 64, 1024),
+    "experts-32x2048x1792-top4-rows128": (_experts, 32, 2048, 1792, 4, 128),
+    "experts-32x2048x1792-top4-rows256": (_experts, 32, 2048, 1792, 4, 256),
     # Falcon-H1-34B's widths at the serving cell's sizes: 20 query heads over 4
     # K/V heads of 128, 128 slots, 3072 pages of 64 under a 1536-token row; 32
     # mixer heads of 128 x state 256 in 2 groups, 4 layers
