@@ -331,6 +331,12 @@ class FalconH1ForCausalLM(nn.Module):
         return ServingTraits(
             vocab_size=cfg.vocab_size, hidden_size=cfg.hidden_size, window=cfg.max_seq_len, finish_ids=0,
             recurrent_bytes_per_slot=cfg.num_hidden_layers * state,
+            # (g) ``in_proj`` is the one matrix whose column count (z | xBC | dt: 9,248 at the published
+            # widths) is no multiple of 128, so the one the compiler takes transposed, the layout that pads
+            # nothing. The decode step reads that; the chunk lanes' product does not, and copied each layer's
+            # 94.7 MB three times in front of the lanes' loop. Row-major is what every other matrix arrives in
+            # and both branches read without a copy (the compiled tick: tests/test_aot_tpu_compile.py)
+            row_major_leaves=tuple(f"params/layers_{i}_in_proj" for i in range(cfg.num_hidden_layers)),
             unsupported={
                 "prefix_cache": f"{missing} at page boundaries, so a shared prefix's pages would come "
                                 "without the state that goes with them",

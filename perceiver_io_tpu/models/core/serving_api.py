@@ -2,7 +2,7 @@
 
 The engine owns slots, pages, the tick loop and the descriptor; it knows no
 architecture. A model class answers five questions, by methods the engine
-calls on the (unbound) module:
+calls on the (unbound) module, and may make two statements more, (f) and (g):
 
 (a) its cache: ``init_paged_cache(num_slots, num_pages, page_size, dtype,
     kv_quant)`` returns ONE pytree holding everything a slot keeps between
@@ -47,6 +47,23 @@ the device knows, which experts its tokens were routed to:
     books them (``EngineMetrics.record_expert_counts``, the tick's record). A
     model without the field counts nothing and its tick returns what it did.
 
+A model MAY also say in which layout the tick must RECEIVE a weight:
+
+(g) ``ServingTraits.row_major_leaves``: leaves of the parameter tree, each by
+    its path of keys joined with "/" (``"params/layers_0_in_proj"``). An
+    argument's layout is the compiler's choice, made from the leaf's shape
+    alone and before any program exists; where a branch of the tick cannot
+    read that choice, the branch copies the whole weight in front of its
+    work, every time it runs. The engine keeps a named leaf on the device
+    row-major (last dimension minor), laid out ONCE, at construction and at
+    ``set_params``, and hands the model its own matrix back at the entry of
+    every program it builds over the parameters (``serving/weight_layout.py``
+    says how, and why not through a ``Format``), so it is the layout of the
+    ARGUMENT and nothing is laid out again per call. A name that is no leaf
+    of the tree is refused at construction. A statement, not a rule over
+    shapes: a model names the matrices whose compiled tick was read and found
+    to copy. A model that names none is handed its weights as it always was.
+
 ``serving_traits()`` says, in plain data, what else differs: which prompts
 take the split admission, what the descriptor's lanes carry, and which engine
 options the model does not carry yet (each with the piece that is missing, so
@@ -79,6 +96,9 @@ class ServingTraits:
     # keeps on the device and the tick returns with its tokens; None: the model
     # has no routed experts, counts nothing and pays nothing
     expert_counters: Optional[Tuple[int, int]] = None
+    # (g): parameter leaves ("/"-joined key paths) the tick must receive
+    # row-major; (): the compiler lays every argument out as it chooses
+    row_major_leaves: Tuple[str, ...] = ()
     # engine option -> why this model cannot be served with it yet. (What the
     # grouped-query paged decode kernel takes is no option: full-precision
     # pages whose row ``kv_heads * head_dim`` is lane-aligned: heads of 128, or

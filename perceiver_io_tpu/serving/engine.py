@@ -192,6 +192,7 @@ from perceiver_io_tpu.serving.quant import (
 )
 from perceiver_io_tpu.serving.scheduler import SlotScheduler, preemption_enabled
 from perceiver_io_tpu.serving.tick_descriptor import TickDescriptorLayout
+from perceiver_io_tpu.serving.weight_layout import merge_rows, missing_leaves, split_rows
 
 
 class SlotState(flax.struct.PyTreeNode):
@@ -524,10 +525,18 @@ class ServingEngine:
                 f"weight_dtype must be one of {WEIGHT_DTYPES} or None, got {weight_dtype!r}"
             )
         self.weight_dtype = weight_dtype
+        # serving_api.py (g): leaves the model says the tick must receive
+        # row-major are laid out so here, once (serving/weight_layout.py: in
+        # a shape whose default layout is row-major), and every program views
+        # them as the model's matrices again in its entry hook, beside the
+        # dequantization; a model that names none is handed its tree as it came
+        absent = missing_leaves(params, traits.row_major_leaves)
+        if absent:
+            raise ValueError(
+                f"{type(model).__name__}.serving_traits().row_major_leaves names "
+                f"{absent}, which are no leaves of the parameter tree")
         (self.params, self._dequant_params,
-         self._param_bytes, self._param_bytes_fp) = serve_params(
-            params, self.weight_dtype
-        )
+         self._param_bytes, self._param_bytes_fp) = self._serve_params(params)
         self.num_slots = num_slots
         # observability namespace: a router fronting N engines on ONE shared
         # recorder gives each replica its own prefix ("serving.r0", ...) so
@@ -925,6 +934,8 @@ class ServingEngine:
         # FIRST op of every params-consuming program — the resident tree
         # stays int8, the dequantized copy is a per-execution transient.
         # Identity for weight_dtype None/bf16: the traces are untouched.
+        # Leaves the model states row-major (_serve_params) become its
+        # matrices again in the same hook: a bitcast, no transient.
         dq = self._dequant_params
 
         @partial(jax.jit, static_argnames=("bucket",))
@@ -1243,6 +1254,17 @@ class ServingEngine:
         return self._jit_decode.lower(self.params, self._cache, self._state, *idle)
 
     # ----------------------------------------------------------------- params
+    def _serve_params(self, params):
+        """``serve_params`` under this engine's ``weight_dtype``, then the
+        layouts the model states (serving_api.py (g)) on the leaves the
+        transform left in place: the tree construction and ``set_params`` hand
+        the compiled programs, and the hook that undoes both on entry."""
+        served, dq, served_bytes, fp_bytes = serve_params(params, self.weight_dtype)
+        names = self._traits.row_major_leaves
+        if not names:
+            return served, dq, served_bytes, fp_bytes
+        return split_rows(served, names), (lambda p: merge_rows(dq(p), names)), served_bytes, fp_bytes
+
     def set_params(self, params) -> None:
         """Swap the served parameters IN PLACE — the live model-version
         rollout primitive (docs/serving.md "Fleet operations"). The compiled
@@ -1258,7 +1280,7 @@ class ServingEngine:
         that holds no in-flight sessions: a running slot's KV was built by
         the OLD params and continuing it under new ones would break the
         token-identity contract."""
-        served, _dq, served_bytes, fp_bytes = serve_params(params, self.weight_dtype)
+        served, _dq, served_bytes, fp_bytes = self._serve_params(params)
         if tree_layout_mismatch(self.params, served):
             raise ValueError(
                 "set_params requires a tree with the structure, shapes, and "

@@ -199,3 +199,53 @@ def test_kernel_compiles_for_v5e(v5e, case):
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
     compiled = build(sds, *shape).compile()  # raises what the chip's compiler would raise
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def falcon_tick_text(v5e):
+    """The compiled text of ``serve-falcon-h1-chat``'s WHOLE tick: the published widths
+    of ``benchmark/configs/falcon-h1-34b-4l.json`` under the cell's engine settings,
+    abstract weights that lie on the described v5e, lowered through the engine's own
+    arguments and jit (about 40 s; the pool's 3.8 GB of zeros are built on the CPU)."""
+    from benchmark.harness import manifest
+    from perceiver_io_tpu.serving import ServingEngine
+
+    cell = manifest.resolve_cell("serve-falcon-h1-chat")
+    config = cell["config"]
+    family = manifest.load_family(config["family"])
+    weights = jax.eval_shape(lambda: family.build_weights(config["sizes"], jax.random.PRNGKey(0), jnp.bfloat16))
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e)
+    params = jax.tree_util.tree_map(sds, family.to_program_params(weights))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels' gates ask it; the engine is built on the CPU
+        engine = ServingEngine(family.build_model(config, deterministic=True), params, **cell["settings"]["engine"],
+                               telemetry=False)
+        args = engine._ragged_args(True, engine._forced_none, engine._use_forced_none)
+        # the parameters are the shapes the engine keeps; the pool's arrays become shapes on the v5e
+        shapes = jax.tree_util.tree_map(lambda x: x if isinstance(x, jax.ShapeDtypeStruct) else sds(x), args)
+        return engine._jit_ragged_tick.lower(*shapes).compile().as_text()
+
+
+# hidden x (z 4096 | xBC 5120 | dt 32): the one matrix with a ragged column count, as the model holds it and as
+# the engine hands it to the tick (its rows in tiles of 16: serving/weight_layout.py)
+IN_PROJ, IN_PROJ_KEPT = "bf16[5120,9248]", "bf16[320,16,9248]"
+
+
+@pytest.mark.parametrize("holds", ["no copy of in_proj", "in_proj arrives row-major", "the kernels are named"])
+def test_falcon_tick_is_handed_in_proj_as_both_branches_read_it(falcon_tick_text, holds):
+    """ISSUE 45: taken as the compiler chooses, ``in_proj`` arrives transposed (9,248
+    columns pad nothing that way) and the chunk branch copied each layer's 94.7 MB three
+    times in front of its loop, twelve copies a lane tick; stated row-major
+    (``ServingTraits.row_major_leaves``) no computation of the tick copies it."""
+    lines = falcon_tick_text.splitlines()
+    if holds == "no copy of in_proj":
+        copies = [line.strip()[:160] for line in lines if " copy(" in line and (IN_PROJ in line or IN_PROJ_KEPT in line)]
+        assert not copies, copies
+    elif holds == "in_proj arrives row-major":
+        entry = [line for line in lines if "_in_proj" in line and " parameter(" in line and "params___" in line]
+        assert len(entry) == 4 and all(IN_PROJ_KEPT + "{2,1,0:T(8,128)(2,1)}" in line for line in entry), \
+            [line[:160] for line in entry]
+        # and both branches read it as the matrix without moving it
+        assert any(IN_PROJ + "{1,0:T(8,128)(2,1)} bitcast(" in line for line in lines)
+    else:
+        assert "ssm_decode_update" in falcon_tick_text and "fused_paged_decode_attention_gqa" in falcon_tick_text
