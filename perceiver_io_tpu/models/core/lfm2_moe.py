@@ -33,6 +33,7 @@ normalisation, and the softmax in float32.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Callable, Optional, Tuple
 
 import flax.linen as nn
@@ -42,13 +43,19 @@ import jax.numpy as jnp
 
 from perceiver_io_tpu.models.core.config import Lfm2MoeConfig
 from perceiver_io_tpu.models.core.falcon_h1 import rms_norm, rope_half
-from perceiver_io_tpu.models.core.serving_api import ServingTraits
+from perceiver_io_tpu.models.core.serving_api import TICK_CHUNK_SCOPE, TICK_DECODE_SCOPE, ServingTraits
 from perceiver_io_tpu.ops import moe
 from perceiver_io_tpu.ops import paged_decode_kernel as paged
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 # rows of ``Lfm2MoeCache.expert_counts``: the decode step's assignments, the chunk lanes'
 DECODE_COUNTS, CHUNK_COUNTS = 0, 1
+
+
+def _lane(lanes, i):
+    """Chunk lane ``i`` of the tick's descriptor, as ``prefill_chunk_paged`` takes it."""
+    return (lanes.ch_ids[i], lanes.ch_offset[i], lanes.ch_count[i], lanes.ch_reset[i], lanes.ch_slot[i],
+            lanes.ch_tables[i])
 
 
 # ------------------------------------------------------------------- the cache
@@ -234,9 +241,10 @@ class Lfm2MoeForCausalLM(nn.Module):
                        preferred_element_type=jnp.float32).astype(dt)
         return self._mm(o.reshape(n, hq * hd), p["o_proj"])
 
-    def _ffn(self, p, h: jax.Array, valid: Optional[jax.Array] = None):
+    def _ffn(self, p, h: jax.Array, valid: Optional[jax.Array] = None, load_groups: Optional[jax.Array] = None):
         """h (T, hidden) -> (the feed-forward's contribution (T, hidden), the
-        experts' load (experts,) int32, or None for a dense layer)."""
+        experts' load (experts,) int32, by group of rows (G, experts) where
+        ``load_groups`` (G, T) names them, or None for a dense layer)."""
         cfg = self.config
         x = self._norm(h, p["ffn_norm"])
         if "w1" in p:
@@ -249,7 +257,8 @@ class Lfm2MoeForCausalLM(nn.Module):
             return moe.expert_layer(
                 x.astype(self._dt), weights, (0, cfg.num_experts), cfg.num_experts_per_tok,
                 cfg.routed_scaling_factor, cfg.norm_topk_prob, valid,
-                use_kernel=moe.grouped_kernel_supported(cfg.hidden_size, cfg.moe_intermediate_size))
+                use_kernel=moe.grouped_kernel_supported(cfg.hidden_size, cfg.moe_intermediate_size),
+                load_groups=load_groups)
 
     # --------------------------------------------------------- full forward
     def _forward_one(self, ids: jax.Array) -> jax.Array:
@@ -309,6 +318,9 @@ class Lfm2MoeForCausalLM(nn.Module):
             vocab_size=cfg.vocab_size, hidden_size=cfg.hidden_size, window=cfg.max_seq_len, finish_ids=0,
             recurrent_bytes_per_slot=state,
             expert_counters=(len(cfg.expert_layers), cfg.num_experts) if cfg.expert_layers else None,
+            # an expert's matrices are read once a call, and a decode step and a chunk lane are
+            # both bound by that read: the lane's rows ride the decode step's one call a layer
+            chunk_rides_decode=bool(cfg.expert_layers),
             unsupported={
                 "prefix_cache": f"{missing} at page boundaries, so a shared prefix's pages would come "
                                 "without the columns that go with them",
@@ -323,15 +335,24 @@ class Lfm2MoeForCausalLM(nn.Module):
         """(b) every attention layer is full attention: a request holds all its tokens."""
         return -(-min(prompt_tokens + max_new_tokens, self.config.max_seq_len) // page_size)
 
-    def serving_chunk_phase(self, params, cache: Lfm2MoeCache, lanes) -> Lfm2MoeCache:
-        """(c) the tick's chunk lanes, packed from lane 0: each writes its rows'
-        keys and values into the slot's pages in every attention layer and
-        carries the slot's convolution columns on."""
-        def lane(i, cache):
-            return self.apply(params, lanes.ch_ids[i], lanes.ch_offset[i], lanes.ch_count[i], lanes.ch_reset[i],
-                              lanes.ch_slot[i], lanes.ch_tables[i], cache, method=type(self).prefill_chunk_paged)
+    def serving_ride_phase(self, params, cache: Lfm2MoeCache, lanes, ids: jax.Array, decodes: jax.Array):
+        """(h, in place of (c)'s chunk phase) the tick's chunk lanes, packed from
+        lane 0, with the decode step riding the first: one pass of
+        ``decode_rows_with_chunk_paged`` a carried lane. ``ids`` (B, 1) the
+        sampled tokens, ``decodes`` (traced) whether the tick decodes: the decode
+        rows are live in lane 0's pass where it does, and computed for nobody in
+        every other (a lane past the first, a tick that only carries lanes: both
+        rare, and no second traced body is kept for them). Returns ``(rows (B,
+        hidden), cache)``."""
+        def lane(i, carry):
+            rows, cache = carry
+            live = decodes & (i == 0)
+            new, cache = self.apply(params, ids, cache, *_lane(lanes, i), live,
+                                    method=type(self).decode_rows_with_chunk_paged)
+            return jnp.where(live, new, rows), cache
 
-        return jax.lax.fori_loop(0, jnp.sum((lanes.ch_count > 0).astype(jnp.int32)), lane, cache)
+        rows = jnp.zeros_like(cache.last_hidden)
+        return jax.lax.fori_loop(0, jnp.sum((lanes.ch_count > 0).astype(jnp.int32)), lane, (rows, cache))
 
     def serving_finish_phase(self, params, cache: Lfm2MoeCache, state, lanes, install_state: Callable):
         """(c, the end of a prompt) the last chunk left the slot's newest hidden
@@ -347,57 +368,156 @@ class Lfm2MoeForCausalLM(nn.Module):
 
         return jax.lax.fori_loop(0, jnp.sum(lanes.fin_active.astype(jnp.int32)), lane, (cache, state))
 
+    def _decode_operator(self, p, kind: str, l: int, h: jax.Array, cache: Lfm2MoeCache, pools, at):
+        """Layer ``l`` of its kind's operator for the decode rows ``h`` (B,
+        hidden), one row a slot: ``(the operator's contribution, pools)``;
+        ``pools`` = (kp, vp, conv_state) as the layers so far left them, ``at``
+        = (the rows that decode, positions, page ids, offsets, visible)."""
+        cfg = self.config
+        b, tail_rows = h.shape[0], cfg.conv_L_cache - 1
+        kp, vp, conv_state = pools
+        active, pos, page_ids, offs, visible = at
+        x = self._norm(h, p["operator_norm"])
+        if kind == "conv":
+            with jax.named_scope("short_conv"):
+                z, c = self._conv_in(p, x)
+                tails = conv_state[l].reshape(b, tail_rows, cfg.hidden_size)
+                window = jnp.concatenate([tails.astype(z.dtype), z[:, None]], axis=1)  # (B, L, hidden)
+                conv_state = conv_state.at[l].set(jnp.where(
+                    active[:, None], window[:, 1:].astype(conv_state.dtype).reshape(b, -1), conv_state[l]))
+                conv = jnp.sum(window.astype(jnp.float32) * p["conv"].astype(jnp.float32), axis=1)
+                return self._conv_out(p, c, conv), (kp, vp, conv_state)
+        with jax.named_scope("attention"):
+            # each slot is its own sequence of one row: positions (B, 1)
+            q, k, v = jax.vmap(lambda xr, pr: self._qkv(p, xr, pr))(x[:, None], pos[:, None])
+            kp = kp.at[l, page_ids, offs].set(k[:, 0].astype(kp.dtype))
+            vp = vp.at[l, page_ids, offs].set(v[:, 0].astype(vp.dtype))
+            use_kernel = paged.paged_gqa_decode_supported(cache.page_size, cfg.head_dim, cfg.num_key_value_heads)
+            attend = paged.fused_paged_decode_attention_gqa if use_kernel else paged.paged_gqa_reference_attention
+            o = attend(q[:, 0], kp, vp, cache.page_table, visible, l)
+            return self._mm(o.reshape(b, -1), p["o_proj"]), (kp, vp, conv_state)
+
+    def _chunk_operator(self, p, kind: str, l: int, h: jax.Array, pools, lane, at):
+        """Layer ``l`` of its kind's operator for a chunk's rows ``h`` (cap,
+        hidden) of ONE slot: ``(the operator's contribution, pools)``; ``lane`` =
+        (ids, offset, count, reset, slot, table_row), ``at`` = (positions, page
+        ids, offsets, visible, real) of the rows."""
+        cfg = self.config
+        cap, tail_rows = h.shape[0], cfg.conv_L_cache - 1
+        kp, vp, conv_state = pools
+        _, _, count, reset, slot, table_row = lane
+        pos, page_ids, offs, visible, real = at
+        x = self._norm(h, p["operator_norm"])
+        if kind == "conv":
+            with jax.named_scope("short_conv"):
+                z, c = self._conv_in(p, x)
+                tail = jnp.where(reset, 0, conv_state[l, slot]).reshape(tail_rows, cfg.hidden_size)
+                window = jnp.concatenate([tail.astype(z.dtype), z])
+                # the columns of the last real rows: window row count + i is z row count - 2 + i
+                conv_state = conv_state.at[l, slot].set(
+                    jax.lax.dynamic_slice_in_dim(window, count, tail_rows, axis=0)
+                    .astype(conv_state.dtype).reshape(-1))
+                return self._conv_out(p, c, self._conv_rows(p, window, cap)), (kp, vp, conv_state)
+        with jax.named_scope("attention"):
+            q, k, v = self._qkv(p, x, pos)
+            kp = kp.at[l, page_ids, offs].set(jnp.where(real[:, None], k, 0).astype(kp.dtype))
+            vp = vp.at[l, page_ids, offs].set(jnp.where(real[:, None], v, 0).astype(vp.dtype))
+            # the slot's pages, the chunk's own rows among them, in position order
+            out = self._attend(p, q, kp[l, table_row].reshape(-1, k.shape[-1]),
+                               vp[l, table_row].reshape(-1, v.shape[-1]), visible)
+            return out, (kp, vp, conv_state)
+
+    def _rows_paged(self, cache: Lfm2MoeCache, ids: Optional[jax.Array] = None, lane=None, live=True):
+        """THE loop over the layers, over an optional group of decode rows
+        (``ids`` (B, 1): one token for every decoding slot) and an optional chunk
+        (``lane`` = (ids (cap,), offset, count, reset, slot, table_row): prompt
+        tokens ``[offset, offset + count)`` of the request in ``slot``). Each
+        layer's operator runs for the two groups apart (their slots are
+        disjoint: a prefilling slot does not decode), its feed-forward ONCE over
+        their rows together, so an expert's matrices are read once for both.
+        ``live`` (may be traced): False makes every decode row a discarded one.
+        Returns ``(the decode rows' new last rows (B, hidden) or None, cache)``.
+        With both groups the operations carry the tick's phase names
+        (``serving_api.py`` (h)); with one, the caller's."""
+        cfg = self.config
+        ps, pages = cache.page_size, cache.pages_per_slot
+        decodes, chunks = ids is not None, lane is not None
+        both = decodes and chunks
+        phase = jax.named_scope if both else (lambda name: contextlib.nullcontext())
+        pools, counts = (cache.kp, cache.vp, cache.conv_state), cache.expert_counts
+        h_d = h_c = None
+        if decodes:
+            with phase(TICK_DECODE_SCOPE):
+                active = cache.active if live is True else cache.active & live
+                pos = jnp.where(active, cache.length, 0)
+                page_ids = jnp.where(
+                    active, cache.page_table[jnp.arange(ids.shape[0]), jnp.clip(pos // ps, 0, pages - 1)], 0)
+                decode_at = (active, pos, page_ids, jnp.where(active, pos % ps, 0), jnp.where(active, pos + 1, 0))
+                h_d = self._embed(ids[:, 0])
+        if chunks:
+            with phase(TICK_CHUNK_SCOPE):
+                chunk_ids, offset, count, _, slot, table_row = lane
+                real = jnp.arange(chunk_ids.shape[0]) < count
+                pos = offset + jnp.arange(chunk_ids.shape[0])
+                # rows to pages: padding rows land on the trash page with a zero payload
+                page_ids = jnp.where(real, table_row[jnp.clip(pos // ps, 0, pages - 1)], 0)
+                kpos = jnp.arange(pages * ps)
+                visible = (kpos[None, :] <= pos[:, None]) & (kpos[None, :] < offset + count)
+                chunk_at = (pos, page_ids, jnp.where(real, pos % ps, 0), visible, real)
+                h_c = self._embed(chunk_ids)
+        # the rows a feed-forward sees, the decode rows first; each group's assignments
+        # are counted in its own row of the counters
+        book = [DECODE_COUNTS] * decodes + [CHUNK_COUNTS] * chunks
+        valid, groups = (active, None) if decodes else (real, None)
+        if both:
+            b = ids.shape[0]
+            valid = jnp.concatenate([active, real])
+            groups = jnp.stack([jnp.arange(valid.shape[0]) < b, jnp.arange(valid.shape[0]) >= b])
+        at = {"conv": 0, "full_attention": 0, "experts": 0}
+        for kind, p in zip(cfg.layer_types, self.layers):
+            l = at[kind]
+            at[kind] += 1
+            if decodes:
+                with phase(TICK_DECODE_SCOPE):
+                    out, pools = self._decode_operator(p, kind, l, h_d, cache, pools, decode_at)
+                    if chunks:
+                        # the chunk's rows land in the pools AFTER the decode rows' operator has read
+                        # them: an order the compiler is told, or it keeps the pools apart by copying them
+                        out, pools = jax.lax.optimization_barrier((out, pools))
+                    h_d = h_d + out
+            if chunks:
+                with phase(TICK_CHUNK_SCOPE):
+                    out, pools = self._chunk_operator(p, kind, l, h_c, pools, lane, chunk_at)
+                    h_c = h_c + out
+            # what the two groups share is booked where the decode step's is
+            with phase(TICK_DECODE_SCOPE):
+                h = jnp.concatenate([h_d, h_c]) if both else h_d if decodes else h_c
+                out, load = self._ffn(p, h, valid, groups)
+                if decodes:
+                    h_d = h_d + (out[:b] if both else out)
+                if chunks:
+                    h_c = h_c + (out[b:] if both else out)
+                if load is not None:
+                    counts = counts.at[jnp.asarray(book), at["experts"]].add(load.reshape(len(book), -1))
+                    at["experts"] += 1
+        kp, vp, conv_state = pools
+        cache = cache.replace(kp=kp, vp=vp, conv_state=conv_state, expert_counts=counts)
+        if decodes:
+            with phase(TICK_DECODE_SCOPE):
+                cache = cache.replace(length=cache.length + active.astype(jnp.int32))
+        if chunks:
+            with phase(TICK_CHUNK_SCOPE):
+                last = jax.lax.dynamic_index_in_dim(h_c, jnp.maximum(count - 1, 0), axis=0, keepdims=False)
+                cache = cache.replace(last_hidden=cache.last_hidden.at[slot].set(last.astype(cache.last_hidden.dtype)))
+        return h_d, cache
+
     def prefill_chunk_paged(self, ids: jax.Array, offset: jax.Array, count: jax.Array, reset: jax.Array,
                             slot: jax.Array, table_row: jax.Array, cache: Lfm2MoeCache) -> Lfm2MoeCache:
         """Prompt tokens ``[offset, offset + count)`` of the request in ``slot``;
         ids (cap,) with the rows past ``count`` padding. ``reset`` starts the
         convolution columns from zero (a slot's first chunk); otherwise they
         are carried from the chunk before."""
-        cfg = self.config
-        cap, ps = ids.shape[0], cache.page_size
-        tail_rows = cfg.conv_L_cache - 1
-        j = jnp.arange(cap)
-        real = j < count
-        pos = offset + j
-        # rows to pages: padding rows land on the trash page with a zero payload
-        pidx = jnp.clip(pos // ps, 0, cache.pages_per_slot - 1)
-        page_ids = jnp.where(real, table_row[pidx], 0)
-        offs = jnp.where(real, pos % ps, 0)
-        kpos = jnp.arange(cache.pages_per_slot * ps)
-        visible = (kpos[None, :] <= pos[:, None]) & (kpos[None, :] < offset + count)
-        kp, vp, conv_state, counts = cache.kp, cache.vp, cache.conv_state, cache.expert_counts
-        at = {"conv": 0, "full_attention": 0, "experts": 0}
-        h = self._embed(ids)
-        for kind, p in zip(cfg.layer_types, self.layers):
-            x = self._norm(h, p["operator_norm"])
-            l = at[kind]
-            at[kind] += 1
-            if kind == "conv":
-                with jax.named_scope("short_conv"):
-                    z, c = self._conv_in(p, x)
-                    tail = jnp.where(reset, 0, conv_state[l, slot]).reshape(tail_rows, cfg.hidden_size)
-                    window = jnp.concatenate([tail.astype(z.dtype), z])
-                    # the columns of the last real rows: window row count + i is z row count - 2 + i
-                    conv_state = conv_state.at[l, slot].set(
-                        jax.lax.dynamic_slice_in_dim(window, count, tail_rows, axis=0)
-                        .astype(conv_state.dtype).reshape(-1))
-                    h = h + self._conv_out(p, c, self._conv_rows(p, window, cap))
-            else:
-                with jax.named_scope("attention"):
-                    q, k, v = self._qkv(p, x, pos)
-                    kp = kp.at[l, page_ids, offs].set(jnp.where(real[:, None], k, 0).astype(kp.dtype))
-                    vp = vp.at[l, page_ids, offs].set(jnp.where(real[:, None], v, 0).astype(vp.dtype))
-                    # the slot's pages, the chunk's own rows among them, in position order
-                    h = h + self._attend(p, q, kp[l, table_row].reshape(-1, k.shape[-1]),
-                                         vp[l, table_row].reshape(-1, v.shape[-1]), visible)
-            out, load = self._ffn(p, h, real)
-            h = h + out
-            if load is not None:
-                counts = counts.at[CHUNK_COUNTS, at["experts"]].add(load)
-                at["experts"] += 1
-        last = jax.lax.dynamic_index_in_dim(h, jnp.maximum(count - 1, 0), axis=0, keepdims=False)
-        return cache.replace(kp=kp, vp=vp, conv_state=conv_state, expert_counts=counts,
-                             last_hidden=cache.last_hidden.at[slot].set(last.astype(cache.last_hidden.dtype)))
+        return self._rows_paged(cache, lane=(ids, offset, count, reset, slot, table_row))[1]
 
     def decode_rows_paged(self, ids: jax.Array, cache: Lfm2MoeCache) -> Tuple[jax.Array, Lfm2MoeCache]:
         """(d) one token for every decoding slot: ids (B, 1) -> the residual
@@ -405,50 +525,16 @@ class Lfm2MoeForCausalLM(nn.Module):
         ``active`` (free, or in the middle of its prefill) computes a discarded
         row: its key and value go to the trash page, it is routed to no expert,
         and its length and convolution columns stay as they are."""
-        cfg = self.config
-        b, ps = ids.shape[0], cache.page_size
-        tail_rows = cfg.conv_L_cache - 1
-        active = cache.active
-        pos = jnp.where(active, cache.length, 0)
-        rows = jnp.arange(b)
-        page_ids = jnp.where(active, cache.page_table[rows, jnp.clip(pos // ps, 0, cache.pages_per_slot - 1)], 0)
-        offs = jnp.where(active, pos % ps, 0)
-        visible = jnp.where(active, pos + 1, 0)
-        use_gqa_kernel = paged.paged_gqa_decode_supported(ps, cfg.head_dim, cfg.num_key_value_heads)
-        kp, vp, conv_state, counts = cache.kp, cache.vp, cache.conv_state, cache.expert_counts
-        at = {"conv": 0, "full_attention": 0, "experts": 0}
-        h = self._embed(ids[:, 0])
-        for kind, p in zip(cfg.layer_types, self.layers):
-            x = self._norm(h, p["operator_norm"])
-            l = at[kind]
-            at[kind] += 1
-            if kind == "conv":
-                with jax.named_scope("short_conv"):
-                    z, c = self._conv_in(p, x)
-                    tails = conv_state[l].reshape(b, tail_rows, cfg.hidden_size)
-                    window = jnp.concatenate([tails.astype(z.dtype), z[:, None]], axis=1)  # (B, L, hidden)
-                    conv_state = conv_state.at[l].set(jnp.where(
-                        active[:, None], window[:, 1:].astype(conv_state.dtype).reshape(b, -1), conv_state[l]))
-                    conv = jnp.sum(window.astype(jnp.float32) * p["conv"].astype(jnp.float32), axis=1)
-                    h = h + self._conv_out(p, c, conv)
-            else:
-                with jax.named_scope("attention"):
-                    # each slot is its own sequence of one row: positions (B, 1)
-                    q, k, v = jax.vmap(lambda xr, pr: self._qkv(p, xr, pr))(x[:, None], pos[:, None])
-                    kp = kp.at[l, page_ids, offs].set(k[:, 0].astype(kp.dtype))
-                    vp = vp.at[l, page_ids, offs].set(v[:, 0].astype(vp.dtype))
-                    attend = (paged.fused_paged_decode_attention_gqa if use_gqa_kernel
-                              else paged.paged_gqa_reference_attention)
-                    o = attend(q[:, 0], kp, vp, cache.page_table, visible, l)
-                    h = h + self._mm(o.reshape(b, -1), p["o_proj"])
-            out, load = self._ffn(p, h, active)
-            h = h + out
-            if load is not None:
-                counts = counts.at[DECODE_COUNTS, at["experts"]].add(load)
-                at["experts"] += 1
-        cache = cache.replace(kp=kp, vp=vp, conv_state=conv_state, expert_counts=counts,
-                              length=cache.length + active.astype(jnp.int32))
-        return h, cache
+        return self._rows_paged(cache, ids=ids)
+
+    def decode_rows_with_chunk_paged(self, ids: jax.Array, cache: Lfm2MoeCache, chunk_ids: jax.Array, offset: jax.Array,
+                                     count: jax.Array, reset: jax.Array, slot: jax.Array, table_row: jax.Array,
+                                     live=True) -> Tuple[jax.Array, Lfm2MoeCache]:
+        """(h) the decode step with a chunk riding it: what ``prefill_chunk_paged``
+        of the chunk then ``decode_rows_paged`` return, every layer's feed-forward
+        run once over the rows of both. Where ``live`` (traced) is False no slot
+        decodes in this pass: the chunk alone is served."""
+        return self._rows_paged(cache, ids=ids, lane=(chunk_ids, offset, count, reset, slot, table_row), live=live)
 
     def decode_step_paged(self, ids: jax.Array, cache: Lfm2MoeCache) -> Tuple[jax.Array, Lfm2MoeCache]:
         """ids (B, 1) -> logits (B, 1, vocab): the head of ``decode_rows_paged``'s rows."""

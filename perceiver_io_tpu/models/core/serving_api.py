@@ -2,7 +2,8 @@
 
 The engine owns slots, pages, the tick loop and the descriptor; it knows no
 architecture. A model class answers five questions, by methods the engine
-calls on the (unbound) module, and may make two statements more, (f) and (g):
+calls on the (unbound) module, and may make three statements more, (f), (g)
+and (h):
 
 (a) its cache: ``init_paged_cache(num_slots, num_pages, page_size, dtype,
     kv_quant)`` returns ONE pytree holding everything a slot keeps between
@@ -31,8 +32,14 @@ calls on the (unbound) module, and may make two statements more, (f) and (g):
 
 Between two ticks a slot carries its row, never its logits: the tick's decode
 phase begins with ONE pass of (e) over every slot's row, samples from it, runs
-(d) on the sampled tokens and stores the new rows. A slot whose prompt ended
-in this tick's finish lane is sampled in the same tick, through the same pass.
+(d) on the sampled tokens and stores the new rows. The tick's phases run in
+one of two orders, by what the model states in (h). A model that states
+nothing: chunk lanes, finish lanes, then the decode phase, so a slot whose
+prompt ended in this tick's finish lane is sampled in the same tick, through
+the same pass of the head. A model that states (h): the head and the sampler
+first, over the slots that decoded at the tick's entry, then the rows, then
+the finish lanes, so such a slot is sampled by the NEXT tick's one pass of the
+head (the path a finish takes under either order when no slot decodes).
 
 A model with routed expert layers MAY also count, on the device, what only
 the device knows, which experts its tokens were routed to:
@@ -64,6 +71,27 @@ A model MAY also say in which layout the tick must RECEIVE a weight:
     shapes: a model names the matrices whose compiled tick was read and found
     to copy. A model that names none is handed its weights as it always was.
 
+A model MAY also say that its chunk rows ride its decode pass:
+
+(h) ``ServingTraits.chunk_rides_decode``. Where a layer's weights are read
+    once a CALL and both the decode step and a chunk lane are bound by that
+    read (routed experts at a handful of rows an expert), a tick that carries
+    a chunk lane AND decodes pays the stream twice. Such a model answers, in
+    place of (c)'s chunk phase, with ``serving_ride_phase(params, cache,
+    lanes, ids (B, 1), decodes)``: ``(rows (B, hidden_size), cache)``, its
+    loop over the carried chunk lanes, each lane ONE loop over the layers in
+    which the decode step rides the first lane where ``decodes`` (a traced
+    flag: the tick decodes): what the lanes' chunk step then (d) return for
+    slots that are disjoint (they are: a prefilling slot does not decode).
+    The engine then runs the tick's other order (above): a tick that carries
+    a chunk lane runs this phase, a tick that only decodes runs (d) alone.
+    The engine enters no scope around the phase; the model names the decode
+    rows' work and what the two groups share ``TICK_DECODE_SCOPE/...`` and
+    the chunk rows' own work ``TICK_CHUNK_SCOPE/...``, as the tick's other
+    phases are named. A statement by the model, not a rule over shapes: a
+    finish costs its request one tick, which only a second weight stream
+    saved pays for.
+
 ``serving_traits()`` says, in plain data, what else differs: which prompts
 take the split admission, what the descriptor's lanes carry, and which engine
 options the model does not carry yet (each with the piece that is missing, so
@@ -74,6 +102,10 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 from typing import Dict, Optional, Tuple
+
+# the tick program's scope names (``serving/engine.py`` ``TICK_SCOPES``) a model's
+# merged pass (h) names its two groups' operations by
+TICK_DECODE_SCOPE, TICK_CHUNK_SCOPE = "tick.decode", "tick.chunk_lanes"
 
 
 @dataclass(frozen=True)
@@ -99,6 +131,10 @@ class ServingTraits:
     # (g): parameter leaves ("/"-joined key paths) the tick must receive
     # row-major; (): the compiler lays every argument out as it chooses
     row_major_leaves: Tuple[str, ...] = ()
+    # (h): the decode step rides the first carried chunk lane, in the model's
+    # ``serving_ride_phase``, and a finished prompt's first token is the next
+    # tick's; False: chunk lanes, finish lanes, then the decode step
+    chunk_rides_decode: bool = False
     # engine option -> why this model cannot be served with it yet. (What the
     # grouped-query paged decode kernel takes is no option: full-precision
     # pages whose row ``kv_heads * head_dim`` is lane-aligned: heads of 128, or

@@ -188,7 +188,7 @@ def grouped_matmul(
 def expert_layer(
     x: jax.Array, weights: ExpertWeights, held: Tuple[int, int], top_k: int, scale: float = 1.0,
     renormalize: bool = True, valid: Optional[jax.Array] = None, use_kernel: bool = False,
-    interpret: bool = False,
+    interpret: bool = False, load_groups: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """The held experts' part of the routed sum. x (T, hidden) in the dtype the
     products run in; ``held`` = (first, count): ``weights.w13`` / ``w2`` are
@@ -196,8 +196,10 @@ def expert_layer(
     (T,): rows that are tokens (the others are routed nowhere and counted
     nowhere). Returns (y (T, hidden) in x's dtype, load (experts,) int32: the
     valid assignments each of ALL the experts received, held here or not).
-    Products accumulate in float32, by ``grouped_matmul`` (``use_kernel``: its
-    Pallas form)."""
+    ``load_groups`` (G, T) bool: the load comes back apart for each group of
+    rows, (G, experts): one call, and so one read of an expert's matrices, for
+    rows whose assignments are counted in different books. Products accumulate
+    in float32, by ``grouped_matmul`` (``use_kernel``: its Pallas form)."""
     t, dtype = x.shape[0], x.dtype
     first, count = held
     if weights.w13.shape[0] != count or weights.w2.shape[0] != count:
@@ -205,8 +207,11 @@ def expert_layer(
     with jax.named_scope("route"):
         chosen, picked = route(x, weights.router, weights.bias, top_k, scale, renormalize)
         counted = jnp.ones((t,), bool) if valid is None else valid
-        load = jnp.sum((chosen[..., None] == jnp.arange(weights.router.shape[1])) & counted[:, None, None],
-                       axis=(0, 1), dtype=jnp.int32)
+        assigned = (chosen[..., None] == jnp.arange(weights.router.shape[1])) & counted[:, None, None]
+        if load_groups is None:
+            load = jnp.sum(assigned, axis=(0, 1), dtype=jnp.int32)
+        else:
+            load = jnp.sum(assigned[None] & load_groups[:, :, None, None], axis=(1, 2), dtype=jnp.int32)
     with jax.named_scope("experts"):
         local = chosen - first
         here = (local >= 0) & (local < count) & counted[:, None]

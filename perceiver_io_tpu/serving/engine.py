@@ -448,6 +448,7 @@ class TickRecord(NamedTuple):
     decoding: int  # slots the tick decodes
     transfers: int  # the descriptor's host-to-device transfers: 0 = the resident one, 1 = a packed one
     after_empty: int  # 1: the engine held no request at some point since the previous dispatch
+    riding_chunk_lanes: int  # chunk lanes that rode the decode step's pass over the layers (serving_api.py (h))
 
 
 # span arguments of a tick with telemetry off: nothing is built
@@ -1025,17 +1026,15 @@ class ServingEngine:
             type(model).decode_rows_paged if self.paged else type(model).decode_rows
         )
 
-        def decode_body(params, cache, state, forced, use_forced):
-            # THE decode step, traced by the dense pool's ``decode_step`` and
-            # by the fused tick's decode phase (``params`` already dequantized).
-            # Mirrors _generate_single's loop body per row: process logits ->
-            # sample -> one cached model step. The logits are the head of the
-            # row each slot carries (models/core/serving_api.py (e)): ONE pass
-            # of the head a step, over every slot's row — a decoding slot's
-            # from the step before, a just-finished prompt's from this tick's
-            # finish lane — and the (B, V) logits die in the sampler: never
-            # state, never a cond operand or a loop carry. Inactive rows decode
-            # their pad token; their outputs are never harvested.
+        def sample_step(params, state, forced, use_forced):
+            # The first half of THE decode step: process logits -> sample, as
+            # _generate_single's loop body does per row. The logits are the
+            # head of the row each slot carries (models/core/serving_api.py
+            # (e)): ONE pass of the head a step, over every slot's row — a
+            # decoding slot's from the step before, a just-finished prompt's
+            # from a finish lane — and the (B, V) logits die in the sampler:
+            # never state, never a cond operand or a loop carry. Inactive rows
+            # decode their pad token; their outputs are never harvested.
             # ``finite`` is the containment probe (docs/reliability.md): per
             # ACTIVE slot, were the logits this step sampled from all finite?
             # Computed in the same program, harvested with the same device
@@ -1066,18 +1065,28 @@ class ServingEngine:
                 # tick) this is a no-op select and the f64 parity pins run
                 # through it.
                 tok = jnp.where(use_forced, forced, tok).astype(jnp.int32)
+            return tok, finite, keys
+
+        def advance_state(state, live, rows, keys):
+            # the slots that decoded carry their new rows and rng on; the rest
+            # keep their (zeroed-at-release) rng/row frozen: freed-slot state
+            # stays canonical across steps, so pool dumps are reproducible
+            # regardless of how long slots idle between requests
+            return state.replace(
+                next_hidden=jnp.where(live[:, None], rows, state.next_hidden),
+                rng=jnp.where(live[:, None], keys[:, 0], state.rng),
+            )
+
+        def decode_body(params, cache, state, forced, use_forced):
+            # THE decode step, traced by the dense pool's ``decode_step`` and
+            # by the fused tick's decode phase (``params`` already
+            # dequantized): sample, then one cached model step on the tokens
+            tok, finite, keys = sample_step(params, state, forced, use_forced)
             with jax.named_scope(TICK_SCOPES["decode"]):
                 rows, cache = model.apply(
                     params, tok[:, None], cache, method=decode_method
                 )
-            # inactive rows keep their (zeroed-at-release) rng/row frozen:
-            # freed-slot state stays canonical across steps, so pool dumps are
-            # reproducible regardless of how long slots idle between requests
-            state = state.replace(
-                next_hidden=jnp.where(state.active[:, None], rows, state.next_hidden),
-                rng=jnp.where(state.active[:, None], keys[:, 0], state.rng),
-            )
-            return tok, finite, cache, state
+            return tok, finite, cache, advance_state(state, state.active, rows, keys)
 
         @partial(jax.jit, donate_argnums=(0,))
         def quarantine(cache, slot):
@@ -1120,7 +1129,10 @@ class ServingEngine:
             def ragged_tick(params_, cache, state, descriptor, forced, use_forced):
                 # ONE program per tick, its phases in dependency order:
                 # scale resets, prefill chunks, latent finishes, fault
-                # poison, batched decode. Every phase is gated by a TRACED
+                # poison, batched decode (for a model whose chunk rows ride
+                # its decode pass, serving_api.py (h): resets, poison, the
+                # head and the sampler, the rows, the finishes). Every phase
+                # is gated by a TRACED
                 # any-flag (lax.cond), so one compiled program covers every
                 # tick mix and the watchdog budget is exactly 1. Per-slot
                 # state is disjoint across a phase's lanes, so the lanes of
@@ -1150,41 +1162,83 @@ class ServingEngine:
                             lambda c: c, cache,
                         )
 
-                # the two prefill phases are the MODEL's (its chunk step and
-                # what ends a prompt: models/core/serving_api.py); the engine
-                # gates each on the tick's flags and hands it the lanes
-                with jax.named_scope(TICK_SCOPES["chunk_lanes"]):
-                    cache = jax.lax.cond(
-                        d.any_chunk,
-                        lambda c: model.serving_chunk_phase(params, c, d),
-                        lambda c: c, cache)
+                def poison(state):
+                    # serving.nan fault point: before decode's head reads the
+                    # rows (a NaN row gives NaN logits)
+                    with jax.named_scope(TICK_SCOPES["poison"]):
+                        return jax.lax.cond(
+                            poison_slot >= 0,
+                            lambda s: s.replace(next_hidden=s.next_hidden.at[
+                                jnp.maximum(poison_slot, 0)].set(jnp.nan)),
+                            lambda s: s, state,
+                        )
 
-                with jax.named_scope(TICK_SCOPES["finish_lanes"]):
-                    cache, state = jax.lax.cond(
-                        d.any_finish,
-                        lambda a: model.serving_finish_phase(params, a[0], a[1], d, _install_state),
-                        lambda a: a, (cache, state)
-                    )
-                # serving.nan fault point: after finishes install their rows,
-                # before decode's head reads them (a NaN row gives NaN logits)
-                with jax.named_scope(TICK_SCOPES["poison"]):
-                    state = jax.lax.cond(
-                        poison_slot >= 0,
-                        lambda s: s.replace(next_hidden=s.next_hidden.at[
-                            jnp.maximum(poison_slot, 0)].set(jnp.nan)),
-                        lambda s: s, state,
-                    )
+                def finish_lanes(cache, state):
+                    with jax.named_scope(TICK_SCOPES["finish_lanes"]):
+                        return jax.lax.cond(
+                            d.any_finish,
+                            lambda a: model.serving_finish_phase(params, a[0], a[1], d, _install_state),
+                            lambda a: a, (cache, state)
+                        )
 
-                def decode_phase(args):
-                    return decode_body(params, *args, forced, use_forced)
+                slots = self.num_slots
+                if self._traits.chunk_rides_decode:
+                    # serving_api.py (h): the head and the sampler over the
+                    # slots that decode at the tick's entry, then the rows —
+                    # the decode step rides the first carried chunk lane's
+                    # loop over the layers, or runs alone where the tick
+                    # carries none — then the finish lanes, whose slots the
+                    # NEXT tick samples
+                    state = poison(state)
+                    tok, finite, keys = jax.lax.cond(
+                        any_decode,
+                        lambda s: sample_step(params, s, forced, use_forced),
+                        lambda s: (jnp.zeros((slots,), jnp.int32), jnp.ones((slots,), bool),
+                                   jnp.zeros((slots, 2) + s.rng.shape[1:], s.rng.dtype)),
+                        state)
+                    any_chunk = d.any_chunk
 
-                def no_decode(args):
-                    cache, state = args
-                    return (jnp.zeros((self.num_slots,), jnp.int32),
-                            jnp.ones((self.num_slots,), bool), cache, state)
+                    def lanes_ridden(cache):
+                        # the model loops its carried lanes and names its two
+                        # groups' operations itself
+                        return model.serving_ride_phase(params, cache, d, tok[:, None], any_decode)
 
-                tok, finite, cache, state = jax.lax.cond(
-                    any_decode, decode_phase, no_decode, (cache, state))
+                    def decode_alone(cache):
+                        return model.apply(params, tok[:, None], cache, method=decode_method)
+
+                    # a tick takes one of the two or neither; each cond has
+                    # the shape of the other order's
+                    rows, cache = jax.lax.cond(
+                        any_chunk, lanes_ridden, lambda c: (jnp.zeros_like(state.next_hidden), c), cache)
+                    with jax.named_scope(TICK_SCOPES["decode"]):
+                        rows, cache = jax.lax.cond(
+                            any_decode & ~any_chunk, decode_alone, lambda c: (rows, c), cache)
+                    state = advance_state(state, state.active & any_decode, rows, keys)
+                    cache, state = finish_lanes(cache, state)
+                else:
+                    # the two prefill phases are the MODEL's (its chunk step and
+                    # what ends a prompt: models/core/serving_api.py); the engine
+                    # gates each on the tick's flags and hands it the lanes
+                    with jax.named_scope(TICK_SCOPES["chunk_lanes"]):
+                        cache = jax.lax.cond(
+                            d.any_chunk,
+                            lambda c: model.serving_chunk_phase(params, c, d),
+                            lambda c: c, cache)
+                    # the finishes install their rows before the poison and the
+                    # decode phase's head: their slots are sampled in this tick
+                    cache, state = finish_lanes(cache, state)
+                    state = poison(state)
+
+                    def decode_phase(args):
+                        return decode_body(params, *args, forced, use_forced)
+
+                    def no_decode(args):
+                        cache, state = args
+                        return (jnp.zeros((slots,), jnp.int32),
+                                jnp.ones((slots,), bool), cache, state)
+
+                    tok, finite, cache, state = jax.lax.cond(
+                        any_decode, decode_phase, no_decode, (cache, state))
                 if self._traits.expert_counters is not None:
                     # serving_api.py (f): the experts' counters ride the token
                     # output, so the host's one readback of the tokens brings
@@ -1856,7 +1910,9 @@ class ServingEngine:
             )
             # the fused program's finish phase runs after every chunk lane
             # (this slot's tail chunk included) and before decode, so the
-            # newly active slot decodes THIS tick
+            # newly active slot decodes THIS tick; for a model whose chunk
+            # rows ride its decode pass (serving_api.py (h)) after the rows,
+            # so it decodes from the NEXT tick on
             self._tick_finishes.append(
                 (slot, task.table_row, ids_latent, task.n,
                  np.asarray(request.rng), sampling)
@@ -2601,6 +2657,13 @@ class ServingEngine:
             # are claimed for every scheduler purpose but must not be
             # harvested — the decode step would hand them pad tokens
             occupied = [(s, r) for s, r in occupied if s not in self._prefilling]
+            rides = self._traits.chunk_rides_decode
+            if rides and self._tick_finishes:
+                # serving_api.py (h): this tick samples before its finish
+                # lanes install their rows, so a slot whose prompt ends here
+                # is harvested from the next tick on
+                finishing = {w[0] for w in self._tick_finishes}
+                occupied = [(s, r) for s, r in occupied if s not in finishing]
             tick_work = bool(self._tick_chunks or self._tick_finishes
                              or self._tick_resets)
             if not occupied and not tick_work:
@@ -2608,7 +2671,7 @@ class ServingEngine:
                 if self._tick_programs:
                     # eviction/admission programs ran but nothing decodes:
                     # still a dispatching tick for the programs-per-tick view
-                    record = self._record_tick_dispatch(tick, 0, 0, 0, 0, 0)
+                    record = self._record_tick_dispatch(tick, 0, 0, 0, 0, 0, 0)
                     obs.span_end(self._span_tick, at=t_end, **self._span_fields(record))
                 else:
                     obs.span_end(self._span_tick, at=t_end)
@@ -2630,6 +2693,8 @@ class ServingEngine:
             # schedule ends where the dispatch begins: one clock reading, no seam
             t_dispatch = obs.span_end(self._span_schedule)
             n_resets = len(self._tick_resets)  # the dispatch drains them
+            # the first carried chunk lane rides the decode step of a tick that decodes
+            riding = int(rides and bool(occupied) and bool(self._tick_chunks))
             if self._obs_on:
                 # what the pack-and-send is about to carry: the lanes are
                 # buffered, the transfer is not known yet. A tick that
@@ -2659,11 +2724,12 @@ class ServingEngine:
                 self._book_host_gap(t_entry, t_dispatch, t_dispatched)
             record = self._record_tick_dispatch(
                 tick, self._tick_chunk_items, self._tick_finish_items,
-                self._tick_chunk_tokens, n_resets, len(occupied))
+                self._tick_chunk_tokens, n_resets, len(occupied), riding)
             if not occupied:
                 # ragged tick that only carried prefill work: nothing to
                 # harvest (the finish lanes activate slots for NEXT tick's
-                # decode when the tail chunk and finish split across ticks)
+                # decode: when the tail chunk and finish split across ticks,
+                # and always for a model of serving_api.py (h))
                 obs.span_end(self._span_tick, **self._span_fields(record))
                 return False
         except BaseException:
@@ -2673,17 +2739,20 @@ class ServingEngine:
         return True
 
     def _record_tick_dispatch(self, tick: int, chunk_lanes: int, finish_lanes: int,
-                              chunk_tokens: int, resets: int, decoding: int) -> TickRecord:
+                              chunk_tokens: int, resets: int, decoding: int,
+                              riding_chunk_lanes: int) -> TickRecord:
         """The dispatching tick's composition, made ONCE: the metrics' books
         and the tick's spans are handed the same values."""
         record = TickRecord(
             tick, self._tick_programs, self._tick_oneshot, chunk_lanes,
             finish_lanes, chunk_tokens, resets, decoding,
-            self._tick_transfers or 0, int(self._was_empty))
+            self._tick_transfers or 0, int(self._was_empty), riding_chunk_lanes)
         self._was_empty = False
         self.metrics.record_tick_dispatch(
             record.programs, record.chunk_lanes, record.finish_lanes,
             record.decoding, self._tick_build_s, self._tick_transfers)
+        if riding_chunk_lanes:
+            self.metrics.record_riding_chunk_lanes(riding_chunk_lanes)
         return record
 
     def _span_fields(self, record: TickRecord):

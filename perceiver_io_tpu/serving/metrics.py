@@ -142,7 +142,10 @@ Schema history:
     slots a tick decodes: what the decode kernels' time follows), ``lanes``
     (the tick program's compiled lane count: the descriptor's size) and
     ``chunk_lanes`` mean/p50/p95 (the chunk lanes a tick carried, over the
-    ticks that carried one: the trips of the model's chunk phase).
+    ticks that carried one: the trips of the model's chunk phase) and
+    ``riding_chunk_lanes`` (a total: the chunk lanes that rode the decode
+    step's pass over the layers instead of a trip of their own,
+    models/core/serving_api.py (h); 0 for a model that does not state it).
   * ``serving-metrics/v12`` — the out-of-process-replica schema
     (docs/serving.md "Out-of-process replicas"): every snapshot carries a
     ``transport`` field — ``None`` on plain engines and on in-process
@@ -449,6 +452,7 @@ class EngineMetrics(_JsonlMetrics):
     ragged_enabled: Optional[bool] = None
     ragged_lanes: Optional[int] = None  # the tick program's compiled lane count
     ragged_ticks: int = 0
+    riding_chunk_lanes: int = 0
     _tick_program_counts: Deque[int] = field(default_factory=lambda: deque(maxlen=LATENCY_WINDOW))
     _tick_chunk_counts: Deque[int] = field(default_factory=lambda: deque(maxlen=LATENCY_WINDOW))
     _tick_finish_counts: Deque[int] = field(default_factory=lambda: deque(maxlen=LATENCY_WINDOW))
@@ -654,6 +658,12 @@ class EngineMetrics(_JsonlMetrics):
         self._tick_build_times.append(float(build_s))
         if descriptor_transfers is not None:
             self._tick_transfer_counts.append(int(descriptor_transfers))
+
+    def record_riding_chunk_lanes(self, lanes: int) -> None:
+        """Chunk lanes of one dispatching tick that rode the decode step's pass
+        over the layers (models/core/serving_api.py (h)); a total over the
+        engine's life, and never called for a model that does not state it."""
+        self.riding_chunk_lanes += int(lanes)
 
     def set_weight_serving(self, dtype: str, param_bytes: int,
                            param_bytes_fp: int) -> None:
@@ -939,6 +949,10 @@ class EngineMetrics(_JsonlMetrics):
                         [n for n in self._tick_chunk_counts if n]).items()
                     if k in _MEAN_AND_PERCENTILE_KEYS
                 },
+                # of the chunk lanes the ticks carried, those that rode the
+                # decode step's one pass over the layers (a model that states
+                # it: models/core/serving_api.py (h)); a total
+                "riding_chunk_lanes": self.riding_chunk_lanes,
                 "descriptor_build_s": {
                     k: v for k, v in _latency_dict(self._tick_build_times).items()
                     if k in _PERCENTILE_KEYS

@@ -456,7 +456,8 @@ def main(argv=None) -> Dict:
             if rt.get("lanes") is not None:
                 carried = rt.get("chunk_lanes") or {}
                 print(f"  lanes: {rt['lanes']} compiled; a tick with a chunk lane carried "
-                      f"mean={carried.get('mean')} p95={carried.get('p95')}")
+                      f"mean={carried.get('mean')} p95={carried.get('p95')}; "
+                      f"{rt.get('riding_chunk_lanes', 0)} rode a decode step")
         # v10 fleet-operations rendering (suppressed where the reader
         # normalized to None: plain engine or pre-v10 stream) — the
         # migration/recycle/rollout/autoscale story an operator audits

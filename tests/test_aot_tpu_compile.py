@@ -201,16 +201,15 @@ def test_kernel_compiles_for_v5e(v5e, case):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.fixture(scope="module")
-def falcon_tick_text(v5e):
-    """The compiled text of ``serve-falcon-h1-chat``'s WHOLE tick: the published widths
-    of ``benchmark/configs/falcon-h1-34b-4l.json`` under the cell's engine settings,
-    abstract weights that lie on the described v5e, lowered through the engine's own
-    arguments and jit (about 40 s; the pool's 3.8 GB of zeros are built on the CPU)."""
+def _compiled_tick(v5e, cell_name):
+    """A serving cell's WHOLE tick, compiled: the published widths of the cell's
+    configuration under the cell's engine settings, abstract weights that lie on the
+    described v5e, lowered through the engine's own arguments and jit (the pool's
+    gigabytes of zeros are built on the CPU)."""
     from benchmark.harness import manifest
     from perceiver_io_tpu.serving import ServingEngine
 
-    cell = manifest.resolve_cell("serve-falcon-h1-chat")
+    cell = manifest.resolve_cell(cell_name)
     config = cell["config"]
     family = manifest.load_family(config["family"])
     weights = jax.eval_shape(lambda: family.build_weights(config["sizes"], jax.random.PRNGKey(0), jnp.bfloat16))
@@ -223,7 +222,14 @@ def falcon_tick_text(v5e):
         args = engine._ragged_args(True, engine._forced_none, engine._use_forced_none)
         # the parameters are the shapes the engine keeps; the pool's arrays become shapes on the v5e
         shapes = jax.tree_util.tree_map(lambda x: x if isinstance(x, jax.ShapeDtypeStruct) else sds(x), args)
-        return engine._jit_ragged_tick.lower(*shapes).compile().as_text()
+        return engine._jit_ragged_tick.lower(*shapes).compile()
+
+
+@pytest.fixture(scope="module")
+def falcon_tick_text(v5e):
+    """The compiled text of ``serve-falcon-h1-chat``'s WHOLE tick at the published widths of
+    ``benchmark/configs/falcon-h1-34b-4l.json`` (about 40 s; the pool is 3.8 GB of zeros)."""
+    return _compiled_tick(v5e, "serve-falcon-h1-chat").as_text()
 
 
 # hidden x (z 4096 | xBC 5120 | dt 32): the one matrix with a ragged column count, as the model holds it and as
@@ -249,3 +255,47 @@ def test_falcon_tick_is_handed_in_proj_as_both_branches_read_it(falcon_tick_text
         assert any(IN_PROJ + "{1,0:T(8,128)(2,1)} bitcast(" in line for line in lines)
     else:
         assert "ssm_decode_update" in falcon_tick_text and "fused_paged_decode_attention_gqa" in falcon_tick_text
+
+
+@pytest.fixture(scope="module")
+def lfm2_tick(v5e):
+    """``serve-lfm2-moe-assist``'s WHOLE tick at the published widths of
+    ``benchmark/configs/lfm2-8b-a1b-13l.json``: (the compiled text, its memory account); about a minute."""
+    compiled = _compiled_tick(v5e, "serve-lfm2-moe-assist")
+    return compiled.as_text(), compiled.memory_analysis()
+
+
+# the page pools (3 attention layers x 2,049 pages of 64 x 8 heads of 64) and an expert layer's two stacks
+LFM2_BIG = ("bf16[3,2049,64,512]", "bf16[32,2048,3584]", "bf16[32,1792,2048]")
+LFM2_EXPERT_LAYERS = 12
+
+
+@pytest.mark.parametrize("holds", ["one stream of the experts a branch", "no copy of the pools or the stacks",
+                                   "the kernels are named", "the temporaries stay small"])
+def test_lfm2_tick_reads_each_expert_layer_once_whatever_it_carries(lfm2_tick, holds):
+    """ISSUE 46: a tick that carried a chunk lane and decoded ran the model's chunk phase and
+    then its decode step, two loops over the layers, 24 pairs of grouped products. The
+    model states that its chunk rows ride its decode pass (``serving_api.py`` (h)): every
+    computation of the compiled tick that holds grouped products holds ONE pair an expert
+    layer, and nothing is copied in front of the new branches."""
+    text, memory = lfm2_tick
+    lines = text.splitlines()
+    if holds == "one stream of the experts a branch":
+        held, at = {}, None
+        for line in lines:
+            if line.endswith("{") and " (" in line and not line.startswith(" "):
+                at = line.split(" (")[0]
+            for kernel in ("grouped_gated_matmul", "grouped_matmul"):
+                if "custom-call(" in line and line.lstrip().startswith(f"%{kernel}."):
+                    held.setdefault(at, {"grouped_gated_matmul": 0, "grouped_matmul": 0})[kernel] += 1
+        # the loop over the carried lanes, the decode step riding the first; the decode step alone
+        assert len(held) == 2 and all(set(n.values()) == {LFM2_EXPERT_LAYERS} for n in held.values()), held
+    elif holds == "no copy of the pools or the stacks":
+        copies = [line.strip()[:160] for line in lines if " copy(" in line and any(f"= {big}" in line for big in LFM2_BIG)]
+        assert not copies, copies
+        assert any(big in text for big in LFM2_BIG)
+    elif holds == "the kernels are named":
+        assert "fused_paged_decode_attention_gqa" in text and "ragged-dot" not in text
+    else:
+        # PR 44's tick: 10.03 GB of arguments + 0.16 GB of temporaries; the cell's peak on the chip is 10.06 GB
+        assert memory.argument_size_in_bytes < 10.05e9 and memory.temp_size_in_bytes < 0.3e9, memory

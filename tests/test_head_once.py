@@ -7,7 +7,9 @@ row, never its logits. Pinned here, on the CPU at toy sizes, for both served fam
 * a prompt whose last chunk and finish ride a tick that also decodes other slots gets its
   first token from THAT tick, and every served token, greedy and sampled, is what the
   model's own logits path (``prefill`` / ``decode_step``, ``decode_step_paged``) gives
-  under the engine's rng chain;
+  under the engine's rng chain; for a model whose chunk rows ride its decode pass
+  (``serving_api.py`` (h), ISSUE 46: LFM2) from the NEXT tick, the same tokens, and the
+  branch such a tick takes holds one pair of grouped products an expert layer;
 * ``decode_step_paged`` is the head of ``decode_rows_paged``'s rows;
 * a poisoned row trips ``finite`` for exactly its slot, on the paged and the dense pool.
 """
@@ -19,32 +21,36 @@ import pytest
 from jax.extend import core as jex_core
 
 from benchmark.families.falcon_h1 import reference as falcon_reference
+from benchmark.families.lfm2_moe import reference as lfm2_reference
 from perceiver_io_tpu.generation.generate import GenerationConfig
 from perceiver_io_tpu.generation.sampling import process_logits_batched, sample_token_batched
 from perceiver_io_tpu.models.core.perceiver_ar import CausalSequenceModel
 from perceiver_io_tpu.reliability import armed
 from perceiver_io_tpu.serving import ServingEngine
 from perceiver_io_tpu.serving.engine import TICK_SCOPES, RequestStatus
-from tests import falcon_h1_toy
+from tests import falcon_h1_toy, lfm2_moe_toy
 from tests.test_falcon_h1 import _prefill as _falcon_prefill
+from tests.test_lfm2_moe import _prefill as _lfm2_prefill
 from tests.test_paging import _reference_tokens
 from tests.test_ragged_tick import LATENTS, PS, VOCAB, WINDOW, _make_model
 
 FALCON_ENGINE = dict(num_slots=3, kv_page_size=8, prefill_chunk_tokens=8, num_kv_pages=40)
 AR_PAGED = dict(num_slots=3, kv_page_size=PS, prefill_chunk_tokens=4, max_prefill_slots=2)
 AR_DENSE = dict(num_slots=3)
-ENGINES = {"falcon_h1": FALCON_ENGINE, "perceiver_ar_paged": AR_PAGED, "perceiver_ar_dense": AR_DENSE}
+ENGINES = {"falcon_h1": FALCON_ENGINE, "lfm2_moe": FALCON_ENGINE, "perceiver_ar_paged": AR_PAGED,
+           "perceiver_ar_dense": AR_DENSE}
 
 
 @pytest.fixture(scope="module")
 def models():
     falcon, falcon_params, falcon_weights = falcon_h1_toy.build()
     ar, ar_params = _make_model()
-    return {"falcon_h1": (falcon, falcon_params, falcon_weights), "perceiver_ar": (ar, ar_params, None)}
+    return {"falcon_h1": (falcon, falcon_params, falcon_weights), "perceiver_ar": (ar, ar_params, None),
+            "lfm2_moe": lfm2_moe_toy.build()}
 
 
 def _engine(models, kind, **more):
-    model, params, _ = models["falcon_h1" if kind == "falcon_h1" else "perceiver_ar"]
+    model, params, _ = models[kind if kind in models else "perceiver_ar"]
     return ServingEngine(model, params, **ENGINES[kind], **more)
 
 
@@ -129,6 +135,48 @@ def test_the_tick_holds_one_head_matmul_and_carries_nothing_of_the_vocabularys_w
     assert TICK_SCOPES["finish_lanes"] not in found["dots"][0]
 
 
+GROUPED = "ragged_dot_general"  # ``ops/moe.py``'s grouped products as the CPU lowers them
+
+
+def _count(jaxpr, primitive, loops=False):
+    """Equations of ``primitive`` in a jaxpr and everything it calls, loop bodies aside."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == primitive
+        if loops or eqn.primitive.name not in ("while", "scan"):
+            n += sum(_count(inner, primitive, loops) for inner, _ in _sub_jaxprs(eqn))
+    return n
+
+
+def test_a_tick_with_a_riding_chunk_lane_runs_each_expert_layer_once(models):
+    """ISSUE 46: for a model whose chunk rows ride its decode pass the rows phase is two
+    ``cond``s of which a tick takes one or neither: the model's loop over the carried chunk
+    lanes with the decode step riding the first, or the decode step alone. Each holds one
+    pair of grouped products an expert layer (``ragged_dot`` on the CPU), the first inside
+    its loop over the lanes: a tick with ONE chunk lane and decoding slots reads each expert
+    layer once. The head's one matmul lies in front of both."""
+    engine = _engine(models, "lfm2_moe")
+    assert engine._traits.chunk_rides_decode
+    tick, args = _tick_args(engine)
+    body = jax.make_jaxpr(tick)(*args).jaxpr.eqns[0].params["jaxpr"].jaxpr
+    taken = lambda e: e.params["branches"][1].jaxpr  # a cond's index 1 is the branch of a true predicate
+    rows = [e for e in body.eqns if e.primitive.name == "cond" and _count(taken(e), GROUPED, loops=True)]
+    assert len(rows) == 2
+    assert all(_count(e.params["branches"][0].jaxpr, GROUPED, loops=True) == 0 for e in rows)
+    pairs = len(engine.model.config.expert_layers)
+    lanes_ridden, decode_alone = map(taken, rows)
+    assert _count(lanes_ridden, GROUPED) == 0 and _count(lanes_ridden, GROUPED, loops=True) == 2 * pairs
+    assert _count(decode_alone, GROUPED) == _count(decode_alone, GROUPED, loops=True) == 2 * pairs
+    names = [str(e.source_info.name_stack) for e in rows]
+    assert "tick." not in names[0] and TICK_SCOPES["decode"] in names[1]
+    vocab = engine._traits.vocab_size
+    found = {"dots": [], "crossing": []}
+    _walk(body, set(), vocab, found)
+    assert len(found["dots"]) == 1 and all(found["dots"][0] not in str(e.source_info.name_stack) for e in rows)
+    sampler = body.eqns[:body.eqns.index(rows[0])]
+    assert any(e.primitive.name == "cond" and _count(taken(e), "dot_general", loops=True) for e in sampler)
+
+
 # ------------------------------------------- (b) the finish tick samples; tokens by hand
 def _sample_chain(step_logits, first_logits, n_new, rng, sampling):
     """The engine's documented chain (``decode_body``), by hand for one request: each step
@@ -183,6 +231,25 @@ def _falcon_by_hand(model, params, prompt, n_new, rng, sampling):
     return _sample_chain(step, first[None], n_new, rng, sampling)
 
 
+def _lfm2_by_hand(model, params, prompt, n_new, rng, sampling):
+    """Through ``prefill_chunk_paged``, ``_head`` of the prompt's last row and
+    ``decode_step_paged`` on a cache of one slot: no engine, no riding lane."""
+    ps = FALCON_ENGINE["kv_page_size"]
+    cache = model.init_paged_cache(1, 16, ps, jnp.float32)
+    pages = -(-(len(prompt) + n_new) // ps)
+    table = np.zeros((cache.pages_per_slot,), np.int32)
+    table[:pages] = 1 + np.arange(pages)
+    cache, first = _lfm2_prefill(model, params, cache, np.asarray(prompt), 0, jnp.asarray(table),
+                                 FALCON_ENGINE["prefill_chunk_tokens"])
+    box = [cache]
+
+    def step(tok):
+        step_logits, box[0] = model.apply(params, tok[:, None], box[0], method=type(model).decode_step_paged)
+        return step_logits[:, 0]
+
+    return _sample_chain(step, first[None], n_new, rng, sampling)
+
+
 GREEDY, SAMPLED = (1.0, 0, False), (0.8, 20, True)
 # (family, engine, the decoding neighbour's prompt, the late prompt: chunk lanes, then a
 # finish that rides a decoding tick)
@@ -228,6 +295,45 @@ def test_a_finish_riding_a_decoding_tick_is_sampled_in_that_tick(models, kind, s
     if sampling is GREEDY and family == "falcon_h1":
         tokens = np.asarray(late.output_ids)
         scored = falcon_reference.score_served(weights, falcon_h1_toy.SIZES, late_prompt, tokens, pad_to=16)
+        assert np.array_equal(np.asarray(scored).argmax(axis=-1), tokens)
+
+
+@pytest.mark.parametrize("sampling", [GREEDY, SAMPLED], ids=["greedy", "sampled"])
+def test_a_finish_riding_a_decoding_tick_of_a_model_whose_chunks_ride_is_sampled_in_the_next_tick(models, sampling):
+    """ISSUE 46, the LFM2 twin of the test above: the tick that ends the prompt decodes the
+    neighbour and installs the late slot's row AFTER its sampler ran, so the late request's
+    first token is the next tick's; its chunk lanes rode the decode step in every tick;
+    and the tokens are still the model's own logits path's."""
+    model, params, weights = models["lfm2_moe"]
+    neighbour_prompt, late_prompt = [7, 3, 11, 2, 5], list(range(20, 37))  # 17 tokens: chunks of 8, 8, 1
+    engine = _engine(models, "lfm2_moe")
+    config = GenerationConfig(max_new_tokens=6, do_sample=sampling[2], temperature=sampling[0],
+                              top_k=sampling[1] or None)
+    neighbour = engine.submit(neighbour_prompt, max_new_tokens=24)
+    while len(neighbour.output_ids) < 2:
+        engine.step()
+    riding_before = engine.metrics.snapshot()["ragged_tick"]["riding_chunk_lanes"]
+    late = engine.submit(late_prompt, config=config, rng=jax.random.PRNGKey(11))
+    seen = []
+    for _ in range(8):
+        before = (late.admitted_at, len(late.output_ids), len(neighbour.output_ids))
+        engine.step()
+        seen.append((before, (late.admitted_at, len(late.output_ids), len(neighbour.output_ids))))
+        if late.output_ids:
+            break
+    assert len(seen) == 4  # three chunk lanes, each riding a tick that decoded the neighbour, then one tick more
+    (ready_before, _, _), (ready_after, late_after, _) = seen[-2]
+    assert ready_before is None and ready_after is not None and late_after == 0  # decode-ready, not yet sampled
+    assert seen[-1][0][0] == ready_after and seen[-1][1][1] == 1 and late.first_token_at > late.admitted_at
+    assert all(after[2] == before[2] + 1 for before, after in seen)  # the neighbour gained a token in every tick
+    assert engine.metrics.snapshot()["ragged_tick"]["riding_chunk_lanes"] == riding_before + 3
+    engine.run_until_drained(max_steps=200)
+    assert late.ok and neighbour.ok and engine.decode_compilations == 1
+    assert late.result().tolist() == _lfm2_by_hand(model, params, late_prompt, 6, jax.random.PRNGKey(11), sampling)
+    assert neighbour.result().tolist() == _lfm2_by_hand(model, params, neighbour_prompt, 24, jax.random.PRNGKey(0), GREEDY)
+    if sampling is GREEDY:
+        tokens = np.asarray(late.output_ids)
+        scored = lfm2_reference.score_served(weights, lfm2_moe_toy.SIZES, late_prompt, tokens, pad_to=16)
         assert np.array_equal(np.asarray(scored).argmax(axis=-1), tokens)
 
 

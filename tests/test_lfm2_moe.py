@@ -230,6 +230,60 @@ def test_a_reused_slot_serves_its_second_request_as_if_fresh(toy, tokens):
     np.testing.assert_allclose(got, full[10:], atol=TOL, rtol=0)
 
 
+# ---------------------------------------- (ii b) a chunk lane riding the decode step
+# (the chunk's first position, its rows, its cap, slots that decode beside it)
+RIDING = {"a full chunk": (8, 8, 8, 2), "a chunk shorter than its cap": (16, 5, 8, 2),
+          "a first chunk (reset)": (0, 8, 8, 2), "no slot active": (8, 8, 8, 0)}
+
+
+@pytest.mark.parametrize("case", sorted(RIDING))
+def test_a_chunk_riding_the_decode_step_equals_the_chunk_then_the_step(toy, tokens, case):
+    """``decode_rows_with_chunk_paged`` (serving_api.py (h)) against ``prefill_chunk_paged`` then
+    ``decode_rows_paged`` on the same cache: two slots mid-decode (or none), the third mid-prefill."""
+    model, params, _ = toy
+    offset, count, cap, decoding = RIDING[case]
+    ids = np.asarray(tokens)
+    cache = model.init_paged_cache(3, 16, 8, jnp.float32)
+    tables = [jnp.zeros((cache.pages_per_slot,), jnp.int32).at[:4].set(jnp.arange(1 + 4 * i, 5 + 4 * i)) for i in range(3)]
+    for slot, n in zip(range(decoding), (13, 6)):
+        cache, _ = _prefill(model, params, cache, ids[slot: slot + n], slot, tables[slot], 8)
+    late = np.asarray(jax.random.randint(jax.random.PRNGKey(17), (24,), 1, SIZES["vocab_size"]))
+    if offset:  # the chunks before this one, and columns a first chunk must NOT read otherwise
+        for done in range(0, offset, 8):
+            cache = model.apply(params, jnp.asarray(late[done: done + 8]), done, 8, done == 0, 2, tables[2], cache,
+                                method=type(model).prefill_chunk_paged)
+    else:
+        cache = cache.replace(conv_state=cache.conv_state.at[:, 2].set(0.7))
+    rows = np.zeros((cap,), np.int32)
+    rows[:count] = late[offset: offset + count]
+    step = jnp.asarray([[int(ids[20])], [int(ids[21])], [0]], jnp.int32)
+    apart = model.apply(params, jnp.asarray(rows), offset, count, offset == 0, 2, tables[2], cache,
+                        method=type(model).prefill_chunk_paged)
+    want_rows, want = model.apply(params, step, apart, method=type(model).decode_rows_paged)
+    got_rows, got = model.apply(params, step, cache, jnp.asarray(rows), offset, count, offset == 0, 2, tables[2],
+                                method=type(model).decode_rows_with_chunk_paged)
+    # and with the decode rows switched off (a lane past the first, a tick that only carries lanes): the chunk alone
+    _, alone = model.apply(params, step, cache, jnp.asarray(rows), offset, count, offset == 0, 2, tables[2], jnp.asarray(False),
+                           method=type(model).decode_rows_with_chunk_paged)
+    for name in ("conv_state", "last_hidden", "length", "active", "expert_counts"):
+        np.testing.assert_allclose(np.asarray(getattr(alone, name)), np.asarray(getattr(apart, name)), atol=TOL, rtol=0, err_msg=name)
+    np.testing.assert_allclose(np.asarray(alone.kp)[:, 1:], np.asarray(apart.kp)[:, 1:], atol=TOL, rtol=0)
+    live = np.asarray(cache.active)
+    assert live.sum() == decoding and np.abs(np.asarray(want_rows)[live]).max(initial=1.0) > 0.1
+    np.testing.assert_allclose(np.asarray(got_rows)[live], np.asarray(want_rows)[live], atol=TOL, rtol=0)
+    for name in ("kp", "vp", "conv_state", "last_hidden"):
+        a, b = np.asarray(getattr(got, name)), np.asarray(getattr(want, name))
+        if name in ("kp", "vp"):  # page 0 is the trash page: what lands there is never read
+            a, b = a[:, 1:], b[:, 1:]
+        np.testing.assert_allclose(a, b, atol=TOL, rtol=0, err_msg=name)
+    for name in ("length", "active", "page_table", "expert_counts"):
+        np.testing.assert_array_equal(np.asarray(getattr(got, name)), np.asarray(getattr(want, name)), err_msg=name)
+    # both rows of the counters moved by their own group's assignments alone
+    moved = np.asarray(got.expert_counts - cache.expert_counts).sum(axis=-1)
+    assert (moved[0] == decoding * SIZES["num_experts_per_tok"]).all() and (moved[1] == count * SIZES["num_experts_per_tok"]).all()
+    assert float(jnp.abs(got.last_hidden[2] - cache.last_hidden[2]).max()) > 0.01
+
+
 # ------------------------------------------------------------- (iii) the share test
 @pytest.fixture(scope="module")
 def expert_case():

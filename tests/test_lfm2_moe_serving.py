@@ -108,6 +108,43 @@ def test_the_ticks_record_and_spans_carry_the_counters(served):
     assert all(tuple(e["args"]) == TickRecord._fields for e in syncs)
 
 
+def test_the_riding_lanes_counter_is_the_lane_ticks_that_decoded(served):
+    """ISSUE 46: the tick's record says how many of its chunk lanes rode the decode step (the
+    first, in every tick that carried one and decoded), the snapshot their total; a slot whose
+    finish lane rode a tick is not among that tick's decoding slots."""
+    engine, recorder, handles, prompts, _ = served
+    assert engine._traits.chunk_rides_decode
+    events = [e for e in recorder.chrome_trace()["traceEvents"] if e.get("ph") == "X"]
+    ticks = [e["args"] for e in events if e["name"].endswith(".tick") and "chunk_lanes" in e["args"]]
+    lane_ticks_that_decoded = [t for t in ticks if t["chunk_lanes"] and t["decoding"]]
+    assert len(lane_ticks_that_decoded) >= 5
+    assert all(t["riding_chunk_lanes"] == 1 for t in lane_ticks_that_decoded)
+    assert all(t["riding_chunk_lanes"] == 0 for t in ticks if not (t["chunk_lanes"] and t["decoding"]))
+    block = engine.metrics.snapshot()["ragged_tick"]
+    assert block["riding_chunk_lanes"] == len(lane_ticks_that_decoded)
+    # every prompt ended in a finish lane, and no tick harvested a slot it finished
+    assert sum(t["finish_lanes"] for t in ticks) == len(prompts)
+    syncs = {e["args"]["tick"]: e["args"] for e in events if e["name"].endswith(".sample_sync")}
+    assert all(syncs[t["tick"]]["riding_chunk_lanes"] == t["riding_chunk_lanes"] for t in ticks if t["tick"] in syncs)
+    assert all(h.first_token_at > h.admitted_at for h in handles)
+
+
+def test_a_model_that_states_nothing_has_no_riding_lane():
+    from tests.falcon_h1_toy import build as build_falcon
+
+    model, params, _ = build_falcon()
+    assert not model.serving_traits().chunk_rides_decode
+    engine = ServingEngine(model, params, **ENGINE)
+    first = engine.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=12)
+    engine.step()
+    engine.step()
+    late = engine.submit(np.arange(1, 20, dtype=np.int32), max_new_tokens=3)
+    engine.run_until_drained(max_steps=100)
+    assert first.ok and late.ok
+    block = engine.metrics.snapshot()["ragged_tick"]
+    assert block["chunk_lanes"]["mean"] >= 1 and block["riding_chunk_lanes"] == 0
+
+
 def test_a_model_without_experts_has_no_experts_block_and_its_tick_returns_what_it_did():
     from tests.falcon_h1_toy import build as build_falcon
 

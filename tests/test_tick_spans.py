@@ -393,7 +393,7 @@ def carried(setup):
             "prefill_dispatches": named("serving.prefill_dispatch")}
 
 
-def _case_ten_fields_on_sync_and_tick(run):
+def _case_ten_fields_on_sync_and_tick(run):  # eleven since ISSUE 46: the record's, whatever their number
     assert len(run["dispatching"]) >= 20 and len(run["dispatching"]) < len(run["ticks"])
     for tick in run["dispatching"]:
         assert tuple(tick["args"]) == FIELDS and all(isinstance(v, int) for v in tick["args"].values())
@@ -535,7 +535,9 @@ def test_spans_reach_a_jax_profiler_trace(setup, tmp_path):
     assert [name for name, *_ in carriers] == ["serving.decode_dispatch", "serving.sample_sync"] * 3
     assert ticks.carries_records(carriers)
     for (_, d_start, d_dur, dispatch), (_, s_start, _, sync) in zip(carriers[::2], carriers[1::2]):
-        assert tuple(sync) == ticks.FIELDS == TickRecord._fields and tuple(dispatch) == ticks.DISPATCH_FIELDS
+        # the reader takes its fields by name: the record may carry more (ISSUE 46's riding lanes), after them
+        assert tuple(sync) == TickRecord._fields and TickRecord._fields[:len(ticks.FIELDS)] == ticks.FIELDS
+        assert tuple(dispatch) == ticks.DISPATCH_FIELDS
         assert dispatch == {f: sync[f] for f in dispatch} and d_start + d_dur <= s_start
         assert ticks.tick_class(ticks.record_of("serving.sample_sync", sync)) == "decode_only"
 
