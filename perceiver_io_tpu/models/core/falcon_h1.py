@@ -344,7 +344,6 @@ class FalconH1ForCausalLM(nn.Module):
                 "handle_preemption": f"{missing}, so a drained slot cannot be resumed elsewhere from its pages alone",
                 "journal": f"{missing}: a journal replay re-admits a session through forced decode steps, "
                            "which this model's one admission path has not been proven on",
-                "dense pool (kv_page_size=None)": "its keys and values live in the paged pool only",
             })
 
     def serving_pages(self, prompt_tokens: int, max_new_tokens: int, page_size: int, bucket: int) -> int:

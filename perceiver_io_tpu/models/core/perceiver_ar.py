@@ -93,58 +93,23 @@ class PerceiverARCache(flax.struct.PyTreeNode):
             live=jnp.maximum(self.live - k, 0),
         )
 
-    def write_slot(self, slot: jax.Array, src: "PerceiverARCache") -> "PerceiverARCache":
-        """Install a single request's cache (batch size 1) into batch row
-        ``slot`` of this batched cache — the admission primitive of the
-        serving engine (serving/engine.py). Cache LENGTHS are shared scalars
-        across the batch and are kept from ``self``: the caller must have
-        filled ``src`` to the same lengths (the engine prefills every request
-        to the full window), OR prefilled ``src`` at a smaller cross-attention
-        capacity (a bucketed prefill): then the bucket rows scatter into the
-        slot's TAIL and the head becomes masked left-pad (zero keys,
-        ``pad_slots=True``, ``shift`` grown by the offset) — positionally
-        identical to the canonical full-window form because cache slot ``j``
-        encodes position ``j - shift`` and both keys and RoPE tables shift
-        together."""
-        off = self.ca.capacity - src.ca.capacity
-        if off:
-            b = src.pad_slots.shape[0]
-            zk = jnp.zeros((b, off, src.ca.k.shape[-1]), src.ca.k.dtype)
-            zv = jnp.zeros((b, off, src.ca.v.shape[-1]), src.ca.v.dtype)
-            src = src.replace(
-                ca=src.ca.replace(
-                    k=jnp.concatenate([zk, src.ca.k], axis=1),
-                    v=jnp.concatenate([zv, src.ca.v], axis=1),
-                ),
-                pad_slots=jnp.concatenate([jnp.ones((b, off), bool), src.pad_slots], axis=1),
-                shift=src.shift + off,
-            )
-        return PerceiverARCache(
-            ca=self.ca.write_batch_row(slot, src.ca, batch_axis=0),
-            sa=self.sa.write_batch_row(slot, src.sa, batch_axis=1),
-            pad_slots=jax.lax.dynamic_update_slice_in_dim(self.pad_slots, src.pad_slots, slot, axis=0),
-            shift=jax.lax.dynamic_update_slice_in_dim(self.shift, src.shift, slot, axis=0),
-            live=jax.lax.dynamic_update_slice_in_dim(self.live, src.live, slot, axis=0),
-        )
-
 
 class PagedPerceiverARCache(flax.struct.PyTreeNode):
     """Paged decode state for a Perceiver AR serving pool (docs/serving.md).
 
-    The dense pool (``PerceiverARCache`` at full window capacity per slot)
-    reserves ``window`` cross-attention KV rows per slot whether or not they
-    hold live tokens. Here the cross-attention KV lives in a shared PAGE POOL
+    A ``PerceiverARCache`` at full window capacity per slot would reserve
+    ``window`` cross-attention KV rows per slot whether or not they hold live
+    tokens. Here the cross-attention KV lives in a shared PAGE POOL
     (``ca``: ops/paged_decode_kernel.PagedKVCache) addressed through per-slot
     page tables, so HBM cost scales with live tokens and admission/eviction
-    are page-table edits — the paged forms of ``write_slot`` (install_slot),
-    ``rewind``, and the ``live`` bookkeeping. The self-attention cache
+    are page-table edits — ``install_slot``, the paged form of ``rewind``,
+    and the ``live`` bookkeeping. The self-attention cache
     (capacity ``max_latents``, one per layer) stays dense, as a ring
     (``sa``: ops/attention.RingKVCache): per slot an offset ``sa.start``, an
     append of one row a slot a layer, nothing shifted.
 
     Engine-only invariants (serving/engine.py): every row sits at FULL window
-    occupancy at all times (the same invariant the dense pool pins via shared
-    cache lengths), so validity is fully encoded by ``live`` and the ring
+    occupancy at all times, so validity is fully encoded by ``live`` and the ring
     offset ``ca.start`` — there is no pad-slot buffer and no shared length.
     The self-attention ring rests on the same invariant: every slot holds
     ``max_latents`` latents from its install on (a prefill always yields that
@@ -175,7 +140,7 @@ class PagedPerceiverARCache(flax.struct.PyTreeNode):
     def install_slot(
         self, slot: jax.Array, table_row: jax.Array, src: PerceiverARCache
     ) -> "PagedPerceiverARCache":
-        """Paged form of ``write_slot``: install a bucket-prefilled request
+        """The one-shot admission primitive: install a bucket-prefilled request
         (``src``: batch-1 DENSE cache at bucket capacity, straight from the
         shared prefill program) into pool slot ``slot`` whose page table row
         becomes ``table_row`` (P,) — the first ceil(bucket/page) entries are
@@ -191,11 +156,11 @@ class PagedPerceiverARCache(flax.struct.PyTreeNode):
         (n = live prompt length). Page k's contents are therefore a pure
         function of prompt tokens ``[k*ps, (k+1)*ps)`` alone, independent of
         the covering bucket and the tail beyond the page — the property the
-        cross-request prefix cache keys on. Positionally this is the dense
-        ``write_slot`` tail-scatter in a rotated frame (ring slot i holds
-        logical window position ``window - n + i``), with the head left-pad
-        represented by ``live``/``shift`` alone instead of a zero-filled
-        buffer. The rolled-out pad rows land past position n as inert
+        cross-request prefix cache keys on. Positionally this is a scatter
+        of the bucket rows into the window's tail, in a rotated frame (ring
+        slot i holds logical window position ``window - n + i``), with the
+        head left-pad represented by ``live``/``shift`` alone instead of a
+        zero-filled buffer. The rolled-out pad rows land past position n as inert
         garbage: never visible (``live`` bounds the window) and overwritten
         by decode appends before they ever could be.
 
@@ -889,8 +854,8 @@ class CausalSequenceModel(nn.Module):
         # Built from config only, so it works on an unbound module.
         # ``max_seq_len`` overrides the cross-attention capacity for BUCKETED
         # prefill (serving/engine.py): a prompt prefilled at a smaller bucket
-        # window produces a cache whose rows scatter into the tail of a
-        # full-window pool row (PerceiverARCache.write_slot).
+        # window produces a cache whose rows scatter into the slot's pages
+        # (PagedPerceiverARCache.install_slot).
         cfg = self.config
         return _make_ar_cache(
             batch_size, max_seq_len or cfg.max_seq_len, cfg.max_latents,
@@ -933,12 +898,6 @@ class CausalSequenceModel(nn.Module):
         hidden, cache = self.ar.prefill(x, prefix_len=prefix_len, cache=cache, pad_mask=pad_mask)
         return hidden[:, -1], cache
 
-    def decode_rows(self, x: jax.Array, cache: PerceiverARCache) -> Tuple[jax.Array, PerceiverARCache]:
-        """decode_step without the head: (hidden rows (B, C), cache) — the
-        dense pool's form of ``decode_rows_paged``."""
-        hidden, cache = self.ar.decode_step(x, cache)
-        return hidden[:, -1], cache
-
     def decode_block(self, x: jax.Array, cache: PerceiverARCache) -> Tuple[jax.Array, PerceiverARCache]:
         """Decode ``n`` tokens at once (chunked/speculative verification); see
         ``PerceiverAR.decode_block`` for the n > 1 no-roll contract. Returns
@@ -951,8 +910,8 @@ class CausalSequenceModel(nn.Module):
         kv_quant: Optional[str] = None,
     ) -> PagedPerceiverARCache:
         """Paged decode-pool state for the serving engine (serving/paging.py):
-        a shared KV page pool + per-slot page tables in place of the dense
-        per-slot full-window cross-attention cache. Built from config only,
+        a shared KV page pool + per-slot page tables in place of a per-slot
+        full-window cross-attention cache. Built from config only,
         so it works on an unbound module. ``kv_quant="int8"`` makes the pool
         int8 with per-page-per-head scale sidecars (docs/serving.md
         "Quantized KV pages & weight serving")."""

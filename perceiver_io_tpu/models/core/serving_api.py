@@ -1,4 +1,4 @@
-"""What ``ServingEngine`` asks of a model it serves from the paged pool.
+"""What ``ServingEngine`` asks of a model it serves from its page pool.
 
 The engine owns slots, pages, the tick loop and the descriptor; it knows no
 architecture. A model class answers five questions, by methods the engine
@@ -93,9 +93,9 @@ A model MAY also say that its chunk rows ride its decode pass:
     saved pays for.
 
 ``serving_traits()`` says, in plain data, what else differs: which prompts
-take the split admission, what the descriptor's lanes carry, and which engine
-options the model does not carry yet (each with the piece that is missing, so
-the engine can refuse it at construction).
+take the split admission, what the descriptor's lanes carry, and which of four
+engine options (``prefix_cache``, ``kv_quant``, ``handle_preemption``,
+``journal``) the model does not carry yet, each with the piece that is missing.
 """
 
 from __future__ import annotations

@@ -81,22 +81,6 @@ class KVCache(flax.struct.PyTreeNode):
         contents are unreachable behind the causal/validity masks."""
         return self.replace(length=jnp.zeros_like(self.length))
 
-    def write_batch_row(self, idx: jax.Array, src: "KVCache", batch_axis: int = 0) -> "KVCache":
-        """Overwrite batch row ``idx`` (traced OK) with ``src``'s buffers — the
-        slot-install primitive of the serving engine (serving/engine.py):
-        ``src`` is a size-1-batch cache whose k/v rows replace one row of this
-        batched cache. ``batch_axis`` is 0 for plain caches and 1 for stacked
-        per-layer caches (axis 0 is the scanned layer there). The scalar
-        ``length`` is deliberately NOT copied: batched rows share one length,
-        and the caller must guarantee ``src``'s k/v buffers span this cache's
-        full capacity with content positioned consistently with the shared
-        length (``PerceiverARCache.write_slot`` widens bucket-prefilled rows
-        into the tail — masked zero left-pad at the head — before calling)."""
-        return self.replace(
-            k=jax.lax.dynamic_update_slice_in_dim(self.k, src.k.astype(self.k.dtype), idx, axis=batch_axis),
-            v=jax.lax.dynamic_update_slice_in_dim(self.v, src.v.astype(self.v.dtype), idx, axis=batch_axis),
-        )
-
     def append(self, k_new: jax.Array, v_new: jax.Array) -> "KVCache":
         n_new = k_new.shape[1]
         cap = self.capacity
@@ -138,7 +122,7 @@ class RingKVCache(flax.struct.PyTreeNode):
     therefore sees all ``capacity`` rows (no validity bound, no pad mask) and
     an append is one row a slot a layer; nothing is shifted.
     ``KVCache`` (left-aligned, shared scalar length, rolled when full) stays
-    the cache of ``generate()``, prefill and the dense engine pool.
+    the cache of ``generate()`` and of the one-shot prefill.
     """
 
     k: jax.Array

@@ -178,8 +178,7 @@ class PagedKVCache(flax.struct.PyTreeNode):
     ``window``: static logical window length (<= P * page_size).
 
     Unlike the dense ``KVCache`` there is no shared ``length``: the serving
-    pool pins every slot at full window occupancy (the engine invariant the
-    dense pool also maintains), so validity is fully encoded by the per-row
+    pool pins every slot at full window occupancy, so validity is fully encoded by the per-row
     ``live`` count threaded alongside (PagedPerceiverARCache.live).
     """
 

@@ -1,36 +1,35 @@
-"""Continuous-batching inference engine over slot-based ring KV caches.
+"""Continuous-batching inference engine over one paged KV pool.
 
 The single-request decode stack (generation/generate.py) compiles one program
 per prompt shape and serves one request per scan. Serving heavy traffic needs
 the opposite: MANY heterogeneous requests advancing inside ONE compiled step
 whose shapes never change as requests come and go — the "Ragged Paged
-Attention" recipe (PAPERS.md) mapped onto this repo's fixed-capacity
-``PerceiverARCache`` ring buffers.
+Attention" recipe (PAPERS.md): a shared page pool addressed through per-slot
+page tables, and one fused tick program.
 
 Design (see docs/serving.md for the full writeup):
 
-  * The engine owns ``num_slots`` decode slots stacked into one batched
-    ``PerceiverARCache`` (batch axis = slot index). Cache lengths are shared
-    scalars, so every slot must sit at the SAME fill level at all times: the
-    engine pins the whole pool at full capacity; per-request left-pad counts
-    live in the cache's ``shift``/``pad_slots``/``live`` fields exactly as
-    for padded batches.
-  * Admission = one batch-1 prefill at the smallest BUCKET covering the
-    prompt (a small geometric ladder of compiled shapes, ``prefill_buckets``
-    — prefill cost is O(bucket), not O(window)) + a row scatter into the
-    pool (``PerceiverARCache.write_slot`` widens the bucket rows into the
-    slot's tail). Compile count stays bounded: <= one prefill program per
-    bucket, pinned by test. Admission is NON-BLOCKING: prefill/install are
-    dispatched without a device sync so they overlap the decode stream, and
-    all free slots are filled before the tick's single sync point.
-  * One jitted decode step advances ALL slots one token: per-slot sampling
+  * The engine owns ``num_slots`` decode slots over one model-built paged
+    cache (``model.init_paged_cache``; batch axis = slot index). Every slot
+    sits at the SAME fill level at all times: the pool is pinned at full
+    capacity; per-request left-pad counts live in the cache's ``shift`` and
+    ``live`` fields.
+  * Admission of a prompt shorter than the model's ``split_from`` = one
+    batch-1 prefill at the smallest BUCKET covering the prompt (a small
+    geometric ladder of compiled shapes, ``prefill_buckets`` — prefill cost
+    is O(bucket), not O(window)) + a scatter of the bucket's rows into the
+    request's pages (``install_slot``). Compile count stays bounded: <= one
+    prefill program per bucket, pinned by test. Longer prompts ride the tick
+    as chunk and finish lanes. Admission is NON-BLOCKING: prefill/install
+    are dispatched without a device sync so they overlap the decode stream,
+    and all free slots are filled before the tick's single sync point.
+  * One jitted tick advances ALL slots one token: per-slot sampling
     parameters are traced (B,) arrays (``process_logits_batched``), so any
     mix of greedy/temperature/top-k/top-p requests shares the one program.
     Free slots decode pad tokens whose outputs are discarded — compute is
-    wasted, recompilation never happens. Per-slot live lengths ride in
-    ``PerceiverARCache.live`` so the decode kernel skips KV blocks below
-    each slot's live region (ragged length-aware decode,
-    ops/decode_kernel.py).
+    wasted, recompilation never happens. Per-slot live lengths ride in the
+    cache's ``live`` so the decode kernel skips KV pages below each slot's
+    live region (ragged length-aware decode, ops/paged_decode_kernel.py).
   * EOS/length bookkeeping is host-side: the scheduler evicts finished
     requests and admits queued ones between steps. ``max_new_tokens`` is a
     host counter, not a compiled loop bound, so mixed lengths are free.
@@ -63,10 +62,10 @@ program count growing past the churn-never-recompiles budgets at runtime.
 Off by default; the disabled path holds the shared no-op recorder and the
 greedy-parity and compile-count pins run through it unchanged.
 
-Paged KV cache (docs/serving.md "Paged KV cache"; serving/paging.py): with
-``kv_page_size`` set, the per-slot full-window cross-attention cache is
-replaced by a shared physical PAGE POOL addressed through per-slot page
-tables — HBM cost scales with live tokens, not pool capacity. Admission
+Paged KV cache (docs/serving.md "Paged KV cache"; serving/paging.py): the
+cross-attention cache is a shared physical PAGE POOL of ``kv_page_size``-token
+pages addressed through per-slot page tables (``kv_page_size=None``: one page
+a window) — HBM cost scales with live tokens, not pool capacity. Admission
 allocates the request's whole reservation (covering bucket + max_new_tokens,
 capped at the window) from a refcounted, deterministic free list and scatters
 the bucket KV into those pages; eviction returns the pages (no O(window) row
@@ -96,15 +95,14 @@ NOTHING new. Victim selection is a pure function of (priority, admission
 order, page count); each request survives at most ``max_preemptions``
 preemptions, then runs to completion untouchable (no livelock).
 
-Unified ragged tick (docs/serving.md "Unified ragged tick"): a PAGED engine
+Unified ragged tick (docs/serving.md "Unified ragged tick"): the engine
 buffers each tick's prefill chunks, latent finishes, scale resets, and decode
 step into ONE host-built descriptor — one int32 array, sent in one
 transfer, or not at all when the tick carries nothing but decode
 (serving/tick_descriptor.py) — and dispatches ONE fused program per
-tick (``ragged_tick``); the dense pool (``kv_page_size=None``) dispatches
-``decode_step``. The constructor's arguments alone decide which pool, page
+tick (``ragged_tick``). The constructor's arguments alone decide which page
 format, admission path, and prefill ladder an engine runs: ``kv_page_size``
-(None = the dense pool), ``kv_quant`` / ``weight_dtype`` (None = full
+(None = one page a window), ``kv_quant`` / ``weight_dtype`` (None = full
 precision pages / untouched params), ``prefill_chunk_tokens`` (None =
 unchunked admission), ``prefix_cache`` (False = no sharing),
 ``prefill_buckets`` (``(window,)`` = the single full-window bucket).
@@ -273,7 +271,7 @@ class ServedRequest:
     # preemption): the per-class queue-wait stats measure the current wait,
     # not a sum over preemption cycles
     enqueued_at: float = 0.0
-    # the instant a slot (and, paged, the whole page reservation) was claimed
+    # the instant a slot (and the whole page reservation) was claimed
     # for this request — the end of its queue wait on BOTH admission paths
     # (a preempted continuation: its latest claim)
     slot_claimed_at: Optional[float] = None
@@ -288,10 +286,10 @@ class ServedRequest:
     last_token_at: Optional[float] = None
     finished_at: Optional[float] = None
     deadline_s: Optional[float] = None  # TTL from submit; enforced at ticks
-    # paged engines: the request's page reservation, computed ONCE at submit
-    # (it is a pure function of the prompt/config — engine.load and the
-    # admission gate read it per tick, so re-deriving it would make the
-    # queue-bound check O(queue * ladder)); None on dense pools
+    # the request's page reservation, computed ONCE at submit (it is a pure
+    # function of the prompt/config — engine.load and the admission gate read
+    # it per tick, so re-deriving it would make the queue-bound check
+    # O(queue * ladder))
     pages_reserved: Optional[int] = None
     # the admission's actual allocation (== pages_reserved once RUNNING) —
     # the router's failover test pins replay reservations against this
@@ -408,9 +406,9 @@ class _PrefillTask:
 _ENGINE_IDS = itertools.count()
 
 # Stable ``jax.named_scope`` names of the phases INSIDE the tick program (the
-# fused ``ragged_tick``; the dense pool's ``decode_step`` has the last two):
-# they ride every operation's ``op_name`` into a profiler trace, where device
-# time is summed per phase (docs/observability.md "Named scopes"). Inside
+# fused ``ragged_tick``): they ride every operation's ``op_name`` into a
+# profiler trace, where device time is summed per phase
+# (docs/observability.md "Named scopes"). Inside
 # ``tick.decode`` the model's own scopes split further: ``cache_append`` and
 # ``decode_attention`` (ops/attention.py), the flax ``mlp`` modules, and
 # ``head`` (models/core/perceiver_ar.py): the step's one pass of the head over
@@ -509,8 +507,7 @@ class ServingEngine:
         # before anything is built or touched on disk, never run wrong
         traits = self._traits = model.serving_traits()
         options = {"prefix_cache": prefix_cache, "kv_quant": kv_quant,
-                   "handle_preemption": handle_preemption, "journal": journal,
-                   "dense pool (kv_page_size=None)": kv_page_size is None}
+                   "handle_preemption": handle_preemption, "journal": journal}
         for option, reason in traits.unsupported.items():
             if options.get(option):
                 raise ValueError(
@@ -688,8 +685,8 @@ class ServingEngine:
 
         # Prefill bucket ladder (ascending, ends at the window): a prompt is
         # prefilled at the smallest covering bucket — cost O(bucket) — and
-        # write_slot widens the bucket rows into the slot's tail. One compiled
-        # prefill program per bucket, ever.
+        # install_slot widens the bucket rows into the slot's tail. One
+        # compiled prefill program per bucket, ever.
         if prefill_buckets is None:
             ladder = default_prefill_buckets(self._window, traits.prefill_floor)
         else:
@@ -702,89 +699,62 @@ class ServingEngine:
                 )
         self.prefill_buckets: tuple = ladder
 
-        # Paged KV mode (serving/paging.py; module docstring): kv_page_size
-        # opts in; None is the dense pool.
-        self.paged = kv_page_size is not None
-        self.kv_page_size: Optional[int] = None
-        self._pool: Optional[PagePool] = None
-        if kv_page_size is not None and not 1 <= int(kv_page_size) <= self._window:
+        # The page pool (serving/paging.py; module docstring): the engine's
+        # one KV pool. kv_page_size=None is one page a window — a slot's
+        # whole window in a single page.
+        if kv_page_size is None:
+            kv_page_size = self._window
+        if not 1 <= int(kv_page_size) <= self._window:
             raise ValueError(
                 f"kv_page_size must lie in [1..window={self._window}], got {kv_page_size}"
             )
         # Quantized KV pages (docs/serving.md "Quantized KV pages & weight
-        # serving"): int8 pool + per-page-per-head scale sidecars. Requires
-        # paging (quantization is a PAGE layout); configuring it on a dense
-        # engine is a caller bug.
+        # serving"): int8 pool + per-page-per-head scale sidecars.
         from perceiver_io_tpu.ops.paged_decode_kernel import KV_QUANT_MODES
 
         if kv_quant is not None and kv_quant not in KV_QUANT_MODES:
             raise ValueError(
                 f"kv_quant must be one of {KV_QUANT_MODES} or None, got {kv_quant!r}"
             )
-        if kv_quant is not None and kv_page_size is None:
-            raise ValueError("kv_quant requires kv_page_size (quantization is "
-                             "a page layout)")
         self.kv_quant: Optional[str] = kv_quant
-        if self.paged:
-            self.kv_page_size = int(kv_page_size)
-            self._pages_per_slot = -(-self._window // self.kv_page_size)
-            # default pool = exactly the dense layout's backing (one full
-            # window per slot) + the reserved trash page: paged-but-same-
-            # capacity, so enabling paging alone never ADDS admission blocking
-            pages = (
-                int(num_kv_pages) if num_kv_pages is not None
-                else num_slots * self._pages_per_slot + 1
+        self.kv_page_size = int(kv_page_size)
+        self._pages_per_slot = -(-self._window // self.kv_page_size)
+        # default pool = one full window per slot + the reserved trash page:
+        # the page size alone never ADDS admission blocking
+        pages = (
+            int(num_kv_pages) if num_kv_pages is not None
+            else num_slots * self._pages_per_slot + 1
+        )
+        if pages < self._pages_per_slot + 1:
+            # the worst-case single reservation is a full window of pages;
+            # a smaller pool would head-block that request forever
+            raise ValueError(
+                f"num_kv_pages must be >= pages_per_slot + 1 = "
+                f"{self._pages_per_slot + 1} (worst-case reservation + trash "
+                f"page), got {pages}"
             )
-            if pages < self._pages_per_slot + 1:
-                # the worst-case single reservation is a full window of pages;
-                # a smaller pool would head-block that request forever
-                raise ValueError(
-                    f"num_kv_pages must be >= pages_per_slot + 1 = "
-                    f"{self._pages_per_slot + 1} (worst-case reservation + trash "
-                    f"page), got {pages}"
-                )
-            self._pool = PagePool(pages, reserved=1)
-            self._slot_pages: List[Optional[List[int]]] = [None] * num_slots
-            # request id currently head-blocked on the free list, so a long
-            # block reports one alloc_failure episode rather than one per tick
-            self._alloc_blocked_id: Optional[int] = None
-            # the factory pins live at the window, and the self-attention
-            # ring (RingKVCache) is full by construction: the fill-level
-            # invariant the dense pool pins through its shared lengths
-            self._cache = model.init_paged_cache(
-                num_slots, pages, self.kv_page_size, dtype=self.cache_dtype,
-                kv_quant=self.kv_quant,
-            )
-            self.metrics.set_page_pool(self._pool.num_pages - self._pool.reserved, 0)
-        else:
-            # Device pool: batched cache pinned at FULL capacity (free slots
-            # hold zeros — harmless; see module docstring) + per-slot state.
-            # Free-slot live lengths are pinned at the full window so the
-            # ragged decode kernel treats them exactly like the pre-ragged
-            # path (outputs discarded either way).
-            cache = model.init_cache(batch_size=num_slots, dtype=self.cache_dtype)
-            self._cache = cache.replace(
-                ca=cache.ca.replace(length=jnp.asarray(cache.ca.capacity, jnp.int32)),
-                sa=cache.sa.replace(length=jnp.full_like(cache.sa.length, cache.sa.k.shape[2])),
-                live=jnp.full((num_slots,), cache.ca.capacity, jnp.int32),
-            )
+        self._pool = PagePool(pages, reserved=1)
+        self._slot_pages: List[Optional[List[int]]] = [None] * num_slots
+        # request id currently head-blocked on the free list, so a long
+        # block reports one alloc_failure episode rather than one per tick
+        self._alloc_blocked_id: Optional[int] = None
+        # the factory pins live at the window, and the self-attention
+        # ring (RingKVCache) is full by construction: every slot sits at
+        # the same fill level at all times
+        self._cache = model.init_paged_cache(
+            num_slots, pages, self.kv_page_size, dtype=self.cache_dtype,
+            kv_quant=self.kv_quant,
+        )
+        self.metrics.set_page_pool(self._pool.num_pages - self._pool.reserved, 0)
         # Chunked admission prefill + cross-request radix prefix cache
-        # (docs/serving.md "Chunked prefill" / "Prefix cache"). Both compose
-        # over the PAGED pool (chunks write pages, the cache shares them):
-        # configuring either on a dense engine is a caller bug.
-        if prefill_chunk_tokens is not None:
-            if kv_page_size is None:
-                raise ValueError("prefill_chunk_tokens requires kv_page_size "
-                                 "(chunks are written page-wise)")
-            if int(prefill_chunk_tokens) < 1:
-                raise ValueError(f"prefill_chunk_tokens must be >= 1, got "
-                                 f"{prefill_chunk_tokens}")
-        if prefix_cache and kv_page_size is None:
-            raise ValueError("prefix_cache requires kv_page_size (the cache "
-                             "shares pool pages)")
+        # (docs/serving.md "Chunked prefill" / "Prefix cache"): chunks write
+        # pool pages, the cache shares them.
+        if prefill_chunk_tokens is not None and int(prefill_chunk_tokens) < 1:
+            raise ValueError(f"prefill_chunk_tokens must be >= 1, got "
+                             f"{prefill_chunk_tokens}")
         if max_prefill_slots is not None and max_prefill_slots < 1:
             raise ValueError(f"max_prefill_slots must be >= 1, got {max_prefill_slots}")
-        self.chunked = prefill_chunk_tokens is not None and self.paged
+        self.chunked = prefill_chunk_tokens is not None
         self.prefill_chunk_tokens = (int(prefill_chunk_tokens)
                                      if self.chunked else None)
         if (self.kv_quant is not None and self.chunked
@@ -799,25 +769,22 @@ class ServingEngine:
         self.max_prefill_slots = (int(max_prefill_slots)
                                   if max_prefill_slots is not None else num_slots)
         # Unified ragged tick (docs/serving.md "Unified ragged tick"; module
-        # docstring): a paged engine buffers the tick's prefill chunks /
-        # latent finishes / scale resets / decode into ONE host-built
-        # descriptor and dispatches ONE fused program (the descriptor is
-        # page-table work: the dense pool has none and runs decode_step).
-        if self.paged:
-            # lane counts are STATIC program shapes (the descriptor's size;
-            # the model's phases run the lanes a tick carries, not these). At
-            # most one chunk and one finish lane per slot per tick; chunked
-            # engines are further bounded by 2 x max_prefill_slots (advancing
-            # tasks plus the admissions their finishes just unblocked).
-            self._ragged_lanes = (min(num_slots, 2 * self.max_prefill_slots)
-                                  if self.chunked else num_slots)
-            # fixed chunk row capacity — chunk shapes STOP riding the bucket
-            # ladder (no per-rung programs): the chunk cap under chunking,
-            # else the window (the widest single-dispatch tail)
-            self._ragged_chunk_cap = (self.prefill_chunk_tokens
-                                      if self.chunked else self._window)
-        # per-tick ragged work buffers (host side of the descriptor); always
-        # present so _drop_tick_work and the program counters are pool-blind
+        # docstring): the engine buffers the tick's prefill chunks / latent
+        # finishes / scale resets / decode into ONE host-built descriptor and
+        # dispatches ONE fused program.
+        # Lane counts are STATIC program shapes (the descriptor's size; the
+        # model's phases run the lanes a tick carries, not these). At most
+        # one chunk and one finish lane per slot per tick; chunked engines
+        # are further bounded by 2 x max_prefill_slots (advancing tasks plus
+        # the admissions their finishes just unblocked).
+        self._ragged_lanes = (min(num_slots, 2 * self.max_prefill_slots)
+                              if self.chunked else num_slots)
+        # fixed chunk row capacity — chunk shapes STOP riding the bucket
+        # ladder (no per-rung programs): the chunk cap under chunking, else
+        # the window (the widest single-dispatch tail)
+        self._ragged_chunk_cap = (self.prefill_chunk_tokens
+                                  if self.chunked else self._window)
+        # per-tick ragged work buffers (host side of the descriptor)
         self._tick_chunks: List[tuple] = []
         self._tick_finishes: List[tuple] = []
         self._tick_resets: List[tuple] = []
@@ -832,7 +799,7 @@ class ServingEngine:
         # while the tick has dispatched no fused program
         self._tick_transfers: Optional[int] = None
         self._prefix_cache: Optional[PrefixCache] = None
-        if prefix_cache and self.paged:
+        if prefix_cache:
             # the cache is keyed on the pool's byte layout: its mode is fixed
             # at construction. A cache built HERE trivially matches this
             # engine, so this ensure_mode cannot fire today — it stands as
@@ -848,9 +815,8 @@ class ServingEngine:
         self._prefilling: Dict[int, _PrefillTask] = {}
         if self.chunked:
             self.metrics.set_chunked_prefill(self.prefill_chunk_tokens)
-        if self.paged:
-            # serving-metrics/v11: the fused tick's block (None on dense pools)
-            self.metrics.set_ragged_tick(True, self._ragged_lanes)
+        # serving-metrics/v11: the fused tick's block
+        self.metrics.set_ragged_tick(True, self._ragged_lanes)
         if self._prefix_cache is not None:
             self.metrics.set_prefix_cache(self._prefix_cache.stats(), 0)
         # serving-metrics/v9 gauges: quantized-page byte economics and the
@@ -878,40 +844,36 @@ class ServingEngine:
         # mux costs no host->device transfer on ordinary ticks
         self._forced_none = jnp.zeros((num_slots,), jnp.int32)
         self._use_forced_none = jnp.zeros((num_slots,), bool)
-        if self.paged:
-            # the fused tick's descriptor is ONE int32 array (its layout:
-            # serving/tick_descriptor.py). A tick that carries a lane, a
-            # reset or poison packs a copy of the idle template
-            # ``_desc_idle_host`` and sends it in one transfer; a tick that
-            # carries nothing but decode passes ``_desc_decode_only``, built
-            # here once and resident on the device — the program only reads
-            # its descriptor and it is never donated, so it stays valid.
-            self._desc_layout = TickDescriptorLayout(
-                self._ragged_lanes, self._ragged_chunk_cap,
-                self._pages_per_slot, self._latents,
-                recurrent=traits.recurrent_state)
-            self._desc_idle_host = self._desc_layout.idle(any_decode=False)
-            # both paths hand the jit a device array placed as the pool's
-            # own cache and state are (uncommitted, default device): one call
-            # signature, one compiled program. A COMMITTED descriptor would
-            # commit the tick's outputs, so the donated cache and state
-            # would change signature after the first call (a second entry
-            # in the jit's cache, a second compile).
-            self._desc_decode_only = jax.device_put(
-                self._desc_layout.idle(any_decode=True))
+        # the fused tick's descriptor is ONE int32 array (its layout:
+        # serving/tick_descriptor.py). A tick that carries a lane, a reset or
+        # poison packs a copy of the idle template ``_desc_idle_host`` and
+        # sends it in one transfer; a tick that carries nothing but decode
+        # passes ``_desc_decode_only``, built here once and resident on the
+        # device — the program only reads its descriptor and it is never
+        # donated, so it stays valid.
+        self._desc_layout = TickDescriptorLayout(
+            self._ragged_lanes, self._ragged_chunk_cap,
+            self._pages_per_slot, self._latents,
+            recurrent=traits.recurrent_state)
+        self._desc_idle_host = self._desc_layout.idle(any_decode=False)
+        # both paths hand the jit a device array placed as the pool's own
+        # cache and state are (uncommitted, default device): one call
+        # signature, one compiled program. A COMMITTED descriptor would
+        # commit the tick's outputs, so the donated cache and state would
+        # change signature after the first call (a second entry in the jit's
+        # cache, a second compile).
+        self._desc_decode_only = jax.device_put(
+            self._desc_layout.idle(any_decode=True))
         self._build_jits()
         if self.watchdog is not None:
             # the engine's own compile-count pins, as runtime budgets: one
-            # decode/install/release/quarantine program ever, <= one prefill
-            # program per ladder bucket (tests/test_serving.py churn test)
-            if self.paged:
-                # the whole steady-state tick — chunks, finishes, poison,
-                # decode — is ONE program whatever the tick mix (every phase
-                # gates on traced flags, lanes are fixed-shape)
-                self.watchdog.watch(f"{obs_ns}.ragged_tick",
-                                    self._jit_ragged_tick, budget=1)
-            else:
-                self.watchdog.watch(f"{obs_ns}.decode_step", self._jit_decode, budget=1)
+            # tick/release/quarantine program ever, <= one prefill program
+            # per ladder bucket (tests/test_serving.py churn test). The whole
+            # steady-state tick — chunks, finishes, poison, decode — is ONE
+            # program whatever the tick mix (every phase gates on traced
+            # flags, lanes are fixed-shape)
+            self.watchdog.watch(f"{obs_ns}.ragged_tick",
+                                self._jit_ragged_tick, budget=1)
             self.watchdog.watch(f"{obs_ns}.prefill", self._jit_prefill,
                                 budget=len(self.prefill_buckets))
             # install consumes the BUCKET-shaped req_cache, so like prefill it
@@ -921,8 +883,7 @@ class ServingEngine:
                                 budget=len(self.prefill_buckets))
             self.watchdog.watch(f"{obs_ns}.release", self._jit_release, budget=1)
             self.watchdog.watch(f"{obs_ns}.quarantine", self._jit_quarantine, budget=1)
-            if self._jit_release_pages is not None:
-                self.watchdog.watch(f"{obs_ns}.release_pages", self._jit_release_pages, budget=1)
+            self.watchdog.watch(f"{obs_ns}.release_pages", self._jit_release_pages, budget=1)
 
     # ------------------------------------------------------------------- jits
     def _build_jits(self):
@@ -972,22 +933,14 @@ class ServingEngine:
         # instead of updating it in place. (CPU jax warns donation is
         # unsupported and falls back to copies — correct either way.)
         @partial(jax.jit, donate_argnums=(0, 1))
-        def install(cache, state, slot, req_cache, row, rng,
+        def install(cache, state, slot, table_row, req_cache, row, rng,
                     temperature, top_k, top_p, do_sample, pad_id):
-            cache = cache.write_slot(slot, req_cache)
-            state = _install_state(state, slot, row, rng,
-                                   temperature, top_k, top_p, do_sample, pad_id)
-            return cache, state
-
-        @partial(jax.jit, donate_argnums=(0, 1))
-        def install_paged(cache, state, slot, table_row, req_cache, row, rng,
-                          temperature, top_k, top_p, do_sample, pad_id):
-            # paged admission: scatter the BUCKET-shaped prefill cache into
+            # one-shot admission: scatter the BUCKET-shaped prefill cache into
             # the freshly allocated pages and write the slot's page-table row
-            # (reservation + trash padding). Like the dense install this
-            # consumes the bucket-shaped req_cache, so it owns one legitimate
-            # program per ladder bucket — table_row is a fixed (P,) array,
-            # so varying reservations never add programs.
+            # (reservation + trash padding). It consumes the bucket-shaped
+            # req_cache, so it owns one legitimate program per ladder bucket
+            # — table_row is a fixed (P,) array, so varying reservations
+            # never add programs.
             cache = cache.install_slot(slot, table_row, req_cache)
             state = _install_state(state, slot, row, rng,
                                    temperature, top_k, top_p, do_sample, pad_id)
@@ -1013,18 +966,16 @@ class ServingEngine:
 
         @partial(jax.jit, donate_argnums=(0,))
         def release_pages(cache, slot):
-            # paged eviction's device half: table row -> trash page, ring
+            # eviction's device half: table row -> trash page, ring
             # offset 0, live pinned full, the slot's self-attention ring no
             # longer read (the free-slot canonical form). NOT
             # hygiene — a freed slot goes on appending, and a stale table entry
             # would route its writes into a page since handed to a new
             # tenant. The page CONTENTS are untouched: returning ids to the
-            # free list replaces the dense path's O(window) row zeroing.
+            # free list is all an eviction costs.
             return cache.release_slot(slot)
 
-        decode_method = (
-            type(model).decode_rows_paged if self.paged else type(model).decode_rows
-        )
+        decode_method = type(model).decode_rows_paged
 
         def sample_step(params, state, forced, use_forced):
             # The first half of THE decode step: process logits -> sample, as
@@ -1078,9 +1029,9 @@ class ServingEngine:
             )
 
         def decode_body(params, cache, state, forced, use_forced):
-            # THE decode step, traced by the dense pool's ``decode_step`` and
-            # by the fused tick's decode phase (``params`` already
-            # dequantized): sample, then one cached model step on the tokens
+            # THE decode step, the fused tick's decode phase (``params``
+            # already dequantized): sample, then one cached model step on the
+            # tokens
             tok, finite, keys = sample_step(params, state, forced, use_forced)
             with jax.named_scope(TICK_SCOPES["decode"]):
                 rows, cache = model.apply(
@@ -1089,193 +1040,162 @@ class ServingEngine:
             return tok, finite, cache, advance_state(state, state.active, rows, keys)
 
         @partial(jax.jit, donate_argnums=(0,))
-        def quarantine(cache, slot):
-            # containment eviction: zero every per-slot row of a poisoned
-            # slot's cache and reset its pad/shift/live fields to the free-slot
-            # canonical form (live pinned at full capacity, matching __init__),
-            # so no non-finite value survives in the pool and the next
-            # admission's write_slot starts from the same state as a fresh slot
-            return cache.replace(
-                ca=cache.ca.replace(
-                    k=cache.ca.k.at[slot].set(0), v=cache.ca.v.at[slot].set(0)
-                ),
-                sa=cache.sa.replace(
-                    k=cache.sa.k.at[:, slot].set(0), v=cache.sa.v.at[:, slot].set(0)
-                ),
-                pad_slots=cache.pad_slots.at[slot].set(False),
-                shift=cache.shift.at[slot].set(0),
-                live=cache.live.at[slot].set(cache.ca.capacity),
-            )
-
-        @partial(jax.jit, donate_argnums=(0,))
-        def quarantine_paged(cache, slot, table_row):
-            # paged containment: the cache zeroes the condemned slot's own
+        def quarantine(cache, slot, table_row):
+            # containment eviction: the cache zeroes the condemned slot's own
             # rows and every page its table references BEFORE the pages
             # return to the free list. A normally-evicted page's stale FINITE
             # garbage is safe for the next tenant (gathered at softmax weight
-            # 0), but a NaN would poison the sum through 0 * NaN — the same
-            # reason the dense quarantine zeroes its rows. O(pages), not
-            # O(window * slots), and only on the containment path.
+            # 0), but a NaN would poison the sum through 0 * NaN. O(pages),
+            # not O(window * slots), and only on the containment path.
             return cache.quarantine_slot(slot, table_row)
 
-        # the tick program: the fused ragged tick on a paged engine, the
-        # decode step on the dense pool — exactly one of the two is built
-        self._jit_ragged_tick = self._jit_decode = None
-        if self.paged:
-            quantized = self.kv_quant is not None
-            unpack = self._desc_layout.unpack
+        quantized = self.kv_quant is not None
+        unpack = self._desc_layout.unpack
 
-            @partial(jax.jit, donate_argnums=(1, 2))
-            def ragged_tick(params_, cache, state, descriptor, forced, use_forced):
-                # ONE program per tick, its phases in dependency order:
-                # scale resets, prefill chunks, latent finishes, fault
-                # poison, batched decode (for a model whose chunk rows ride
-                # its decode pass, serving_api.py (h): resets, poison, the
-                # head and the sampler, the rows, the finishes). Every phase
-                # is gated by a TRACED
-                # any-flag (lax.cond), so one compiled program covers every
-                # tick mix and the watchdog budget is exactly 1. Per-slot
-                # state is disjoint across a phase's lanes, so the lanes of
-                # one loop do not interact (f64-pinned against generate()).
-                # The phases carry STABLE jax.named_scope names (TICK_SCOPES;
-                # metadata only — the program's instructions are unchanged):
-                # a profiler trace's device time is read per phase from the
-                # operations' op_name (benchmark/trace/gaps.py).
-                params = dq(params_)
-                # the tick's work arrives as ONE int32 array: static slices
-                # and bitcasts name its fields (serving/tick_descriptor.py)
-                d = unpack(descriptor)
-                poison_slot, any_decode = d.poison, d.any_decode
+        @partial(jax.jit, donate_argnums=(1, 2))
+        def ragged_tick(params_, cache, state, descriptor, forced, use_forced):
+            # ONE program per tick, its phases in dependency order:
+            # scale resets, prefill chunks, latent finishes, fault
+            # poison, batched decode (for a model whose chunk rows ride
+            # its decode pass, serving_api.py (h): resets, poison, the
+            # head and the sampler, the rows, the finishes). Every phase
+            # is gated by a TRACED
+            # any-flag (lax.cond), so one compiled program covers every
+            # tick mix and the watchdog budget is exactly 1. Per-slot
+            # state is disjoint across a phase's lanes, so the lanes of
+            # one loop do not interact (f64-pinned against generate()).
+            # The phases carry STABLE jax.named_scope names (TICK_SCOPES;
+            # metadata only — the program's instructions are unchanged):
+            # a profiler trace's device time is read per phase from the
+            # operations' op_name (benchmark/trace/gaps.py).
+            params = dq(params_)
+            # the tick's work arrives as ONE int32 array: static slices
+            # and bitcasts name its fields (serving/tick_descriptor.py)
+            d = unpack(descriptor)
+            poison_slot, any_decode = d.poison, d.any_decode
 
-                if quantized:
-                    # quantized split admission: zero the PRIVATE
-                    # reservations' scale sidecars before any chunk writes,
-                    # so a page's first ratcheted append starts from scale 0
-                    # and zeroes stale tenant bytes
-                    # (ops/paged_decode_kernel.reset_page_scales). Shared
-                    # prefix pages are never in ``reset_ids`` — their scales
-                    # belong to the cache.
-                    with jax.named_scope(TICK_SCOPES["resets"]):
-                        cache = jax.lax.cond(
-                            d.any_reset,
-                            lambda c: c.replace(ca=c.ca.reset_page_scales(d.reset_ids)),
-                            lambda c: c, cache,
-                        )
+            if quantized:
+                # quantized split admission: zero the PRIVATE
+                # reservations' scale sidecars before any chunk writes,
+                # so a page's first ratcheted append starts from scale 0
+                # and zeroes stale tenant bytes
+                # (ops/paged_decode_kernel.reset_page_scales). Shared
+                # prefix pages are never in ``reset_ids`` — their scales
+                # belong to the cache.
+                with jax.named_scope(TICK_SCOPES["resets"]):
+                    cache = jax.lax.cond(
+                        d.any_reset,
+                        lambda c: c.replace(ca=c.ca.reset_page_scales(d.reset_ids)),
+                        lambda c: c, cache,
+                    )
 
-                def poison(state):
-                    # serving.nan fault point: before decode's head reads the
-                    # rows (a NaN row gives NaN logits)
-                    with jax.named_scope(TICK_SCOPES["poison"]):
-                        return jax.lax.cond(
-                            poison_slot >= 0,
-                            lambda s: s.replace(next_hidden=s.next_hidden.at[
-                                jnp.maximum(poison_slot, 0)].set(jnp.nan)),
-                            lambda s: s, state,
-                        )
+            def poison(state):
+                # serving.nan fault point: before decode's head reads the
+                # rows (a NaN row gives NaN logits)
+                with jax.named_scope(TICK_SCOPES["poison"]):
+                    return jax.lax.cond(
+                        poison_slot >= 0,
+                        lambda s: s.replace(next_hidden=s.next_hidden.at[
+                            jnp.maximum(poison_slot, 0)].set(jnp.nan)),
+                        lambda s: s, state,
+                    )
 
-                def finish_lanes(cache, state):
-                    with jax.named_scope(TICK_SCOPES["finish_lanes"]):
-                        return jax.lax.cond(
-                            d.any_finish,
-                            lambda a: model.serving_finish_phase(params, a[0], a[1], d, _install_state),
-                            lambda a: a, (cache, state)
-                        )
+            def finish_lanes(cache, state):
+                with jax.named_scope(TICK_SCOPES["finish_lanes"]):
+                    return jax.lax.cond(
+                        d.any_finish,
+                        lambda a: model.serving_finish_phase(params, a[0], a[1], d, _install_state),
+                        lambda a: a, (cache, state)
+                    )
 
-                slots = self.num_slots
-                if self._traits.chunk_rides_decode:
-                    # serving_api.py (h): the head and the sampler over the
-                    # slots that decode at the tick's entry, then the rows —
-                    # the decode step rides the first carried chunk lane's
-                    # loop over the layers, or runs alone where the tick
-                    # carries none — then the finish lanes, whose slots the
-                    # NEXT tick samples
-                    state = poison(state)
-                    tok, finite, keys = jax.lax.cond(
-                        any_decode,
-                        lambda s: sample_step(params, s, forced, use_forced),
-                        lambda s: (jnp.zeros((slots,), jnp.int32), jnp.ones((slots,), bool),
-                                   jnp.zeros((slots, 2) + s.rng.shape[1:], s.rng.dtype)),
-                        state)
-                    any_chunk = d.any_chunk
+            slots = self.num_slots
+            if self._traits.chunk_rides_decode:
+                # serving_api.py (h): the head and the sampler over the
+                # slots that decode at the tick's entry, then the rows —
+                # the decode step rides the first carried chunk lane's
+                # loop over the layers, or runs alone where the tick
+                # carries none — then the finish lanes, whose slots the
+                # NEXT tick samples
+                state = poison(state)
+                tok, finite, keys = jax.lax.cond(
+                    any_decode,
+                    lambda s: sample_step(params, s, forced, use_forced),
+                    lambda s: (jnp.zeros((slots,), jnp.int32), jnp.ones((slots,), bool),
+                               jnp.zeros((slots, 2) + s.rng.shape[1:], s.rng.dtype)),
+                    state)
+                any_chunk = d.any_chunk
 
-                    def lanes_ridden(cache):
-                        # the model loops its carried lanes and names its two
-                        # groups' operations itself
-                        return model.serving_ride_phase(params, cache, d, tok[:, None], any_decode)
+                def lanes_ridden(cache):
+                    # the model loops its carried lanes and names its two
+                    # groups' operations itself
+                    return model.serving_ride_phase(params, cache, d, tok[:, None], any_decode)
 
-                    def decode_alone(cache):
-                        return model.apply(params, tok[:, None], cache, method=decode_method)
+                def decode_alone(cache):
+                    return model.apply(params, tok[:, None], cache, method=decode_method)
 
-                    # a tick takes one of the two or neither; each cond has
-                    # the shape of the other order's
+                # a tick takes one of the two or neither; each cond has
+                # the shape of the other order's
+                rows, cache = jax.lax.cond(
+                    any_chunk, lanes_ridden, lambda c: (jnp.zeros_like(state.next_hidden), c), cache)
+                with jax.named_scope(TICK_SCOPES["decode"]):
                     rows, cache = jax.lax.cond(
-                        any_chunk, lanes_ridden, lambda c: (jnp.zeros_like(state.next_hidden), c), cache)
-                    with jax.named_scope(TICK_SCOPES["decode"]):
-                        rows, cache = jax.lax.cond(
-                            any_decode & ~any_chunk, decode_alone, lambda c: (rows, c), cache)
-                    state = advance_state(state, state.active & any_decode, rows, keys)
-                    cache, state = finish_lanes(cache, state)
-                else:
-                    # the two prefill phases are the MODEL's (its chunk step and
-                    # what ends a prompt: models/core/serving_api.py); the engine
-                    # gates each on the tick's flags and hands it the lanes
-                    with jax.named_scope(TICK_SCOPES["chunk_lanes"]):
-                        cache = jax.lax.cond(
-                            d.any_chunk,
-                            lambda c: model.serving_chunk_phase(params, c, d),
-                            lambda c: c, cache)
-                    # the finishes install their rows before the poison and the
-                    # decode phase's head: their slots are sampled in this tick
-                    cache, state = finish_lanes(cache, state)
-                    state = poison(state)
+                        any_decode & ~any_chunk, decode_alone, lambda c: (rows, c), cache)
+                state = advance_state(state, state.active & any_decode, rows, keys)
+                cache, state = finish_lanes(cache, state)
+            else:
+                # the two prefill phases are the MODEL's (its chunk step and
+                # what ends a prompt: models/core/serving_api.py); the engine
+                # gates each on the tick's flags and hands it the lanes
+                with jax.named_scope(TICK_SCOPES["chunk_lanes"]):
+                    cache = jax.lax.cond(
+                        d.any_chunk,
+                        lambda c: model.serving_chunk_phase(params, c, d),
+                        lambda c: c, cache)
+                # the finishes install their rows before the poison and the
+                # decode phase's head: their slots are sampled in this tick
+                cache, state = finish_lanes(cache, state)
+                state = poison(state)
 
-                    def decode_phase(args):
-                        return decode_body(params, *args, forced, use_forced)
+                def decode_phase(args):
+                    return decode_body(params, *args, forced, use_forced)
 
-                    def no_decode(args):
-                        cache, state = args
-                        return (jnp.zeros((slots,), jnp.int32),
-                                jnp.ones((slots,), bool), cache, state)
+                def no_decode(args):
+                    cache, state = args
+                    return (jnp.zeros((slots,), jnp.int32),
+                            jnp.ones((slots,), bool), cache, state)
 
-                    tok, finite, cache, state = jax.lax.cond(
-                        any_decode, decode_phase, no_decode, (cache, state))
-                if self._traits.expert_counters is not None:
-                    # serving_api.py (f): the experts' counters ride the token
-                    # output, so the host's one readback of the tokens brings
-                    # them; a tick that decodes nothing is not read, and its
-                    # chunk lanes' counts wait in the cache for the next that is
-                    counts, cache = cache.take_expert_counts(any_decode)
-                    tok = jnp.concatenate([tok, counts.reshape(-1)])
-                return tok, finite, cache, state
+                tok, finite, cache, state = jax.lax.cond(
+                    any_decode, decode_phase, no_decode, (cache, state))
+            if self._traits.expert_counters is not None:
+                # serving_api.py (f): the experts' counters ride the token
+                # output, so the host's one readback of the tokens brings
+                # them; a tick that decodes nothing is not read, and its
+                # chunk lanes' counts wait in the cache for the next that is
+                counts, cache = cache.take_expert_counts(any_decode)
+                tok = jnp.concatenate([tok, counts.reshape(-1)])
+            return tok, finite, cache, state
 
-            self._jit_ragged_tick = ragged_tick
-        else:
-            @partial(jax.jit, donate_argnums=(1, 2))
-            def decode_step(params, cache, state, forced, use_forced):
-                return decode_body(dq(params), cache, state, forced, use_forced)
-
-            self._jit_decode = decode_step
+        self._jit_ragged_tick = ragged_tick
 
         self._jit_prefill = prefill_one
-        self._jit_install = install_paged if self.paged else install
+        self._jit_install = install
         self._jit_release = release
-        self._jit_release_pages = release_pages if self.paged else None
-        self._jit_quarantine = quarantine_paged if self.paged else quarantine
+        self._jit_release_pages = release_pages
+        self._jit_quarantine = quarantine
 
     @property
     def ragged(self) -> bool:
-        """True exactly when the engine is paged: its tick is the fused
-        ``ragged_tick`` program (the dense pool's is ``decode_step``)."""
-        return self.paged
+        """Always True: every engine's tick is the fused ``ragged_tick``.
+        Kept because benchmark/harness/loops/_serving.py:66 reads it; a
+        ``benchmark`` PR drops that read, then this property goes."""
+        return True
 
     @property
     def decode_compilations(self) -> int:
         """Number of programs compiled for the steady-state tick step
         (target: 1): the fused tick — chunks, finishes, and decode in a
-        single launch — on a paged engine, the decode step on the dense
-        pool."""
-        tick = self._jit_ragged_tick if self.paged else self._jit_decode
-        return tick._cache_size()
+        single launch."""
+        return self._jit_ragged_tick._cache_size()
 
     @property
     def prefill_compilations(self) -> int:
@@ -1288,24 +1208,20 @@ class ServingEngine:
         compile-tick detector: a tick whose count moved paid a compile, so
         its duration must not count as a stall strike (a handful of int
         reads, cheap enough per tick)."""
-        jits = [
+        jits = (
             self._jit_prefill, self._jit_install, self._jit_release,
-            self._jit_quarantine,
-        ]
-        if self._jit_release_pages is not None:
-            jits.append(self._jit_release_pages)
+            self._jit_quarantine, self._jit_release_pages,
+        )
         return self.decode_compilations + sum(f._cache_size() for f in jits)
 
     def lower_tick(self):
-        """The steady-state tick program — the fused ragged tick, or the
-        decode step on the dense pool — lowered at this engine's shapes (a
-        ``jax.stages.Lowered``). ``.compile().as_text()`` shows which
-        attention path the program holds: a Pallas kernel is a
-        ``tpu_custom_call``. Lowers an idle descriptor; dispatches nothing."""
-        idle = (self._forced_none, self._use_forced_none)
-        if self.paged:
-            return self._jit_ragged_tick.lower(*self._ragged_args(True, *idle))
-        return self._jit_decode.lower(self.params, self._cache, self._state, *idle)
+        """The steady-state tick program, the fused ragged tick, lowered at
+        this engine's shapes (a ``jax.stages.Lowered``).
+        ``.compile().as_text()`` shows which attention path the program
+        holds: a Pallas kernel is a ``tpu_custom_call``. Lowers an idle
+        descriptor; dispatches nothing."""
+        return self._jit_ragged_tick.lower(
+            *self._ragged_args(True, self._forced_none, self._use_forced_none))
 
     # ----------------------------------------------------------------- params
     def _serve_params(self, params):
@@ -1363,14 +1279,12 @@ class ServingEngine:
     def load(self) -> int:
         """Backlog beyond free capacity — the engine's queue-bound metric and
         the router's dispatch-ranking input (one definition of "how full").
-        Dense pools: ``SlotScheduler.load`` (queue depth minus free slots).
-        Paged pools, capacity = free PAGES as much as free rows: the count of
-        queued requests (FIFO order — admission is head-of-line) the free
-        slots and free pages can absorb, plus worst-case-sized headroom
-        beyond the queue. Conservative under page pressure, identical to the
-        dense number when the pool is unconstrained (the default sizing)."""
-        if not self.paged:
-            return self.scheduler.load
+        Capacity = free PAGES as much as free rows: the count of queued
+        requests (FIFO order — admission is head-of-line) the free slots and
+        free pages can absorb, plus worst-case-sized headroom beyond the
+        queue. Conservative under page pressure; ``SlotScheduler.load``
+        (queue depth minus free slots) when the pool is unconstrained (the
+        default sizing)."""
         slots = self.scheduler.free_slots
         pages = self._pool.free_pages
         # prefix-cache accounting (the shared-reservation seam fix,
@@ -1461,8 +1375,6 @@ class ServingEngine:
         """Live page-table entries currently backed by SHARED pages (pool
         refcount >= 2 counting the cache's own hold) — the v8 gauge that
         makes 'sessions at fixed HBM' legible from a snapshot."""
-        if self._pool is None:
-            return 0
         return sum(
             self._pool.shared_count(pages)
             for pages in self._slot_pages
@@ -1657,7 +1569,7 @@ class ServingEngine:
     def _bucket_prompt(self, request: ServedRequest, bucket: int):
         """Left-pad the prompt to its covering bucket; pad positions are masked
         and position-shifted exactly as in the padded-batch pipeline path, and
-        ``write_slot`` grows the left-pad to the full window at install."""
+        ``install_slot`` grows the left-pad to the full window at install."""
         n = request.prompt_ids.size
         ids = np.full((1, bucket), request.config.pad_token_id, np.int32)
         pad = np.ones((1, bucket), bool)
@@ -1676,38 +1588,36 @@ class ServingEngine:
                                     "slot_claimed", slot=slot)
         n = int(request.prompt_ids.size)
         bucket = self._bucket_for(n)
-        pages: Optional[int] = None
-        if self.paged:
-            # SPLIT admission (docs/serving.md "Chunked prefill" / "Prefix
-            # cache" / "Unified ragged tick"): every prompt the finish step
-            # fits (n >= max_latents: the finish consumes the last L prompt
-            # tokens) rides the tick's descriptor — a prompt extending a
-            # cached prefix retains those pages and chunk-prefills only the
-            # uncached tail; a long prompt on a chunked engine spreads its
-            # KV writes one chunk per tick; the chunks and the finish fuse
-            # into the tick program. The finish computes its latents against
-            # the slot's pages AS STORED (gather_slot dequant on a quantized
-            # pool), so a cache-hit fork and a cold admission of the same
-            # prompt see byte-identical KV — the cache-on == cache-off token
-            # identity survives quantization. Shorter prompts keep the
-            # classic prefill + install programs below, the documented
-            # exception: they have no cacheable pages (page keys lie below
-            # the latent boundary), so no identity is at stake.
-            if n >= self._traits.split_from:
-                shared_run: List[int] = []
-                if self._prefix_cache is not None and request.page_keys:
-                    shared_run = self._prefix_cache.probe(request.page_keys)
-                self._admit_split(slot, request, bucket, shared_run, t0)
-                return
-            # the ONLY allocation point (serving/paging.py): the whole
-            # reservation — bucket + generation budget — is claimed here, so
-            # a running slot can never page-fault. pop_admissible's
-            # _can_admit_paged gate guaranteed the fit.
-            pages = self._pages_for(request)
-            page_ids = self._pool.allocate(pages)
-            self._slot_pages[slot] = page_ids
-            table_row = np.zeros((self._pages_per_slot,), np.int32)
-            table_row[: len(page_ids)] = page_ids  # trash-padded reservation
+        # SPLIT admission (docs/serving.md "Chunked prefill" / "Prefix
+        # cache" / "Unified ragged tick"): every prompt the finish step
+        # fits (n >= max_latents: the finish consumes the last L prompt
+        # tokens) rides the tick's descriptor — a prompt extending a
+        # cached prefix retains those pages and chunk-prefills only the
+        # uncached tail; a long prompt on a chunked engine spreads its
+        # KV writes one chunk per tick; the chunks and the finish fuse
+        # into the tick program. The finish computes its latents against
+        # the slot's pages AS STORED (gather_slot dequant on a quantized
+        # pool), so a cache-hit fork and a cold admission of the same
+        # prompt see byte-identical KV — the cache-on == cache-off token
+        # identity survives quantization. Shorter prompts keep the
+        # classic prefill + install programs below, the documented
+        # exception: they have no cacheable pages (page keys lie below
+        # the latent boundary), so no identity is at stake.
+        if n >= self._traits.split_from:
+            shared_run: List[int] = []
+            if self._prefix_cache is not None and request.page_keys:
+                shared_run = self._prefix_cache.probe(request.page_keys)
+            self._admit_split(slot, request, bucket, shared_run, t0)
+            return
+        # the ONLY allocation point (serving/paging.py): the whole
+        # reservation — bucket + generation budget — is claimed here, so
+        # a running slot can never page-fault. pop_admissible's
+        # _can_admit_paged gate guaranteed the fit.
+        pages = self._pages_for(request)
+        page_ids = self._pool.allocate(pages)
+        self._slot_pages[slot] = page_ids
+        table_row = np.zeros((self._pages_per_slot,), np.int32)
+        table_row[: len(page_ids)] = page_ids  # trash-padded reservation
         self._tick_programs += 2  # classic path: prefill + install programs
         self._tick_oneshot += 1
         with self._obs.span(self._span_prefill, request_id=request.request_id):
@@ -1726,16 +1636,10 @@ class ServingEngine:
                 bool(cfg.do_sample),
                 int(cfg.pad_token_id),
             )
-            if self.paged:
-                self._cache, self._state = self._jit_install(
-                    self._cache, self._state, slot, jnp.asarray(table_row),
-                    req_cache, req_row, request.rng, *sampling,
-                )
-            else:
-                self._cache, self._state = self._jit_install(
-                    self._cache, self._state, slot, req_cache, req_row,
-                    request.rng, *sampling,
-                )
+            self._cache, self._state = self._jit_install(
+                self._cache, self._state, slot, jnp.asarray(table_row),
+                req_cache, req_row, request.rng, *sampling,
+            )
         # NON-BLOCKING: no device sync here — the prefill/install dispatch
         # overlaps the decode stream, and step() syncs once per tick (its
         # np.asarray on the decoded tokens). prefill_s is therefore dispatch
@@ -1759,10 +1663,9 @@ class ServingEngine:
             priority=request.priority, preempted_replay=resumed,
             prompt_tokens=n,
         )
-        if self.paged:
-            self.metrics.set_page_pool(
-                self._pool.num_pages - self._pool.reserved, self._pool.pages_in_use
-            )
+        self.metrics.set_page_pool(
+            self._pool.num_pages - self._pool.reserved, self._pool.pages_in_use
+        )
         if self._obs_on:
             self._obs.async_instant(self._span_cat, request.request_id, "prefill",
                                     slot=slot, bucket=bucket)
@@ -1970,22 +1873,21 @@ class ServingEngine:
         self._drop_tick_work(slot)
         self._tick_programs += 1
         self._state = self._jit_release(self._state, slot)
-        if self.paged:
-            # paged eviction: reset the slot's table to the trash page on
-            # device (a freed slot goes on appending — stale entries would
-            # corrupt reallocated pages) and return the ids to the free
-            # list. No O(window) row zeroing — that is the point. A SHARED
-            # page's release only drops this slot's reference: the prefix
-            # cache and any sibling sessions keep theirs (serving/paging.py).
-            self._tick_programs += 1
-            self._cache = self._jit_release_pages(self._cache, slot)
-            pages = self._slot_pages[slot]
-            if pages:
-                self._pool.release(pages)
-            self._slot_pages[slot] = None
-            self.metrics.set_page_pool(
-                self._pool.num_pages - self._pool.reserved, self._pool.pages_in_use
-            )
+        # reset the slot's table to the trash page on device (a freed slot
+        # goes on appending — stale entries would corrupt reallocated pages)
+        # and return the ids to the free list. No O(window) row zeroing —
+        # that is the point. A SHARED page's release only drops this slot's
+        # reference: the prefix cache and any sibling sessions keep theirs
+        # (serving/paging.py).
+        self._tick_programs += 1
+        self._cache = self._jit_release_pages(self._cache, slot)
+        pages = self._slot_pages[slot]
+        if pages:
+            self._pool.release(pages)
+        self._slot_pages[slot] = None
+        self.metrics.set_page_pool(
+            self._pool.num_pages - self._pool.reserved, self._pool.pages_in_use
+        )
         request.status = status
         request.finish_reason = reason
         request.finished_at = time.perf_counter()
@@ -2082,14 +1984,12 @@ class ServingEngine:
             nothing and still not admit the head).
         """
         need_slot = self.scheduler.free_slots == 0
-        need_pages = 0
-        if self.paged:
-            # shared-reservation accounting (the prefix-cache seam fix): a
-            # head whose prompt extends a cached prefix RETAINS those pages,
-            # so only the uncovered remainder needs freeing — preempting for
-            # pages the cache already supplies would burn replays for nothing
-            need_pages = (self._pages_for(request) - self._shared_match(request)
-                          - self._pool.free_pages)
+        # shared-reservation accounting (the prefix-cache seam fix): a head
+        # whose prompt extends a cached prefix RETAINS those pages, so only
+        # the uncovered remainder needs freeing — preempting for pages the
+        # cache already supplies would burn replays for nothing
+        need_pages = (self._pages_for(request) - self._shared_match(request)
+                      - self._pool.free_pages)
         if not need_slot and need_pages <= 0:
             return []  # the head is not resource-blocked: nothing to free
         candidates = [
@@ -2098,7 +1998,7 @@ class ServingEngine:
         ]
         candidates.sort(key=lambda sr: (
             sr[1].priority,
-            -(len(self._slot_pages[sr[0]]) if self.paged and self._slot_pages[sr[0]] else 0),
+            -len(self._slot_pages[sr[0]] or ()),
             -sr[1].request_id,
         ))
 
@@ -2110,14 +2010,12 @@ class ServingEngine:
         # releases, it reaches refcount 0 (returns to the free list now) or
         # refcount 1 with the cache the only holder left (the admission
         # gate's refcount-aware LRU reclaims it before reporting
-        # backpressure). Dense engines: every page is refcount 1, so this
-        # degrades to the plain page-list length — the pre-cache behavior.
+        # backpressure). Without a prefix cache every page is refcount 1, so
+        # this degrades to the plain page-list length.
         cached = (self._prefix_cache.cached_page_ids()
                   if self._prefix_cache is not None else frozenset())
 
         def sim_freed(victims) -> int:
-            if not self.paged:
-                return 0
             drops: Dict[int, int] = {}
             for slot, _r in victims:
                 for p in self._slot_pages[slot] or []:
@@ -2169,18 +2067,16 @@ class ServingEngine:
         self._drop_tick_work(slot)
         self._tick_programs += 1
         self._state = self._jit_release(self._state, slot)
-        pages_freed = 0
-        if self.paged:
-            self._tick_programs += 1
-            self._cache = self._jit_release_pages(self._cache, slot)
-            pages = self._slot_pages[slot]
-            if pages:
-                pages_freed = len(pages)
-                self._pool.release(pages)
-            self._slot_pages[slot] = None
-            self.metrics.set_page_pool(
-                self._pool.num_pages - self._pool.reserved, self._pool.pages_in_use
-            )
+        self._tick_programs += 1
+        self._cache = self._jit_release_pages(self._cache, slot)
+        pages = self._slot_pages[slot] or []
+        pages_freed = len(pages)
+        if pages:
+            self._pool.release(pages)
+        self._slot_pages[slot] = None
+        self.metrics.set_page_pool(
+            self._pool.num_pages - self._pool.reserved, self._pool.pages_in_use
+        )
         # the replay stream is the LONGEST known token prefix: normally the
         # emitted tokens, but a victim preempted mid-replay (failover replay,
         # or a second preemption) still owes the tail of its previous stream
@@ -2466,16 +2362,10 @@ class ServingEngine:
             if occupied is None:
                 return
             slot = occupied[0]
-        if self.paged:
-            # stash for the fused program's poison phase — applied between
-            # the finish lanes (which install rows) and decode, without
-            # an eager host-side device op
-            self._tick_poison = slot
-            return
-        # the dense pool has no descriptor: poke the row eagerly
-        self._state = self._state.replace(
-            next_hidden=self._state.next_hidden.at[slot].set(jnp.nan)
-        )
+        # stash for the fused program's poison phase — applied between the
+        # finish lanes (which install rows) and decode, without an eager
+        # host-side device op
+        self._tick_poison = slot
 
     def _ragged_args(self, any_decode: bool, forced, use_forced) -> tuple:
         """The fused tick program's arguments. The tick's buffered work —
@@ -2636,7 +2526,7 @@ class ServingEngine:
                 # FINISH — drain's "in-flight work is finished, not dropped"
                 # contract covers a victim parked by preemption
                 with obs.span(self._span_admit, tick=tick):
-                    can_admit = self._can_admit_paged if self.paged else None
+                    can_admit = self._can_admit_paged
                     # chunk-aware admission bound: a chunked engine schedules
                     # at most max_prefill_slots concurrent chunk streams, so
                     # per-tick prefill work stays bounded at (budget x chunk)
@@ -2705,20 +2595,13 @@ class ServingEngine:
                                finish_lanes=self._tick_finish_items,
                                decoding=len(occupied),
                                after_empty=int(self._was_empty))
-            if self.paged:
-                # the tick's ONE program: resets + chunks + finishes +
-                # poison + decode, fused (docs/serving.md "Unified
-                # ragged tick"); the span holds the descriptor build
-                tok, finite = self._dispatch_ragged(bool(occupied),
-                                                    forced, use_forced)
-            else:
-                # the dense pool's tick is the decode step alone
-                self._tick_programs += 1
-                # dispatch only — the jit call returns before the device step
-                # finishes; the device cost lands in the sample-sync at harvest
-                tok, finite, self._cache, self._state = self._jit_decode(
-                    self.params, self._cache, self._state, forced, use_forced
-                )
+            # the tick's ONE program: resets + chunks + finishes + poison +
+            # decode, fused (docs/serving.md "Unified ragged tick"); the span
+            # holds the descriptor build. Dispatch only — the jit call
+            # returns before the device step finishes; the device cost lands
+            # in the sample-sync at harvest
+            tok, finite = self._dispatch_ragged(bool(occupied),
+                                                forced, use_forced)
             t_dispatched = obs.span_end(self._span_decode_dispatch)
             if self._gap_from is not None:
                 self._book_host_gap(t_entry, t_dispatch, t_dispatched)
@@ -2859,46 +2742,42 @@ class ServingEngine:
                     continue
                 if not finite[slot]:
                     # containment: the token sampled from non-finite logits
-                    # is garbage — never emitted — and the slot's
-                    # cache/state rows (dense) or pages (paged) are zeroed
-                    # so nothing non-finite survives in the pool
-                    if self.paged:
-                        row = np.zeros((self._pages_per_slot,), np.int32)
-                        pages = self._slot_pages[slot] or []
-                        if self._prefix_cache is not None:
-                            # invalidate the cache subtree reached through
-                            # this slot's prefix FIRST, so the possibly-
-                            # tainted run is never served again — and so a
-                            # poisoned page the CACHE alone shared drops to
-                            # refcount 1 here and is zeroed below before its
-                            # release returns it to the free list (filtering
-                            # before invalidating would let it back into the
-                            # pool with the NaN bytes intact). Pages sibling
-                            # forks still hold (refcount >= 2 after the
-                            # invalidation) must not be zeroed — that would
-                            # corrupt a healthy sibling's prefix mid-decode;
-                            # they route to the trash entry instead, and the
-                            # siblings keep their own containment
-                            # (docs/serving.md).
-                            if request.page_keys:
-                                dropped = self._prefix_cache.invalidate(
-                                    request.page_keys
+                    # is garbage — never emitted — and the slot's rows and
+                    # pages are zeroed so nothing non-finite survives in the
+                    # pool
+                    row = np.zeros((self._pages_per_slot,), np.int32)
+                    pages = self._slot_pages[slot] or []
+                    if self._prefix_cache is not None:
+                        # invalidate the cache subtree reached through
+                        # this slot's prefix FIRST, so the possibly-
+                        # tainted run is never served again — and so a
+                        # poisoned page the CACHE alone shared drops to
+                        # refcount 1 here and is zeroed below before its
+                        # release returns it to the free list (filtering
+                        # before invalidating would let it back into the
+                        # pool with the NaN bytes intact). Pages sibling
+                        # forks still hold (refcount >= 2 after the
+                        # invalidation) must not be zeroed — that would
+                        # corrupt a healthy sibling's prefix mid-decode;
+                        # they route to the trash entry instead, and the
+                        # siblings keep their own containment
+                        # (docs/serving.md).
+                        if request.page_keys:
+                            dropped = self._prefix_cache.invalidate(
+                                request.page_keys
+                            )
+                            if dropped:
+                                self.metrics.set_prefix_cache(
+                                    self._prefix_cache.stats(),
+                                    self._shared_pages_in_use(),
                                 )
-                                if dropped:
-                                    self.metrics.set_prefix_cache(
-                                        self._prefix_cache.stats(),
-                                        self._shared_pages_in_use(),
-                                    )
-                            pages = [p for p in pages
-                                     if self._pool.refcount(p) < 2]
-                        row[: len(pages)] = pages
-                        self._tick_programs += 1
-                        self._cache = self._jit_quarantine(
-                            self._cache, slot, jnp.asarray(row)
-                        )
-                    else:
-                        self._tick_programs += 1
-                        self._cache = self._jit_quarantine(self._cache, slot)
+                        pages = [p for p in pages
+                                 if self._pool.refcount(p) < 2]
+                    row[: len(pages)] = pages
+                    self._tick_programs += 1
+                    self._cache = self._jit_quarantine(
+                        self._cache, slot, jnp.asarray(row)
+                    )
                     self._evict(slot, request, "nonfinite_logits",
                                 status=RequestStatus.FAILED)
                     continue
@@ -2983,7 +2862,7 @@ class ServingEngine:
         case a failure ever lands between dispatch and harvest). Such a
         half-tick's requests were failed over, so its tokens must never
         land; the orphaned step's device-side effect is per-slot state that
-        the next admission's ``write_slot`` fully overwrites — the normal
+        the next admission's install fully overwrites — the normal
         churn contract. Balances the tick span the dispatch opened (a
         dangling begin would sit in the recorder's open-span stack
         forever)."""

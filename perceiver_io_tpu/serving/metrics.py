@@ -44,8 +44,8 @@ Schema history:
     reader normalizes v3-and-older snapshots with ``None`` — "not recorded"
     stays distinguishable from "none happened", the v2->v3 discipline.
   * ``serving-metrics/v5`` — the paged-KV schema (docs/serving.md, paging
-    section): every snapshot carries a ``page_pool`` field — ``None`` on
-    engines running the dense pool (there IS no page pool), else a dict of
+    section): every snapshot carries a ``page_pool`` field — ``None`` where
+    no pool exists (a router's own snapshot), else a dict of
     ``pages_total`` / ``pages_in_use`` / ``alloc_failures`` (head-of-line
     blocking episodes — a request's reservation did not fit the free list) /
     ``pages_per_request`` p50/p95 over the latency window. ``admit`` events
@@ -124,9 +124,8 @@ Schema history:
     The reader normalizes pre-v10 snapshots with ``None``.
   * ``serving-metrics/v11`` — the unified-ragged-tick schema (docs/serving.md
     "Unified ragged tick"): every snapshot carries a ``ragged_tick`` field —
-    ``None`` on dense engines and on router snapshots (tick dispatch is
-    per-engine), else ``enabled`` (True on every paged engine: the fused
-    tick is its one dispatcher), ``ticks`` (dispatching ticks recorded),
+    ``None`` on router snapshots (tick dispatch is per-engine), else
+    ``enabled`` (True on every engine: the fused tick is its one dispatcher), ``ticks`` (dispatching ticks recorded),
     ``programs_per_tick`` p50/p95 (the headline gauge: 1 steady-state;
     short prompts' prefill + install and evictions add theirs),
     ``chunk_items`` / ``finish_items`` / ``decode_items`` p50/p95 (the
@@ -298,7 +297,7 @@ def load_metrics_jsonl(path: str) -> Dict:
                     snap.setdefault(k, None)
             if schema in _PRE_V5:
                 # pre-v5 writers had no page pool; None also matches a
-                # newer DENSE engine's truthful "no pool exists"
+                # router's truthful "no pool exists"
                 snap.setdefault("page_pool", None)
             if schema in _PRE_V6:
                 # pre-v6 writers had no priority/preemption counters: None,
@@ -327,7 +326,7 @@ def load_metrics_jsonl(path: str) -> Dict:
                 snap.setdefault("fleet_ops", None)
             if schema in _PRE_V11:
                 # pre-v11 writers had no unified ragged tick; None also
-                # matches a newer DENSE engine's truthful "no tick dispatcher"
+                # matches a router's truthful "no tick dispatcher"
                 snap.setdefault("ragged_tick", None)
             if schema in _PRE_V12:
                 # pre-v12 writers had no out-of-process transport; None also
@@ -416,8 +415,8 @@ class EngineMetrics(_JsonlMetrics):
     decode_seconds: float = 0.0
     prefill_seconds: float = 0.0
     queue_depth: int = 0
-    # page-pool gauges (serving-metrics/v5): pages_total None <=> the engine
-    # runs the dense pool and snapshots report page_pool: None
+    # page-pool gauges (serving-metrics/v5): pages_total None <=> no pool
+    # (a router's own metrics) and snapshots report page_pool: None
     pages_total: Optional[int] = None
     pages_in_use: int = 0
     alloc_failures: int = 0  # head-of-line blocking episodes on the free list
@@ -447,8 +446,8 @@ class EngineMetrics(_JsonlMetrics):
     # weight-serving gauges (serving-metrics/v9): None <=> params untouched
     weight_serving: Optional[Dict] = None
     # unified-ragged-tick gauges (serving-metrics/v11): ragged_enabled None
-    # <=> dense engine (no tick descriptor) and snapshots report
-    # ragged_tick: None; True on every paged engine
+    # <=> no tick dispatcher (a router's own metrics) and snapshots report
+    # ragged_tick: None; True on every engine
     ragged_enabled: Optional[bool] = None
     ragged_lanes: Optional[int] = None  # the tick program's compiled lane count
     ragged_ticks: int = 0
@@ -644,7 +643,7 @@ class EngineMetrics(_JsonlMetrics):
         compiled programs the tick launched (ragged steady-state: exactly 1),
         the tick's mixed-batch composition (prefill chunk lanes, latent
         finish lanes, decoding slots), the host-side descriptor build
-        time (0 on the dense pool — there is no descriptor), and how many
+        time, and how many
         host-to-device transfers the fused tick's descriptor cost (0: the
         resident decode-only descriptor; 1: a packed one; None: the tick
         dispatched no fused program). Windowed, no JSONL
@@ -917,9 +916,9 @@ class EngineMetrics(_JsonlMetrics):
                 },
                 "load_max_over_mean": _load_max_over_mean(self.expert_assignments),
             },
-            # v11: None on dense engines (no tick dispatcher exists — same
-            # reading as a pre-v11 snapshot); on paged engines the per-tick
-            # program/work gauges, whichever dispatcher is live
+            # v11: None on a router's own snapshot (no tick dispatcher exists
+            # — same reading as a pre-v11 snapshot); on engines the per-tick
+            # program/work gauges
             "ragged_tick": None if self.ragged_enabled is None else {
                 "enabled": self.ragged_enabled,
                 "ticks": self.ragged_ticks,
@@ -970,8 +969,8 @@ class EngineMetrics(_JsonlMetrics):
                     if k in _PERCENTILE_KEYS
                 },
             },
-            # v5: None on dense engines (no pool exists — same reading as a
-            # pre-v5 snapshot), real gauges on paged engines
+            # v5: None on a router's own snapshot (no pool exists — same
+            # reading as a pre-v5 snapshot), real gauges on engines
             "page_pool": None if self.pages_total is None else {
                 "pages_total": self.pages_total,
                 "pages_in_use": self.pages_in_use,

@@ -40,9 +40,9 @@ them into its page table (O(page-table copy), zero KV duplication or
 recompute), and the cache itself holds one reference per cached page so a
 cached run outlives the request that built it.
 
-The engine's arguments alone turn these on (``kv_page_size``,
-``prefix_cache``, ``prefill_chunk_tokens``; serving/engine.py): this module
-reads no environment.
+The engine's arguments alone size the pages (``kv_page_size``) and turn the
+cache and the chunking on (``prefix_cache``, ``prefill_chunk_tokens``;
+serving/engine.py): this module reads no environment.
 """
 
 from __future__ import annotations

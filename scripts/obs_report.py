@@ -243,7 +243,7 @@ def report_serving_metrics(path: str) -> Dict:
                       "timed_out", "failed", "tokens_generated", "decode_tokens_per_s",
                       "wall_tokens_per_s", "mean_slot_occupancy", "queue_depth")
         }
-        # serving-metrics/v5 page pool (None: dense engine or pre-v5 stream)
+        # serving-metrics/v5 page pool (None: router snapshot or pre-v5 stream)
         out["page_pool"] = snap.get("page_pool")
         alloc_failures = sum(1 for e in loaded["events"] if e.get("event") == "alloc_failure")
         if alloc_failures:
@@ -266,8 +266,8 @@ def report_serving_metrics(path: str) -> Dict:
         # serving-metrics/v10 fleet-operations gauges (None: plain engine
         # or pre-v10 stream; real on router snapshots)
         out["fleet_ops"] = snap.get("fleet_ops")
-        # serving-metrics/v11 unified-ragged-tick gauges (None: dense
-        # engine, router snapshot, or pre-v11 stream)
+        # serving-metrics/v11 unified-ragged-tick gauges (None: router
+        # snapshot or pre-v11 stream)
         out["ragged_tick"] = snap.get("ragged_tick")
         # serving-metrics/v12 out-of-process transport gauges (None:
         # in-process fleet, plain engine, or pre-v12 stream)
@@ -434,7 +434,7 @@ def main(argv=None) -> Dict:
             print("weight serving: "
                   f"dtype={ws.get('dtype')}, params {served} bytes ({ratio})")
         # v11 unified-ragged-tick rendering (suppressed where the reader
-        # normalized to None: dense engine, router, pre-v11 stream) — the
+        # normalized to None: router, pre-v11 stream) — the
         # programs-per-tick headline an operator checks before trusting the
         # one-launch steady state, plus the tick's mixed-batch composition
         rt = section.get("ragged_tick")

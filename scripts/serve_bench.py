@@ -566,7 +566,7 @@ def run_paging_capacity(model, config, params, page_size: int, num_slots: int,
             "tokens_per_s": round(new_tokens / drain, 2) if drain > 0 else 0.0,
             "decode_compilations": engine.decode_compilations,
         }
-        if engine.paged:
+        if name == "paged":  # the arm given a page size and a page budget
             snap = engine.metrics.snapshot()
             arms[name]["num_kv_pages"] = num_pages
             arms[name]["pages_per_request"] = snap["page_pool"]["pages_per_request"]

@@ -11,7 +11,7 @@ row, never its logits. Pinned here, on the CPU at toy sizes, for both served fam
   (``serving_api.py`` (h), ISSUE 46: LFM2) from the NEXT tick, the same tokens, and the
   branch such a tick takes holds one pair of grouped products an expert layer;
 * ``decode_step_paged`` is the head of ``decode_rows_paged``'s rows;
-* a poisoned row trips ``finite`` for exactly its slot, on the paged and the dense pool.
+* a poisoned row trips ``finite`` for exactly its slot, at a small page and on the default engine.
 """
 
 import jax
@@ -36,7 +36,7 @@ from tests.test_ragged_tick import LATENTS, PS, VOCAB, WINDOW, _make_model
 
 FALCON_ENGINE = dict(num_slots=3, kv_page_size=8, prefill_chunk_tokens=8, num_kv_pages=40)
 AR_PAGED = dict(num_slots=3, kv_page_size=PS, prefill_chunk_tokens=4, max_prefill_slots=2)
-AR_DENSE = dict(num_slots=3)
+AR_DENSE = dict(num_slots=3)  # the default-constructed engine: one page a window, one-shot admission
 ENGINES = {"falcon_h1": FALCON_ENGINE, "lfm2_moe": FALCON_ENGINE, "perceiver_ar_paged": AR_PAGED,
            "perceiver_ar_dense": AR_DENSE}
 
@@ -56,10 +56,7 @@ def _engine(models, kind, **more):
 
 # ---------------------------------------------------------------- (a) the compiled tick
 def _tick_args(engine):
-    idle = (engine._forced_none, engine._use_forced_none)
-    if engine.paged:
-        return engine._jit_ragged_tick, engine._ragged_args(True, *idle)
-    return engine._jit_decode, (engine.params, engine._cache, engine._state, *idle)
+    return engine._jit_ragged_tick, engine._ragged_args(True, engine._forced_none, engine._use_forced_none)
 
 
 def _sub_jaxprs(eqn):

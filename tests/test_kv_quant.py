@@ -568,8 +568,8 @@ def test_weight_serving_engine_runs_and_reports(setup):
 # ------------------------------------------------------------- construction
 def test_constructor_validation(setup):
     model, params = setup
-    with pytest.raises(ValueError, match="requires kv_page_size"):
-        ServingEngine(model, params, num_slots=2, kv_quant="int8")
+    with pytest.raises(ValueError, match="multiple of kv_page_size"):  # no page size: one page a window
+        ServingEngine(model, params, num_slots=2, kv_quant="int8", prefill_chunk_tokens=6)
     with pytest.raises(ValueError, match="kv_quant must be one of"):
         ServingEngine(model, params, num_slots=2, kv_page_size=PS, kv_quant="int2")
     with pytest.raises(ValueError, match="weight_dtype must be one of"):

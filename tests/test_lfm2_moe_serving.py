@@ -184,20 +184,30 @@ def test_the_tick_names_the_models_scopes(toy):
     ("kv_quant", "int8", "full-precision pages"),
     ("handle_preemption", True, "snapshotted"),
     ("journal", "DIR", "journal replay"),
-    ("kv_page_size", None, "paged pool"),
+    ("kv_page_size", None, "one page a window: served, and with the configured page's tokens"),
     ("router.prefix_cache", True, "snapshotted at page boundaries"),
 ])
 def test_options_the_model_does_not_carry_are_refused_at_construction(toy, tmp_path, option, value, names):
     model, params, _ = toy
     kwargs = dict(ENGINE)
+    if option == "kv_page_size":
+        # no refusal: the page pool is the engine's one pool, and no page size means a slot's window in one page
+        def serve(**engine_kwargs):
+            engine = ServingEngine(model, params, **engine_kwargs)
+            handles = [engine.submit(np.arange(1, 1 + n, dtype=np.int32), max_new_tokens=4) for n in (3, 11, 20)]
+            engine.run_until_drained(max_steps=200)
+            return engine, [h.result().tolist() for h in handles]
+
+        engine, tokens = serve(num_slots=3)
+        assert engine.kv_page_size == model.serving_traits().window and engine._pages_per_slot == 1
+        assert tokens == serve(**ENGINE)[1] == serve(num_slots=3, prefill_chunk_tokens=8)[1]
+        return
     if value == "DIR":
         value = str(tmp_path / "journal")
     with pytest.raises(ValueError) as refusal:
         if option.startswith("router."):
             ServingRouter(model, params, num_replicas=1, **{**kwargs, option.split(".")[1]: value})
         else:
-            if option == "kv_page_size":
-                kwargs = {"num_slots": 3}
             ServingEngine(model, params, **{**kwargs, option: value})
     assert "cannot be served with" in str(refusal.value) and names in str(refusal.value)
     if option == "journal":

@@ -258,7 +258,7 @@ def test_watchdog_silent_across_engine_churn(x64):
     assert engine.watchdog.violations == []
     summary = engine.telemetry_summary()
     assert summary["compile"]["unexpected"] == []
-    assert summary["compile"]["per_function"]["serving.decode_step"]["compilations"] == 1
+    assert summary["compile"]["per_function"]["serving.ragged_tick"]["compilations"] == 1
     assert "serving.tick" in summary["phases"]
     engine.close()
 

@@ -3,8 +3,8 @@
 The parity contract: a paged engine's greedy output is token-identical to
 ``generate()``'s canonical full-window form — pinned in float64 across page
 sizes straddling every prefill-ladder rung (page < bucket, page = bucket,
-page not dividing the window) and against the dense pool
-(``kv_page_size=None``). The kernel contract: the paged Pallas kernel's dead-page skipping is
+page not dividing the window) and against the default
+(``kv_page_size=None``: one page a window). The kernel contract: the paged Pallas kernel's dead-page skipping is
 BIT-identical to the skip-off kernel, and both match the XLA gather + masked
 softmax fallback applying the same (start, live) visibility bound. The churn
 contract: paging never adds decode programs (1, pinned) and every page
@@ -169,7 +169,7 @@ def test_paged_engine_matches_generate_across_page_sizes(x64, page_size):
     sizes straddling every rung themselves."""
     model, params = _make_model(param_dtype=jnp.float64)
     engine = ServingEngine(model, params, num_slots=3, kv_page_size=page_size)
-    assert engine.paged and engine.prefill_buckets == (LATENTS, WINDOW)
+    assert engine.prefill_buckets == (LATENTS, WINDOW)
     lengths = sorted({1, *(n for b in engine.prefill_buckets for n in (b, min(b + 1, WINDOW))), WINDOW})
     prompts = [list(range(3, 3 + n)) for n in lengths]
     handles = [engine.submit(p, max_new_tokens=5) for p in prompts]
@@ -184,20 +184,64 @@ def test_paged_engine_matches_generate_across_page_sizes(x64, page_size):
 
 
 def test_paged_off_value_is_the_dense_pool_and_matches(x64):
-    """``kv_page_size=None`` is the dense pool, and (greedy, float64) it
-    produces the same tokens as the paged one."""
+    """``kv_page_size=None`` is one page a window, and (greedy, float64) it
+    produces page 4's tokens."""
     model, params = _make_model(param_dtype=jnp.float64)
 
     def run(kv_page_size):
         engine = ServingEngine(model, params, num_slots=2, kv_page_size=kv_page_size)
         handles = [engine.submit(p, max_new_tokens=4) for p in ([5, 6, 7], list(range(40, 49)))]
         engine.run_until_drained(max_steps=100)
-        return [h.result().tolist() for h in handles], engine.paged
+        return [h.result().tolist() for h in handles], engine.kv_page_size, engine._pages_per_slot
 
-    toks_paged, paged_on = run(4)
-    toks_dense, paged_off = run(None)
-    assert paged_on and not paged_off
-    assert toks_paged == toks_dense
+    toks_paged, page, per_slot = run(4)
+    toks_default, default_page, default_per_slot = run(None)
+    assert (page, per_slot) == (4, 3) and (default_page, default_per_slot) == (WINDOW, 1)
+    assert toks_paged == toks_default
+
+
+@pytest.mark.parametrize("option", [dict(prefix_cache=True), dict(prefill_chunk_tokens=4), dict(kv_quant="int8")],
+                         ids=["prefix_cache", "prefill_chunk_tokens", "kv_quant"])
+def test_pool_options_need_no_page_size(x64, option):
+    """What the page pool carries is accepted by an engine built without
+    ``kv_page_size`` (one page a window) and serves; at full precision with
+    the plain default engine's tokens."""
+    model, params = _make_model(param_dtype=jnp.float64)
+    prompts = ([5, 6, 7], list(range(40, 49)), list(range(40, 49)))
+
+    def run(**pool):
+        engine = ServingEngine(model, params, num_slots=2, **pool)
+        handles = [engine.submit(p, max_new_tokens=4) for p in prompts]
+        engine.run_until_drained(max_steps=100)
+        assert all(h.ok and len(h.output_ids) == 4 for h in handles)
+        return engine, [h.result().tolist() for h in handles]
+
+    engine, tokens = run(**option)
+    assert engine.kv_page_size == WINDOW and engine._pool.pages_in_use == 0
+    snap = engine.metrics.snapshot()
+    if "kv_quant" in option:
+        assert snap["kv_quant"]["mode"] == "int8" and engine._cache.ca.kp.dtype == jnp.int8
+    else:
+        assert tokens == run()[1]
+        block = "prefix_cache" if "prefix_cache" in option else "chunked_prefill"
+        assert snap[block] is not None
+
+
+def test_default_engine_is_one_page_a_window(setup):
+    """``ServingEngine(model, params)`` and nothing else: a page pool of one
+    page a window a slot plus the trash page, the fused tick, and a snapshot
+    that carries both blocks."""
+    model, params = setup
+    engine = ServingEngine(model, params)
+    assert engine.kv_page_size == WINDOW and engine._pages_per_slot == 1
+    assert engine._pool.num_pages == engine.num_slots + 1 and engine.ragged
+    handle = engine.submit([5, 6, 7], max_new_tokens=3)
+    engine.run_until_drained(max_steps=50)
+    snap = engine.metrics.snapshot()
+    assert handle.ok and handle.pages_allocated == 1
+    assert snap["page_pool"]["pages_total"] == engine.num_slots and snap["page_pool"]["pages_in_use"] == 0
+    assert snap["ragged_tick"]["enabled"] and snap["ragged_tick"]["programs_per_tick"]["p50"] is not None
+    assert engine.decode_compilations == 1 and not hasattr(engine, "paged")
 
 
 def test_paged_sampled_requests_reproducible(setup):

@@ -567,13 +567,11 @@ def test_metrics_v8_sections_and_reader_backcompat(setup, tmp_path):
 # ------------------------------------------------------------- constructor
 def test_constructor_validation(setup):
     model, params = setup
-    with pytest.raises(ValueError, match="requires kv_page_size"):
-        ServingEngine(model, params, num_slots=2, prefill_chunk_tokens=4)
-    with pytest.raises(ValueError, match="requires kv_page_size"):
-        ServingEngine(model, params, num_slots=2, prefix_cache=True)
-    with pytest.raises(ValueError, match="must be >= 1"):
+    with pytest.raises(ValueError, match="must be >= 1"):  # with and without a page size
         ServingEngine(model, params, num_slots=2, kv_page_size=PS,
                       prefill_chunk_tokens=0)
+    with pytest.raises(ValueError, match="must be >= 1"):
+        ServingEngine(model, params, num_slots=2, prefill_chunk_tokens=0)
     with pytest.raises(ValueError, match="max_prefill_slots"):
         ServingEngine(model, params, num_slots=2, kv_page_size=PS,
                       max_prefill_slots=0)

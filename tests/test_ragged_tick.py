@@ -103,7 +103,8 @@ def test_ragged_tick_f64_matches_generate(setup64, request_no, admission):
 def test_ragged_tick_sampled_rng_chain_identical(setup):
     """Sampling: the per-slot rng split chain is part of the fused decode
     phase, and a finish lane installs its request's key — sampled streams
-    match the dense pool's seed for seed, on both admission paths."""
+    match the default engine's (one page a window) seed for seed, on both
+    admission paths."""
     model, params = setup
 
     def run(**pool):
@@ -132,7 +133,7 @@ def test_ragged_tick_one_program_ever(setup):
     if engine.watchdog is not None:
         engine.watchdog.check()  # ragged_tick budget=1 holds after churn
     # no per-phase program exists beside it: the tick is the one dispatcher
-    assert engine._jit_decode is None and not hasattr(engine, "_jit_chunk_kv")
+    assert not hasattr(engine, "_jit_decode") and not hasattr(engine, "_jit_chunk_kv")
     snap = engine.metrics.snapshot()
     assert snap["ragged_tick"]["enabled"] is True
     assert snap["ragged_tick"]["ticks"] > 0
@@ -662,7 +663,7 @@ def test_serve_bench_ragged_arm_smoke(tmp_path):
 
 def test_schema_v11_and_reader_normalizes_pre_v11(tmp_path):
     """The writer stamps serving-metrics/v12; the reader backfills
-    ragged_tick: None onto pre-v11 snapshots (and dense engines truthfully
+    ragged_tick: None onto pre-v11 snapshots (and routers truthfully
     report None — 'not recorded' stays indistinguishable from 'no tick
     dispatcher exists', the schema's long-standing discipline)."""
     assert SCHEMA == "serving-metrics/v13"

@@ -597,7 +597,8 @@ def test_nan_containment_failed_eviction_and_survivor_parity(x64):
     assert survivor.ok and survivor.result().tolist() == ref.result().tolist()
     # quarantine: nothing non-finite survives anywhere in the pool
     assert np.isfinite(np.asarray(engine._state.next_hidden)).all()
-    assert np.isfinite(np.asarray(engine._cache.ca.k)).all()
+    assert np.isfinite(np.asarray(engine._cache.ca.kp)).all()
+    assert np.isfinite(np.asarray(engine._cache.ca.vp)).all()
     snap = engine.metrics.snapshot()
     assert snap["failed"] == 1 and snap["requests_finished"] == 1
     # useful-tokens accounting: the quarantined slot's garbage sample is not

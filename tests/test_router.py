@@ -6,7 +6,7 @@ SIGTERM/SIGINT graceful drain.
 The failover contract (docs/serving.md, router section): after a replica is
 lost mid-decode, the router re-prefills ``prompt + already-emitted tokens``
 on a healthy replica and the greedy continuation is token-identical to the
-uninterrupted run — the widened ``write_slot`` left-pad path at a different
+uninterrupted run — the widened ``install_slot`` left-pad path at a different
 covering bucket is the risk, so prompt AND continuation lengths straddle
 every ladder boundary here, in float64 where equality is exact.
 """
@@ -129,18 +129,18 @@ def test_paged_failover_replays_at_victims_page_count(x64):
     """Satellite (docs/serving.md, paging section): with paging on, a
     failover replay re-prefills at the victim's covering bucket and allocates
     EXACTLY the victim's page reservation on the new replica — same bucket +
-    same generation budget, never a dense-window fallback — while the
-    continuation stays f64 token-identical to the dense uninterrupted run."""
+    same generation budget, never a full-window fallback — while the
+    continuation stays f64 token-identical to the default engine's uninterrupted run."""
     model, params = _make_model(param_dtype=jnp.float64)
     prompt, max_new = [7, 3, 9], 2
     expected = _engine_reference(model, params, [prompt], [max_new])[0]
 
     # page 3 over window 12: a full-window reservation would be 4 pages; this
     # request's (bucket 6 + 2 new -> ceil(8/3)) is 3 — the counts distinguish
-    # the replay path from any dense-window fallback
+    # the replay path from any full-window fallback
     router = ServingRouter(model, params, num_replicas=2, num_slots=1,
                            kv_page_size=3, breaker_cooldown_ticks=1)
-    assert all(r.engine.paged for r in router.replicas)
+    assert all(r.engine.kv_page_size == 3 for r in router.replicas)
     victim = router.submit(prompt, max_new_tokens=max_new)
     router.step()  # one token decoded: the crash is mid-request
     victim_pages = victim._engine_handle.pages_allocated
@@ -568,8 +568,8 @@ def test_router_shared_trace_per_replica_report(setup, tmp_path):
     assert "serving.r0.tick" in summary["phases"]
     assert "serving.r1.tick" in summary["phases"]
     assert "router.tick" in summary["phases"]
-    assert summary["compile"]["per_function"]["serving.r0.decode_step"]["compilations"] == 1
-    assert summary["compile"]["per_function"]["serving.r1.decode_step"]["compilations"] == 1
+    assert summary["compile"]["per_function"]["serving.r0.ragged_tick"]["compilations"] == 1
+    assert summary["compile"]["per_function"]["serving.r1.ragged_tick"]["compilations"] == 1
     assert summary["compile"]["unexpected"] == []
     router.close()  # writes the Chrome trace
     assert all(h.ok for h in handles)

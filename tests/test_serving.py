@@ -43,19 +43,57 @@ def setup():
     return _make_model()
 
 
+def _left_padded(prompt, width, pad_token_id):
+    ids = np.full((1, width), pad_token_id, np.int64)
+    pad = np.ones((1, width), bool)
+    ids[0, width - len(prompt):] = prompt
+    pad[0, width - len(prompt):] = False
+    return jnp.asarray(ids), jnp.asarray(pad)
+
+
 def _reference_tokens(model, params, prompt, config: GenerationConfig, rng=None):
     """generate() on the engine's canonical form, truncated at EOS inclusive
     (generate pads past EOS; the engine evicts instead)."""
-    n = len(prompt)
-    ids = np.full((1, WINDOW), config.pad_token_id, np.int64)
-    pad = np.ones((1, WINDOW), bool)
-    ids[0, WINDOW - n:] = prompt
-    pad[0, WINDOW - n:] = False
-    out = generate(model, params, jnp.asarray(ids), num_latents=LATENTS,
-                   pad_mask=jnp.asarray(pad), rng=rng, config=config)
-    toks = np.asarray(out)[0, WINDOW:].tolist()
+    if len(prompt) < LATENTS:
+        toks = _short_prompt_tokens(model, params, prompt, config)
+    else:
+        ids, pad = _left_padded(prompt, WINDOW, config.pad_token_id)
+        out = generate(model, params, ids, num_latents=LATENTS, pad_mask=pad, rng=rng, config=config)
+        toks = np.asarray(out)[0, WINDOW:].tolist()
     if config.eos_token_id is not None and config.eos_token_id in toks:
         toks = toks[: toks.index(config.eos_token_id) + 1]
+    return toks
+
+
+def _short_prompt_tokens(model, params, prompt, config: GenerationConfig):
+    """The canonical form of a prompt SHORTER than ``max_latents``, greedy, on
+    generate()'s own cache and steps: prefilled left-padded to ``max_latents``
+    (no prefix), then widened into the tail of a full window for the decode.
+    A padded latent sees no key and so attends uniformly over every slot of
+    what it was prefilled in, and self-attention masks no padded latent: the
+    width of the padding shows in the real rows. Padded to the WINDOW (six
+    more all-padding prefix slots) [7, 3, 9] gives 132 where this form, the
+    engine on either page size and the independent float32 reference
+    (benchmark/families/perceiver_ar/reference.py: "left-padded to it") give 9
+    (PERF.md 6, PR 47). From ``max_latents`` tokens on no latent is padded and
+    the two forms are one."""
+    assert not config.do_sample
+    off = WINDOW - LATENTS
+    ids, pad = _left_padded(prompt, LATENTS, config.pad_token_id)
+    cache = model.init_cache(batch_size=1, dtype=jnp.float64, max_seq_len=LATENTS)
+    logits, cache = model.apply(params, ids, 0, cache, pad_mask=pad, method=type(model).prefill)
+    head = lambda x: jnp.zeros((1, off, x.shape[-1]), x.dtype)
+    cache = cache.replace(
+        ca=cache.ca.replace(k=jnp.concatenate([head(cache.ca.k), cache.ca.k], axis=1),
+                            v=jnp.concatenate([head(cache.ca.v), cache.ca.v], axis=1),
+                            length=jnp.asarray(WINDOW, jnp.int32)),
+        pad_slots=jnp.concatenate([jnp.ones((1, off), bool), cache.pad_slots], axis=1),
+        shift=cache.shift + off,
+    )
+    toks = []
+    for _ in range(config.max_new_tokens):
+        toks.append(int(jnp.argmax(logits[0, -1])))
+        logits, cache = model.apply(params, jnp.asarray([[toks[-1]]]), cache, method=type(model).decode_step)
     return toks
 
 
@@ -79,7 +117,7 @@ def test_bucketed_prefill_parity_at_bucket_boundaries(x64):
     """Acceptance: greedy engine output stays token-identical to generate()'s
     canonical full-window form for prompt lengths straddling EVERY bucket
     boundary of the ladder (1, bucket, bucket + 1, window), in float64 — the
-    bucketed-prefill + write_slot tail-scatter must be positionally invisible."""
+    bucketed-prefill + install_slot tail-scatter must be positionally invisible."""
     model, params = _make_model(param_dtype=jnp.float64)
     engine = ServingEngine(model, params, num_slots=2)
     assert engine.prefill_buckets == (LATENTS, WINDOW)  # the default halving ladder
@@ -312,7 +350,7 @@ def test_metrics_standalone_counters():
     snap = m.snapshot()
     assert snap["schema"] == "serving-metrics/v13"
     assert snap["rejected"] == snap["timed_out"] == snap["failed"] == 0
-    assert snap["page_pool"] is None  # dense engine: no pool exists
+    assert snap["page_pool"] is None  # standalone metrics (a router's): no pool exists
     assert snap["mean_slot_occupancy"] == 0.5
     assert snap["tokens_generated"] == 2 and snap["decode_steps"] == 1
     assert snap["queue_wait_s"] == {"mean": 0.5, "max": 0.5, "p50": 0.5, "p95": 0.5}
@@ -440,7 +478,7 @@ def test_serve_bench_profile_smoke(tmp_path):
     # breakdown and runtime compile counts, plus a run manifest sibling
     telemetry = on_disk["telemetry"]
     assert "serving.tick" in telemetry["phases"]
-    assert telemetry["compile"]["per_function"]["serving.decode_step"]["compilations"] == 1
+    assert telemetry["compile"]["per_function"]["serving.ragged_tick"]["compilations"] == 1
     assert telemetry["compile"]["unexpected"] == []
     manifest = json.loads((tmp_path / "BENCH_serving.manifest.json").read_text())
     assert manifest["schema"] == "run-manifest/v1" and manifest["versions"]["jax"]

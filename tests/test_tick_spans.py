@@ -1,7 +1,7 @@
 """The serving tick measured from inside the engine (docs/observability.md "The tick,
 tiled" / "A request's life"): span parents and self time in the recorder core, the
-phases that tile the tick and the host gap under a fake clock (the paged engine's
-fused tick and the dense pool's decode step), the same spans on a ``jax.profiler`` trace's clock, the engine's own stamps
+phases that tile the tick and the host gap under a fake clock (the fused tick at a
+small page and on the default-constructed engine), the same spans on a ``jax.profiler`` trace's clock, the engine's own stamps
 of a request's life, the documented table of emitted names, and the bitwise inertness
 of all of it (served tokens, compile counts) recorder on vs off."""
 
@@ -55,12 +55,10 @@ def setup64(x64):
 
 
 def _engine(model, params, telemetry, paged=True, **more):
-    """The paged engine (chunked admission, prefix cache: the fused tick), or the dense
-    pool, whose tick is the decode step and whose every admission is one-shot."""
+    """The engine at a small page (chunked admission, prefix cache), or default-constructed
+    (the ``dense`` id: one page a window, no chunking, no prefix cache): the fused tick both."""
     pool = dict(kv_page_size=PAGE, prefill_chunk_tokens=4, max_prefill_slots=2, prefix_cache=True) if paged else {}
-    engine = ServingEngine(model, params, num_slots=3, telemetry=telemetry, **pool, **more)
-    assert engine.ragged is paged
-    return engine
+    return ServingEngine(model, params, num_slots=3, telemetry=telemetry, **pool, **more)
 
 
 def _serve(engine, upfront=True):
@@ -290,8 +288,9 @@ def test_phases_tile_the_tick_and_the_host_gap_under_a_fake_clock(setup, paged):
     # each tick's wall time (dispatch's start to sync's return) went to one of the two books
     walls = "serving.tick_wall.decode_only", "serving.tick_wall.with_prefill"
     assert grew(walls[0], "count") + grew(walls[1], "count") == len(ticks)
-    # chunk and finish lanes exist in the fused tick alone
-    assert grew(walls[0], "count") >= 3 and (grew(walls[1], "count") >= 3 if paged else grew(walls[1], "count") == 0)
+    # the default engine's lanes are its split admissions' (a prompt of the latent floor or more: its whole tail
+    # and its finish in the admitting tick); chunking spreads a prompt over more ticks
+    assert grew(walls[0], "count") >= 3 and (grew(walls[1], "count") >= 3 if paged else grew(walls[1], "count") >= 1)
     assert grew(walls[0]) + grew(walls[1]) == pytest.approx(
         sum(end(children[n]["serving.sample_sync"]) - children[n]["serving.decode_dispatch"]["ts"] for n in numbers), abs=0.5)
     # nesting below the tiles, and the ids the per-admission spans carry
@@ -301,12 +300,11 @@ def test_phases_tile_the_tick_and_the_host_gap_under_a_fake_clock(setup, paged):
     assert by_parent["serving.admit"] == {"serving.schedule"} and by_parent["serving.evict"] == {"serving.harvest"}
     assert by_parent["serving.prefill_dispatch"] == {"serving.admit"} == by_parent["serving.install"]
     per_admission = ["serving.prefill_dispatch", "serving.install"]
-    if paged:
-        assert by_parent["serving.prefill_chunk"] == {"serving.admit", "serving.schedule"}
-        assert by_parent["serving.prefill_finish"] <= {"serving.admit", "serving.schedule"}
-        per_admission += ["serving.prefill_chunk", "serving.prefill_finish"]
-    else:
-        assert "serving.prefill_chunk" not in by_parent and "serving.prefill_finish" not in by_parent
+    # a chunked engine's later chunks are advanced by the schedule; unchunked, a prompt's lanes are its admission's
+    carried_by = {"serving.admit", "serving.schedule"} if paged else {"serving.admit"}
+    assert by_parent["serving.prefill_chunk"] == carried_by
+    assert by_parent["serving.prefill_finish"] <= carried_by
+    per_admission += ["serving.prefill_chunk", "serving.prefill_finish"]
     for name in per_admission:
         ids = {e["args"]["request_id"] for e in events if e["name"] == name}
         assert ids and ids <= set(range(2 * len(PROMPTS)))
@@ -562,7 +560,7 @@ def test_the_engine_stamps_a_requests_life(setup, paged):
 
     engine.metrics.record_admit, engine.metrics.record_prefix_hit = spy_admit, spy_hit
     handles = _serve(engine, upfront=False)
-    assert {len(h.prompt_ids) >= LATENTS for h in handles} == {True, False}  # either side of the latent floor: on the paged engine both admission paths ran
+    assert {len(h.prompt_ids) >= LATENTS for h in handles} == {True, False}  # either side of the latent floor: both admission paths ran
     for h in handles:
         assert h.enqueued_at <= h.slot_claimed_at <= h.admitted_at <= h.first_token_at <= h.finished_at
         assert h.first_token_at <= h.last_token_at <= h.finished_at
@@ -636,7 +634,7 @@ def test_emitted_serving_names_are_exactly_the_documented_table(setup):
 
 def test_tokens_and_compile_counts_are_the_same_recorder_on_and_off(setup64):
     """f64 bitwise pin over the paged engine and its spans, stamps and named scopes:
-    telemetry times host calls and never touches a device value (the dense pool's pin is
+    telemetry times host calls and never touches a device value (the default engine's pin is
     tests/test_obs.py::test_engine_disabled_telemetry_is_null_and_token_identical)."""
     model, params = setup64
 
