@@ -310,3 +310,89 @@ class Lfm2MoeConfig:
         known = {f.name for f in fields(cls)}
         picked = {k: (tuple(v) if isinstance(v, list) else v) for k, v in kwargs.items() if k in known}
         return cls(**picked)
+
+
+@dataclass(frozen=True)
+class NemotronHConfig:
+    """A Nemotron-H decoder (``models/core/nemotron_h.py``) under the keys of
+    its published ``config.json`` (``model_type`` ``nemotron_h``): layer ``i``
+    is ONE mixer under one norm and one residual, by
+    ``hybrid_override_pattern[i]``: ``M`` a Mamba-2 mixer (``mamba_num_heads``
+    heads of ``mamba_head_dim``, so its inner width is their product and NOT
+    ``expand`` x hidden; ``n_groups`` groups of state ``ssm_state_size``), ``E``
+    ``n_routed_experts`` routed experts of ``moe_intermediate_size`` in the
+    ungated ``relu(x W_up)^2 W_down`` form, ``num_experts_per_tok`` a token,
+    beside one shared expert of ``moe_shared_expert_intermediate_size``; ``*``
+    grouped-query attention with NO position signal.
+
+    This program's own: ``experts_held`` = (first, count), the experts of the
+    router's ``n_routed_experts`` that lie here (the chip's share of an
+    expert-parallel layer; the router keeps its width); ``max_seq_len``, the
+    most tokens a serving slot can hold."""
+
+    vocab_size: int = 131072
+    hidden_size: int = 2688
+    num_hidden_layers: int = 52
+    hybrid_override_pattern: str = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 2
+    head_dim: int = 128
+    mamba_num_heads: int = 64
+    mamba_head_dim: int = 64
+    n_groups: int = 8
+    ssm_state_size: int = 128
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    moe_intermediate_size: int = 1856
+    moe_shared_expert_intermediate_size: int = 3712
+    n_routed_experts: int = 128
+    experts_held: Tuple[int, int] = (0, 128)
+    num_experts_per_tok: int = 6
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 2.5
+    layer_norm_epsilon: float = 1e-5
+    max_position_embeddings: int = 262144
+    max_seq_len: int = 2048
+    init_scale: float = 0.02
+
+    def __post_init__(self):
+        if len(self.hybrid_override_pattern) != self.num_hidden_layers:
+            raise ValueError(f"hybrid_override_pattern names {len(self.hybrid_override_pattern)} layers, "
+                             f"num_hidden_layers is {self.num_hidden_layers}")
+        unknown = set(self.hybrid_override_pattern) - set("ME*")
+        if unknown:
+            raise ValueError(f"hybrid_override_pattern holds {sorted(unknown)}: a layer is 'M', 'E' or '*'")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError(
+                f"num_attention_heads ({self.num_attention_heads}) must be a multiple of "
+                f"num_key_value_heads ({self.num_key_value_heads})")
+        if self.mamba_num_heads % self.n_groups:
+            raise ValueError(f"n_groups ({self.n_groups}) must divide mamba_num_heads ({self.mamba_num_heads})")
+        first, count = self.experts_held
+        if not (0 <= first and count >= 1 and first + count <= self.n_routed_experts):
+            raise ValueError(f"experts_held {self.experts_held} is no range of the router's "
+                             f"{self.n_routed_experts} experts")
+        if self.num_experts_per_tok > self.n_routed_experts:
+            raise ValueError("num_experts_per_tok exceeds n_routed_experts")
+        if not 1 <= self.max_seq_len <= self.max_position_embeddings:
+            raise ValueError(
+                f"max_seq_len ({self.max_seq_len}) must lie in [1..max_position_embeddings="
+                f"{self.max_position_embeddings}]")
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return self.mamba_inner + 2 * self.n_groups * self.ssm_state_size
+
+    def layers_of(self, kind: str) -> Tuple[int, ...]:
+        """The layers of one kind (``M``, ``E`` or ``*``), in order."""
+        return tuple(i for i, k in enumerate(self.hybrid_override_pattern) if k == kind)
+
+    @classmethod
+    def create(cls, **kwargs):
+        known = {f.name for f in fields(cls)}
+        picked = {k: (tuple(v) if isinstance(v, list) else v) for k, v in kwargs.items() if k in known}
+        return cls(**picked)

@@ -53,6 +53,16 @@ the device knows, which experts its tokens were routed to:
     tick's ONE readback of the tokens (no copy of their own), and the engine
     books them (``EngineMetrics.record_expert_counts``, the tick's record). A
     model without the field counts nothing and its tick returns what it did.
+    The counters run over ALL the router's experts. A model that holds a SHARE
+    of them (the chip's part of an expert-parallel layer) also states
+    ``ServingTraits.experts_held`` = (first, count), and the book then tells
+    the two things apart: the matrices a decode step READ are the held experts
+    that received a row (``touched_per_step`` counts those, of ``count``), and
+    of a decode step's assignments only those that fell to held experts were
+    computed here (``held_assignment_pct``; the others are some other chip's,
+    computed nowhere in a one-chip cell). ``assignments`` and
+    ``load_max_over_mean`` stay over all the router's experts: the router's
+    balance is the model's, whoever holds the experts.
 
 A model MAY also say in which layout the tick must RECEIVE a weight:
 
@@ -128,6 +138,9 @@ class ServingTraits:
     # keeps on the device and the tick returns with its tokens; None: the model
     # has no routed experts, counts nothing and pays nothing
     expert_counters: Optional[Tuple[int, int]] = None
+    # (f): (first, count) of the router's experts whose matrices lie here; None:
+    # every expert the counters run over is held
+    experts_held: Optional[Tuple[int, int]] = None
     # (g): parameter leaves ("/"-joined key paths) the tick must receive
     # row-major; (): the compiler lays every argument out as it chooses
     row_major_leaves: Tuple[str, ...] = ()

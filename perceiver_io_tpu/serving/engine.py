@@ -427,6 +427,12 @@ TICK_SCOPES.update({part: f"tick.{part}" for part in (
 # expert products under ``moe``, the gated short convolution
 TICK_SCOPES.update({f"{phase}/{part}": f"tick.{phase}/{part}" for phase in ("decode", "chunk_lanes")
                     for part in ("moe/route", "moe/experts", "short_conv", "attention", "mlp")})
+# and a model whose layers are Mamba-2, expert and attention layers alone in one
+# stack (models/core/nemotron_h.py), in both phases alike: the mixer's
+# projections, convolution and recurrence under ``ssm``, the shared expert's
+# two dense products under ``moe/shared``
+TICK_SCOPES.update({f"{phase}/{part}": f"tick.{phase}/{part}" for phase in ("decode", "chunk_lanes")
+                    for part in ("ssm", "moe/shared")})
 
 
 class TickRecord(NamedTuple):
@@ -824,7 +830,7 @@ class ServingEngine:
         if traits.recurrent_state:
             self.metrics.set_recurrent_state(num_slots * traits.recurrent_bytes_per_slot)
         if traits.expert_counters is not None:
-            self.metrics.set_expert_counters(*traits.expert_counters)
+            self.metrics.set_expert_counters(*traits.expert_counters, traits.experts_held)
         if self.kv_quant is not None:
             cfg = model.config
             fp_b, served_b = kv_bytes_per_token(
@@ -2698,9 +2704,10 @@ class ServingEngine:
             # what they say rides, beside the record, the spans that begin or
             # end after this readback (harvest, tick)
             tok, counts = tok[:self.num_slots], tok[self.num_slots:].reshape(2, *self._traits.expert_counters)
-            assignments, touched = self.metrics.record_expert_counts(counts)
+            assignments, touched, held = self.metrics.record_expert_counts(counts)
             if self._obs_on:
-                expert_fields = {"expert_assignments": assignments, "experts_touched": touched}
+                expert_fields = {"expert_assignments": assignments, "experts_touched": touched,
+                                 "experts_held_assignments": held}
         # NOT free: the program is done, but this is a second device-to-host
         # copy after the first (0.4 ms a tick on a TPU v5 lite, PERF.md 6 PR 38)
         finite = np.asarray(finite)

@@ -470,6 +470,11 @@ class EngineMetrics(_JsonlMetrics):
     # experts) int64 sums of what the ticks' counters returned, and per
     # decoding tick the mean number of experts a layer that received a row
     expert_assignments: Optional[np.ndarray] = None
+    # (first, count) of the counters' experts that are held here, and the decode
+    # steps' assignments that fell to them / to any expert, since the start
+    experts_held: Tuple[int, int] = (0, 0)
+    decode_assignments_held: int = 0
+    decode_assignments: int = 0
     _experts_touched: Deque[float] = field(default_factory=lambda: deque(maxlen=LATENCY_WINDOW))
     _start_time: Optional[float] = None
     _occupancy_sum: float = 0.0  # sum over steps of active_slots / num_slots
@@ -599,24 +604,33 @@ class EngineMetrics(_JsonlMetrics):
         ``state_bytes``: what the pool's recurrent states take on the device."""
         self.recurrent_state_bytes = int(state_bytes)
 
-    def set_expert_counters(self, layers: int, experts: int) -> None:
+    def set_expert_counters(self, layers: int, experts: int,
+                            held: Optional[Tuple[int, int]] = None) -> None:
         """Mark a model whose routed expert layers count their assignments on
         the device (models/core/serving_api.py (f)): snapshots report the
-        experts section instead of None."""
+        experts section instead of None. ``held`` = (first, count): the experts
+        whose matrices lie here; None: all of them."""
         self.expert_assignments = np.zeros((int(layers), int(experts)), np.int64)
+        self.experts_held = (0, int(experts)) if held is None else (int(held[0]), int(held[1]))
 
-    def record_expert_counts(self, counts: np.ndarray) -> Tuple[int, float]:
+    def record_expert_counts(self, counts: np.ndarray) -> Tuple[int, float, int]:
         """One harvested tick's counters, ``counts`` (2, expert layers, experts):
         the assignments each expert received in the tick's decode step (row 0)
         and in chunk lanes since the last harvested tick (row 1). Returns what
         goes on the tick's record: (assignments, both rows; the mean number of
-        experts a layer with a row in the DECODE step, the call whose weight
-        stream bounds a decoding tick). Windowed, no JSONL event: it fires
-        every tick."""
+        HELD experts a layer with a row in the DECODE step, the call whose
+        weight stream bounds a decoding tick: an expert that lies elsewhere
+        is not read here; the decode step's assignments that fell to held
+        experts, the ones computed). Windowed, no JSONL event: it fires every
+        tick."""
         self.expert_assignments += counts.sum(axis=0)
-        touched = float((counts[0] > 0).sum(axis=-1).mean())
+        first, count = self.experts_held
+        held = counts[0, :, first:first + count]
+        touched, computed = float((held > 0).sum(axis=-1).mean()), int(held.sum())
         self._experts_touched.append(touched)
-        return int(counts.sum()), touched
+        self.decode_assignments_held += computed
+        self.decode_assignments += int(counts[0].sum())
+        return int(counts.sum()), touched, computed
 
     def record_recurrent_chunk(self, reset: bool) -> None:
         """One chunk lane of a recurrent model: it either starts its slot's
@@ -905,10 +919,16 @@ class EngineMetrics(_JsonlMetrics):
             # on the device: assignments per layer and expert since the start
             # (prefill and decode alike), per decoding tick the experts a layer
             # that received a row in the decode step, and the busiest expert's
-            # assignments over the mean of its layer's, the worst layer's
+            # assignments over the mean of its layer's, the worst layer's;
+            # ``held`` the experts whose matrices lie here (``touched_per_step``
+            # counts among them) and the share of the decode steps'
+            # assignments that fell to them (100 where every expert is held)
             "experts": None if self.expert_assignments is None else {
                 "layers": int(self.expert_assignments.shape[0]),
                 "experts": int(self.expert_assignments.shape[1]),
+                "held": list(self.experts_held),
+                "held_assignment_pct": (100.0 * self.decode_assignments_held / self.decode_assignments
+                                        if self.decode_assignments else None),
                 "assignments": self.expert_assignments.tolist(),
                 "touched_per_step": {
                     k: v for k, v in _latency_dict(self._experts_touched).items()

@@ -152,7 +152,28 @@ def _experts(sds, experts, hidden, width, top_k, rows):
         sds((rows, hidden), bf16), weights, sds((rows,), jnp.bool_))
 
 
+def _experts_share(sds, held, routed, hidden, width, shared, top_k, rows):
+    """An ungated expert layer on a SHARE of its experts, through ``expert_layer``: the router
+    over all ``routed`` experts, the ``relu(.)^2`` product and the down product over the
+    ``held`` stacks as laid out (``width`` a multiple of the lanes), the shared expert dense."""
+    bf16 = jnp.bfloat16
+    weights = moe.ExpertWeights(sds((hidden, routed), bf16), sds((routed,), bf16), sds((held, hidden, width), bf16),
+                                sds((held, width, hidden), bf16), sds((hidden, shared), bf16), sds((shared, hidden), bf16))
+    return jax.jit(lambda x, w, valid: moe.expert_layer(x, w, (0, held), top_k, 2.5, valid=valid, use_kernel=True,
+                                                        form="relu2", norm_eps=1e-20)).lower(
+        sds((rows, hidden), bf16), weights, sds((rows,), jnp.bool_))
+
+
 CASES = {
+    # Nemotron-3-Nano-30B-A3B's widths at the serving cell's sizes: 32 query heads over 2 K/V
+    # heads of 128 (the pool's row is 256 wide), 2 attention layers, 128 slots, 2049 pages of 64
+    # under a 1024-token row; 64 mixer heads of 64 x state 128 in 8 groups, 6 layers; 64 held of
+    # 128 experts of 2688 x 1856 laid out at 1920, 6 a token, beside the shared 3712, a decode
+    # step's 128 rows and a chunk lane's 256
+    "gqa-paged-32over2x128-l2-b128-w1024": (_gqa, 32, 2, 128, 2, 128, 2049, 64, 1024),
+    "ssm-update-64x64x128-g8-l6-b128": (_ssm_update, 64, 8, 64, 128, 6, 128),
+    "experts-relu2-64of128x2688x1920-top6-rows128": (_experts_share, 64, 128, 2688, moe.pad_width(1856), 3712, 6, 128),
+    "experts-relu2-64of128x2688x1920-top6-rows256": (_experts_share, 64, 128, 2688, moe.pad_width(1856), 3712, 6, 256),
     # LFM2-8B-A1B's widths at the serving cell's sizes: 32 query heads over 8 K/V
     # heads of 64 (the pool's row is 512 wide; a slot's result leaves the kernel at
     # that width), 3 attention layers, 128 slots, 2049 pages of 64 under a 1024-token
@@ -299,3 +320,35 @@ def test_lfm2_tick_reads_each_expert_layer_once_whatever_it_carries(lfm2_tick, h
     else:
         # PR 44's tick: 10.03 GB of arguments + 0.16 GB of temporaries; the cell's peak on the chip is 10.06 GB
         assert memory.argument_size_in_bytes < 10.05e9 and memory.temp_size_in_bytes < 0.3e9, memory
+
+
+@pytest.fixture(scope="module")
+def nemotron_tick(v5e):
+    """``serve-nemotron3-nano-agent``'s WHOLE tick at the published widths of
+    ``benchmark/configs/nemotron3-nano-30b-a3b-14l.json``: (the compiled text, its memory account); about half a minute."""
+    compiled = _compiled_tick(v5e, "serve-nemotron3-nano-agent")
+    return compiled.as_text(), compiled.memory_analysis()
+
+
+@pytest.mark.parametrize("holds", ["the routed products are the grouped kernels", "no copy of in_proj or of an expert stack",
+                                   "it fits beside the harness's weights"])
+def test_nemotron_tick_streams_the_held_experts_through_the_grouped_kernels(nemotron_tick, holds):
+    """ISSUE 49: at the published 1856 columns the grouped kernels were refused and the products fell to
+    ``ragged_dot``; laid out at 1920 where the weights are made they are the Pallas kernels, the stacks
+    arrive as laid out, and ``in_proj`` (10,304 columns) arrives row-major as the model states."""
+    text, memory = nemotron_tick
+    lines = text.splitlines()
+    if holds == "the routed products are the grouped kernels":
+        assert "ragged-dot" not in text
+        for kernel in ("grouped_relu2_matmul", "grouped_matmul", "ssm_decode_update", "fused_paged_decode_attention_gqa"):
+            assert kernel in text, kernel
+        assert "grouped_gated_matmul" not in text
+    elif holds == "no copy of in_proj or of an expert stack":
+        big = ("bf16[2688,10304]", "bf16[168,16,10304]", "bf16[64,2688,1920]", "bf16[64,1920,2688]")
+        copies = [line.strip()[:160] for line in lines if " copy(" in line and any(b in line for b in big)]
+        assert not copies, copies
+        entry = [line for line in lines if "_experts_" in line and " parameter(" in line and "params___" in line]
+        assert len(entry) == 12 and all("{2,1,0:T(8,128)(2,1)}" in line for line in entry), [line[:160] for line in entry]
+    else:
+        # 11.35 GB of arguments (weights 9.44 as laid out, state 1.61, pages 0.27) + 0.15 GB of temporaries
+        assert memory.argument_size_in_bytes < 11.4e9 and memory.temp_size_in_bytes < 0.3e9, memory

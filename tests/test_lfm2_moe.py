@@ -361,9 +361,9 @@ def test_grouped_kernel_equals_ragged_dot_with_an_expert_that_receives_no_row(dt
     w13 = (jax.random.normal(keys[1], (groups, k, 2 * n)) * k ** -0.5).astype(dtype)
     w2 = (jax.random.normal(keys[2], (groups, n, k)) * n ** -0.5).astype(dtype)
     visited = np.asarray(jnp.arange(layout.rows) < layout.n_tiles * layout.tile_rows)
-    for weights, gated, rows in ((w13, True, lhs), (w2, False, jax.random.normal(keys[3], (layout.rows, n)).astype(dtype))):
-        want = moe.grouped_matmul(rows, weights, layout, jnp.float32, gated)
-        got = moe.grouped_matmul(rows, weights, layout, jnp.float32, gated, use_kernel=True, interpret=True)
+    for weights, form, rows in ((w13, "gated_silu", lhs), (w2, None, jax.random.normal(keys[3], (layout.rows, n)).astype(dtype))):
+        want = moe.grouped_matmul(rows, weights, layout, jnp.float32, form)
+        got = moe.grouped_matmul(rows, weights, layout, jnp.float32, form, use_kernel=True, interpret=True)
         np.testing.assert_allclose(np.asarray(got)[visited], np.asarray(want)[visited], atol=tol, rtol=0)
     # the tile table names expert 2 nowhere among the tiles that hold rows
     assert 2 not in np.asarray(layout.tile_group)[: int(layout.n_tiles)].tolist()

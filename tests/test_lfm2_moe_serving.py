@@ -100,8 +100,8 @@ def test_the_ticks_record_and_spans_carry_the_counters(served):
     assert sum(e["args"]["expert_assignments"] for e in harvests) == tokens * SIZES["num_experts_per_tok"] * EXPERT_LAYERS
     assert all(0 < e["args"]["experts_touched"] <= SIZES["num_experts"] for e in harvests)
     ticks = [e for e in events if e["name"].endswith(".tick") and e["args"].get("decoding")]
-    # the tick span ends with the record's ten fields, then the two the readback brought
-    both = TickRecord._fields + ("expert_assignments", "experts_touched")
+    # the tick span ends with the record's ten fields, then the three the readback brought
+    both = TickRecord._fields + ("expert_assignments", "experts_touched", "experts_held_assignments")
     assert ticks and all(tuple(e["args"]) == both for e in ticks)
     assert all(e["args"]["expert_assignments"] == h["args"]["expert_assignments"]
                for e in ticks for h in harvests if h["args"]["tick"] == e["args"]["tick"])
