@@ -26,10 +26,10 @@ convolution's last three inputs for every ``M`` layer, pages of keys and values
 for the ``*`` layers only, and nothing for an ``E`` layer but its share of the
 pool's assignment counters. ``NemotronHCache`` is the one pytree that holds all
 three; ``prefill_chunk_paged`` and ``decode_rows_paged`` are the two steps the
-serving engine's tick program is built from (``models/core/serving_api.py``).
-The tick runs in the plain order (chunk lanes, finish lanes, the decode step):
-a lane tick streams the experts a second time (``serving_api.py`` (h) is not
-stated; PERF.md 7.18).
+serving engine's tick program is built from (``models/core/serving_api.py``),
+and ``decode_rows_with_chunk_paged`` is both in one loop over the layers: the
+model states ``serving_api.py`` (h), so a tick that carries a chunk lane and
+decodes reads each ``E`` layer's held experts once, for the rows of both.
 
 The expert stacks lie at ``ops/moe.pad_width(moe_intermediate_size)`` columns
 (1856 -> 1920, the padding zero: exact), laid out where the weights are made.
@@ -42,6 +42,7 @@ always.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Optional, Tuple
 
 import flax.linen as nn
@@ -50,7 +51,8 @@ import jax.numpy as jnp
 
 from perceiver_io_tpu.models.core.config import NemotronHConfig
 from perceiver_io_tpu.models.core.falcon_h1 import FalconH1Cache, FalconH1ForCausalLM, gated_group_rms_norm, rms_norm
-from perceiver_io_tpu.models.core.serving_api import ServingTraits
+from perceiver_io_tpu.models.core.lfm2_moe import Lfm2MoeForCausalLM
+from perceiver_io_tpu.models.core.serving_api import TICK_CHUNK_SCOPE, TICK_DECODE_SCOPE, ServingTraits
 from perceiver_io_tpu.ops import moe
 from perceiver_io_tpu.ops import paged_decode_kernel as paged
 from perceiver_io_tpu.ops import ssm
@@ -193,9 +195,10 @@ class NemotronHForCausalLM(nn.Module):
                        preferred_element_type=jnp.float32).astype(dt)
         return self._mm(o.reshape(n, hq * hd), p["o_proj"])
 
-    def _experts(self, p, x: jax.Array, valid: Optional[jax.Array] = None):
+    def _experts(self, p, x: jax.Array, valid: Optional[jax.Array] = None, load_groups: Optional[jax.Array] = None):
         """x (T, hidden) normed -> (the held experts' part of the routed sum plus
-        the shared expert (T, hidden), the load (the router's experts,) int32)."""
+        the shared expert (T, hidden), the load (the router's experts,) int32, by
+        group of rows (G, experts) where ``load_groups`` (G, T) names them)."""
         cfg = self.config
         with jax.named_scope("moe"):
             weights = moe.ExpertWeights(p["router"], p["expert_bias"], p["experts_up"], p["experts_down"],
@@ -203,7 +206,8 @@ class NemotronHForCausalLM(nn.Module):
             return moe.expert_layer(
                 x.astype(self._dt), weights, cfg.experts_held, cfg.num_experts_per_tok, cfg.routed_scaling_factor,
                 cfg.norm_topk_prob, valid, form="relu2", norm_eps=1e-20,
-                use_kernel=moe.grouped_kernel_supported(cfg.hidden_size, moe.pad_width(cfg.moe_intermediate_size)))
+                use_kernel=moe.grouped_kernel_supported(cfg.hidden_size, moe.pad_width(cfg.moe_intermediate_size)),
+                load_groups=load_groups)
 
     # --------------------------------------------------------- full forward
     def _forward_one(self, ids: jax.Array) -> jax.Array:
@@ -268,6 +272,9 @@ class NemotronHForCausalLM(nn.Module):
             recurrent_bytes_per_slot=len(cfg.layers_of("M")) * state,
             expert_counters=(expert_layers, cfg.n_routed_experts) if expert_layers else None,
             experts_held=cfg.experts_held if expert_layers else None,
+            # (h) a held expert's matrices are read once a call, and a decode step and a chunk lane are
+            # both bound by that read: the lane's rows ride the decode step's one call an ``E`` layer
+            chunk_rides_decode=bool(expert_layers),
             # (g) ``in_proj`` (z | xBC | dt: 10,304 columns at the published widths, no multiple of 128) is
             # the one matrix the compiler takes transposed; the chunk lanes' product cannot read that and
             # copied each ``M`` layer's 55.4 MB in front of the lanes' loop (the compiled tick, read before
@@ -286,9 +293,163 @@ class NemotronHForCausalLM(nn.Module):
         """(b) every attention layer is full attention: a request holds all its tokens."""
         return -(-min(prompt_tokens + max_new_tokens, self.config.max_seq_len) // page_size)
 
-    # (c) the lanes' loops ask nothing of the block they loop over: a hybrid's, whatever its layers
-    serving_chunk_phase = FalconH1ForCausalLM.serving_chunk_phase
+    # (h) and (c): the lanes' loops ask nothing of the layers they loop over: the expert model's ride, a hybrid's finish
+    serving_ride_phase = Lfm2MoeForCausalLM.serving_ride_phase
     serving_finish_phase = FalconH1ForCausalLM.serving_finish_phase
+
+    def _decode_operator(self, p, kind: str, l: int, h: jax.Array, cache: NemotronHCache, pools, at):
+        """Layer ``l`` of its kind (``M`` or ``*``) for the decode rows ``h`` (B,
+        hidden), one row a slot: ``(the mixer's contribution, pools)``; ``pools``
+        = (kp, vp, ssm_state, conv_state) as the layers so far left them, ``at`` =
+        (the rows that decode, page ids, offsets, visible)."""
+        cfg = self.config
+        b, tail_rows = h.shape[0], cfg.conv_kernel - 1
+        kp, vp, ssm_state, conv_state = pools
+        active, page_ids, offs, visible = at
+        x = self._norm(h, p["norm"])
+        if kind == "M":
+            with jax.named_scope("ssm"):
+                step = (ssm.ssm_decode_update if ssm.ssm_kernel_supported(
+                    cfg.mamba_num_heads, cfg.n_groups, cfg.mamba_head_dim, cfg.ssm_state_size)
+                    else ssm.ssm_decode_update_xla)
+                z, xbc, dt_raw = self._mixer_in(p, x)
+                tails = conv_state[l].reshape(b, tail_rows, cfg.conv_dim)
+                window = jnp.concatenate([tails.astype(xbc.dtype), xbc[:, None]], axis=1)  # (B, conv_kernel, C)
+                conv_state = conv_state.at[l].set(jnp.where(
+                    active[:, None], window[:, 1:].astype(conv_state.dtype).reshape(b, -1), conv_state[l]))
+                conv = jnp.sum(window.astype(jnp.float32) * p["conv_weight"].astype(jnp.float32), axis=1)
+                conv = jax.nn.silu(conv + p["conv_bias"].astype(jnp.float32))
+                xs, bm, cm, dt, a = self._mixer_split(p, conv, dt_raw)
+                ssm_state, y = step(ssm_state, l, xs, dt, a, bm, cm, active)
+                return self._mixer_out(p, y, xs, z), (kp, vp, ssm_state, conv_state)
+        with jax.named_scope("attention"):
+            attend = (paged.fused_paged_decode_attention_gqa
+                      if paged.paged_gqa_decode_supported(cache.page_size, cfg.head_dim, cfg.num_key_value_heads)
+                      else paged.paged_gqa_reference_attention)
+            q, k, v = self._qkv(p, x)
+            kp = kp.at[l, page_ids, offs].set(k.astype(kp.dtype))
+            vp = vp.at[l, page_ids, offs].set(v.astype(vp.dtype))
+            o = attend(q, kp, vp, cache.page_table, visible, l)
+            return self._mm(o.reshape(b, -1), p["o_proj"]), (kp, vp, ssm_state, conv_state)
+
+    def _chunk_operator(self, p, kind: str, l: int, h: jax.Array, pools, lane, at):
+        """Layer ``l`` of its kind (``M`` or ``*``) for a chunk's rows ``h`` (cap,
+        hidden) of ONE slot: ``(the mixer's contribution, pools)``; ``lane`` =
+        (ids, offset, count, reset, slot, table_row), ``at`` = (page ids, offsets,
+        visible, real) of the rows."""
+        cfg = self.config
+        cap, tail_rows = h.shape[0], cfg.conv_kernel - 1
+        kp, vp, ssm_state, conv_state = pools
+        _, _, count, reset, slot, table_row = lane
+        page_ids, offs, visible, real = at
+        x = self._norm(h, p["norm"])
+        if kind == "M":
+            with jax.named_scope("ssm"):
+                z, xbc, dt_raw = self._mixer_in(p, x)
+                tail = jnp.where(reset, 0, conv_state[l, slot]).reshape(tail_rows, cfg.conv_dim)
+                window = jnp.concatenate([tail.astype(xbc.dtype), xbc])
+                xs, b, c, dt, a = self._mixer_split(p, self._conv(p, window, cap), dt_raw)
+                before = jnp.where(reset, 0.0, ssm_state[l, slot])
+                y, after = ssm.ssd_chunk_scan(xs, jnp.where(real[:, None], dt, 0.0), a, b, c, before, cfg.chunk_size)
+                ssm_state = ssm_state.at[l, slot].set(after)
+                conv_state = conv_state.at[l, slot].set(
+                    jax.lax.dynamic_slice_in_dim(window, count, tail_rows, axis=0)
+                    .astype(conv_state.dtype).reshape(-1))
+                return self._mixer_out(p, y, xs, z), (kp, vp, ssm_state, conv_state)
+        with jax.named_scope("attention"):
+            q, k, v = self._qkv(p, x)
+            kp = kp.at[l, page_ids, offs].set(jnp.where(real[:, None], k, 0).astype(kp.dtype))
+            vp = vp.at[l, page_ids, offs].set(jnp.where(real[:, None], v, 0).astype(vp.dtype))
+            # the slot's pages, the chunk's own rows among them, in position order
+            out = self._attend(p, q, kp[l, table_row].reshape(-1, k.shape[-1]),
+                               vp[l, table_row].reshape(-1, v.shape[-1]), visible)
+            return out, (kp, vp, ssm_state, conv_state)
+
+    def _rows_paged(self, cache: NemotronHCache, ids: Optional[jax.Array] = None, lane=None, live=True):
+        """THE loop over the layers, over an optional group of decode rows
+        (``ids`` (B, 1): one token for every decoding slot) and an optional chunk
+        (``lane`` = (ids (cap,), offset, count, reset, slot, table_row): prompt
+        tokens ``[offset, offset + count)`` of the request in ``slot``). An ``M``
+        or ``*`` layer runs for the two groups apart (their slots are disjoint: a
+        prefilling slot does not decode; a recurrence's decode update and its
+        chunk scan share nothing, nor do the two attentions), an ``E`` layer ONCE
+        over their rows together, so a held expert's matrices are read once for
+        both. ``live`` (may be traced): False makes every decode row a discarded
+        one. Returns ``(the decode rows' new last rows (B, hidden) or None,
+        cache)``. With both groups the operations carry the tick's phase names
+        (``serving_api.py`` (h)); with one, the caller's."""
+        cfg = self.config
+        ps, pages = cache.page_size, cache.pages_per_slot
+        decodes, chunks = ids is not None, lane is not None
+        both = decodes and chunks
+        phase = jax.named_scope if both else (lambda name: contextlib.nullcontext())
+        pools, counts = (cache.kp, cache.vp, cache.ssm_state, cache.conv_state), cache.expert_counts
+        h_d = h_c = None
+        if decodes:
+            with phase(TICK_DECODE_SCOPE):
+                b = ids.shape[0]
+                active = cache.active if live is True else cache.active & live
+                pos = jnp.where(active, cache.length, 0)
+                page_ids = jnp.where(active, cache.page_table[jnp.arange(b), jnp.clip(pos // ps, 0, pages - 1)], 0)
+                decode_at = (active, page_ids, jnp.where(active, pos % ps, 0), jnp.where(active, pos + 1, 0))
+                h_d = self._embed(ids[:, 0])
+        if chunks:
+            with phase(TICK_CHUNK_SCOPE):
+                chunk_ids, offset, count, _, slot, table_row = lane
+                real = jnp.arange(chunk_ids.shape[0]) < count
+                pos = offset + jnp.arange(chunk_ids.shape[0])
+                # rows to pages: padding rows land on the trash page with a zero payload
+                page_ids = jnp.where(real, table_row[jnp.clip(pos // ps, 0, pages - 1)], 0)
+                kpos = jnp.arange(pages * ps)
+                visible = (kpos[None, :] <= pos[:, None]) & (kpos[None, :] < offset + count)
+                chunk_at = (page_ids, jnp.where(real, pos % ps, 0), visible, real)
+                h_c = self._embed(chunk_ids)
+        # the rows an expert layer sees, the decode rows first; each group's assignments
+        # are counted in its own row of the counters
+        book = [DECODE_COUNTS] * decodes + [CHUNK_COUNTS] * chunks
+        valid, groups = (active, None) if decodes else (real, None)
+        if both:
+            valid = jnp.concatenate([active, real])
+            groups = jnp.stack([jnp.arange(valid.shape[0]) < b, jnp.arange(valid.shape[0]) >= b])
+        at = {"M": 0, "E": 0, "*": 0}
+        for kind, p in zip(cfg.hybrid_override_pattern, self.layers):
+            l = at[kind]
+            at[kind] += 1
+            if kind == "E":
+                # what the two groups share is booked where the decode step's is
+                with phase(TICK_DECODE_SCOPE):
+                    h = jnp.concatenate([h_d, h_c]) if both else h_d if decodes else h_c
+                    out, load = self._experts(p, self._norm(h, p["norm"]), valid, groups)
+                    if decodes:
+                        h_d = h_d + (out[:b] if both else out)
+                    if chunks:
+                        h_c = h_c + (out[b:] if both else out)
+                    counts = counts.at[jnp.asarray(book), l].add(load.reshape(len(book), -1))
+                continue
+            if decodes:
+                with phase(TICK_DECODE_SCOPE):
+                    out, pools = self._decode_operator(p, kind, l, h_d, cache, pools, decode_at)
+                    if chunks and kind == "*":
+                        # the chunk's rows land in the page pools AFTER the decode kernel has READ them: an order
+                        # the compiler is told, or it keeps the pools apart by copying them. (An ``M`` layer's chunk
+                        # reads the state its decode update WROTE: the data orders them, and a barrier there made the
+                        # compiled loop move the convolution columns' pool out of VMEM and back, every layer.)
+                        out, pools = jax.lax.optimization_barrier((out, pools))
+                    h_d = h_d + out
+            if chunks:
+                with phase(TICK_CHUNK_SCOPE):
+                    out, pools = self._chunk_operator(p, kind, l, h_c, pools, lane, chunk_at)
+                    h_c = h_c + out
+        kp, vp, ssm_state, conv_state = pools
+        cache = cache.replace(kp=kp, vp=vp, ssm_state=ssm_state, conv_state=conv_state, expert_counts=counts)
+        if decodes:
+            with phase(TICK_DECODE_SCOPE):
+                cache = cache.replace(length=cache.length + active.astype(jnp.int32))
+        if chunks:
+            with phase(TICK_CHUNK_SCOPE):
+                last = jax.lax.dynamic_index_in_dim(h_c, jnp.maximum(count - 1, 0), axis=0, keepdims=False)
+                cache = cache.replace(last_hidden=cache.last_hidden.at[slot].set(last.astype(cache.last_hidden.dtype)))
+        return h_d, cache
 
     def prefill_chunk_paged(self, ids: jax.Array, offset: jax.Array, count: jax.Array, reset: jax.Array,
                             slot: jax.Array, table_row: jax.Array, cache: NemotronHCache) -> NemotronHCache:
@@ -296,51 +457,7 @@ class NemotronHForCausalLM(nn.Module):
         ids (cap,) with the rows past ``count`` padding. ``reset`` starts the
         recurrent state and the convolution tail from zero (a slot's first
         chunk); otherwise they are carried from the chunk before."""
-        cfg = self.config
-        cap, ps, tail_rows = ids.shape[0], cache.page_size, cfg.conv_kernel - 1
-        j = jnp.arange(cap)
-        real = j < count
-        pos = offset + j
-        # rows to pages: padding rows land on the trash page with a zero payload
-        page_ids = jnp.where(real, table_row[jnp.clip(pos // ps, 0, cache.pages_per_slot - 1)], 0)
-        offs = jnp.where(real, pos % ps, 0)
-        kpos = jnp.arange(cache.pages_per_slot * ps)
-        visible = (kpos[None, :] <= pos[:, None]) & (kpos[None, :] < offset + count)
-        kp, vp, ssm_state, conv_state, counts = cache.kp, cache.vp, cache.ssm_state, cache.conv_state, cache.expert_counts
-        at = {"M": 0, "E": 0, "*": 0}
-        h = self._embed(ids)
-        for kind, p in zip(cfg.hybrid_override_pattern, self.layers):
-            l = at[kind]
-            at[kind] += 1
-            x = self._norm(h, p["norm"])
-            if kind == "M":
-                with jax.named_scope("ssm"):
-                    z, xbc, dt_raw = self._mixer_in(p, x)
-                    tail = jnp.where(reset, 0, conv_state[l, slot]).reshape(tail_rows, cfg.conv_dim)
-                    window = jnp.concatenate([tail.astype(xbc.dtype), xbc])
-                    xs, b, c, dt, a = self._mixer_split(p, self._conv(p, window, cap), dt_raw)
-                    before = jnp.where(reset, 0.0, ssm_state[l, slot])
-                    y, after = ssm.ssd_chunk_scan(xs, jnp.where(real[:, None], dt, 0.0), a, b, c, before, cfg.chunk_size)
-                    ssm_state = ssm_state.at[l, slot].set(after)
-                    conv_state = conv_state.at[l, slot].set(
-                        jax.lax.dynamic_slice_in_dim(window, count, tail_rows, axis=0)
-                        .astype(conv_state.dtype).reshape(-1))
-                    h = h + self._mixer_out(p, y, xs, z)
-            elif kind == "E":
-                out, load = self._experts(p, x, real)
-                counts = counts.at[CHUNK_COUNTS, l].add(load)
-                h = h + out
-            else:
-                with jax.named_scope("attention"):
-                    q, k, v = self._qkv(p, x)
-                    kp = kp.at[l, page_ids, offs].set(jnp.where(real[:, None], k, 0).astype(kp.dtype))
-                    vp = vp.at[l, page_ids, offs].set(jnp.where(real[:, None], v, 0).astype(vp.dtype))
-                    # the slot's pages, the chunk's own rows among them, in position order
-                    h = h + self._attend(p, q, kp[l, table_row].reshape(-1, k.shape[-1]),
-                                         vp[l, table_row].reshape(-1, v.shape[-1]), visible)
-        last = jax.lax.dynamic_index_in_dim(h, jnp.maximum(count - 1, 0), axis=0, keepdims=False)
-        return cache.replace(kp=kp, vp=vp, ssm_state=ssm_state, conv_state=conv_state, expert_counts=counts,
-                             last_hidden=cache.last_hidden.at[slot].set(last.astype(cache.last_hidden.dtype)))
+        return self._rows_paged(cache, lane=(ids, offset, count, reset, slot, table_row))[1]
 
     def decode_rows_paged(self, ids: jax.Array, cache: NemotronHCache) -> Tuple[jax.Array, NemotronHCache]:
         """(d) one token for every decoding slot: ids (B, 1) -> the residual
@@ -348,51 +465,16 @@ class NemotronHForCausalLM(nn.Module):
         ``active`` (free, or in the middle of its prefill) computes a discarded
         row: its key and value go to the trash page, it is routed to no expert,
         and its length, recurrent state and convolution tail stay as they are."""
-        cfg = self.config
-        b, ps, tail_rows = ids.shape[0], cache.page_size, cfg.conv_kernel - 1
-        active = cache.active
-        pos = jnp.where(active, cache.length, 0)
-        page_ids = jnp.where(active, cache.page_table[jnp.arange(b), jnp.clip(pos // ps, 0, cache.pages_per_slot - 1)], 0)
-        offs = jnp.where(active, pos % ps, 0)
-        visible = jnp.where(active, pos + 1, 0)
-        step = (ssm.ssm_decode_update if ssm.ssm_kernel_supported(
-            cfg.mamba_num_heads, cfg.n_groups, cfg.mamba_head_dim, cfg.ssm_state_size) else ssm.ssm_decode_update_xla)
-        attend = (paged.fused_paged_decode_attention_gqa
-                  if paged.paged_gqa_decode_supported(ps, cfg.head_dim, cfg.num_key_value_heads)
-                  else paged.paged_gqa_reference_attention)
-        kp, vp, ssm_state, conv_state, counts = cache.kp, cache.vp, cache.ssm_state, cache.conv_state, cache.expert_counts
-        at = {"M": 0, "E": 0, "*": 0}
-        h = self._embed(ids[:, 0])
-        for kind, p in zip(cfg.hybrid_override_pattern, self.layers):
-            l = at[kind]
-            at[kind] += 1
-            x = self._norm(h, p["norm"])
-            if kind == "M":
-                with jax.named_scope("ssm"):
-                    z, xbc, dt_raw = self._mixer_in(p, x)
-                    tails = conv_state[l].reshape(b, tail_rows, cfg.conv_dim)
-                    window = jnp.concatenate([tails.astype(xbc.dtype), xbc[:, None]], axis=1)  # (B, conv_kernel, C)
-                    conv_state = conv_state.at[l].set(jnp.where(
-                        active[:, None], window[:, 1:].astype(conv_state.dtype).reshape(b, -1), conv_state[l]))
-                    conv = jnp.sum(window.astype(jnp.float32) * p["conv_weight"].astype(jnp.float32), axis=1)
-                    conv = jax.nn.silu(conv + p["conv_bias"].astype(jnp.float32))
-                    xs, bm, cm, dt, a = self._mixer_split(p, conv, dt_raw)
-                    ssm_state, y = step(ssm_state, l, xs, dt, a, bm, cm, active)
-                    h = h + self._mixer_out(p, y, xs, z)
-            elif kind == "E":
-                out, load = self._experts(p, x, active)
-                counts = counts.at[DECODE_COUNTS, l].add(load)
-                h = h + out
-            else:
-                with jax.named_scope("attention"):
-                    q, k, v = self._qkv(p, x)
-                    kp = kp.at[l, page_ids, offs].set(k.astype(kp.dtype))
-                    vp = vp.at[l, page_ids, offs].set(v.astype(vp.dtype))
-                    o = attend(q, kp, vp, cache.page_table, visible, l)
-                    h = h + self._mm(o.reshape(b, -1), p["o_proj"])
-        cache = cache.replace(kp=kp, vp=vp, ssm_state=ssm_state, conv_state=conv_state, expert_counts=counts,
-                              length=cache.length + active.astype(jnp.int32))
-        return h, cache
+        return self._rows_paged(cache, ids=ids)
+
+    def decode_rows_with_chunk_paged(self, ids: jax.Array, cache: NemotronHCache, chunk_ids: jax.Array,
+                                     offset: jax.Array, count: jax.Array, reset: jax.Array, slot: jax.Array,
+                                     table_row: jax.Array, live=True) -> Tuple[jax.Array, NemotronHCache]:
+        """(h) the decode step with a chunk riding it: what ``prefill_chunk_paged``
+        of the chunk then ``decode_rows_paged`` return, every ``E`` layer run once
+        over the rows of both. Where ``live`` (traced) is False no slot decodes in
+        this pass: the chunk alone is served."""
+        return self._rows_paged(cache, ids=ids, lane=(chunk_ids, offset, count, reset, slot, table_row), live=live)
 
     def decode_step_paged(self, ids: jax.Array, cache: NemotronHCache) -> Tuple[jax.Array, NemotronHCache]:
         """ids (B, 1) -> logits (B, 1, vocab): the head of ``decode_rows_paged``'s rows."""

@@ -101,6 +101,19 @@ A model MAY also say that its chunk rows ride its decode pass:
     phases are named. A statement by the model, not a rule over shapes: a
     finish costs its request one tick, which only a second weight stream
     saved pays for.
+    Two models state it, each from its own configuration (it has expert
+    layers): LFM2 and Nemotron-H. What runs ONCE over the rows of both groups
+    is the layer whose weights a call reads once: LFM2's feed-forward,
+    Nemotron-H's ``E`` layer (its held share, its shared expert). Every other
+    operator runs for the two groups APART, because they share nothing: a
+    convolution's decode update and its chunk's, a recurrence's
+    ``ssm_decode_update`` over every slot and its ``ssd_chunk_scan`` over one
+    slot's state, the paged decode attention and a chunk's attention over its
+    slot's pages; their projections are read at 128 and 256 rows and are not
+    bound by the weight read. The lanes' loop itself asks nothing of the
+    layers: it is ONE function, ``Lfm2MoeForCausalLM.serving_ride_phase``,
+    which Nemotron-H takes by reference and which calls the class's own
+    ``decode_rows_with_chunk_paged``.
 
 ``serving_traits()`` says, in plain data, what else differs: which prompts
 take the split admission, what the descriptor's lanes carry, and which of four

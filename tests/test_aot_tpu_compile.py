@@ -11,6 +11,7 @@ kernel functions are compiled directly: their ``*_supported`` gates ask
 not a chip run; ``chip_smoke.py`` is.
 """
 
+import collections
 import os
 
 import jax
@@ -278,6 +279,25 @@ def test_falcon_tick_is_handed_in_proj_as_both_branches_read_it(falcon_tick_text
         assert "ssm_decode_update" in falcon_tick_text and "fused_paged_decode_attention_gqa" in falcon_tick_text
 
 
+def _by_computation(lines):
+    """(the computation a line of a compiled module's text lies in, the line)."""
+    at = None
+    for line in lines:
+        if line.endswith("{") and " (" in line and not line.startswith(" "):
+            at = line.split(" (")[0]
+        yield at, line
+
+
+def _kernels_by_computation(lines, kernels):
+    """{computation: {kernel: its custom calls there}}, over the computations that hold any."""
+    held = {}
+    for at, line in _by_computation(lines):
+        for kernel in kernels:
+            if "custom-call(" in line and line.lstrip().startswith(f"%{kernel}."):
+                held.setdefault(at, dict.fromkeys(kernels, 0))[kernel] += 1
+    return held
+
+
 @pytest.fixture(scope="module")
 def lfm2_tick(v5e):
     """``serve-lfm2-moe-assist``'s WHOLE tick at the published widths of
@@ -302,13 +322,7 @@ def test_lfm2_tick_reads_each_expert_layer_once_whatever_it_carries(lfm2_tick, h
     text, memory = lfm2_tick
     lines = text.splitlines()
     if holds == "one stream of the experts a branch":
-        held, at = {}, None
-        for line in lines:
-            if line.endswith("{") and " (" in line and not line.startswith(" "):
-                at = line.split(" (")[0]
-            for kernel in ("grouped_gated_matmul", "grouped_matmul"):
-                if "custom-call(" in line and line.lstrip().startswith(f"%{kernel}."):
-                    held.setdefault(at, {"grouped_gated_matmul": 0, "grouped_matmul": 0})[kernel] += 1
+        held = _kernels_by_computation(lines, ("grouped_gated_matmul", "grouped_matmul"))
         # the loop over the carried lanes, the decode step riding the first; the decode step alone
         assert len(held) == 2 and all(set(n.values()) == {LFM2_EXPERT_LAYERS} for n in held.values()), held
     elif holds == "no copy of the pools or the stacks":
@@ -352,3 +366,34 @@ def test_nemotron_tick_streams_the_held_experts_through_the_grouped_kernels(nemo
     else:
         # 11.35 GB of arguments (weights 9.44 as laid out, state 1.61, pages 0.27) + 0.15 GB of temporaries
         assert memory.argument_size_in_bytes < 11.4e9 and memory.temp_size_in_bytes < 0.3e9, memory
+
+
+# the float32 recurrent state (6 ``M`` layers x 128 slots x 64 heads x 64 x 128: 1.61 GB), the two page pools (2 ``*`` layers x
+# 2,049 pages of 64 x 2 heads of 128) and the convolution columns' pool (28 MB)
+NEMOTRON_POOLS, NEMOTRON_CONV = ("f32[6,128,64,64,128]", "bf16[2,2049,64,256]"), "bf16[6,128,18432]"
+NEMOTRON_EXPERT_LAYERS = 6
+
+
+@pytest.mark.parametrize("holds", ["one stream of the experts a branch", "no copy of the pools"])
+def test_nemotron_tick_reads_each_held_expert_layer_once_whatever_it_carries(nemotron_tick, holds):
+    """ISSUE 50: in the plain order a tick that carried a chunk lane and decoded streamed all 64 held experts of six
+    layers for the chunk's rows and 45 of them again for the decode rows. The model states that its chunk rows ride its
+    decode pass (``serving_api.py`` (h)): every computation of the compiled tick that holds grouped products holds ONE
+    pair an ``E`` layer, and the new branch keeps no pool apart by copying it (1.61 GB of state: 4 ms a lane tick)."""
+    text, _ = nemotron_tick
+    lines = text.splitlines()
+    if holds == "one stream of the experts a branch":
+        held = _kernels_by_computation(lines, ("grouped_relu2_matmul", "grouped_matmul"))
+        # the loop over the carried lanes, the decode step riding the first; the decode step alone
+        assert len(held) == 2 and all(set(n.values()) == {NEMOTRON_EXPERT_LAYERS} for n in held.values()), held
+        updates = _kernels_by_computation(lines, ("ssm_decode_update",))
+        assert set(updates) == set(held) and all(n == {"ssm_decode_update": 6} for n in updates.values()), updates
+    else:
+        copies = [line.strip()[:160] for line in lines if " copy(" in line and any(f"= {pool}" in line for pool in NEMOTRON_POOLS)]
+        assert not copies, copies
+        assert all(pool in text for pool in NEMOTRON_POOLS)
+        # the columns' pool is re-laid around the decode rows' update (slots to the minor dimension and back: PERF.md 7.18 c),
+        # as in the parent's decode step: TWO of these a tick, into that layout in whichever branch of the first ``cond``
+        # the tick takes (the lanes' loop | nothing), and back at the tick's exit
+        relaid = collections.Counter(at for at, line in _by_computation(lines) if " copy(" in line and f"= {NEMOTRON_CONV}" in line)
+        assert sum(relaid.values()) <= 3 and set(relaid.values()) == {1}, relaid

@@ -108,11 +108,10 @@ def test_the_ticks_record_and_spans_carry_the_counters(served):
     assert all(tuple(e["args"]) == TickRecord._fields for e in syncs)
 
 
-def test_the_riding_lanes_counter_is_the_lane_ticks_that_decoded(served):
-    """ISSUE 46: the tick's record says how many of its chunk lanes rode the decode step (the
-    first, in every tick that carried one and decoded), the snapshot their total; a slot whose
-    finish lane rode a tick is not among that tick's decoding slots."""
-    engine, recorder, handles, prompts, _ = served
+def riding_lanes_book(served):
+    """What holds of a served run of ANY model that states ``serving_api.py`` (h); ``served`` = (engine,
+    recorder, handles, prompts, ...). Nemotron-H's serving tests call it too."""
+    engine, recorder, handles, prompts = served[:4]
     assert engine._traits.chunk_rides_decode
     events = [e for e in recorder.chrome_trace()["traceEvents"] if e.get("ph") == "X"]
     ticks = [e["args"] for e in events if e["name"].endswith(".tick") and "chunk_lanes" in e["args"]]
@@ -127,6 +126,13 @@ def test_the_riding_lanes_counter_is_the_lane_ticks_that_decoded(served):
     syncs = {e["args"]["tick"]: e["args"] for e in events if e["name"].endswith(".sample_sync")}
     assert all(syncs[t["tick"]]["riding_chunk_lanes"] == t["riding_chunk_lanes"] for t in ticks if t["tick"] in syncs)
     assert all(h.first_token_at > h.admitted_at for h in handles)
+
+
+def test_the_riding_lanes_counter_is_the_lane_ticks_that_decoded(served):
+    """ISSUE 46: the tick's record says how many of its chunk lanes rode the decode step (the
+    first, in every tick that carried one and decoded), the snapshot their total; a slot whose
+    finish lane rode a tick is not among that tick's decoding slots."""
+    riding_lanes_book(served)
 
 
 def test_a_model_that_states_nothing_has_no_riding_lane():

@@ -1,7 +1,7 @@
 """Nemotron-H on the CPU at a toy size: the program's model against the plain reference,
 prefill in chunks then decode through the paged cache against the reference's full forward
 pass, the two halves of the experts plus the shared expert counted once against the uncut
-layer, the ungated kernels (interpreted) against the reference's expert layer at a width
+layer, a chunk riding the decode step against the chunk then the step, the ungated kernels (interpreted) against the reference's expert layer at a width
 that is no multiple of 128, and four wrong models that the comparison has to fail."""
 
 import jax
@@ -145,6 +145,64 @@ def test_a_reused_slot_serves_its_second_request_as_if_fresh(toy, tokens):
     cache, rest = _decode(model, params, cache, 2, 0, second[11:])
     got = np.stack([np.asarray(first)] + [np.asarray(r) for r in rest])
     np.testing.assert_allclose(got, full[10:], atol=TOL, rtol=0)
+
+
+# ---------------------------------------- a chunk lane riding the decode step
+# (the chunk's first position, its rows, its cap, slots that decode beside it)
+RIDING = {"a later chunk that carries the state and the tail": (8, 8, 8, 2), "a chunk shorter than its cap": (16, 5, 8, 2),
+          "a chunk of two scan chunks": (8, 13, 16, 2), "a first chunk (reset)": (0, 8, 8, 2), "no slot active": (8, 8, 8, 0)}
+LEAVES = ("kp", "vp", "ssm_state", "conv_state", "last_hidden", "length", "active", "page_table", "expert_counts")
+
+
+def _leaf(cache, name):
+    value = np.asarray(getattr(cache, name))
+    return value[:, 1:] if name in ("kp", "vp") else value  # page 0 is the trash page: what lands there is never read
+
+
+@pytest.mark.parametrize("case", sorted(RIDING))
+def test_a_chunk_riding_the_decode_step_equals_the_chunk_then_the_step(toy, tokens, case):
+    """``decode_rows_with_chunk_paged`` (serving_api.py (h)) against ``prefill_chunk_paged`` then
+    ``decode_rows_paged`` on the same cache: two slots mid-decode (or none), the third mid-prefill;
+    the rows and EVERY leaf of the cache, and with ``live`` False the chunk alone."""
+    model, params, _ = toy
+    offset, count, cap, decoding = RIDING[case]
+    ids = np.asarray(tokens)
+    cache = model.init_paged_cache(3, 16, 8, jnp.float32)
+    tables = [jnp.zeros((cache.pages_per_slot,), jnp.int32).at[:4].set(jnp.arange(1 + 4 * i, 5 + 4 * i)) for i in range(3)]
+    for slot, n in zip(range(decoding), (13, 6)):
+        cache, _ = _prefill(model, params, cache, ids[slot: slot + n], slot, tables[slot], 8)
+    late = np.asarray(jax.random.randint(jax.random.PRNGKey(17), (24,), 1, SIZES["vocab_size"]))
+    if offset:  # the chunks before this one leave a state and a tail behind
+        for done in range(0, offset, 8):
+            cache = model.apply(params, jnp.asarray(late[done: done + 8]), done, 8, done == 0, 2, tables[2], cache,
+                                method=type(model).prefill_chunk_paged)
+        assert float(jnp.abs(cache.ssm_state[:, 2]).max()) > 0.01 and float(jnp.abs(cache.conv_state[:, 2]).max()) > 0.01
+    else:  # a state and a tail that a first chunk must NOT read
+        cache = cache.replace(ssm_state=cache.ssm_state.at[:, 2].set(0.3), conv_state=cache.conv_state.at[:, 2].set(0.7))
+    rows = np.zeros((cap,), np.int32)
+    rows[:count] = late[offset: offset + count]
+    lane = (jnp.asarray(rows), offset, count, offset == 0, 2, tables[2])
+    step = jnp.asarray([[int(ids[20])], [int(ids[21])], [0]], jnp.int32)
+    apart = model.apply(params, *lane, cache, method=type(model).prefill_chunk_paged)
+    want_rows, want = model.apply(params, step, apart, method=type(model).decode_rows_paged)
+    got_rows, got = model.apply(params, step, cache, *lane, method=type(model).decode_rows_with_chunk_paged)
+    # and with the decode rows switched off (a lane past the first, a tick that only carries lanes): the chunk alone
+    _, alone = model.apply(params, step, cache, *lane, jnp.asarray(False), method=type(model).decode_rows_with_chunk_paged)
+    live = np.asarray(cache.active)
+    assert live.sum() == decoding and np.abs(np.asarray(want_rows)[live]).max(initial=1.0) > 0.1
+    np.testing.assert_allclose(np.asarray(got_rows)[live], np.asarray(want_rows)[live], atol=TOL, rtol=0)
+    for name in LEAVES:
+        exact = np.asarray(getattr(cache, name)).dtype.kind in "bi"
+        for merged, plain in ((got, want), (alone, apart)):
+            np.testing.assert_allclose(_leaf(merged, name), _leaf(plain, name), atol=0 if exact else TOL, rtol=0, err_msg=name)
+    # both rows of the counters moved by their own group's assignments alone
+    moved = np.asarray(got.expert_counts - cache.expert_counts).sum(axis=-1)
+    top_k = SIZES["num_experts_per_tok"]
+    assert (moved[0] == decoding * top_k).all() and (moved[1] == count * top_k).all()
+    assert float(jnp.abs(got.last_hidden[2] - cache.last_hidden[2]).max()) > 0.01
+    # the chunk moved its own slot's state and the decode step the decoding slots', nobody else's
+    changed = np.abs(np.asarray(got.ssm_state - cache.ssm_state)).max(axis=(0, 2, 3, 4)) > 0
+    assert changed.tolist() == [decoding > 0, decoding > 1, True]
 
 
 # ------------------------------------------------------------------ the expert layer

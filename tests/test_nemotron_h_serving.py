@@ -1,9 +1,10 @@
 """Nemotron-H through ``ServingEngine`` on the CPU at a toy size: the engine's greedy tokens
 against the plain reference with requests joining mid run, one compilation of the tick in
-the plain phase order, the counters' book knowing which experts are held, the model's
+the ride order (``serving_api.py`` (h)) and its counter of riding lanes, the counters' book knowing which experts are held, the model's
 scopes in the lowered tick, the options it does not carry refused, and the benchmark's new
 cell under ``--rehearse``."""
 
+import dataclasses
 import json
 import os
 import subprocess
@@ -13,11 +14,13 @@ import numpy as np
 import pytest
 
 from benchmark.families.nemotron_h import reference
+from perceiver_io_tpu.models.core.lfm2_moe import Lfm2MoeForCausalLM
 from perceiver_io_tpu.obs.core import TelemetryRecorder
 from perceiver_io_tpu.serving import ServingEngine
 from perceiver_io_tpu.serving.engine import TICK_SCOPES
 from perceiver_io_tpu.serving.metrics import EngineMetrics
 from tests.nemotron_h_toy import SIZES, build
+from tests.test_lfm2_moe_serving import riding_lanes_book
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ENGINE = dict(num_slots=3, kv_page_size=8, prefill_chunk_tokens=8, num_kv_pages=40)
@@ -60,15 +63,34 @@ def test_greedy_tokens_are_the_references_argmax(toy, served, request_no):
     assert np.array_equal(logits.argmax(axis=-1), tokens)
 
 
-def test_one_tick_program_in_the_plain_order(served):
+def test_one_tick_program_in_the_ride_order(served):
     engine = served[0]
     traits = engine._traits
     assert engine.decode_compilations == 1 and engine.prefill_compilations == 0
-    # chunk lanes, finish lanes, the decode step: (h) is not stated; (g) names the ``M`` layers' in_proj alone
-    assert not traits.chunk_rides_decode
+    # (h) is stated, from the configuration: the head and the sampler, the rows (the decode step riding the first
+    # carried chunk lane), the finish lanes; (g) names the ``M`` layers' in_proj alone
+    assert traits.chunk_rides_decode
     assert traits.row_major_leaves == tuple(f"params/layers_{i}_in_proj" for i, k in enumerate(PATTERN) if k == "M")
     assert traits.expert_counters == (EXPERT_LAYERS, ROUTED) and traits.experts_held == (0, HELD)
-    assert engine.metrics.snapshot()["ragged_tick"]["riding_chunk_lanes"] == 0
+    assert engine.metrics.snapshot()["ragged_tick"]["riding_chunk_lanes"] > 0
+    # the lanes' loop is the expert model's, taken by reference: one function, no second copy
+    assert type(engine.model).serving_ride_phase is Lfm2MoeForCausalLM.serving_ride_phase
+    assert not hasattr(type(engine.model), "serving_chunk_phase")
+
+
+def test_a_stack_without_expert_layers_states_nothing(toy):
+    """The statement follows from the configuration: with no ``E`` layer no call reads its weights once for
+    few rows, and the tick keeps the plain order."""
+    model = toy[0]
+    plain = type(model)(config=dataclasses.replace(model.config, hybrid_override_pattern="MM*M", num_hidden_layers=4))
+    traits = plain.serving_traits()
+    assert not traits.chunk_rides_decode and traits.expert_counters is None and traits.experts_held is None
+
+
+def test_the_riding_lanes_counter_is_the_lane_ticks_that_decoded(served):
+    """LFM2's twin: every lane tick that decoded rode exactly one lane, the snapshot counts them,
+    and no tick harvested a slot it finished."""
+    riding_lanes_book(served)
 
 
 def test_the_snapshot_books_the_held_experts_and_the_state(served):
